@@ -1,0 +1,630 @@
+"""Chip smoke: the train step and the generation server, once, on the TPU.
+
+    python chip_smoke.py
+
+Drives the two hot paths through the entry points users call, at the
+full width of the headline 953M Llama-shaped decoder (``bench.py``'s
+non-smoke config; only step and request counts are small, weights are
+random from a seed), in ONE process that holds the chip throughout:
+
+1. train — ``fleet.build_train_step`` -> ``init_state`` -> ``shard_batch``
+   -> a few AdamW steps on one repeated batch (finite loss/grad-norm,
+   loss falls);
+2. parity — logits of a training forward, a prefill and an engine-style
+   vmapped cached decode step, compiled kernels vs the same code under
+   ``ops.pallas.force_interpret()``;
+3. serve — the trained weights behind ``io.InferenceServer`` on a
+   loopback port, a contiguous-cache and a paged+prefix-cache generator,
+   concurrent ``InferenceClient.generate`` streams from threads;
+4. on a host with >= 4 chips, additionally ZeRO-3 x4 training and
+   ``mesh_tp=4`` serving, with every Pallas unit asserted on the kernel
+   arm of its per-shard (shard_map) dispatch and state spread over all
+   devices.
+
+Every program it runs is first lowered and checked for the Mosaic
+kernels it must contain (by ``pallas_call`` name), so a silent jnp
+fallback at the headline shapes fails the smoke. Exits non-zero, printing
+no result line, unless ``jax.default_backend()`` is ``"tpu"`` and every
+phase passed; otherwise the LAST stdout line is
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": N}}``.
+
+The phase functions take the model config and sizes as arguments —
+``tests/test_chip_smoke.py`` runs them on the CPU with
+``LlamaConfig.tiny`` — and ``on_chip`` switches the assertions that only
+mean something on the device (kernel names, dispatch mode, platform).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import gc
+import json
+import sys
+import threading
+import time
+
+import numpy as np
+
+# Pallas kernels (``pallas_call(name=...)``) each lowered program must
+# contain at the headline shapes. The paged prefill attends its chunk
+# against the gathered page context through the einsum path (the flash
+# kernel has no cache-offset form), so it carries no flash kernel; a
+# decode step is T=1, below the norm/rope kernels' row blocks.
+TRAIN_KERNELS = (
+    "ptpu_flash_fwd", "ptpu_flash_bwd_dq", "ptpu_flash_bwd_dkv",
+    "ptpu_rms_norm_fwd", "ptpu_rms_norm_bwd", "ptpu_rope",
+    "ptpu_linear_xent_fwd", "ptpu_linear_xent_dh", "ptpu_linear_xent_dw")
+PREFILL_KERNELS = {
+    "contiguous": ("ptpu_flash_fwd", "ptpu_rms_norm_fwd", "ptpu_rope"),
+    "paged": ("ptpu_rms_norm_fwd", "ptpu_rope"),
+}
+DECODE_KERNELS = ("ptpu_decode_attn",)
+# per-shard (shard_map) units the four-chip programs must take on the
+# kernel arm (``ops.pallas.partition_stats()`` keys ``<unit>:kernel``)
+TRAIN_UNITS = ("flash_fwd", "flash_bwd", "rms_fwd", "rms_bwd", "rope",
+               "flce_fwd", "flce_dh", "flce_dw")
+SERVE_UNITS = ("flash_fwd", "rms_fwd", "rope", "decode_attn")
+
+# Logit agreement between two evaluations of the same mathematics
+# (compiled vs interpreted kernels; chunked prefill or a cached decode
+# step vs the one-shot prefill), as the rms difference over the rms
+# logit and the largest difference over the largest logit. Both sides
+# feed the MXU the same bf16 operands and
+# accumulate in f32; they differ in accumulation order, in the exp/rsqrt
+# implementations and in what XLA fuses around the kernels, hence in
+# where each activation lands when it rounds to bf16 (eps = 2^-8 =
+# 3.9e-3 per flipped rounding, compounding over 16 layers into
+# near-uniform random-weight logits). Measured on the v5e at these
+# widths: this bf16 pipeline sits rms 2.0e-2 from an f32
+# precision-"highest" jnp reference, compiled and interpreted kernels
+# 0.9-1.9e-2 apart, chunked and one-shot prefill 2.3-2.9e-2 apart, the
+# vmapped decode step 2.0e-2 from the one-shot prefill. The bounds leave
+# ~2x on that floor; an 8-bit intermediate (eps >= 6e-2 per rounding)
+# or a dropped term lands far outside them.
+LOGIT_RMS_RTOL = 5e-2
+LOGIT_MAX_RTOL = 8e-2
+
+
+class SmokeFailure(AssertionError):
+    """A smoke check did not hold."""
+
+
+def check(cond, message: str) -> None:
+    if not cond:
+        raise SmokeFailure(message)
+
+
+def log(message: str) -> None:
+    print(f"[chip_smoke {time.strftime('%H:%M:%S')}] {message}", flush=True)
+
+
+def headline_config():
+    """The 953M Llama-shaped decoder of ``bench.py``'s non-smoke branch,
+    every width as there."""
+    from paddle_tpu.models import LlamaConfig
+
+    return LlamaConfig(
+        vocab_size=32000, hidden_size=2048, intermediate_size=5632,
+        num_layers=16, num_heads=16, num_kv_heads=16, max_seq_len=2048,
+        dtype="bfloat16", remat=True, remat_policy="save_mlp_dots_attn",
+        lm_head_mode="fused")
+
+
+@dataclasses.dataclass(frozen=True)
+class Request:
+    prompt: np.ndarray
+    new_tokens: int
+    sampling: dict          # generate() kwargs; empty = greedy
+
+
+def make_requests(vocab: int, prompt_lens, new_tokens, shared_prefix: int,
+                  seed: int = 0) -> list[Request]:
+    """One request per prompt length. The LAST TWO prompts share their
+    first ``shared_prefix`` tokens (the prefix-cache case); request 1 is
+    sampled, the rest greedy; request 0 is the greedy probe repeated
+    after the others."""
+    rs = np.random.RandomState(seed)
+    prompts = [rs.randint(1, vocab, n).astype(np.int32)
+               for n in prompt_lens]
+    prompts[-1][:shared_prefix] = prompts[-2][:shared_prefix]
+    sampled = dict(temperature=0.8, top_k=40, top_p=0.95, seed=7)
+    return [Request(p, n, sampled if i == 1 else {})
+            for i, (p, n) in enumerate(zip(prompts, new_tokens))]
+
+
+def headline_requests(vocab: int) -> list[Request]:
+    """Prompts over 128-1024 tokens (prefill buckets 128/256/512/1024),
+    32-64 new tokens, two 384-token prompts sharing a 256-token prefix."""
+    return make_requests(vocab, (128, 200, 1024, 640, 384, 384),
+                         (64, 32, 40, 32, 48, 48), shared_prefix=256)
+
+
+class CompileMeter:
+    """Where set-up time went, from jax's own monitoring events: seconds
+    the backend spent compiling, seconds spent fetching and loading
+    executables the persistent cache already held, the cache's hit/miss
+    counts, and the jitted functions that cost the most (on a warm run:
+    the ones the cache did not serve)."""
+
+    def __init__(self):
+        import jax
+
+        self.backend_seconds = 0.0      # compile or cache load, per program
+        self.retrieval_seconds = 0.0    # the cache-load part of it
+        self.by_function: dict = {}     # jitted function name -> seconds
+        self.programs = 0
+        self.cache_hits = 0
+        self.cache_misses = 0
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event: str, seconds: float, fun_name: str = "?",
+                  **_) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.backend_seconds += seconds
+            self.programs += 1
+            self.by_function[fun_name] = (
+                self.by_function.get(fun_name, 0.0) + seconds)
+        elif event == "/jax/compilation_cache/cache_retrieval_time_sec":
+            self.retrieval_seconds += seconds
+
+    def _event(self, event: str, **_) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            self.cache_hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.cache_misses += 1
+
+    def report(self) -> dict:
+        return {"compile_seconds": round(self.backend_seconds
+                                         - self.retrieval_seconds, 1),
+                "cache_load_seconds": round(self.retrieval_seconds, 1),
+                "programs": self.programs, "cache_hits": self.cache_hits,
+                "cache_misses": self.cache_misses,
+                "slowest": {name: round(secs, 1) for name, secs in sorted(
+                    self.by_function.items(), key=lambda kv: -kv[1])[:5]}}
+
+
+def _missing(text: str, names) -> list[str]:
+    return [n for n in names if n not in text]
+
+
+def _check_dispatch(chips: int) -> None:
+    """The kernel set must be about to compile for the device, not
+    interpret or stay on jnp — asserted where the steps are built."""
+    from paddle_tpu.ops import pallas as pk
+
+    check(pk._support.interpret() is False,
+          "Pallas kernels would run interpreted on this backend")
+    want = "raw" if chips == 1 else "partitioned"
+    check(pk.dispatch_mode() == want,
+          f"dispatch_mode() is {pk.dispatch_mode()!r}, expected {want!r} "
+          f"on {chips} chip(s)")
+
+
+def _check_spread(what: str, devices, tree=None) -> list[int]:
+    """State really lives on every device: each large leaf has a shard
+    on all of them, and no device's live bytes are out of line with
+    device 0's (same order: within 2x either way)."""
+    import jax
+
+    if tree is not None:
+        for leaf in jax.tree_util.tree_leaves(tree):
+            if leaf.size >= 1 << 20:
+                check(len(leaf.sharding.device_set) == len(devices)
+                      and not leaf.sharding.is_fully_replicated,
+                      f"{what}: a {leaf.shape} leaf is not sharded over "
+                      f"all {len(devices)} devices ({leaf.sharding})")
+    used = [int(d.memory_stats()["bytes_in_use"]) for d in devices]
+    check(all(used[0] / 2 <= u <= used[0] * 2 for u in used),
+          f"{what}: bytes_in_use per device {used} — not spread")
+    return used
+
+
+# ---------------------------------------------------------------------------
+# phase 1: train
+# ---------------------------------------------------------------------------
+
+def train_phase(cfg, *, batch: int, seq: int, steps: int, chips: int = 1,
+                on_chip: bool = True):
+    """``steps`` AdamW steps of ``bench.py``'s train-step wiring on one
+    repeated batch over ``chips`` devices (ZeRO-3 when > 1). Returns
+    ``(trained model, report)``; the optimizer state is dropped."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu
+    import paddle_tpu.distributed as dist
+    from paddle_tpu import optimizer as optim
+    from paddle_tpu.models import LlamaForCausalLM
+    from paddle_tpu.ops import pallas as pk
+    from paddle_tpu.optimizer import lr as lr_mod
+    from paddle_tpu.parallel import mesh as M
+
+    paddle_tpu.seed(0)
+    model = LlamaForCausalLM(cfg)
+    strategy = dist.DistributedStrategy()
+    if chips > 1:
+        strategy.sharding.enable = True
+        strategy.sharding.stage = 3
+        strategy.sharding.degree = chips
+    devices = jax.devices()[:chips]
+    mesh = M.mesh_from_strategy(strategy, devices)
+    report = {"chips": chips, "params_m": round(cfg.num_params() / 1e6, 1),
+              "batch": batch, "seq": seq}
+    with M.MeshContext(mesh):
+        step = dist.fleet.build_train_step(
+            model,
+            optimizer=optim.AdamW(
+                lr_mod.warmup_cosine(3e-4, 100, 10000),
+                grad_clip=optim.ClipGradByGlobalNorm(1.0)),
+            strategy=strategy, mesh=mesh)
+        state = step.init_state(model)
+        del model                    # the state owns the weights now
+        ids = np.random.RandomState(0).randint(
+            0, cfg.vocab_size, (batch, seq)).astype(np.int32)
+        data = step.shard_batch({"input_ids": jnp.asarray(ids),
+                                 "labels": jnp.asarray(ids)})
+        if on_chip:
+            _check_dispatch(chips)
+            pk.reset_partition_stats()
+            absent = _missing(
+                step.lower(state, data, jax.random.PRNGKey(0)).as_text(),
+                TRAIN_KERNELS)
+            check(not absent, f"train step lowered without {absent}")
+            report["kernels"] = list(TRAIN_KERNELS)
+
+        losses, norms = [], []
+        for i in range(steps):
+            state, metrics = step(state, data, jax.random.PRNGKey(i))
+            losses.append(float(metrics["loss"]))
+            norms.append(float(metrics["grad_norm"]))
+            log(f"train chips={chips} step {i}: loss={losses[-1]:.4f} "
+                f"grad_norm={norms[-1]:.4f}")
+        if on_chip and chips > 1:
+            report["bytes_in_use"] = _check_spread(
+                "ZeRO-3 train state", devices, state.model)
+    report.update(losses=losses, grad_norms=norms)
+    check(all(np.isfinite(losses)) and all(np.isfinite(norms)),
+          f"non-finite loss/grad-norm: {losses} {norms}")
+    check(losses[-1] < losses[0],
+          f"loss did not fall on the repeated batch: {losses}")
+    if on_chip and chips > 1:
+        report["partition_stats"] = _check_units("train", TRAIN_UNITS)
+    return state.model, report
+
+
+def _check_units(what: str, units) -> dict:
+    """Every per-shard unit took its kernel arm."""
+    from paddle_tpu.ops import pallas as pk
+
+    stats = pk.partition_stats()
+    fallbacks = sorted(k for k in stats if k.endswith(":fallback"))
+    check(not fallbacks, f"{what}: units lowered to their jnp fallback: "
+                         f"{fallbacks} ({stats})")
+    absent = [u for u in units if not stats.get(f"{u}:kernel")]
+    check(not absent, f"{what}: units never partitioned: {absent} "
+                      f"({stats})")
+    return dict(sorted(stats.items()))
+
+
+# ---------------------------------------------------------------------------
+# phase 2: compiled kernels vs the interpreter
+# ---------------------------------------------------------------------------
+
+def _parity_programs(seq: int):
+    """Fresh jits of the four compared programs (the interpret flag is
+    read while tracing, so each mode traces its own)."""
+    import jax
+
+    def prefill(m, x):
+        cache = m.init_cache(1, 2 * seq)
+        return m.forward_with_cache(x, cache, index=0)
+
+    def decode(m, caches, toks, fills):
+        def one(cache, tok, fill):
+            logits, _ = m.forward_with_cache(tok[None, None], cache,
+                                             index=fill)
+            return logits[0, -1]
+        return jax.vmap(one)(caches, toks, fills)
+
+    def chunked_prefill(m, x):
+        # the prompt's tail forwarded against its already-cached head:
+        # what a prefix-cache hit and chunked prefill run
+        head = seq - seq // 8
+        cache = m.init_cache(1, 2 * seq)
+        _, cache = m.forward_with_cache(x[:, :head], cache, index=0)
+        logits, _ = m.forward_with_cache(x[:, head:], cache, index=head)
+        return logits
+
+    return (jax.jit(lambda m, x: m(x)), jax.jit(prefill), jax.jit(decode),
+            jax.jit(chunked_prefill))
+
+
+def parity_phase(model, *, seq: int, rms_rtol: float = LOGIT_RMS_RTOL,
+                 max_rtol: float = LOGIT_MAX_RTOL) -> dict:
+    """Logits of one training forward, one prefill, one chunked prefill
+    and one engine-style cached decode step (vmapped over two slots with
+    different fill positions — the batched scalar-prefetch form the
+    engine sends the decode kernel through), compiled vs traced under
+    ``force_interpret()`` (both decode variants read the SAME cache);
+    and, across paths, the chunked prefill and the decode step against
+    the one-shot prefill's logits at the same positions."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import pallas as pk
+
+    vocab = model.config.vocab_size
+    ids = jnp.asarray(np.random.RandomState(1).randint(
+        0, vocab, (1, seq)).astype(np.int32))
+    # each decode slot re-feeds a prompt token at its own position, with
+    # the cache filled up to it: the logits the one-shot prefill also
+    # produced at that position
+    fills = jnp.asarray([seq - 1, seq - 1 - seq // 4], jnp.int32)
+
+    def run(cache=None):
+        forward, prefill, decode, chunked = _parity_programs(seq)
+        full = forward(model, ids)
+        pre, own_cache = prefill(model, ids)
+        cache = own_cache if cache is None else cache
+        slots = jax.tree_util.tree_map(lambda c: jnp.stack([c, c]), cache)
+        dec = decode(model, slots, ids[0, fills], fills)
+        tail = chunked(model, ids)
+        return {"train_forward": full, "prefill": pre, "decode": dec,
+                "chunked_prefill": tail}, cache
+
+    compiled, cache = run()
+    with pk.force_interpret():
+        interpreted, _ = run(cache)
+
+    head = seq - seq // 8
+    pairs = [(f"{name}: compiled vs interpreted", got, interpreted[name])
+             for name, got in compiled.items()]
+    # and across paths: the tail prefilled against its cached head must
+    # reproduce the one-shot prefill's logits at the same positions
+    pairs.append(("chunked vs one-shot prefill",
+                  compiled["chunked_prefill"], compiled["prefill"][:, head:]))
+    pairs.append(("decode vs one-shot prefill",
+                  compiled["decode"], compiled["prefill"][0, fills]))
+    report = {}
+    for name, got, ref in pairs:
+        got = np.asarray(got, np.float32)
+        ref = np.asarray(ref, np.float32)
+        check(np.isfinite(got).all(), f"parity {name}: non-finite logits")
+        diff = got - ref
+        err = {"rms_rel": float(np.sqrt(np.mean(diff ** 2))
+                                / np.sqrt(np.mean(ref ** 2))),
+               "max_rel": float(np.abs(diff).max() / np.abs(ref).max()),
+               "argmax_agree": float(np.mean(
+                   got.argmax(-1) == ref.argmax(-1)))}
+        report[name] = err
+        log(f"parity {name}: rms {err['rms_rel']:.2e} (tolerance "
+            f"{rms_rtol:.0e}), max {err['max_rel']:.2e} (tolerance "
+            f"{max_rtol:.0e}), argmax agrees {err['argmax_agree']:.4f}")
+        check(err["rms_rel"] <= rms_rtol and err["max_rel"] <= max_rtol,
+              f"parity {name}: logits differ by rms {err['rms_rel']:.3e} "
+              f"/ max {err['max_rel']:.3e}")
+    return report
+
+
+# ---------------------------------------------------------------------------
+# phase 3: serve
+# ---------------------------------------------------------------------------
+
+def _agree(a, b) -> int:
+    """Length of the common prefix of two token lists."""
+    n = 0
+    while n < min(len(a), len(b)) and a[n] == b[n]:
+        n += 1
+    return n
+
+
+def _drive(endpoint: str, name: str, engine, requests, vocab: int):
+    """All requests as concurrent client streams (one thread and one
+    connection each), then the greedy probe again — which must return
+    the same tokens whenever it runs the same programs. Through a prefix
+    cache it does not: the repeat prefills only the tail past its cached
+    pages, a differently shaped program that may round differently in
+    bf16, so that repeat is reported and the gated one follows
+    ``clear_prefix_cache()``. Returns ``(token lists, repeat report)``."""
+    from paddle_tpu import io
+
+    results: list = [None] * len(requests)
+    errors: list = []
+
+    def stream(i: int, r: Request) -> None:
+        try:
+            with io.InferenceClient(endpoint) as client:
+                results[i] = list(client.generate(
+                    name, r.prompt, r.new_tokens, **r.sampling))
+        except Exception as e:           # surfaced below, with the index
+            errors.append(f"stream {i}: {type(e).__name__}: {e}")
+
+    threads = [threading.Thread(target=stream, args=(i, r), daemon=True)
+               for i, r in enumerate(requests)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=900)
+    check(not any(t.is_alive() for t in threads),
+          f"{name}: streams still running after 900 s")
+    check(not errors, f"{name}: {errors}")
+    for i, (r, toks) in enumerate(zip(requests, results)):
+        check(len(toks) == r.new_tokens,
+              f"{name}: stream {i} returned {len(toks)} tokens, declared "
+              f"{r.new_tokens}")
+        check(all(0 <= t < vocab for t in toks),
+              f"{name}: stream {i} returned an out-of-vocab token")
+    probe = requests[0]
+
+    def repeat() -> list:
+        with io.InferenceClient(endpoint) as client:
+            return list(client.generate(name, probe.prompt,
+                                        probe.new_tokens))
+
+    info = {}
+    if engine.stats().get("prefix_entries"):
+        info["through_prefix_cache_agrees_for"] = (
+            f"{_agree(repeat(), results[0])}/{probe.new_tokens} tokens")
+        engine.clear_prefix_cache()
+    again = repeat()
+    info["same_programs_identical"] = again == results[0]
+    check(again == results[0],
+          f"{name}: the greedy probe repeated after the others returned "
+          f"different tokens (first {_agree(again, results[0])} agree)")
+    return results, info
+
+
+def _check_generator(name: str, g: dict, *, platform: str,
+                     devices: int) -> None:
+    check(g["broken"] is None, f"{name}: engine broken: {g['broken']}")
+    check(not g["stuck"] and g["rebuilds"] == 0 and g["quarantined"] == 0,
+          f"{name}: stuck={g['stuck']} rebuilds={g['rebuilds']} "
+          f"quarantined={g['quarantined']}")
+    check(g["device"]["platform"] == platform
+          and g["device"]["devices"] == devices,
+          f"{name}: serving from {g['device']}, expected {devices} x "
+          f"{platform}")
+    check(g["active"] == 0 and g["queued"] == 0,
+          f"{name}: not drained: active={g['active']} "
+          f"queued={g['queued']}")
+    if g["paged"]:
+        check(g["pages_free"] + g["prefix_entries"] == g["pages"],
+              f"{name}: page pool leaked: free={g['pages_free']} "
+              f"prefix={g['prefix_entries']} pages={g['pages']}")
+
+
+def serve_phase(model, requests, *, slots: int, max_len: int,
+                mesh_tp: int = 0, on_chip: bool = True) -> dict:
+    """``model`` behind an ``InferenceServer`` on a loopback port with a
+    default (contiguous) and a paged+prefix-cache generator; every
+    request streamed concurrently against each. Also reports — without
+    gating on it — whether the engine's greedy probe equals solo
+    ``generate()`` (unsharded engines only)."""
+    import jax
+
+    from paddle_tpu import io
+    from paddle_tpu.core import monitor
+    from paddle_tpu.models.generation import generate
+    from paddle_tpu.ops import pallas as pk
+
+    vocab = model.config.vocab_size
+    devices = jax.devices()[:max(mesh_tp, 1)]
+    sharded = {"mesh_tp": mesh_tp} if mesh_tp else {}
+    traps_before = monitor.get_stat("gen/traps")
+    if on_chip and mesh_tp:
+        pk.reset_partition_stats()
+    elif on_chip:
+        _check_dispatch(1)
+    report: dict = {}
+    server = io.InferenceServer(port=0).start()
+    try:
+        engines = {
+            "contiguous": server.add_generator(
+                "contiguous", model, slots=slots, max_len=max_len,
+                **sharded),
+            "paged": server.add_generator(
+                "paged", model, slots=slots, max_len=max_len, paged=True,
+                prefix_cache=True, **sharded),
+        }
+        if on_chip:
+            longest = max(len(r.prompt) for r in requests)
+            for name, engine in engines.items():
+                text = engine.lowered_text(longest)
+                absent = (_missing(text["prefill"], PREFILL_KERNELS[name])
+                          + _missing(text["decode"], DECODE_KERNELS))
+                check(not absent, f"{name} engine lowered without {absent}")
+                report[name] = {"kernels": {
+                    "prefill": list(PREFILL_KERNELS[name]),
+                    "decode": list(DECODE_KERNELS)}}
+        tokens, repeats = {}, {}
+        for name in engines:
+            t0 = time.monotonic()
+            tokens[name], repeats[name] = _drive(
+                server.endpoint, name, engines[name], requests, vocab)
+            log(f"serve {name} (mesh_tp={mesh_tp}): {len(requests)} "
+                f"concurrent streams + probe in "
+                f"{time.monotonic() - t0:.1f}s wall, compiles included")
+        with io.InferenceClient(server.endpoint) as client:
+            health = client.health()
+        for name in engines:
+            g = health["generators"][name]
+            _check_generator(name, g, platform=devices[0].platform,
+                             devices=len(devices))
+            report.setdefault(name, {}).update(
+                streams=len(requests), probe_repeat=repeats[name],
+                compiles=g["compiles"],
+                device=g["device"],
+                pages=({k: g[k] for k in ("pages", "pages_free",
+                                          "prefix_entries")}
+                       if g["paged"] else None))
+        traps = monitor.get_stat("gen/traps") - traps_before
+        check(traps == 0, f"{traps} engine trap(s) during serving")
+        if on_chip and mesh_tp:
+            report["bytes_in_use"] = _check_spread(
+                f"mesh_tp={mesh_tp} engines", devices)
+            report["partition_stats"] = _check_units("serve", SERVE_UNITS)
+        report["paged_equals_contiguous"] = (
+            tokens["paged"][0] == tokens["contiguous"][0])
+        if not mesh_tp:
+            probe = requests[0]
+            solo = np.asarray(jax.jit(
+                lambda m, x: generate(m, x, probe.new_tokens))(
+                    model, probe.prompt[None]))[0, probe.prompt.size:]
+            report["engine_agrees_with_solo_generate_for"] = {
+                name: f"{_agree(solo.tolist(), toks[0])}/"
+                      f"{probe.new_tokens} tokens"
+                for name, toks in tokens.items()}
+    finally:
+        server.stop()
+    return report
+
+
+# ---------------------------------------------------------------------------
+# main
+# ---------------------------------------------------------------------------
+
+def main() -> int:
+    import jax
+
+    from paddle_tpu.core.compile_cache import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
+    backend = jax.default_backend()
+    if backend != "tpu":
+        print(f"chip_smoke: no TPU — jax.default_backend() is {backend!r} "
+              f"(devices: {jax.devices()}). The smoke measures nothing "
+              "on a CPU; run it through the chip tool.", file=sys.stderr)
+        return 2
+    dev = jax.devices()[0]
+    n_dev = len(jax.devices())
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": n_dev}
+    print(f"platform={dev.platform} device_kind={dev.device_kind} "
+          f"devices={n_dev} compile_cache={cache_dir}", flush=True)
+    meter = CompileMeter()
+    t_start = time.monotonic()
+    cfg = headline_config()
+    requests = headline_requests(cfg.vocab_size)
+    report: dict = {"device": device}
+
+    model, report["train"] = train_phase(cfg, batch=4, seq=2048, steps=8)
+    report["parity"] = parity_phase(model, seq=512)
+    report["serve"] = serve_phase(model, requests, slots=8, max_len=2048)
+    if n_dev >= 4:
+        del model
+        gc.collect()
+        model, report["train_zero3_x4"] = train_phase(
+            cfg, batch=4, seq=2048, steps=8, chips=4)
+        report["serve_mesh_tp4"] = serve_phase(
+            model, requests, slots=8, max_len=2048, mesh_tp=4)
+
+    report["compile"] = meter.report()
+    report["wall_seconds"] = round(time.monotonic() - t_start, 1)
+    print("chip_smoke report: " + json.dumps(report), flush=True)
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -8,8 +8,8 @@ multi-host data parallelism over DCN, where cutting gradient bytes
 (the ``dgc_sparsity`` metric), compares against a dense-DP run, and
 prints the per-step wire-byte estimate the sparse exchange implies.
 
-Self-bootstraps a virtual 8-device CPU mesh when fewer than 8 devices
-are present (the same recipe as tests/conftest.py), so it runs anywhere:
+On the CPU backend it self-bootstraps a virtual 8-device mesh when fewer
+than 8 devices are present (the same recipe as tests/conftest.py):
 
     python examples/dgc_dcn.py
 """
@@ -23,11 +23,18 @@ import sys
 def _ensure_devices(n: int = 8) -> bool:
     """Re-exec on a virtual n-device CPU mesh if needed. Returns True in
     the child/ready process; the parent that delegated never returns —
-    it raises SystemExit with the child's exit code."""
+    it raises SystemExit with the child's exit code. CPU backend only:
+    a process that holds an accelerator with too few chips fails (a CPU
+    child would hide that nothing ran on the device)."""
     import jax
 
     if len(jax.devices()) >= n or os.environ.get("_PTPU_DGC_CHILD") == "1":
         return True
+    if jax.default_backend() != "cpu":
+        raise SystemExit(
+            f"need {n} devices, the {jax.default_backend()} backend has "
+            f"{len(jax.devices())}; run with JAX_PLATFORMS=cpu for the "
+            "virtual mesh")
     env = dict(os.environ)
     flags = " ".join(f for f in env.get("XLA_FLAGS", "").split()
                      if "host_platform_device_count" not in f)
@@ -35,8 +42,7 @@ def _ensure_devices(n: int = 8) -> bool:
         f"{flags} --xla_force_host_platform_device_count={n}".strip()
     env["JAX_PLATFORMS"] = "cpu"
     env["_PTPU_DGC_CHILD"] = "1"
-    code = ("import jax; jax.config.update('jax_platforms', 'cpu'); "
-            "import runpy, sys; sys.argv = [sys.argv[0]] + "
+    code = ("import runpy, sys; sys.argv = [sys.argv[0]] + "
             f"{sys.argv[1:]!r}; "
             f"runpy.run_path({os.path.abspath(__file__)!r}, "
             "run_name='__main__')")

@@ -6,8 +6,8 @@ holds only T/4 of the sequence) and verifies the losses match a plain
 data-parallel run — the correctness contract that lets the same config
 scale to sequences no single chip could hold.
 
-Self-bootstraps a virtual 8-device CPU mesh when fewer than 4 devices
-are present (the same recipe as tests/conftest.py), so it runs anywhere:
+On the CPU backend it self-bootstraps a virtual 8-device mesh when fewer
+than 4 devices are present (the same recipe as tests/conftest.py):
 
     python examples/long_context_sp.py
 """
@@ -21,11 +21,18 @@ import sys
 def _ensure_devices(n: int = 8) -> bool:
     """Re-exec on a virtual n-device CPU mesh if needed. Returns True in
     the child/ready process; the parent that delegated never returns —
-    it raises SystemExit with the child's exit code."""
+    it raises SystemExit with the child's exit code. CPU backend only:
+    a process that holds an accelerator with too few chips fails (a CPU
+    child would hide that nothing ran on the device)."""
     import jax
 
     if len(jax.devices()) >= 4 or os.environ.get("_PTPU_SP_CHILD") == "1":
         return True
+    if jax.default_backend() != "cpu":
+        raise SystemExit(
+            f"need 4 devices, the {jax.default_backend()} backend has "
+            f"{len(jax.devices())}; run with JAX_PLATFORMS=cpu for the "
+            "virtual mesh")
     env = dict(os.environ)
     flags = " ".join(f for f in env.get("XLA_FLAGS", "").split()
                      if "host_platform_device_count" not in f)
@@ -33,8 +40,7 @@ def _ensure_devices(n: int = 8) -> bool:
         f"{flags} --xla_force_host_platform_device_count={n}".strip()
     env["JAX_PLATFORMS"] = "cpu"
     env["_PTPU_SP_CHILD"] = "1"
-    code = ("import jax; jax.config.update('jax_platforms', 'cpu'); "
-            "import runpy, sys; sys.argv = [sys.argv[0]] + "
+    code = ("import runpy, sys; sys.argv = [sys.argv[0]] + "
             f"{sys.argv[1:]!r}; "
             f"runpy.run_path({os.path.abspath(__file__)!r}, "
             "run_name='__main__')")

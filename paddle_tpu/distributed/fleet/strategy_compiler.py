@@ -535,16 +535,22 @@ class CompiledTrainStep:
             donate_argnums=(0,) if self._donate else (),
         )
 
+    def lower(self, state, batch, key=None):
+        """Lower the train step over concrete or abstract
+        (ShapeDtypeStruct) state/batch with the SAME jit wiring
+        (shardings, donation) as ``__call__`` — nothing executes or is
+        donated. ``.as_text()`` shows what the step dispatches (Pallas
+        kernels by their ``name=``); ``.compile()`` is
+        :meth:`compile_abstract`."""
+        if key is None:
+            key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+        return self._build_jit(state, batch).lower(state, batch, key)
+
     def compile_abstract(self, abstract_state, abstract_batch, key=None):
         """AOT-compile the train step over abstract (ShapeDtypeStruct)
         state/batch — full-size flagship configs compile and report XLA
-        memory analysis without materializing any weights. Uses the SAME
-        jit wiring (shardings, donation) as ``__call__``."""
-        if key is None:
-            key = jax.ShapeDtypeStruct((2,), jnp.uint32)
-        lowered = self._build_jit(abstract_state, abstract_batch).lower(
-            abstract_state, abstract_batch, key)
-        return lowered.compile()
+        memory analysis without materializing any weights."""
+        return self.lower(abstract_state, abstract_batch, key).compile()
 
     def __call__(self, state: TrainState, batch, key=None):
         if key is None:

@@ -22,10 +22,7 @@ def flops(model_or_fn: Callable, *example_inputs: Any,
     """Analytical FLOPs of one forward pass at the example shapes."""
     fn = model_or_fn
     compiled = jax.jit(lambda *xs: fn(*xs)).lower(*example_inputs).compile()
-    analysis = compiled.cost_analysis()
-    if isinstance(analysis, list):  # older jax returns [dict]
-        analysis = analysis[0]
-    total = int(analysis.get("flops", 0))
+    total = int(compiled.cost_analysis().get("flops", 0))
     if per_sample:
         batch = example_inputs[0].shape[0]
         return total // max(batch, 1)

@@ -207,7 +207,7 @@ class InferenceServer(FrameService):
             stat_add("serving/models_unloaded")
         return existed
 
-    def add_generator(self, name: str, model, **engine_kwargs) -> None:
+    def add_generator(self, name: str, model, **engine_kwargs):
         """Register a continuous-batching :class:`~paddle_tpu.serving.
         engine.GenerationEngine` for the ``generate_start`` /
         ``generate_poll`` / ``generate_cancel`` ops. ``model`` is a live
@@ -219,7 +219,8 @@ class InferenceServer(FrameService):
         mode (``FLAGS_gen_paged`` or ``paged=True`` in
         ``engine_kwargs``, plus ``page_tokens``/``pages``/
         ``prefill_chunk``/``prefix_cache``) changes only the engine's
-        memory management — the wire surface is identical."""
+        memory management — the wire surface is identical. Returns the
+        engine now serving ``name``."""
         from paddle_tpu.serving.engine import GenerationEngine
 
         engine = (model if isinstance(model, GenerationEngine)
@@ -237,6 +238,7 @@ class InferenceServer(FrameService):
             # never double-shed and class headroom applies consistently
             self.set_shed_gate(sched.wire_gate)
             self._batcher.set_sched(sched)
+        return engine
 
     def _generator(self, name: str):
         with self._lock:

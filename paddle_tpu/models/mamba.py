@@ -24,6 +24,7 @@ from paddle_tpu.nn.common import Embedding, Linear
 from paddle_tpu.nn.initializer import Normal, Uniform
 from paddle_tpu.nn.norm import RMSNorm
 from paddle_tpu.nn.scan import ScannedBlocks
+from paddle_tpu.ops import pallas as _pk
 
 __all__ = ["MambaConfig", "MambaBlock", "MambaForCausalLM",
            "selective_scan"]
@@ -218,14 +219,12 @@ class MambaBlock(Module):
                  else None)
         uf = u.astype(jnp.float32)
         y = None
-        _pk = F._pallas()
-        if _pk is not None:
-            mode = _pk.dispatch_mode()
-            if mode != "off" and _pk.selective_scan_supported(
-                    uf, delta, A, Bc, Cc, self.D, chunk=chunk):
-                y = _pk.selective_scan(
-                    uf, delta, A, Bc, Cc, self.D, chunk=chunk,
-                    partitioned=mode == "partitioned")
+        mode = _pk.dispatch_mode()
+        if mode != "off" and _pk.selective_scan_supported(
+                uf, delta, A, Bc, Cc, self.D, chunk=chunk):
+            y = _pk.selective_scan(
+                uf, delta, A, Bc, Cc, self.D, chunk=chunk,
+                partitioned=mode == "partitioned")
         if y is None:
             y = selective_scan(uf, delta, A, Bc, Cc, self.D,
                                chunk_size=chunk)

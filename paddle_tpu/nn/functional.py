@@ -19,22 +19,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from paddle_tpu.core import rng
-
-_PALLAS_UNSET = object()
-_PALLAS = _PALLAS_UNSET
-
-
-def _pallas():
-    """The paddle_tpu.ops.pallas kernel set, or None when Pallas is
-    unavailable in this jax build (dispatch then stays on the jnp path)."""
-    global _PALLAS
-    if _PALLAS is _PALLAS_UNSET:
-        try:
-            from paddle_tpu.ops import pallas as pk
-            _PALLAS = pk
-        except ImportError:
-            _PALLAS = None
-    return _PALLAS
+from paddle_tpu.ops import pallas as _pk
 
 __all__ = [
     "relu", "relu6", "gelu", "silu", "swish", "sigmoid", "tanh",
@@ -150,8 +135,7 @@ def layer_norm(x, weight=None, bias=None, epsilon: float = 1e-5, axis=-1):
     """Row layer-norm (reference kernel ``operators/layer_norm_op.cu``,
     Welford rows). On TPU, supported shapes dispatch to the fused Pallas
     kernel (``paddle_tpu.ops.pallas.layer_norm``)."""
-    _pk = _pallas()
-    if _pk is not None and axis in (-1, x.ndim - 1):
+    if axis in (-1, x.ndim - 1):
         from paddle_tpu.ops.pallas import norm as _pn
         mode = _pk._support.dispatch_mode()
         if mode != "off" and _pn.supported(x, weight, bias):
@@ -171,13 +155,11 @@ def rms_norm(x, weight=None, epsilon: float = 1e-6):
     """RMSNorm (no mean subtraction) — the Llama-family norm. Computed in
     fp32 and cast back, matching standard practice for bf16 training. On
     TPU, supported shapes dispatch to the fused Pallas kernel."""
-    _pk = _pallas()
-    if _pk is not None:
-        from paddle_tpu.ops.pallas import norm as _pn
-        mode = _pk._support.dispatch_mode()
-        if mode != "off" and _pn.supported(x, weight):
-            return _pk.rms_norm(x, weight, epsilon,
-                                partitioned=mode == "partitioned")
+    from paddle_tpu.ops.pallas import norm as _pn
+    mode = _pk._support.dispatch_mode()
+    if mode != "off" and _pn.supported(x, weight):
+        return _pk.rms_norm(x, weight, epsilon,
+                            partitioned=mode == "partitioned")
     dtype = x.dtype
     xf = x.astype(jnp.promote_types(x.dtype, jnp.float32))
     var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
@@ -330,8 +312,7 @@ def softmax_with_cross_entropy(logits, label, soft_label: bool = False,
     (``operators/softmax_with_cross_entropy_op.cu``); on TPU the [N, V]
     int-label hot case dispatches to the Pallas kernel, which saves only
     the [N] log-sum-exp for backward instead of the [N, V] probabilities."""
-    _pk = _pallas()
-    if _pk is not None and not soft_label and axis in (-1, logits.ndim - 1):
+    if not soft_label and axis in (-1, logits.ndim - 1):
         from paddle_tpu.ops.pallas import softmax_xent as _px
         v = logits.shape[-1]
         flat = logits.reshape(-1, v)
@@ -439,11 +420,8 @@ def linear_cross_entropy(hidden, weight, label, ignore_index: int = -100,
 
     loss = None
     if mode in ("auto", "fused", "chunked"):
-        _pk = _pallas()
-        lmod = None
-        if _pk is not None:
-            from paddle_tpu.ops.pallas import linear_xent as lmod
-        if lmod is not None and mode != "chunked":
+        from paddle_tpu.ops.pallas import linear_xent as lmod
+        if mode != "chunked":
             dmode = _pk._support.dispatch_mode()
             # row-pad to the kernel block (ignore-masked rows are free:
             # they select no label and carry a zero cotangent); below one
@@ -461,8 +439,7 @@ def linear_cross_entropy(hidden, weight, label, ignore_index: int = -100,
                     loss = lmod.fused_linear_cross_entropy(
                         flat_p, weight, lab_p,
                         partitioned=dmode == "partitioned")[:n]
-        if loss is None and lmod is not None and mode in ("chunked",
-                                                         "fused"):
+        if loss is None and mode in ("chunked", "fused"):
             loss = lmod.chunked_linear_cross_entropy(flat, weight, lab)
     if loss is None:
         logits = (flat @ weight).astype(jnp.float32)
@@ -569,13 +546,11 @@ def scaled_dot_product_attention(q, k, v, mask=None, *, causal: bool = False,
     if scale is None:
         scale = 1.0 / math.sqrt(D)
 
-    _pk = _pallas()
-    if (_pk is not None and use_pallas != "never" and dropout_p == 0.0
-            and mask is None):
+    if use_pallas != "never" and dropout_p == 0.0 and mask is None:
         mode = _pk._support.dispatch_mode()
         if mode == "off" and use_pallas == "always":
             # Forced dispatch: inside any manual shard_map only the raw
-            # kernel is safe (custom_partitioning cannot lower there).
+            # kernel is safe (a nested shard_map unit cannot lower there).
             any_manual, _ = _pk._support._manual_axes()
             if any_manual or _pk._support.single_device():
                 mode = "raw"
@@ -623,8 +598,7 @@ def apply_rotary(x, cos, sin):
     """Apply rotary embedding to [B, T, H, D] (cos/sin [B?, T, D/2]).
     On TPU, the [T, D/2]-table case dispatches to the fused Pallas
     kernel."""
-    _pk = _pallas()
-    if _pk is not None and x.ndim == 4 and cos.ndim == 2:
+    if x.ndim == 4 and cos.ndim == 2:
         from paddle_tpu.ops.pallas import rope as _pr
         mode = _pk._support.dispatch_mode()
         if mode != "off" and _pr.supported(x, cos, sin):

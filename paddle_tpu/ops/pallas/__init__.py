@@ -39,8 +39,8 @@ dispatch_mode = _support.dispatch_mode
 
 
 def partition_stats() -> dict:
-    """Lowering decisions taken by the multi-chip (custom_partitioning)
-    kernel wrappers, keyed ``<unit>:<kernel|fallback>`` — recorded in the
+    """Lowering decisions taken by the multi-chip (shard_map) kernel
+    units, keyed ``<unit>:<kernel|fallback>`` — recorded in the
     multichip driver artifact as proof the Pallas path executed under
     sharding."""
     from paddle_tpu.ops.pallas import _partition

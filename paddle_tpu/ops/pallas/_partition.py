@@ -3,32 +3,33 @@
 The reference runs its fused CUDA kernels under the multi-device executor
 (``paddle/fluid/operators/fused/multihead_matmul_op.cu`` launched per
 device by ``framework/parallel_executor.cc:504``). The TPU-native
-equivalent: each Pallas call-unit is wrapped in
-``jax.experimental.custom_partitioning`` so the SPMD partitioner (Shardy
-or GSPMD) runs the kernel *per shard* inside jit over a multi-device mesh
-instead of falling back to the dense jnp path.
+equivalent: each Pallas call-unit runs *per shard* inside a
+``jax.shard_map`` over the ambient mesh, embedded in the automatically
+partitioned jit around it, instead of falling back to the dense jnp
+path. (``jax.experimental.custom_partitioning`` — the earlier mechanism
+— does not compile on the TPU backend: libtpu answers "Custom emitter
+for CustomSPMDPartitioning not found".)
 
 Design per unit:
 
-- a **sharding rule** (einsum-like string) tells Shardy how shardings
-  propagate through the op — batch-like factors pass through, row-stat
-  lane factors and normalized/contracted dims need replication;
-- a **sanitizing partition()** is the enforcement layer: whatever the
-  partitioner suggests, it returns arg/result shardings the kernel can
-  actually run on (dims the kernel reduces over are forced replicated,
-  GQA head shardings must divide the kv heads, batch shardings must
-  divide the batch). The partitioner inserts the reshards/collectives to
-  match — this is load-bearing because explicitly committed input
-  shardings are *not* auto-gathered to satisfy ``need_replication``
-  factors;
-- the **per-shard lowering** calls the raw kernel on local shapes, with
-  a jnp fallback when a shard's row count breaks the kernel's block
+- a **plan** decides, from the mesh and the operand shapes alone, which
+  dims shard over which mesh axes. It follows the framework-wide layout
+  (``parallel/mesh.py``, the table in ``models/llama.py``): batch-like
+  dims over the data axes (dp, fsdp), head / vocab / channel dims over
+  tp, a RoPE sequence dim over sp; whatever the kernel reduces over or
+  tiles on stays replicated. A dim shards only when the axes divide it
+  (GQA: both head counts; batch: the batch). An operand that arrives
+  laid out differently is resharded by the partitioner at the shard_map
+  boundary, exactly as a ``with_sharding_constraint`` would — e.g. a
+  ZeRO-3-sharded lm-head weight is all-gathered for the fused loss, as
+  the dense matmul path would gather it;
+- the **per-shard body** calls the raw kernel on local shapes, with a
+  jnp fallback when a shard's row count breaks the kernel's block
   alignment, and emits the cross-shard collectives (psum of dw/db,
   log-sum-exp combine over a sharded vocab) itself.
 
-Factories are keyed on the static config (lru_cache) so one
-custom_partitioning object is reused per (causal, scale, blocks, ...)
-combination and jit caches stay warm.
+Factories are keyed on the static config (lru_cache) so one callable is
+reused per (causal, scale, blocks, ...) combination.
 """
 
 from __future__ import annotations
@@ -39,14 +40,16 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.custom_partitioning import custom_partitioning
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
+
+from paddle_tpu.parallel.mesh import BATCH_AXES, get_mesh
 
 LANES = 128
 
-# Lowering decisions, keyed "<unit>:<kernel|fallback>". Recorded into the
-# multichip driver artifact so "the Pallas path executed under sharding"
-# is a checkable claim, not an assumption.
+# Per-shard lowering decisions, keyed "<unit>:<kernel|fallback>", counted
+# as each unit is traced: "the Pallas path executed under sharding" is a
+# checkable claim (chip_smoke.py, the multichip dryrun), not an
+# assumption.
 stats: collections.Counter = collections.Counter()
 
 
@@ -79,23 +82,16 @@ def _size(mesh, entry) -> int:
     return s
 
 
-def _spec_entries(sharding, ndim) -> list:
-    spec = tuple(getattr(sharding, "spec", ()) or ())
-    out = list(spec[:ndim])
-    return out + [None] * (ndim - len(out))
+def _live(mesh, *names):
+    """The named mesh axes that exist with degree > 1, as a spec entry
+    (None when there are none)."""
+    live = tuple(a for a in names if mesh.shape.get(a, 1) > 1)
+    return live or None
 
 
-def _sharding_of(arg):
-    sh = getattr(arg, "sharding", None)
-    return sh if isinstance(sh, NamedSharding) else None
-
-
-def _mesh_from(arg_shapes, fallback_mesh):
-    for a in arg_shapes:
-        sh = _sharding_of(a)
-        if sh is not None:
-            return sh.mesh
-    return fallback_mesh
+def _data(mesh):
+    """Spec entry for a batch-like dim: the data axes (dp, fsdp)."""
+    return _live(mesh, *BATCH_AXES)
 
 
 def _rows_aligned(n_local: int, block: int) -> bool:
@@ -106,139 +102,94 @@ def _rows_aligned(n_local: int, block: int) -> bool:
     return n_local <= block or n_local % block == 0
 
 
-def _valid_dim(mesh, entry, dim_size: int, used: set) -> object:
-    """Keep a suggested dim sharding only if it divides the dim and does
-    not reuse an axis already consumed by another dim of the same spec."""
+def _valid_dim(mesh, entry, dim_size: int, used: set,
+               multiple: int = 1) -> object:
+    """Keep a dim sharding only if it divides the dim into shards that
+    are a multiple of ``multiple`` (a kernel tile) and does not reuse an
+    axis already consumed by another dim of the same spec."""
     ax = _axes(entry)
     if not ax or set(ax) & used:
         return None
     s = _size(mesh, entry)
-    if s <= 1 or dim_size % s:
+    if s <= 1 or dim_size % s or (dim_size // s) % multiple:
         return None
     used.update(ax)
     return entry
 
 
-def _build(global_fn, plan, rule, *, need_replication=(), reduction=(),
-           factor_sizes=None):
-    """Wire a pallas call-unit into custom_partitioning.
+def _build(body, plan):
+    """Wire a pallas call-unit into shard_map over the ambient mesh.
 
-    ``plan(mesh, arg_shapes) -> (arg_specs, out_specs, ctx)`` makes the
-    sharding decision; ``global_fn(ctx, *args)`` is also the per-shard
-    lowering (ctx carries the axes it must psum over / whether to take
-    the jnp fallback).
+    ``plan(mesh, args) -> (arg_specs, out_specs, ctx)`` makes the
+    sharding decision (``out_specs`` mirrors the unit's return
+    structure); ``body(ctx, *local_args)`` is the per-shard lowering
+    (ctx carries the axes it must psum over / whether to take the jnp
+    fallback).
     """
-    cp = custom_partitioning(lambda *args: global_fn(None, *args))
+    def call(*args):
+        mesh = get_mesh()
+        arg_specs, out_specs, ctx = plan(mesh, args)
+        return jax.shard_map(
+            functools.partial(body, ctx), mesh=mesh,
+            in_specs=tuple(arg_specs), out_specs=out_specs,
+            check_vma=False)(*args)
 
-    def partition(mesh, arg_shapes, result_shape):
-        nmesh = _mesh_from(arg_shapes, mesh)
-        arg_specs, out_specs, ctx = plan(nmesh, arg_shapes)
-        out_sh = tuple(NamedSharding(nmesh, s) for s in out_specs)
-        if not isinstance(result_shape, (tuple, list)):
-            out_sh = out_sh[0]
-        arg_sh = tuple(NamedSharding(nmesh, s) for s in arg_specs)
-        return nmesh, functools.partial(global_fn, ctx), out_sh, arg_sh
-
-    def infer(mesh, arg_shapes, result_shape):
-        nmesh = _mesh_from(arg_shapes, mesh)
-        _, out_specs, _ = plan(nmesh, arg_shapes)
-        out_sh = tuple(NamedSharding(nmesh, s) for s in out_specs)
-        if not isinstance(result_shape, (tuple, list)):
-            return out_sh[0]
-        return out_sh
-
-    cp.def_partition(partition=partition,
-                     infer_sharding_from_operands=infer,
-                     sharding_rule=rule,
-                     need_replication_factors=tuple(need_replication),
-                     reduction_factors=tuple(reduction),
-                     **(factor_sizes or {}))
-    return cp
+    return call
 
 
 # ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------
 
-def _batch_head_plan(mesh, B, Hq, Hkv, b_entry, h_entry):
+def _batch_head_plan(mesh, B, Hq, Hkv):
     """Shared batch/head sharding selection for the attention units:
-    shard batch and heads, everything else replicated. The head
-    sharding must divide BOTH head counts so each shard keeps whole GQA
-    groups (contiguous blocks: q heads [i·Hq/s, …) ↔ kv heads
+    batch over the data axes, heads over tp, everything else replicated.
+    The head sharding must divide BOTH head counts so each shard keeps
+    whole GQA groups (contiguous blocks: q heads [i·Hq/s, …) ↔ kv heads
     [i·Hkv/s, …))."""
     used: set = set()
-    b = _valid_dim(mesh, b_entry, B, used)
-    h = h_entry
-    if _size(mesh, h) > 1 and (Hkv % _size(mesh, h) or Hq % _size(mesh, h)):
-        h = None
-    h = _valid_dim(mesh, h, math.gcd(Hq, Hkv), used)
+    b = _valid_dim(mesh, _data(mesh), B, used)
+    h = _valid_dim(mesh, _live(mesh, "tp"), math.gcd(Hq, Hkv), used)
     return b, h
 
 
-def _flash_plan(mesh, arg_shapes):
-    B, Hq = arg_shapes[0].shape[0], arg_shapes[0].shape[1]
-    Hkv = arg_shapes[1].shape[1]
-    qspec = _spec_entries(_sharding_of(arg_shapes[0]), 4)
-    kspec = _spec_entries(_sharding_of(arg_shapes[1]), 4)
-    return _batch_head_plan(mesh, B, Hq, Hkv, qspec[0] or kspec[0],
-                            qspec[1] or kspec[1])
+def _flash_plan(mesh, args):
+    B, Hq = args[0].shape[0], args[0].shape[1]
+    Hkv = args[1].shape[1]
+    return _batch_head_plan(mesh, B, Hq, Hkv)
 
 
 @functools.lru_cache(maxsize=None)
-def flash_fwd(causal: bool, scale: float, block_q, block_k, group: int):
+def flash_fwd(causal: bool, scale: float, block_q, block_k):
     FA = _mod("flash_attention")
 
-    def fn(ctx, qt, kt, vt):
+    def body(ctx, qt, kt, vt):
         stats["flash_fwd:kernel"] += 1
-        return FA._fwd(qt, kt, vt, causal, scale, block_q, block_k)
+        return tuple(FA._fwd(qt, kt, vt, causal, scale, block_q, block_k))
 
-    def plan(mesh, arg_shapes):
-        b, h = _flash_plan(mesh, arg_shapes)
+    def plan(mesh, args):
+        b, h = _flash_plan(mesh, args)
         io = P(b, h, None, None)
         return (io, io, io), (io, io), None
 
-    if group > 1:
-        rule = ("b (h g) t d, b h s e, b h s e "
-                "-> b (h g) t d, b (h g) t l")
-        sizes = {"g": group}
-    else:
-        rule = "b h t d, b h s e, b h s e -> b h t d, b h t l"
-        sizes = None
-    return _build(fn, plan, rule,
-                  # sorted by factor first-appearance (Shardy requirement)
-                  need_replication=("t", "d", "s", "e", "l"),
-                  factor_sizes=sizes)
+    return _build(body, plan)
 
 
 @functools.lru_cache(maxsize=None)
-def flash_bwd(causal: bool, scale: float, block_q, block_k, group: int):
+def flash_bwd(causal: bool, scale: float, block_q, block_k):
     FA = _mod("flash_attention")
 
-    def fn(ctx, qt, kt, vt, ot, lse, do_t):
+    def body(ctx, qt, kt, vt, ot, lse, do_t):
         stats["flash_bwd:kernel"] += 1
         return FA._bwd_impl(qt, kt, vt, ot, lse, do_t, causal, scale,
                             block_q, block_k)
 
-    def plan(mesh, arg_shapes):
-        b, h = _flash_plan(mesh, arg_shapes)
-        q_like = P(b, h, None, None)
-        kv_like = P(b, h, None, None)
-        args = (q_like, kv_like, kv_like, q_like, q_like, q_like)
-        outs = (q_like, kv_like, kv_like)
-        return args, outs, None
+    def plan(mesh, args):
+        b, h = _flash_plan(mesh, args)
+        io = P(b, h, None, None)
+        return (io,) * 6, (io, io, io), None
 
-    if group > 1:
-        rule = ("b (h g) t d, b h s e, b h s e, b (h g) t d, b (h g) t l, "
-                "b (h g) t d -> b (h g) t d, b h s e, b h s e")
-        sizes = {"g": group}
-    else:
-        rule = ("b h t d, b h s e, b h s e, b h t d, b h t l, b h t d "
-                "-> b h t d, b h s e, b h s e")
-        sizes = None
-    return _build(fn, plan, rule,
-                  # sorted by factor first-appearance (Shardy requirement)
-                  need_replication=("t", "d", "s", "e", "l"),
-                  factor_sizes=sizes)
+    return _build(body, plan)
 
 
 # ---------------------------------------------------------------------------
@@ -246,47 +197,40 @@ def flash_bwd(causal: bool, scale: float, block_q, block_k, group: int):
 # ---------------------------------------------------------------------------
 
 def _rows_plan(mesh, x_arg, block_rows):
-    """Row sharding passes through; feature dim replicated. ctx = (row
-    axes for psum, use_kernel)."""
+    """Rows over the data axes; feature dim replicated. Returns (row
+    entry, row axes for psum, use_kernel)."""
     n = x_arg.shape[0]
-    spec = _spec_entries(_sharding_of(x_arg), 2)
-    used: set = set()
-    r = _valid_dim(mesh, spec[0], n, used)
-    n_local = n // _size(mesh, r) if r is not None else n
-    return r, _axes(r), _rows_aligned(n_local, block_rows)
+    r = _valid_dim(mesh, _data(mesh), n, set())
+    return r, _axes(r), _rows_aligned(n // _size(mesh, r), block_rows)
 
 
 @functools.lru_cache(maxsize=None)
 def rms_fwd(eps: float):
     N = _mod("norm")
 
-    def fn(ctx, x2d, w):
-        use_kernel = ctx is None or ctx[1]
+    def body(use_kernel, x2d, w):
         if use_kernel:
             stats["rms_fwd:kernel"] += 1
-            return N._rms_fwd(x2d, w, eps)
+            return tuple(N._rms_fwd(x2d, w, eps))
         stats["rms_fwd:fallback"] += 1
         xf = x2d.astype(jnp.float32)
         rstd = jax.lax.rsqrt(jnp.mean(xf * xf, axis=1, keepdims=True) + eps)
         y = (xf * rstd * w.astype(jnp.float32)).astype(x2d.dtype)
         return y, jnp.broadcast_to(rstd, (x2d.shape[0], LANES))
 
-    def plan(mesh, arg_shapes):
-        r, raxes, ok = _rows_plan(mesh, arg_shapes[0], N._BLOCK_ROWS)
-        return ((P(r, None), P(None)),
-                (P(r, None), P(r, None)),
-                (raxes, ok))
+    def plan(mesh, args):
+        r, _, ok = _rows_plan(mesh, args[0], N._BLOCK_ROWS)
+        return (P(r, None), P(None)), (P(r, None), P(r, None)), ok
 
-    return _build(fn, plan, "n h, h -> n h, n l",
-                  need_replication=("h", "l"))
+    return _build(body, plan)
 
 
 @functools.lru_cache(maxsize=None)
 def rms_bwd(eps: float):
     N = _mod("norm")
 
-    def fn(ctx, x2d, w, rstd, g):
-        raxes, use_kernel = ctx if ctx is not None else ((), True)
+    def body(ctx, x2d, w, rstd, g):
+        raxes, use_kernel = ctx
         if use_kernel:
             stats["rms_bwd:kernel"] += 1
             dx, dw = N._rms_bwd_call(x2d, w, rstd, g)
@@ -305,25 +249,23 @@ def rms_bwd(eps: float):
             dw = jax.lax.psum(dw, raxes)
         return dx, dw
 
-    def plan(mesh, arg_shapes):
-        r, raxes, ok = _rows_plan(mesh, arg_shapes[0], N._BLOCK_ROWS)
+    def plan(mesh, args):
+        r, raxes, ok = _rows_plan(mesh, args[0], N._BLOCK_ROWS)
         return ((P(r, None), P(None), P(r, None), P(r, None)),
                 (P(r, None), P(None)),
                 (raxes, ok))
 
-    return _build(fn, plan, "n h, h, n l, n h -> n h, h",
-                  need_replication=("h", "l"))
+    return _build(body, plan)
 
 
 @functools.lru_cache(maxsize=None)
 def ln_fwd(eps: float):
     N = _mod("norm")
 
-    def fn(ctx, x2d, w, b):
-        use_kernel = ctx is None or ctx[1]
+    def body(use_kernel, x2d, w, b):
         if use_kernel:
             stats["ln_fwd:kernel"] += 1
-            return N._ln_fwd(x2d, w, b, eps)
+            return tuple(N._ln_fwd(x2d, w, b, eps))
         stats["ln_fwd:fallback"] += 1
         xf = x2d.astype(jnp.float32)
         mean = jnp.mean(xf, axis=1, keepdims=True)
@@ -336,22 +278,21 @@ def ln_fwd(eps: float):
         return (y, jnp.broadcast_to(mean, (n, LANES)),
                 jnp.broadcast_to(rstd, (n, LANES)))
 
-    def plan(mesh, arg_shapes):
-        r, raxes, ok = _rows_plan(mesh, arg_shapes[0], N._BLOCK_ROWS)
+    def plan(mesh, args):
+        r, _, ok = _rows_plan(mesh, args[0], N._BLOCK_ROWS)
         return ((P(r, None), P(None), P(None)),
                 (P(r, None), P(r, None), P(r, None)),
-                (raxes, ok))
+                ok)
 
-    return _build(fn, plan, "n h, h, h -> n h, n l, n l",
-                  need_replication=("h", "l"))
+    return _build(body, plan)
 
 
 @functools.lru_cache(maxsize=None)
 def ln_bwd(eps: float):
     N = _mod("norm")
 
-    def fn(ctx, x2d, w, mean, rstd, g):
-        raxes, use_kernel = ctx if ctx is not None else ((), True)
+    def body(ctx, x2d, w, mean, rstd, g):
+        raxes, use_kernel = ctx
         if use_kernel:
             stats["ln_bwd:kernel"] += 1
             dx, dw, db = N._ln_bwd_call(x2d, w, mean, rstd, g)
@@ -373,46 +314,43 @@ def ln_bwd(eps: float):
             db = jax.lax.psum(db, raxes)
         return dx, dw, db
 
-    def plan(mesh, arg_shapes):
-        r, raxes, ok = _rows_plan(mesh, arg_shapes[0], N._BLOCK_ROWS)
+    def plan(mesh, args):
+        r, raxes, ok = _rows_plan(mesh, args[0], N._BLOCK_ROWS)
         return ((P(r, None), P(None), P(r, None), P(r, None), P(r, None)),
                 (P(r, None), P(None), P(None)),
                 (raxes, ok))
 
-    return _build(fn, plan, "n h, h, n l, n l, n h -> n h, h, h",
-                  need_replication=("h", "l"))
+    return _build(body, plan)
 
 
 # ---------------------------------------------------------------------------
 # softmax cross-entropy — [n, v] units
 # ---------------------------------------------------------------------------
 
-def _xent_plan(mesh, x_arg, *, shard_v: bool):
+def _xent_plan(mesh, x_arg):
+    """Rows over the data axes, vocab over tp (Megatron lm-head) when
+    each vocab shard still tiles. Returns (row entry, vocab entry, vocab
+    axes, use_kernel)."""
     X = _mod("softmax_xent")
 
     n, v = x_arg.shape
-    spec = _spec_entries(_sharding_of(x_arg), 2)
     used: set = set()
-    r = _valid_dim(mesh, spec[0], n, used)
-    vv = _valid_dim(mesh, spec[1], v, used) if shard_v else None
-    if vv is not None and (v // _size(mesh, vv)) % X._BLOCK_V:
-        used.difference_update(_axes(vv))
-        vv = None
-    n_local = n // _size(mesh, r) if r is not None else n
-    ok = _rows_aligned(n_local, X._BLOCK_N)
+    r = _valid_dim(mesh, _data(mesh), n, used)
+    vv = _valid_dim(mesh, _live(mesh, "tp"), v, used, multiple=X._BLOCK_V)
+    ok = _rows_aligned(n // _size(mesh, r), X._BLOCK_N)
     return r, vv, _axes(vv), ok
 
 
 @functools.lru_cache(maxsize=None)
 def xent_lse():
-    """Row log-sum-exp over [n, v] (lane-replicated [n, 128] out). The
-    vocab dim may be sharded (Megatron-style tp lm-head): each shard
+    """Row log-sum-exp over [n, v] (lane-replicated [n, 128] out). With
+    the vocab dim sharded (Megatron-style tp lm-head) each shard
     computes its local lse and the shards combine with the standard
     max/psum log-sum-exp merge over the vocab axes."""
     X = _mod("softmax_xent")
 
-    def fn(ctx, logits):
-        vaxes, use_kernel = ctx if ctx is not None else ((), True)
+    def body(ctx, logits):
+        vaxes, use_kernel = ctx
         if use_kernel and logits.shape[1] % X._BLOCK_V == 0:
             stats["xent_lse:kernel"] += 1
             lse = X._lse_call(logits)
@@ -426,12 +364,11 @@ def xent_lse():
             lse = m + jnp.log(jax.lax.psum(jnp.exp(lse - m), vaxes))
         return lse
 
-    def plan(mesh, arg_shapes):
-        r, vv, vaxes, ok = _xent_plan(mesh, arg_shapes[0], shard_v=True)
-        return ((P(r, vv),), (P(r, None),), (vaxes, ok))
+    def plan(mesh, args):
+        r, vv, vaxes, ok = _xent_plan(mesh, args[0])
+        return (P(r, vv),), P(r, None), (vaxes, ok)
 
-    return _build(fn, plan, "n v -> n l",
-                  need_replication=("l",), reduction=("v",))
+    return _build(body, plan)
 
 
 @functools.lru_cache(maxsize=None)
@@ -440,8 +377,7 @@ def xent_dx():
     v, so both n and v shard cleanly."""
     X = _mod("softmax_xent")
 
-    def fn(ctx, logits, lse_b, g_b):
-        use_kernel = ctx is None or ctx[1]
+    def body(use_kernel, logits, lse_b, g_b):
         if use_kernel and logits.shape[1] % X._BLOCK_V == 0:
             stats["xent_dx:kernel"] += 1
             return X._dx_call(logits, lse_b, g_b)
@@ -449,12 +385,11 @@ def xent_dx():
         return (jnp.exp(logits.astype(jnp.float32) - lse_b[:, :1])
                 * g_b[:, :1]).astype(logits.dtype)
 
-    def plan(mesh, arg_shapes):
-        r, vv, _, ok = _xent_plan(mesh, arg_shapes[0], shard_v=True)
-        return ((P(r, vv), P(r, None), P(r, None)), (P(r, vv),), ((), ok))
+    def plan(mesh, args):
+        r, vv, _, ok = _xent_plan(mesh, args[0])
+        return (P(r, vv), P(r, None), P(r, None)), P(r, vv), ok
 
-    return _build(fn, plan, "n v, n l, n l -> n v",
-                  need_replication=("l",))
+    return _build(body, plan)
 
 
 # ---------------------------------------------------------------------------
@@ -462,20 +397,18 @@ def xent_dx():
 # ---------------------------------------------------------------------------
 
 def _flce_plan(mesh, h_arg, w_arg):
-    """Rows shard from h dim0; vocab shards from w dim1 (Megatron tp
-    lm-head); the contracted e dim is forced replicated (the partitioner
-    all-gathers a ZeRO-sharded weight, exactly as the dense matmul path
-    would). ctx = (vaxes, vsizes, raxes, use_kernel)."""
+    """Rows over the data axes; vocab over tp (Megatron lm-head); the
+    contracted e dim replicated (a ZeRO-sharded weight is all-gathered
+    at the boundary, exactly as the dense matmul path would). ctx =
+    (vaxes, vsizes, raxes, use_kernel)."""
     X = _mod("linear_xent")
     n, e = h_arg.shape
     v = w_arg.shape[1]
-    hspec = _spec_entries(_sharding_of(h_arg), 2)
-    wspec = _spec_entries(_sharding_of(w_arg), 2)
     used: set = set()
-    r = _valid_dim(mesh, hspec[0], n, used)
-    vv = _valid_dim(mesh, wspec[1], v, used)
-    n_local = n // _size(mesh, r) if r is not None else n
-    v_local = v // _size(mesh, vv) if vv is not None else v
+    r = _valid_dim(mesh, _data(mesh), n, used)
+    vv = _valid_dim(mesh, _live(mesh, "tp"), v, used)
+    n_local = n // _size(mesh, r)
+    v_local = v // _size(mesh, vv)
     itemsize = jnp.dtype(w_arg.dtype).itemsize
     ok = (e % LANES == 0 and n_local % 8 == 0
           and n_local % X._pick_bn(n_local, e) == 0
@@ -526,9 +459,8 @@ def flce_fwd():
     (exactly one shard holds each in-range label)."""
     X = _mod("linear_xent")
 
-    def fn(ctx, h, w, lab_b):
-        vaxes, vsizes, _, use_kernel = ctx if ctx is not None \
-            else ((), (), (), True)
+    def body(ctx, h, w, lab_b):
+        vaxes, vsizes, _, use_kernel = ctx
         lab_local = _flce_shift(lab_b, vaxes, vsizes, w.shape[1])
         if use_kernel:
             stats["flce_fwd:kernel"] += 1
@@ -542,13 +474,12 @@ def flce_fwd():
             sel = jax.lax.psum(sel, vaxes)
         return lse, sel
 
-    def plan(mesh, arg_shapes):
-        r, vv, ctx = _flce_plan(mesh, arg_shapes[0], arg_shapes[1])
+    def plan(mesh, args):
+        r, vv, ctx = _flce_plan(mesh, args[0], args[1])
         return ((P(r, None), P(None, vv), P(r, None)),
                 (P(r, None), P(r, None)), ctx)
 
-    return _build(fn, plan, "n e, e v, n l -> n l, n l",
-                  need_replication=("e", "l"), reduction=("v",))
+    return _build(body, plan)
 
 
 @functools.lru_cache(maxsize=None)
@@ -557,9 +488,8 @@ def flce_dh():
     ``dlogits @ Wᵀ`` partial; psum over the vocab axes."""
     X = _mod("linear_xent")
 
-    def fn(ctx, h, w, lab_b, lse_b, g_b):
-        vaxes, vsizes, _, use_kernel = ctx if ctx is not None \
-            else ((), (), (), True)
+    def body(ctx, h, w, lab_b, lse_b, g_b):
+        vaxes, vsizes, _, use_kernel = ctx
         lab_local = _flce_shift(lab_b, vaxes, vsizes, w.shape[1])
         if use_kernel:
             stats["flce_dh:kernel"] += 1
@@ -574,13 +504,12 @@ def flce_dh():
             dh = jax.lax.psum(dh, vaxes)
         return dh
 
-    def plan(mesh, arg_shapes):
-        r, vv, ctx = _flce_plan(mesh, arg_shapes[0], arg_shapes[1])
+    def plan(mesh, args):
+        r, vv, ctx = _flce_plan(mesh, args[0], args[1])
         io = (P(r, None), P(None, vv), P(r, None), P(r, None), P(r, None))
-        return io, (P(r, None),), ctx
+        return io, P(r, None), ctx
 
-    return _build(fn, plan, "n e, e v, n l, n l, n l -> n e",
-                  need_replication=("e", "l"), reduction=("v",))
+    return _build(body, plan)
 
 
 @functools.lru_cache(maxsize=None)
@@ -589,9 +518,8 @@ def flce_dw():
     inputs psum their partials over the row axes (f32 for the combine)."""
     X = _mod("linear_xent")
 
-    def fn(ctx, h, w, lab_b, lse_b, g_b):
-        vaxes, vsizes, raxes, use_kernel = ctx if ctx is not None \
-            else ((), (), (), True)
+    def body(ctx, h, w, lab_b, lse_b, g_b):
+        vaxes, vsizes, raxes, use_kernel = ctx
         lab_local = _flce_shift(lab_b, vaxes, vsizes, w.shape[1])
         if use_kernel:
             stats["flce_dw:kernel"] += 1
@@ -607,13 +535,12 @@ def flce_dw():
                               raxes).astype(w.dtype)
         return dw
 
-    def plan(mesh, arg_shapes):
-        r, vv, ctx = _flce_plan(mesh, arg_shapes[0], arg_shapes[1])
+    def plan(mesh, args):
+        r, vv, ctx = _flce_plan(mesh, args[0], args[1])
         io = (P(r, None), P(None, vv), P(r, None), P(r, None), P(r, None))
-        return io, (P(None, vv),), ctx
+        return io, P(None, vv), ctx
 
-    return _build(fn, plan, "n e, e v, n l, n l, n l -> e v",
-                  need_replication=("e", "l"), reduction=("n",))
+    return _build(body, plan)
 
 
 # ---------------------------------------------------------------------------
@@ -624,8 +551,7 @@ def flce_dw():
 def rope(sign: float):
     R = _mod("rope")
 
-    def fn(ctx, x, cos, sin):
-        use_kernel = ctx is None or ctx[1]
+    def body(use_kernel, x, cos, sin):
         if use_kernel:
             stats["rope:kernel"] += 1
             return R._rope_call(x, cos, sin, sign)
@@ -638,41 +564,35 @@ def rope(sign: float):
         return jnp.concatenate(
             [x1f * c - x2f * s, x2f * c + x1f * s], axis=-1).astype(x.dtype)
 
-    def plan(mesh, arg_shapes):
-        B, T, H, D = arg_shapes[0].shape
-        spec = _spec_entries(_sharding_of(arg_shapes[0]), 4)
+    def plan(mesh, args):
+        B, T, H, D = args[0].shape
         used: set = set()
-        b = _valid_dim(mesh, spec[0], B, used)
-        t = _valid_dim(mesh, spec[1], T, used)
-        h = _valid_dim(mesh, spec[2], H, used)
-        t_local = T // _size(mesh, t) if t is not None else T
-        ok = _rows_aligned(t_local, R._BLOCK_T)
+        b = _valid_dim(mesh, _data(mesh), B, used)
+        t = _valid_dim(mesh, _live(mesh, "sp"), T, used)
+        h = _valid_dim(mesh, _live(mesh, "tp"), H, used)
+        ok = _rows_aligned(T // _size(mesh, t), R._BLOCK_T)
         # the tables shard with the sequence so each shard rotates by its
         # own absolute positions
         return ((P(b, t, h, None), P(t, None), P(t, None)),
-                (P(b, t, h, None),), ((), ok))
+                P(b, t, h, None), ok)
 
-    return _build(fn, plan, "b t h d, t e, t e -> b t h d",
-                  need_replication=("d", "e"))
+    return _build(body, plan)
 
 
 # ---------------------------------------------------------------------------
 # selective scan (Mamba) — [B, T, Ei] with [N, Ei] state matrix
 # ---------------------------------------------------------------------------
 
-def _ss_plan(mesh, arg_shapes):
-    """Batch and channel (lane) dims shard; time is sequential and the
-    state dim lives on sublanes — both replicated. Channel shardings must
-    keep each shard lane-tiled (Ei_local % 128), else they are dropped
-    (the kernel then runs on the full channel width per batch shard)."""
-    Bsz, T, Ei = arg_shapes[0].shape
-    spec = _spec_entries(_sharding_of(arg_shapes[0]), 3)
+def _ss_plan(mesh, args):
+    """Batch over the data axes, channels (lanes) over tp; time is
+    sequential and the state dim lives on sublanes — both replicated.
+    A channel sharding must keep each shard lane-tiled (Ei_local % 128),
+    else it is dropped (the kernel then runs on the full channel width
+    per batch shard)."""
+    Bsz, T, Ei = args[0].shape
     used: set = set()
-    b = _valid_dim(mesh, spec[0], Bsz, used)
-    e = spec[2]
-    if _size(mesh, e) > 1 and (Ei // _size(mesh, e)) % LANES:
-        e = None
-    e = _valid_dim(mesh, e, Ei, used)
+    b = _valid_dim(mesh, _data(mesh), Bsz, used)
+    e = _valid_dim(mesh, _live(mesh, "tp"), Ei, used, multiple=LANES)
     return b, e
 
 
@@ -680,35 +600,28 @@ def _ss_plan(mesh, arg_shapes):
 def selective_scan_fwd(k: int):
     SS = _mod("selective_scan")
 
-    def fn(ctx, u, delta, At, B, C, D2):
+    def body(ctx, u, delta, At, B, C, D2):
         stats["selective_scan_fwd:kernel"] += 1
-        return SS._fwd_call(u, delta, At, B, C, D2, k)
+        return tuple(SS._fwd_call(u, delta, At, B, C, D2, k))
 
-    def plan(mesh, arg_shapes):
-        b, e = _ss_plan(mesh, arg_shapes)
+    def plan(mesh, args):
+        b, e = _ss_plan(mesh, args)
         te = P(b, None, e)
         tn = P(b, None, None)
-        args = (te, te, P(None, e), tn, tn, P(None, e))
-        outs = (te, P(b, None, None, e))
-        return args, outs, None
+        arg_specs = (te, te, P(None, e), tn, tn, P(None, e))
+        return arg_specs, (te, P(b, None, None, e)), None
 
-    # factors: b t e (u) | n (A.T) | o (the D row dim) | c (chunk count,
-    # result-only); t/n sequential/sublane -> replicated
-    return _build(fn, plan,
-                  "b t e, b t e, n e, b t n, b t n, o e "
-                  "-> b t e, b c n e",
-                  need_replication=("t", "n", "o", "c"))
+    return _build(body, plan)
 
 
 @functools.lru_cache(maxsize=None)
 def selective_scan_bwd(k: int):
     SS = _mod("selective_scan")
 
-    def fn(ctx, u, delta, At, B, C, h0, dy):
+    def body(caxes, u, delta, At, B, C, h0, dy):
         stats["selective_scan_bwd:kernel"] += 1
         du, ddt, dB, dC, dA_part = SS._bwd_call(u, delta, At, B, C, h0,
                                                 dy, k)
-        caxes = ctx if ctx is not None else ()
         if caxes:
             # dB/dC reduce over channels; with channels sharded each
             # shard holds a partial sum
@@ -716,61 +629,40 @@ def selective_scan_bwd(k: int):
             dC = jax.lax.psum(dC, caxes)
         return du, ddt, dB, dC, dA_part
 
-    def plan(mesh, arg_shapes):
-        b, e = _ss_plan(mesh, arg_shapes)
+    def plan(mesh, args):
+        b, e = _ss_plan(mesh, args)
         te = P(b, None, e)
         tn = P(b, None, None)
-        args = (te, te, P(None, e), tn, tn, P(b, None, None, e), te)
-        outs = (te, te, tn, tn, P(b, None, e))
-        return args, outs, _axes(e)
+        arg_specs = (te, te, P(None, e), tn, tn, P(b, None, None, e), te)
+        return arg_specs, (te, te, tn, tn, P(b, None, e)), _axes(e)
 
-    return _build(fn, plan,
-                  "b t e, b t e, n e, b t n, b t n, b c n e, b t e "
-                  "-> b t e, b t e, b t n, b t n, b n e",
-                  need_replication=("t", "n", "c"))
+    return _build(body, plan)
 
 
 # ---------------------------------------------------------------------------
 # decode attention (serving): shard over batch + kv heads
 # ---------------------------------------------------------------------------
 
-def _decode_plan(mesh, arg_shapes):
-    """args: (sp [2], q2 [B,Hq,D], kn2 [B,Hkv,D], vn2, kc [L,B,Hkv,S,D],
-    vc, [ks [L,B,Hkv,S], vs]). Shard batch + heads (whole GQA groups);
-    layer/seq/head_dim and the scalar-prefetch vector replicated."""
-    B, Hq = arg_shapes[1].shape[0], arg_shapes[1].shape[1]
-    Hkv = arg_shapes[2].shape[1]
-    qspec = _spec_entries(_sharding_of(arg_shapes[1]), 3)
-    cspec = _spec_entries(_sharding_of(arg_shapes[4]), 5)
-    return _batch_head_plan(mesh, B, Hq, Hkv, qspec[0] or cspec[1],
-                            qspec[1] or cspec[2])
-
-
 @functools.lru_cache(maxsize=None)
-def decode_attn(scale: float, group: int, quantized: bool):
+def decode_attn(scale: float, quantized: bool):
+    """args: (sp [2], q2 [B,Hq,D], kn2 [B,Hkv,D], vn2, kc [L,B,Hkv,S,D],
+    vc, [ks [L,B,Hkv,S], vs]). Batch over the data axes, heads over tp
+    (whole GQA groups); layer/seq/head_dim and the scalar-prefetch
+    vector replicated."""
     DA = _mod("decode_attention")
 
-    def fn(ctx, sp, q2, kn2, vn2, *cache):
+    def body(ctx, sp, q2, kn2, vn2, *cache):
         stats["decode_attn:kernel"] += 1
         return DA.raw_call(sp, q2, kn2, vn2, *cache, scale=scale)
 
-    def plan(mesh, arg_shapes):
-        b, h = _decode_plan(mesh, arg_shapes)
-        q_like = P(b, h, None)
-        kv_like = P(b, h, None)
-        c_like = P(None, b, h, None, None)
-        args = [P(None), q_like, kv_like, kv_like, c_like, c_like]
+    def plan(mesh, args):
+        B, Hq = args[1].shape[0], args[1].shape[1]
+        b, h = _batch_head_plan(mesh, B, Hq, args[2].shape[1])
+        qkv = P(b, h, None)
+        arg_specs = [P(None), qkv, qkv, qkv,
+                     P(None, b, h, None, None), P(None, b, h, None, None)]
         if quantized:
-            args += [P(None, b, h, None), P(None, b, h, None)]
-        return tuple(args), (q_like,), None
+            arg_specs += [P(None, b, h, None), P(None, b, h, None)]
+        return tuple(arg_specs), qkv, None
 
-    hq = "(h g)" if group > 1 else "h"
-    if quantized:
-        rule = (f"z, b {hq} d, b h d, b h d, l b h s d, l b h s d, "
-                f"l b h s, l b h s -> b {hq} d")
-    else:
-        rule = (f"z, b {hq} d, b h d, b h d, l b h s d, l b h s d "
-                f"-> b {hq} d")
-    return _build(fn, plan, rule,
-                  need_replication=("z", "d", "l", "s"),
-                  factor_sizes=({"g": group} if group > 1 else None))
+    return _build(body, plan)

@@ -27,11 +27,8 @@ def single_device() -> bool:
 
 def _manual_axes():
     """(any_manual, all_manual) over the ambient abstract mesh axes."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return False, False
-    if am is None or not am.shape:
+    am = jax.sharding.get_abstract_mesh()
+    if not am.shape:
         return False, False
     manual = [t == jax.sharding.AxisType.Manual for t in am.axis_types]
     return any(manual), all(manual)
@@ -41,13 +38,13 @@ def dispatch_mode() -> str:
     """How the kernel set should dispatch at this trace point.
 
     - ``"off"`` — stay on the jnp path (not on TPU, or inside a
-      partially-manual shard_map where neither raw local shapes nor
-      custom_partitioning are safe).
+      partially-manual shard_map where neither raw local shapes nor a
+      nested shard_map unit are safe).
     - ``"raw"`` — call pallas directly: single-device jit, or inside a
       fully-manual shard_map where shapes are already per-device (the
       Ulysses local-attention case).
     - ``"partitioned"`` — multi-device mesh under the automatic
-      partitioner: route through the custom_partitioning wrappers
+      partitioner: route through the shard_map units
       (``ops/pallas/_partition.py``) so the kernel runs per shard. This
       is what the reference gets from launching its fused CUDA kernels
       per device under ``framework/parallel_executor.cc:504``.
@@ -71,9 +68,7 @@ def compiler_params(**kwargs):
         return None
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 class force_interpret:

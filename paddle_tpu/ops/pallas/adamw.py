@@ -91,6 +91,7 @@ def adamw_update(p, m, v, g, *, lr, beta1=0.9, beta2=0.999, eps=1e-8,
         ],
         input_output_aliases={1: 0, 2: 1, 3: 2},
         interpret=_support.interpret(),
+        name="ptpu_adamw",
     )(scalars, p2, m2, v2, g2)
 
     def un2d(x, dt):

@@ -2,8 +2,8 @@
 
 The serving hot loop: every generated token attends its one query
 against the filled prefix of the per-layer cache. The XLA einsum path
-pays three taxes this kernel deletes (all measured on the v5e bench
-geometry, BASELINE.md decode table):
+pays three taxes this kernel deletes (the timings below date from an
+earlier build and are not measured on the current code):
 
 - the per-layer ``lax.scan`` slice of the stacked cache materializes a
   full layer copy per layer per step (XLA cannot fuse a dynamic-slice
@@ -32,11 +32,14 @@ feeding ``inference/api/analysis_predictor.h``); inference-only, no VJP.
 
 Batching: the GenerationEngine's fused decode step invokes this kernel
 under ``jax.vmap`` (one mapped axis per engine slot, per-slot caches
-and fill positions). jax's pallas batching rule lowers that by growing
-the grid, and ``tests/test_decode_attention.py`` pins the behavior
-(vmapped output bit-equal to per-slot calls, interpret mode) along with
-the off-TPU einsum fallback arm — the engine's dispatch is explicit,
-not incidental.
+and fill positions). The per-slot ``index`` is a scalar-prefetch
+operand, and jax's pallas batching rule lowers a batched scalar-prefetch
+operand as an explicit loop over the mapped axis (one kernel call per
+slot on a dynamic slice of that slot's cache), not by growing the grid.
+``tests/test_decode_attention.py`` pins the behavior (vmapped output
+bit-equal to per-slot calls, interpret mode) along with the off-TPU
+einsum fallback arm; ``chip_smoke.py`` compiles and checks it on the
+chip.
 """
 
 from __future__ import annotations
@@ -65,12 +68,11 @@ def supported(q, cache) -> bool:
     """Kernel gate; callers fall back to the einsum path when False.
     Decode chunks only (T == 1); prefill always takes the flash path.
     ``cache`` holds the STACKED buffers ([L, B, Hkv, S, D]). Under a
-    multi-device mesh the custom_partitioning wrapper
-    (``_partition.decode_attn``) runs the kernel per batch/head shard —
-    TP-sharded serving keeps the kernel path (tp must divide
-    num_kv_heads, the same constraint correct Megatron attention
-    sharding already imposes; a larger tp fails inside jax's sharding
-    conversion before any fallback can intercept)."""
+    multi-device mesh the shard_map unit (``_partition.decode_attn``)
+    runs the kernel per batch/head shard — TP-sharded serving keeps the
+    kernel path when tp divides num_kv_heads (the constraint
+    ``shard_for_inference`` already validates); otherwise the kernel
+    runs on replicated heads."""
     mode = _support.dispatch_mode()
     if mode not in ("raw", "partitioned"):
         return False
@@ -201,7 +203,7 @@ def decode_attention(q, k_new, v_new, cache, layer, index, *, scale: float):
 
     if _support.dispatch_mode() == "partitioned":
         from paddle_tpu.ops.pallas import _partition
-        out = _partition.decode_attn(float(scale), G, quantized)(
+        out = _partition.decode_attn(float(scale), quantized)(
             sp, q2, kn2, vn2, *cache)
     else:
         out = raw_call(sp, q2, kn2, vn2, *cache, scale=scale)
@@ -263,4 +265,5 @@ def raw_call(sp, q2, kn2, vn2, *cache, scale: float):
         compiler_params=_support.compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_support.interpret(),
+        name="ptpu_decode_attn",
     )(sp, *args)

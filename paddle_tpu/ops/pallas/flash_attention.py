@@ -164,6 +164,7 @@ def _fwd(qt, kt, vt, causal, scale, block_q, block_k):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_support.interpret(),
+        name="ptpu_flash_fwd",
     )(qt, kt, vt)
     return o, lse
 
@@ -290,6 +291,7 @@ def _bwd_impl(qt, kt, vt, ot, lse, do_t, causal, scale, block_q, block_k):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_support.interpret(),
+        name="ptpu_flash_bwd_dq",
     )(qt, kt, vt, do_t, lse, dta)
 
     # dkv grid order: (b, h, ik, iq) — q blocks innermost
@@ -323,6 +325,7 @@ def _bwd_impl(qt, kt, vt, ot, lse, do_t, causal, scale, block_q, block_k):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_support.interpret(),
+        name="ptpu_flash_bwd_dkv",
     )(qt, kt, vt, do_t, lse, dta)
 
     if group > 1:
@@ -340,9 +343,8 @@ def _bwd_impl(qt, kt, vt, ot, lse, do_t, causal, scale, block_q, block_k):
 def _fwd_dispatch(qt, kt, vt, causal, scale, block_q, block_k, part):
     if part:
         from paddle_tpu.ops.pallas import _partition
-        group = qt.shape[1] // kt.shape[1]
-        return _partition.flash_fwd(causal, scale, block_q, block_k,
-                                    group)(qt, kt, vt)
+        return _partition.flash_fwd(causal, scale, block_q, block_k)(
+            qt, kt, vt)
     return _fwd(qt, kt, vt, causal, scale, block_q, block_k)
 
 
@@ -361,9 +363,8 @@ def _flash_bwd(causal, scale, block_q, block_k, part, res, do):
     qt, kt, vt, o, lse = res
     if part:
         from paddle_tpu.ops.pallas import _partition
-        group = qt.shape[1] // kt.shape[1]
-        return _partition.flash_bwd(causal, scale, block_q, block_k,
-                                    group)(qt, kt, vt, o, lse, do)
+        return _partition.flash_bwd(causal, scale, block_q, block_k)(
+            qt, kt, vt, o, lse, do)
     return _bwd_impl(qt, kt, vt, o, lse, do, causal, scale, block_q, block_k)
 
 
@@ -378,7 +379,7 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None,
     ``supported(q, k, v, causal=...)`` must hold; callers are expected to
     fall back to the dense path otherwise (``nn.functional.
     scaled_dot_product_attention`` does this automatically).
-    ``partitioned`` routes both passes through custom_partitioning so the
+    ``partitioned`` routes both passes through the shard_map units so the
     kernels run per-shard (batch/head sharded, sequence replicated) under
     a multi-device mesh.
     """

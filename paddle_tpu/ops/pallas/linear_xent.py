@@ -27,8 +27,7 @@ Cost model: 10·N·E·V matmul FLOPs vs the unfused 6 (both backward
 kernels recompute their logits tile), in exchange for O(N·V) → O(N)
 loss-path HBM traffic and activation memory. At bench shapes the
 lm-head is ~7% of model FLOPs, so the ~4% FLOP overhead buys back
-gigabytes of HBM — the lever for larger batch/seq (BASELINE.md r3
-sweep: bs12/16 and seq-4096 OOM with logits resident).
+gigabytes of HBM — the lever for larger batch/seq.
 
 Alignment: E % 128 == 0, V divisible by one of the candidate vocab
 tiles, rows divisible by the row block (callers pad rows or fall back).
@@ -55,8 +54,9 @@ _BV_CANDIDATES = (1024, 896, 768, 640, 512, 384, 256, 128)
 # bytes of VMEM per vocab-tile column the kernel holds, by kernel kind:
 # fwd/dh hold the W tile (itemsize, double-buffered); dw additionally
 # holds its f32 accumulator output block (double-buffered by the
-# pipeline) — measured: bv=640 @ E=2048 compiles for fwd/dh but blows
-# VMEM for dw, bv=384 fits all three.
+# pipeline). At the headline E=2048, V=32000 bf16 these budgets pick
+# bv=640 for fwd/dh and bv=256 for dw, and all three compile under
+# libtpu 0.0.34's default scoped-VMEM limit (chip_smoke.py).
 _BUDGET_FWD = 6 * 1024 * 1024
 _BUDGET_DW = 10 * 1024 * 1024
 
@@ -215,6 +215,7 @@ def _fwd_call(hidden, weight, lab_b):
         compiler_params=_support.compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_support.interpret(),
+        name="ptpu_linear_xent_fwd",
     )(hidden, weight, lab_b)
 
 
@@ -241,6 +242,7 @@ def _dh_call(hidden, weight, lab_b, lse_b, g_b):
         compiler_params=_support.compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_support.interpret(),
+        name="ptpu_linear_xent_dh",
     )(hidden, weight, lab_b, lse_b, g_b)
 
 
@@ -267,6 +269,7 @@ def _dw_call(hidden, weight, lab_b, lse_b, g_b):
         compiler_params=_support.compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_support.interpret(),
+        name="ptpu_linear_xent_dw",
     )(hidden, weight, lab_b, lse_b, g_b)
 
 
@@ -328,7 +331,7 @@ def fused_linear_cross_entropy(hidden, weight, labels, *,
     onehot term to the gradients — combined with a zero cotangent from
     the caller's mask, ignored rows produce exactly zero grad.
 
-    ``partitioned`` routes the three kernels through custom_partitioning
+    ``partitioned`` routes the three kernels through the shard_map units
     (``_partition.flce_*``) so they run per shard on a multi-device mesh,
     including a Megatron vocab-sharded lm-head (local online lse + lse
     merge over the vocab axes, dW sharded over vocab, dH psum-reduced).
@@ -347,7 +350,7 @@ def chunked_linear_cross_entropy(hidden, weight, labels,
     recomputes each tile instead of saving it. Same O(N) loss-path
     memory as the Pallas kernel; used as the dispatch fallback for
     unsupported shapes and as the microbench competitor that keeps the
-    kernel honest (BASELINE.md's DISPATCH_MAX_V methodology)."""
+    kernel honest."""
     n, e = hidden.shape
     v = weight.shape[1]
     block_v = min(block_v, v)

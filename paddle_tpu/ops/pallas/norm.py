@@ -95,6 +95,7 @@ def _rms_fwd(x2d, w, eps):
             jax.ShapeDtypeStruct((n, _LANES), jnp.float32),
         ],
         interpret=_support.interpret(),
+        name="ptpu_rms_norm_fwd",
     )(x2d, w)
     return y, rstd
 
@@ -123,6 +124,7 @@ def _rms_bwd_call(x2d, w, rstd, g):
         compiler_params=_support.compiler_params(
             dimension_semantics=("arbitrary",)),
         interpret=_support.interpret(),
+        name="ptpu_rms_norm_bwd",
     )(x2d, w, rstd, g)
     return dx, dw
 
@@ -161,7 +163,7 @@ _rms.defvjp(_rms_vjp_fwd, _rms_vjp_bwd)
 def rms_norm(x, weight, epsilon: float = 1e-6, *, partitioned: bool = False):
     """Fused RMSNorm over the last axis. ``supported(x, weight)`` must
     hold. Matches ``nn.functional.rms_norm`` numerics (fp32 statistics).
-    ``partitioned`` routes through custom_partitioning so the kernel runs
+    ``partitioned`` routes through the shard_map unit so the kernel runs
     per-shard under a multi-device mesh."""
     n, h = _shape2d(x)
     w = weight if weight is not None else jnp.ones((h,), x.dtype)
@@ -232,6 +234,7 @@ def _ln_fwd(x2d, w, b, eps):
             jax.ShapeDtypeStruct((n, _LANES), jnp.float32),
         ],
         interpret=_support.interpret(),
+        name="ptpu_layer_norm_fwd",
     )(x2d, w, b)
 
 
@@ -280,6 +283,7 @@ def _ln_bwd_call(x2d, w, mean, rstd, g):
         compiler_params=_support.compiler_params(
             dimension_semantics=("arbitrary",)),
         interpret=_support.interpret(),
+        name="ptpu_layer_norm_bwd",
     )(x2d, w, mean, rstd, g)
     return dx, dw, db
 
@@ -300,7 +304,7 @@ _ln.defvjp(_ln_vjp_fwd, _ln_vjp_bwd)
 def layer_norm(x, weight, bias, epsilon: float = 1e-5, *,
                partitioned: bool = False):
     """Fused LayerNorm over the last axis (``supported`` must hold).
-    ``partitioned`` routes through custom_partitioning so the kernel runs
+    ``partitioned`` routes through the shard_map unit so the kernel runs
     per-shard under a multi-device mesh."""
     n, h = _shape2d(x)
     w = weight if weight is not None else jnp.ones((h,), x.dtype)

@@ -40,7 +40,7 @@ step (replacing the gather inside ``forward_with_cache``) and the TPU
 timing run are the honest remaining caveat; off-TPU callers take the
 ``paged_reference`` einsum fallback under the same ``supported()`` gate
 as the stacked kernel. Multi-device meshes fall back too (no
-custom_partitioning wrapper yet — the pool's KV-head shard would need a
+``_partition`` unit yet — the pool's KV-head shard would need a
 per-shard grid).
 """
 
@@ -231,6 +231,7 @@ def raw_call(sp, q2, kn2, vn2, *pool, scale: float):
         compiler_params=_support.compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_support.interpret(),
+        name="ptpu_paged_decode_attn",
     )(sp, *args)
 
 

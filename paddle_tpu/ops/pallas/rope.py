@@ -61,6 +61,7 @@ def _rope_call(x, cos, sin, sign):
         out_specs=pl.BlockSpec((1, 1, bt, D), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(xt.shape, x.dtype),
         interpret=_support.interpret(),
+        name="ptpu_rope",
     )(xt, cos.astype(jnp.float32), sin.astype(jnp.float32))
     return jnp.transpose(ot, (0, 2, 1, 3))
 
@@ -92,6 +93,6 @@ _rope.defvjp(_rope_fwd, _rope_bwd)
 
 def apply_rotary(x, cos, sin, *, partitioned: bool = False):
     """Fused RoPE for [B, T, H, D] x with [T, D/2] cos/sin tables.
-    ``partitioned`` routes through custom_partitioning (batch/seq/head
+    ``partitioned`` routes through the shard_map unit (batch/seq/head
     shardable; the tables shard with the sequence)."""
     return _rope(bool(partitioned), x, cos, sin)

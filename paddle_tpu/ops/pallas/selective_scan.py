@@ -141,6 +141,7 @@ def _fwd_call(u, delta, At, B, C, D2, k):
         compiler_params=_support.compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_support.interpret(),
+        name="ptpu_selective_scan_fwd",
     )(u, delta, At, B, C, D2)
     return y, h0
 
@@ -254,6 +255,7 @@ def _bwd_call(u, delta, At, B, C, h0, dy, k):
         compiler_params=_support.compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_support.interpret(),
+        name="ptpu_selective_scan_bwd",
     )(u, delta, At, B, C, h0, dy)
     # reduce the per-lane-block partials over the channel-block dim
     return du, ddt, jnp.sum(dB_blocks, axis=1), jnp.sum(dC_blocks, axis=1), \
@@ -306,7 +308,7 @@ def selective_scan(u, delta, A, B, C, D, chunk: int | None = None, *,
     """Fused selective scan; same contract as
     ``models.mamba.selective_scan`` (u:[B,T,Ei] Δ:[B,T,Ei] A:[Ei,N]
     B,C:[B,T,N] D:[Ei] → y:[B,T,Ei]). ``supported(...)`` must hold.
-    ``partitioned`` routes through custom_partitioning (batch/channel
+    ``partitioned`` routes through the shard_map unit (batch/channel
     shardable; time sequential, replicated)."""
     k = _chunk(u.shape[1], chunk)
     y = _scan(k, bool(partitioned), u.astype(jnp.float32),

@@ -104,6 +104,7 @@ def _lse_call(logits):
         compiler_params=_support.compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_support.interpret(),
+        name="ptpu_softmax_xent_lse",
     )(logits)
     return lse
 
@@ -126,6 +127,7 @@ def _dx_call(logits, lse_b, g_b):
         compiler_params=_support.compiler_params(
             dimension_semantics=("parallel", "parallel")),
         interpret=_support.interpret(),
+        name="ptpu_softmax_xent_dx",
     )(logits, lse_b, g_b)
 
 
@@ -173,7 +175,7 @@ _sce.defvjp(_sce_fwd, _sce_bwd)
 def softmax_cross_entropy(logits, labels, *, partitioned: bool = False):
     """Per-row loss ``lse(logits) - logits[labels]`` for [N, V] logits and
     int [N] labels. ``supported(logits, labels)`` must hold.
-    ``partitioned`` routes the kernels through custom_partitioning so they
+    ``partitioned`` routes the kernels through the shard_map units so they
     run per-shard under a multi-device mesh (including a Megatron-style
     vocab-sharded lm head: local lse + log-sum-exp combine over the vocab
     axes)."""

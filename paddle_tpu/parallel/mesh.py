@@ -17,6 +17,7 @@ model.
 from __future__ import annotations
 
 import math
+import threading
 from typing import Sequence
 
 import jax
@@ -31,7 +32,12 @@ AXIS_ORDER = ("pp", "dp", "fsdp", "ep", "sp", "tp")
 # of the batch is what turns parameter sharding into ZeRO-3 semantics
 BATCH_AXES = ("dp", "fsdp")
 
+# process-wide default (``set_mesh`` / ``fleet.init``) and the per-thread
+# ``MeshContext`` override: serving engines trace their entry points on
+# their own loop threads, each under its own mesh, concurrently with the
+# main thread
 _current_mesh: Mesh | None = None
+_scoped = threading.local()
 
 
 def create_mesh(degrees: dict[str, int] | None = None,
@@ -119,34 +125,35 @@ def set_mesh(mesh: Mesh) -> None:
 
 
 def get_mesh() -> Mesh:
-    if _current_mesh is None:
+    mesh = current_mesh()
+    if mesh is None:
         raise RuntimeError(
             "no active mesh: call parallel.set_mesh / fleet.init first")
-    return _current_mesh
+    return mesh
 
 
 def current_mesh() -> Mesh | None:
-    """The ambient mesh, or None if none has been set."""
-    return _current_mesh
+    """The ambient mesh — this thread's innermost ``MeshContext``, else
+    the ``set_mesh`` default — or None if none has been set."""
+    mesh = getattr(_scoped, "mesh", None)
+    return mesh if mesh is not None else _current_mesh
 
 
 class MeshContext:
-    """``with MeshContext(mesh):`` — sets the ambient mesh (and jax's
-    ``set_mesh`` if available) for the block."""
+    """``with MeshContext(mesh):`` — sets the ambient mesh for the block
+    on the calling thread."""
 
     def __init__(self, mesh: Mesh):
         self.mesh = mesh
         self._prev = None
 
     def __enter__(self):
-        global _current_mesh
-        self._prev = _current_mesh
-        _current_mesh = self.mesh
+        self._prev = getattr(_scoped, "mesh", None)
+        _scoped.mesh = self.mesh
         return self.mesh
 
     def __exit__(self, *exc):
-        global _current_mesh
-        _current_mesh = self._prev
+        _scoped.mesh = self._prev
         return False
 
 

@@ -44,11 +44,8 @@ def global_positions(t_local: int, axis: str = "sp"):
     tests/repros/shardy_nested_manual_sp.py) each shard holds the
     ``axis_index``-th sequence slice, so positions offset by rank —
     RoPE and other position encodings stay globally correct."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except Exception:
-        am = None
-    if am is not None and am.shape and axis in am.shape:
+    am = jax.sharding.get_abstract_mesh()
+    if axis in am.shape:
         types = dict(zip(am.axis_names, am.axis_types))
         if types[axis] == jax.sharding.AxisType.Manual:
             return lax.axis_index(axis) * t_local + jnp.arange(t_local)

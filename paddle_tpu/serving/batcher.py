@@ -7,8 +7,7 @@ requests for the same model are queued, coalesced up to
 of waiting, run as ONE ``Predictor.run`` over the concatenated batch,
 and split back per caller. On a TPU (and under XLA's per-call dispatch
 overhead generally) one run of ``k`` rows costs far less than ``k`` runs
-of one row — this is the serving-throughput lever the batch-frontier
-numbers in ``BASELINE.md`` measure device-side, applied across the wire.
+of one row — the device-side batching lever, applied across the wire.
 
 Mechanics:
 

@@ -118,6 +118,7 @@ serving ``health`` op.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import random as _random_mod
 import threading
@@ -866,17 +867,15 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        model = self._model
-
-        def one(cache, tok, idx, key, temp, top_k, top_p):
+        def one(model, cache, tok, idx, key, temp, top_k, top_p):
             logits, cache = model.forward_with_cache(
                 tok[None, None], cache, index=idx)
             key, sub = jax.random.split(key)
             nxt = _sample_slot(logits[0, -1], sub, temp, top_k, top_p)
             return cache, nxt, key
 
-        def step(state, active):
-            cache, nxt, keys = jax.vmap(one)(
+        def step(model, state, active):
+            cache, nxt, keys = jax.vmap(functools.partial(one, model))(
                 state["cache"], state["tok"], state["pos"], state["keys"],
                 state["temp"], state["top_k"], state["top_p"])
             tok = jnp.where(active, nxt, state["tok"])
@@ -884,7 +883,7 @@ class GenerationEngine:
             return dict(state, cache=cache, tok=tok, pos=pos,
                         keys=keys), tok
 
-        return self._layout.jit_entry(step, self._state,
+        return self._layout.jit_entry(step, self._model, self._state,
                                       paged=False, n_in=1, n_out=1)
 
     def _build_prefill(self):
@@ -895,9 +894,10 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        model, S, cache_dtype = self._model, self.max_len, self._cache_dtype
+        S, cache_dtype = self.max_len, self._cache_dtype
 
-        def prefill(state, slot, padded, true_len, key, temp, top_k, top_p):
+        def prefill(model, state, slot, padded, true_len, key, temp, top_k,
+                    top_p):
             b1 = model.init_cache(1, S, dtype=cache_dtype)
             logits, b1 = model.forward_with_cache(padded[None], b1,
                                                   index=0)
@@ -917,7 +917,7 @@ class GenerationEngine:
                 top_p=state["top_p"].at[slot].set(top_p),
             ), tok0
 
-        return self._layout.jit_entry(prefill, self._state,
+        return self._layout.jit_entry(prefill, self._model, self._state,
                                       paged=False, n_in=7, n_out=1)
 
     def _build_paged_step(self):
@@ -933,10 +933,10 @@ class GenerationEngine:
 
         from paddle_tpu.models.generation import paged_gather
 
-        model, P, maxp = self._model, self._page_tokens, self._maxp
+        P, maxp = self._page_tokens, self._maxp
         slots = self.slots
 
-        def one(pt_row, tok, idx, key, temp, top_k, top_p, pool):
+        def one(model, pt_row, tok, idx, key, temp, top_k, top_p, pool):
             cache = paged_gather(pool, pt_row)
             logits, cache = model.forward_with_cache(
                 tok[None, None], cache, index=idx)
@@ -947,10 +947,11 @@ class GenerationEngine:
             nxt = _sample_slot(logits[0, -1], sub, temp, top_k, top_p)
             return nxt, key, new
 
-        def step(state, pt, active):
+        def step(model, state, pt, active):
             pool = state["cache"]
             nxt, keys, new = jax.vmap(
-                one, in_axes=(0, 0, 0, 0, 0, 0, 0, None))(
+                functools.partial(one, model),
+                in_axes=(0, 0, 0, 0, 0, 0, 0, None))(
                 pt, state["tok"], state["pos"], state["keys"],
                 state["temp"], state["top_k"], state["top_p"], pool)
             pidx = jnp.clip(state["pos"] // P, 0, maxp - 1)
@@ -964,7 +965,7 @@ class GenerationEngine:
             return dict(state, cache=pool, tok=tok, pos=pos,
                         keys=keys), tok
 
-        return self._layout.jit_entry(step, self._state,
+        return self._layout.jit_entry(step, self._model, self._state,
                                       paged=True, n_in=2, n_out=1)
 
     def _build_paged_prefill(self):
@@ -981,10 +982,10 @@ class GenerationEngine:
 
         from paddle_tpu.models.generation import paged_gather, paged_scatter
 
-        model, P = self._model, self._page_tokens
+        P = self._page_tokens
 
-        def prefill(state, pt, slot, padded, index, true_len, key, temp,
-                    top_k, top_p):
+        def prefill(model, state, pt, slot, padded, index, true_len, key,
+                    temp, top_k, top_p):
             pool = state["cache"]
             row = pt[slot]
             cache = paged_gather(pool, row)
@@ -1010,7 +1011,7 @@ class GenerationEngine:
                 top_p=state["top_p"].at[slot].set(top_p),
             ), tok0
 
-        return self._layout.jit_entry(prefill, self._state,
+        return self._layout.jit_entry(prefill, self._model, self._state,
                                       paged=True, n_in=9, n_out=1)
 
     def _spec_pick_accept(self, jax, jnp, logits, key, temp, top_k, top_p,
@@ -1057,9 +1058,8 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        model, slots = self._model, self.slots
-
-        def one(cache, tok, idx, key, temp, top_k, top_p, draft, dlen):
+        def one(model, cache, tok, idx, key, temp, top_k, top_p, draft,
+                dlen):
             ids = jnp.concatenate([tok[None], draft])[None]   # [1, K+1]
             logits, cache = model.forward_with_cache(ids, cache,
                                                      index=idx)
@@ -1068,8 +1068,8 @@ class GenerationEngine:
                 dlen)
             return cache, out, emit, new_key
 
-        def step(state, active, drafts, dlens):
-            cache, out, emit, keys = jax.vmap(one)(
+        def step(model, state, active, drafts, dlens):
+            cache, out, emit, keys = jax.vmap(functools.partial(one, model))(
                 state["cache"], state["tok"], state["pos"], state["keys"],
                 state["temp"], state["top_k"], state["top_p"], drafts,
                 dlens)
@@ -1081,7 +1081,7 @@ class GenerationEngine:
             return dict(state, cache=cache, tok=tok, pos=pos,
                         keys=keys), out, emit
 
-        return self._layout.jit_entry(step, self._state,
+        return self._layout.jit_entry(step, self._model, self._state,
                                       paged=False, n_in=3, n_out=2)
 
     def _build_paged_spec_step(self):
@@ -1096,11 +1096,11 @@ class GenerationEngine:
 
         from paddle_tpu.models.generation import paged_gather
 
-        model, P, maxp = self._model, self._page_tokens, self._maxp
+        P, maxp = self._page_tokens, self._maxp
         K = self._spec_k
 
-        def one(pt_row, tok, idx, key, temp, top_k, top_p, draft, dlen,
-                pool):
+        def one(model, pt_row, tok, idx, key, temp, top_k, top_p, draft,
+                dlen, pool):
             cache = paged_gather(pool, pt_row)
             ids = jnp.concatenate([tok[None], draft])[None]
             logits, cache = model.forward_with_cache(ids, cache,
@@ -1113,10 +1113,11 @@ class GenerationEngine:
                 dlen)
             return out, emit, new_key, chunk
 
-        def step(state, pt, active, drafts, dlens):
+        def step(model, state, pt, active, drafts, dlens):
             pool = state["cache"]
             out, emit, keys, chunks = jax.vmap(
-                one, in_axes=(0,) * 9 + (None,))(
+                functools.partial(one, model),
+                in_axes=(0,) * 9 + (None,))(
                 pt, state["tok"], state["pos"], state["keys"],
                 state["temp"], state["top_k"], state["top_p"], drafts,
                 dlens, pool)
@@ -1140,7 +1141,7 @@ class GenerationEngine:
             return dict(state, cache=pool, tok=tok, pos=pos1,
                         keys=keys), out, emit
 
-        return self._layout.jit_entry(step, self._state,
+        return self._layout.jit_entry(step, self._model, self._state,
                                       paged=True, n_in=4, n_out=2)
 
     # -- drafters (host side) ----------------------------------------------
@@ -1183,9 +1184,9 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        draft, K, dtype = self._draft_model, self._spec_k, self._cache_dtype
+        K, dtype = self._spec_k, self._cache_dtype
 
-        def fn(padded, true_len):
+        def fn(draft, padded, true_len):
             cache = draft.init_cache(1, bucket + K, dtype=dtype)
             logits, cache = draft.forward_with_cache(padded[None], cache,
                                                      index=0)
@@ -1203,13 +1204,40 @@ class GenerationEngine:
             out, _ = jax.lax.fori_loop(1, K, body, (out0, cache))
             return out
 
-        return self._layout.jit_aux(fn, n_in=2)
+        return self._layout.jit_aux(fn, self._draft_model, n_in=2)
 
     def _bucket(self, n: int) -> int:
         b = self._min_bucket
         while b < n:
             b *= 2
         return min(b, self.max_len)
+
+    def lowered_text(self, prompt_len: int) -> dict[str, str]:
+        """StableHLO text of the two compiled entry points a request of
+        ``prompt_len`` tokens runs — ``{"prefill": ..., "decode": ...}``
+        (the prefill at that length's bucket, the fused decode step) —
+        lowered from the live state's shapes and shardings. Nothing
+        executes and nothing is donated; call it on an idle engine (the
+        loop thread owns the state). Pallas kernels appear in the text
+        under their ``name=``, which is how ``chip_smoke.py`` checks
+        what the engine really dispatches."""
+        import jax
+        import jax.numpy as jnp
+
+        i32 = jnp.zeros((), jnp.int32)
+        f32 = jnp.zeros((), jnp.float32)
+        sampling = (jax.random.PRNGKey(0), f32, i32, f32)
+        padded = jnp.zeros((self._bucket(int(prompt_len)),), jnp.int32)
+        active = jnp.zeros((self.slots,), bool)
+        if self._paged:
+            pt = jnp.asarray(self._pt)
+            prefill = (self._state, pt, i32, padded, i32, i32, *sampling)
+            decode = (self._state, pt, active)
+        else:
+            prefill = (self._state, i32, padded, i32, *sampling)
+            decode = (self._state, active)
+        return {"prefill": self._prefill_fn.lower(*prefill).as_text(),
+                "decode": self._step.lower(*decode).as_text()}
 
     # -- stream-lifecycle tracing + compile observability -------------------
     def _gen_span(self, gen: Generation, name: str, **attrs):

@@ -4,7 +4,7 @@ The :class:`~paddle_tpu.serving.engine.GenerationEngine` owns a pile of
 device state (the batched KV cache or page pool, per-slot token/
 position/key/sampling arrays) and a set of compiled entry points
 (bucketed prefill, fused decode, speculative verify, draft lookahead)
-that thread that state through ``donate_argnums=(0,)``. This module
+that thread that state through donation. This module
 puts ALL of that behind one object so the engine itself never touches
 ``jax.sharding``:
 
@@ -39,9 +39,25 @@ in ``tests/test_sharded_gen.py`` (``pytest -m sharded``).
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
-__all__ = ["DeviceLayout"]
+__all__ = ["DeviceLayout", "BoundEntry"]
+
+
+class BoundEntry:
+    """A jitted engine entry point with the model bound as its leading
+    operand: callers pass (and lower with) everything after it."""
+
+    def __init__(self, jitted, model):
+        self._jitted = jitted
+        self._model = model
+
+    def __call__(self, *operands):
+        return self._jitted(self._model, *operands)
+
+    def lower(self, *operands):
+        return self._jitted.lower(self._model, *operands)
 
 
 class DeviceLayout:
@@ -141,34 +157,60 @@ class DeviceLayout:
                               self.state_sharding(state, paged=paged))
 
     # -- compilation -------------------------------------------------------
-    def jit_entry(self, fn, state: dict, *, paged: bool, n_in: int,
-                  n_out: int, donate: tuple = (0,)):
-        """Compile an engine entry point whose FIRST argument and FIRST
-        result are the engine state (donated), with ``n_in`` extra
-        operands and ``n_out`` extra results, all replicated. Identity
-        layout: plain ``jax.jit`` — bit-identical compiled surface to
-        the pre-sharding build. Sharded: explicit in/out shardings pin
-        the state to the KV-head split so the SPMD partitioner places
-        the collectives inside the step instead of resharding at the
-        call boundary."""
+    def jit_entry(self, fn, model, state: dict, *, paged: bool, n_in: int,
+                  n_out: int):
+        """Compile an engine entry point ``fn(model, state, *operands)
+        -> (state, *results)``: the state is donated and threads
+        through, with ``n_in`` extra operands and ``n_out`` extra
+        results, all replicated. Identity layout: plain ``jax.jit``.
+        Sharded: explicit in/out shardings pin the state to the KV-head
+        split so the SPMD partitioner places the collectives inside the
+        step instead of resharding at the call boundary (the model
+        keeps the sharding ``shard_model`` committed it to).
+
+        The model is an OPERAND, bound into the returned
+        :class:`BoundEntry` — never a closed-over value: jit embeds
+        closed-over arrays in the program as constants, so every bucket
+        program would carry (and hold in device memory) its own copy of
+        the weights."""
         import jax
         if self.mesh is None:
-            return jax.jit(fn, donate_argnums=donate)
+            return BoundEntry(jax.jit(fn, donate_argnums=(1,)), model)
         st = self.state_sharding(state, paged=paged)
         rep = self.replicated
-        return jax.jit(fn, donate_argnums=donate,
-                       in_shardings=(st,) + (rep,) * n_in,
-                       out_shardings=(st,) + (rep,) * n_out)
+        return BoundEntry(
+            jax.jit(self._under_mesh(fn), donate_argnums=(1,),
+                    in_shardings=(None, st) + (rep,) * n_in,
+                    out_shardings=(st,) + (rep,) * n_out), model)
 
-    def jit_aux(self, fn, *, n_in: int, n_out: int = 1):
-        """Compile a stateless helper (the draft-model lookahead):
-        replicated in/out on the mesh, plain ``jax.jit`` otherwise."""
+    def jit_aux(self, fn, model, *, n_in: int, n_out: int = 1):
+        """Compile a stateless helper ``fn(model, *operands)`` (the
+        draft-model lookahead): replicated in/out on the mesh, plain
+        ``jax.jit`` otherwise; the model bound as in :meth:`jit_entry`."""
         import jax
         if self.mesh is None:
-            return jax.jit(fn)
+            return BoundEntry(jax.jit(fn), model)
         rep = self.replicated
         out = rep if n_out == 1 else (rep,) * n_out
-        return jax.jit(fn, in_shardings=(rep,) * n_in, out_shardings=out)
+        return BoundEntry(
+            jax.jit(self._under_mesh(fn),
+                    in_shardings=(None,) + (rep,) * n_in,
+                    out_shardings=out), model)
+
+    def _under_mesh(self, fn):
+        """Trace ``fn`` with this layout's mesh ambient (the engine's
+        loop thread traces on first call): the Pallas kernel set reads
+        it to dispatch through its shard_map units instead
+        of dropping a raw single-device kernel into an SPMD program."""
+        from paddle_tpu.parallel.mesh import MeshContext
+        mesh = self.mesh
+
+        @functools.wraps(fn)
+        def traced(*args):
+            with MeshContext(mesh):
+                return fn(*args)
+
+        return traced
 
     # -- observability -----------------------------------------------------
     def describe(self, kv_bytes: int) -> dict:

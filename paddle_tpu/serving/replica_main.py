@@ -46,8 +46,6 @@ import signal
 import sys
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
@@ -183,8 +181,11 @@ def main(argv: list[str] | None = None) -> int:
                 os.environ.get("XLA_FLAGS", "") +
                 f" --xla_force_host_platform_device_count={n}").strip()
 
+    from paddle_tpu.core.compile_cache import enable_compile_cache
     from paddle_tpu.core.flags import flag, set_flags
     from paddle_tpu.io.serving import InferenceServer
+
+    enable_compile_cache()
 
     # running as ``python -m`` imports the paddle_tpu package (and
     # with it the flag registry) BEFORE main() runs, so an env export

@@ -394,10 +394,10 @@ class PPYOLOE(Module):
         (backbone/neck/head convs — the FLOPs) rides an ambient
         ``amp.auto_cast``; decode, TAL assignment (top-k/IoU) and the
         VFL/DFL/GIoU losses below are pinned fp32 via ``amp.suspend``.
-        Whole-model autocast measured 15× SLOWER than fp32 on a v5e
-        (BASELINE.md r3): per-op cast boundaries inside the assignment
-        break XLA fusion; the head outputs are small, so casting once
-        here is free."""
+        Whole-model autocast was far slower than fp32 on an earlier
+        build (not measured on the current code): per-op cast
+        boundaries inside the assignment break XLA fusion; the head
+        outputs are small, so casting once here is free."""
         from paddle_tpu import amp as _amp
 
         cls_logits, reg_dist, points, strides = self(

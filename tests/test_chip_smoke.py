@@ -1,0 +1,115 @@
+"""``chip_smoke.py`` off the chip: its phase functions at
+``LlamaConfig.tiny`` on the CPU (the device-only assertions — kernel
+names, dispatch mode — parameterised off), its refusal to report
+anything without a TPU, and the compile-cache placement rule its entry
+point applies."""
+
+import os
+import subprocess
+import sys
+
+import jax
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke as cs  # noqa: E402
+from paddle_tpu.core import compile_cache  # noqa: E402
+from paddle_tpu.models import LlamaConfig  # noqa: E402
+
+
+def _tiny_requests(vocab):
+    # one prefill bucket (32) keeps the compile count down; the last two
+    # prompts share their first page
+    return cs.make_requests(vocab, (20, 18, 22, 17, 24, 24),
+                            (4, 3, 4, 3, 4, 4), shared_prefix=16)
+
+
+def test_train_and_serve_phases_tiny():
+    cfg = LlamaConfig.tiny(max_seq_len=64, lm_head_mode="fused")
+    model, train = cs.train_phase(cfg, batch=2, seq=32, steps=3,
+                                  on_chip=False)
+    assert train["losses"][-1] < train["losses"][0]
+    serve = cs.serve_phase(model, _tiny_requests(cfg.vocab_size), slots=4,
+                           max_len=64, on_chip=False)
+    assert serve["contiguous"]["streams"] == serve["paged"]["streams"] == 6
+    assert serve["paged"]["pages"]["prefix_entries"] >= 1
+    assert serve["paged_equals_contiguous"]
+    # on the CPU (f32) the repeat through the prefix cache and solo
+    # generate() are byte-identical to the engine's greedy stream; on
+    # the chip the smoke reports both without gating on them
+    assert serve["paged"]["probe_repeat"] == {
+        "through_prefix_cache_agrees_for": "4/4 tokens",
+        "same_programs_identical": True}
+    assert serve["engine_agrees_with_solo_generate_for"] == {
+        "contiguous": "4/4 tokens", "paged": "4/4 tokens"}
+
+
+@pytest.mark.slow
+def test_four_device_phases_tiny():
+    """The >= 4-chip branch (ZeRO-3 x4 train, mesh_tp=4 serve) on the
+    virtual CPU mesh."""
+    cfg = LlamaConfig.tiny(max_seq_len=64, num_heads=4, num_kv_heads=4,
+                           lm_head_mode="fused")
+    model, train = cs.train_phase(cfg, batch=4, seq=32, steps=3, chips=4,
+                                  on_chip=False)
+    assert train["losses"][-1] < train["losses"][0]
+    serve = cs.serve_phase(model, _tiny_requests(cfg.vocab_size), slots=4,
+                           max_len=64, mesh_tp=4, on_chip=False)
+    assert serve["paged"]["device"]["mesh"] == {"tp": 4}
+
+
+def test_smoke_check_failure_is_an_error():
+    with pytest.raises(cs.SmokeFailure, match="page pool leaked"):
+        cs._check_generator(
+            "g", {"broken": None, "stuck": False, "rebuilds": 0,
+                  "quarantined": 0, "active": 0, "queued": 0, "paged": True,
+                  "pages": 8, "pages_free": 6, "prefix_entries": 1,
+                  "device": {"platform": "cpu", "devices": 1}},
+            platform="cpu", devices=1)
+
+
+def test_main_refuses_to_run_without_a_tpu():
+    proc = subprocess.run(
+        [sys.executable, "chip_smoke.py"], cwd=REPO, capture_output=True,
+        text=True, timeout=120, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode != 0
+    assert "no TPU" in proc.stderr and "'cpu'" in proc.stderr
+    assert proc.stdout == ""          # no result line off the chip
+
+
+def test_bench_refuses_a_device_without_a_recorded_peak():
+    import bench
+
+    def device(kind, platform):
+        return type("Device", (), {"device_kind": kind,
+                                   "platform": platform})()
+
+    assert bench.detect_peak_flops(device("TPU v5 lite", "tpu")) == 197e12
+    assert bench.detect_peak_bandwidth(device("TPU v5 lite", "tpu")) == 819e9
+    for detect in (bench.detect_peak_flops, bench.detect_peak_bandwidth):
+        with pytest.raises(ValueError, match="device_kind 'cpu'"):
+            detect(device("cpu", "cpu"))
+
+
+def test_compile_cache_placement(monkeypatch):
+    dir_before = jax.config.jax_compilation_cache_dir
+    secs_before = jax.config.jax_persistent_cache_min_compile_time_secs
+    try:
+        # placed from outside: jax reads the variable, nothing set in code
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        assert compile_cache.enable_compile_cache() == "/some/dir"
+        assert jax.config.jax_compilation_cache_dir == dir_before
+        # not placed: the fixed in-checkout path
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert compile_cache.enable_compile_cache() == os.path.join(
+            REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            REPO, ".jax_cache")
+        # small bucket programs are admitted
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        jax.config.update("jax_compilation_cache_dir", dir_before)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          secs_before)

@@ -252,7 +252,7 @@ def test_engine_fused_decode_dispatch_is_explicit(monkeypatch):
 def test_partitioned_kernel_under_tp_mesh(devices8, cache_dtype):
     """TP-sharded serving keeps the kernel: under a tp2 mesh with
     force_dispatch, generate() routes decode steps through the
-    custom_partitioning wrapper (per-shard kernels, stats prove it) and
+    shard_map unit (per-shard kernels, stats prove it) and
     reproduces the single-device tokens exactly — bf16 and int8 cache
     layouts (scales shard with the heads). Shapes sized to the kernel
     gate (prompt 120 + 8 new = S 128, D=64)."""

@@ -191,7 +191,7 @@ def test_llama_loss_fused_path_matches_dense():
 
 
 class TestPartitioned:
-    """custom_partitioning dispatch on the virtual 8-device mesh: rows
+    """shard_map dispatch on the virtual 8-device mesh: rows
     sharded over (dp, fsdp), vocab sharded Megatron-style over tp —
     numerics must match the unsharded dense reference, and the kernel
     (not the fallback) must have lowered when shapes align."""
@@ -199,8 +199,11 @@ class TestPartitioned:
     @pytest.fixture
     def mesh(self, devices8):
         from jax.sharding import Mesh
-        return Mesh(np.array(devices8).reshape(2, 2, 2),
+        from paddle_tpu.parallel.mesh import MeshContext
+        mesh = Mesh(np.array(devices8).reshape(2, 2, 2),
                     ("dp", "fsdp", "tp"))
+        with MeshContext(mesh):       # the units plan from the ambient mesh
+            yield mesh
 
     @pytest.mark.parametrize("aligned", [True, False])
     def test_vocab_sharded_matches_dense(self, mesh, aligned):

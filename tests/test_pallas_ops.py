@@ -259,7 +259,7 @@ def test_dispatch_wrappers_forced(monkeypatch):
 
 def test_dispatch_mode_under_multidevice_mesh(devices8):
     """Under a >1-device mesh the kernel set dispatches through the
-    custom_partitioning wrappers (mode 'partitioned'); single device goes
+    shard_map units (mode 'partitioned'); single device goes
     straight to pallas ('raw'); off-TPU without the force flag stays on
     the jnp path ('off')."""
     from paddle_tpu.parallel import mesh as M

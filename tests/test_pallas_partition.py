@@ -1,8 +1,9 @@
-"""Multi-chip Pallas dispatch: the custom_partitioning wrappers must run
-the kernels per-shard under a multi-device mesh with numerics matching the
-jnp reference — the analogue of the reference's fused CUDA kernels running
-under the multi-device executor (``fused/multihead_matmul_op.cu`` per
-device via ``framework/parallel_executor.cc:504``).
+"""Multi-chip Pallas dispatch: the shard_map units (``_partition``) must
+run the kernels per-shard under the ambient multi-device mesh with
+numerics matching the jnp reference — the analogue of the reference's
+fused CUDA kernels running under the multi-device executor
+(``fused/multihead_matmul_op.cu`` per device via
+``framework/parallel_executor.cc:504``).
 
 Everything runs interpreted on the virtual 8-device CPU mesh
 (``_support.force_dispatch``), exactly the way the multichip dryrun
@@ -22,13 +23,18 @@ from paddle_tpu.ops.pallas import _partition, _support
 from paddle_tpu.ops.pallas import norm as NORM
 from paddle_tpu.ops.pallas import softmax_xent as SX
 from paddle_tpu.ops.pallas import rope as RP
+from paddle_tpu.parallel.mesh import MeshContext
 
 FA = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 
 
 @pytest.fixture
 def mesh222(devices8):
-    return Mesh(np.array(devices8).reshape(2, 2, 2), ("dp", "fsdp", "tp"))
+    """dp2 x fsdp2 x tp2, ambient for the test (the units plan their
+    shardings from the ambient mesh)."""
+    mesh = Mesh(np.array(devices8).reshape(2, 2, 2), ("dp", "fsdp", "tp"))
+    with MeshContext(mesh):
+        yield mesh
 
 
 def put(mesh, x, *spec):
@@ -158,19 +164,20 @@ def test_partitioned_xent_vocab_sharded(mesh222):
                                rtol=1e-4, atol=1e-5)
 
 
-def test_partitioned_rope_seq_sharded(mesh222):
-    """Sequence sharded: the cos/sin tables shard with it so every shard
-    rotates by its own absolute positions."""
+def test_partitioned_rope_seq_sharded(devices8):
+    """Sequence sharded over sp: the cos/sin tables shard with it so
+    every shard rotates by its own absolute positions."""
+    mesh = Mesh(np.array(devices8).reshape(2, 2, 2), ("dp", "sp", "tp"))
     rs = np.random.RandomState(3)
     x = rs.randn(4, 256, 4, 64).astype(np.float32)
     ang = np.arange(256)[:, None] * (0.1 + np.arange(32)[None, :] / 32)
     cos = np.cos(ang).astype(np.float32)
     sin = np.sin(ang).astype(np.float32)
-    xs = put(mesh222, x, "dp", "fsdp", None, None)
-    cs = put(mesh222, cos, "fsdp", None)
-    ss = put(mesh222, sin, "fsdp", None)
+    xs = put(mesh, x, "dp", "sp", None, None)
+    cs = put(mesh, cos, "sp", None)
+    ss = put(mesh, sin, "sp", None)
 
-    with _support.force_dispatch():
+    with MeshContext(mesh), _support.force_dispatch():
         _partition.reset_stats()
 
         def loss(x, c, s):
@@ -204,7 +211,7 @@ def test_partitioned_misaligned_shard_falls_back(mesh222):
     xs = jax.device_put(jnp.asarray(x), NamedSharding(mesh, P("dp", None)))
     ws = jax.device_put(jnp.asarray(w), NamedSharding(mesh, P(None)))
 
-    with _support.force_dispatch():
+    with MeshContext(mesh), _support.force_dispatch():
         _partition.reset_stats()
         y = jax.jit(lambda x, w: NORM.rms_norm(x, w, partitioned=True))(
             xs, ws)

@@ -119,8 +119,9 @@ def test_mamba_block_dispatches_kernel(monkeypatch):
 
 
 def test_partitioned_selective_scan(devices8):
-    """Batch over dp and channels over tp: the custom_partitioning path
-    must match the reference with grads."""
+    """Batch over dp and channels over tp: the shard_map path must
+    match the reference with grads."""
+    from paddle_tpu.parallel.mesh import MeshContext
     mesh = Mesh(np.array(devices8).reshape(4, 2), ("dp", "tp"))
     args = make_inputs(Bsz=4, T=16, Ei=256, N=8)
     u = jax.device_put(args[0], NamedSharding(mesh, P("dp", None, "tp")))
@@ -131,7 +132,7 @@ def test_partitioned_selective_scan(devices8):
                                          partitioned=True) ** 2)
 
     grad_args = tuple(range(6))  # incl. dB/dC: channel-sharded partials
-    with _support.force_dispatch():
+    with MeshContext(mesh), _support.force_dispatch():
         _partition.reset_stats()
         val, gs = jax.jit(jax.value_and_grad(
             loss_k, argnums=grad_args))(u, *rest)
