@@ -621,8 +621,7 @@ def _on_trace(v) -> None:
 def _on_trace_buffer(v) -> None:
     from paddle_tpu.core import trace
 
-    if trace.enabled():            # live resize; keeps the newest spans
-        trace.configure(True, capacity=int(v))
+    trace.resize(int(v))           # live resize; keeps the newest spans
 
 
 def _on_log_json(v) -> None:
@@ -631,26 +630,20 @@ def _on_log_json(v) -> None:
     logging_mod.set_json(bool(v))
 
 
-# trace_buffer must be defined BEFORE trace: trace.configure reads it when
-# a FLAGS_trace env var fires on_set during this import.
 define_flag("trace_buffer", 4096,
             "Span ring-buffer capacity for the in-process tracer "
-            "(core/trace.py); oldest spans are evicted first",
+            "(core/trace.py); oldest spans are evicted first and "
+            "counted as dropped",
             on_set=_on_trace_buffer)
 define_flag("trace", False,
-            "Record framework spans (wire round-trips incl. cross-wire "
-            "trace-id propagation, PS ops, checkpoint save/load, train "
-            "epochs, serving predicts) into an in-process ring buffer "
-            "with per-op latency histograms. Hard-off default: the wire "
-            "fast path pays a single flag check",
+            "Record framework spans (engine-loop phases, train steps, "
+            "wire round-trips incl. cross-wire trace-id propagation, PS "
+            "ops, checkpoint save/load, serving predicts) into the "
+            "in-process ring buffer. All but the per-message wire spans "
+            "are also recorded, flag or no flag, while a jax.profiler "
+            "capture is live. Hard-off default: the hot paths pay one "
+            "check",
             on_set=_on_trace)
-define_flag("trace_sample", 0,
-            "Per-iteration stream-trace sampling: with tracing on, emit "
-            "a gen/decode_sample span for every Nth decoded token of a "
-            "stream that carries a stream trace id (N = this value). "
-            "0 — the default — records no per-iteration spans at all; "
-            "lifecycle events (admitted/prefill/retire) are always "
-            "recorded for traced streams")
 define_flag("log_json", False,
             "Structured logging: one JSON object per line (ts, level, "
             "msg, trace_id of the active span) instead of the human "

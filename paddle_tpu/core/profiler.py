@@ -13,8 +13,8 @@ annotations are two-sided:
 
 - ``jax.named_scope`` tags the *compiled HLO* so ops carry the training-
   step phase name in the trace (the RecordEvent-inside-op-dispatch role);
-- ``jax.profiler.TraceAnnotation`` marks *host* spans (dispatch, data
-  feed), the host-side RecordEvent role.
+- ``core.trace.span`` marks *host* spans (dispatch, data feed) as
+  ``jax.profiler.TraceAnnotation``s, the host-side RecordEvent role.
 
 ``RecordEvent`` here fuses both so one annotation covers either context.
 """
@@ -69,10 +69,11 @@ class RecordEvent:
     outside jit (reference RAII ``RecordEvent``, ``profiler.h:127``).
 
     Inside a jit trace it lowers to a named_scope (op metadata in the
-    device timeline); at host level it opens a TraceAnnotation span.
-    With ``FLAGS_trace`` on it ALSO records a ``core.trace`` span, so
-    user annotations land on the same timeline as the framework's wire/
-    checkpoint spans (the reference RecordEvent → timeline.py pipeline).
+    device timeline; the host half then times tracing, not the device).
+    At host level it is a ``core.trace`` span: a ``TraceAnnotation`` in
+    a live capture and a ring record, on the same timeline as the
+    framework's own spans (the reference RecordEvent → timeline.py
+    pipeline); a no-op while nothing records.
     """
 
     def __init__(self, name: str):
@@ -81,13 +82,10 @@ class RecordEvent:
 
     def __enter__(self):
         self._stack = contextlib.ExitStack()
-        # named_scope tags ops when tracing; TraceAnnotation spans host
-        # time when executing — entering both covers either context (the
-        # unused one is a no-op)
+        # named_scope tags ops when tracing; the span times the host
+        # when executing — entering both covers either context
         self._stack.enter_context(jax.named_scope(self.name))
-        self._stack.enter_context(jax.profiler.TraceAnnotation(self.name))
-        if _trace._ACTIVE is not None:
-            self._stack.enter_context(_trace.span(self.name))
+        self._stack.enter_context(_trace.span(self.name))
         return self
 
     def __exit__(self, *exc):

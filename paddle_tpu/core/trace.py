@@ -1,31 +1,41 @@
-"""Flag-gated in-process distributed tracing.
+"""In-process span recorder on the profiler's clock.
 
 Reference role: the RAII ``RecordEvent`` span stack of
 ``paddle/fluid/platform/profiler.h:127,209`` plus the Chrome-trace
 exporter ``tools/timeline.py:273`` — but framework-level rather than
-CUPTI-level: spans cover the *system* paths jax.profiler cannot see
-(wire round-trips, PS ops, checkpoint uploads, retries/sheds), and a
-trace id crosses the wire so one client request yields a joined
-client→server timeline.
+CUPTI-level: spans cover the *system* paths the device trace cannot see
+(the engine loop's phases, the train step's host side, wire round-trips,
+PS ops, checkpoint uploads, retries/sheds), and a trace id crosses the
+wire so one client request yields a joined client→server timeline.
 
 Design constraints, in order:
 
-1. **Hard-off zero overhead.** ``FLAGS_trace`` defaults off and the hot
-   paths guard on ``_ACTIVE is not None`` — a single module-attribute
-   read, the same pattern as ``core.fault``. :func:`span` itself returns
-   a shared no-op object when disabled, so non-hot call sites can use it
-   unconditionally.
-2. **Bounded memory.** Spans land in a thread-safe ring buffer
-   (``FLAGS_trace_buffer`` entries); a forgotten-enabled tracer can
-   never grow without bound.
-3. **Wire-portable.** A span is a plain JSON-safe dict; the wire
+1. **Recording follows the profiler.** Spans record while
+   ``FLAGS_trace`` is on (the operator's switch) *or* a
+   ``jax.profiler`` capture is live — whoever captures a profile of a
+   process gets its spans with no flag to remember. :func:`recording`
+   is that predicate; off, it costs one attribute read and one
+   ``TraceAnnotation.is_enabled()`` (~0.1 µs), and :func:`span` returns
+   a shared no-op object.
+2. **One clock with the device.** An open span is also a
+   ``jax.profiler.TraceAnnotation`` carrying its attributes and
+   ``span_id``: in a capture it is an event of its thread's line in the
+   ``/host:CPU`` plane, beside the device planes. The ring record keeps
+   ``ts`` from the realtime clock the profiler stamps with and ``dur``
+   from the monotonic one.
+3. **Bounded memory, outliving the capture.** Records land in one
+   process-wide ring (``FLAGS_trace_buffer`` entries) that is read
+   after the capture has ended (:func:`get_spans`, :func:`snapshot`)
+   and emptied by :func:`clear`; what it had to evict it counts
+   (``dropped``).
+4. **Wire-portable.** A span is a plain JSON-safe dict; the wire
    ``trace_dump`` op (``core/wire.py``) ships them to remote scrapers
    and ``tools/obs_dump.py`` merges multiple services into one
    Chrome/Perfetto timeline by trace id.
 
 Usage::
 
-    set_flags({"trace": True})
+    set_flags({"trace": True})            # or: inside jax.profiler.trace(...)
     with trace.span("train/epoch", epoch=3):
         ...
     trace.export_chrome("timeline.json")      # chrome://tracing / Perfetto
@@ -34,6 +44,14 @@ Cross-process linkage: the client side stamps its ``trace_id``/``span_id``
 into the request header; the server opens :func:`server_span` with those
 ids, so both halves share one trace id and the server span's parent is
 the client span.
+
+Compiles: one listener on jax's own compile event keeps a per-thread
+count (:func:`thread_compiles`) from which a caller learns whether the
+call it just made built a program.
+
+The per-message wire paths (``core/wire.py``) trace on the operator's
+switch alone (:func:`flag_on`): a capture of a serving replica would
+otherwise be mostly its clients' polls.
 """
 
 from __future__ import annotations
@@ -45,23 +63,30 @@ import time
 from collections import deque
 from typing import Any
 
+import jax
+from jax.profiler import TraceAnnotation as _Annotation
+
 from paddle_tpu.core.flags import flag
 
-__all__ = ["span", "server_span", "enabled", "configure", "current",
-           "get_spans", "clear", "snapshot", "export_chrome",
-           "to_chrome_events", "new_id"]
+__all__ = ["span", "server_span", "recording", "flag_on", "configure",
+           "resize", "current", "get_spans", "clear", "snapshot",
+           "export_chrome", "to_chrome_events", "new_id",
+           "thread_compiles"]
 
 
-class _Tracer:
-    """Thread-safe span ring buffer."""
+class _Ring:
+    """Thread-safe span ring buffer that counts what it evicts."""
 
     def __init__(self, capacity: int):
-        self.capacity = int(capacity)
+        self.capacity = max(int(capacity), 1)
+        self.dropped = 0
         self._lock = threading.Lock()
-        self._buf: deque[dict] = deque(maxlen=max(self.capacity, 1))
+        self._buf: deque[dict] = deque(maxlen=self.capacity)
 
     def record(self, span_dict: dict) -> None:
         with self._lock:
+            if len(self._buf) == self.capacity:
+                self.dropped += 1
             self._buf.append(span_dict)
 
     def spans(self) -> list[dict]:
@@ -71,40 +96,55 @@ class _Tracer:
     def clear(self) -> None:
         with self._lock:
             self._buf.clear()
+            self.dropped = 0
+
+    def resize(self, capacity: int) -> None:
+        """Keep the newest spans that still fit (shrinking evicts only
+        the oldest tail, and counts it)."""
+        with self._lock:
+            self.capacity = max(int(capacity), 1)
+            self.dropped += max(len(self._buf) - self.capacity, 0)
+            self._buf = deque(self._buf, maxlen=self.capacity)
 
 
-# None == tracing fully off; hot paths gate on this single attribute read
-# (the core.fault._ACTIVE pattern).
-_ACTIVE: _Tracer | None = None
-_lock = threading.Lock()
-_ctx = threading.local()          # per-thread stack of (trace_id, span_id)
+def _flag_capacity() -> int:
+    try:
+        return int(flag("trace_buffer"))
+    except KeyError:               # flag not registered yet (import order)
+        return 4096
 
 
-def configure(enable: bool, capacity: int | None = None) -> None:
-    """(Re)configure tracing; wired to ``FLAGS_trace``. Resizing a live
-    tracer keeps the newest buffered spans that still fit the new
-    capacity (shrinking drops only the oldest tail)."""
-    global _ACTIVE
-    with _lock:
-        if not enable:
-            _ACTIVE = None
-            return
-        if capacity is None:
-            try:
-                capacity = int(flag("trace_buffer"))
-            except KeyError:       # flag not registered yet (import order)
-                capacity = 4096
-        tracer = _Tracer(capacity)
-        old = _ACTIVE
-        if old is not None:
-            # deque(maxlen=capacity) keeps the newest tail automatically
-            with old._lock:
-                tracer._buf.extend(old._buf)
-        _ACTIVE = tracer
+_RING = _Ring(_flag_capacity())
+_FLAG_ON = False                  # FLAGS_trace, set through configure()
+_ctx = threading.local()          # per-thread (trace_id, span_id) stack
+_capture_live = _Annotation.is_enabled
 
 
-def enabled() -> bool:
-    return _ACTIVE is not None
+def recording() -> bool:
+    """True while spans are recorded: ``FLAGS_trace`` is on or a
+    ``jax.profiler`` capture is live. The hot paths' only guard."""
+    return _FLAG_ON or _capture_live()
+
+
+def flag_on() -> bool:
+    """True while ``FLAGS_trace`` itself is on, capture or no capture:
+    the guard of the per-message wire paths."""
+    return _FLAG_ON
+
+
+def configure(enable: bool) -> None:
+    """Wired to ``FLAGS_trace``. Switching the flag off empties the
+    ring, as it always did; a capture's spans stay until
+    :func:`clear`."""
+    global _FLAG_ON
+    if _FLAG_ON and not enable:
+        _RING.clear()
+    _FLAG_ON = bool(enable)
+
+
+def resize(capacity: int) -> None:
+    """Wired to ``FLAGS_trace_buffer``: live resize of the ring."""
+    _RING.resize(capacity)
 
 
 def new_id() -> str:
@@ -118,8 +158,8 @@ def current() -> tuple[str, str] | None:
 
 
 class _NoopSpan:
-    """What :func:`span` returns while tracing is off: every operation a
-    no-op, shared singleton (no per-call allocation)."""
+    """What :func:`span` returns while nothing records: every operation
+    a no-op, shared singleton (no per-call allocation)."""
 
     __slots__ = ()
     trace_id = None
@@ -139,10 +179,12 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    """One open span; records itself into the ring buffer on exit."""
+    """One open span: a ``TraceAnnotation`` while it is open, a ring
+    record on exit. ``t0``/``t1`` (``perf_counter_ns``) are its two
+    clock reads, for a caller that times the same section."""
 
     __slots__ = ("name", "attrs", "trace_id", "span_id", "parent_id",
-                 "_ts", "_t0")
+                 "t0", "t1", "_ts", "_ann")
 
     def __init__(self, name: str, attrs: dict,
                  trace_id: str | None = None,
@@ -161,7 +203,8 @@ class _Span:
 
     def set(self, **attrs) -> None:
         """Attach attributes to an open span (e.g. retry counts known
-        only at the end of the operation)."""
+        only at the end of the operation); they reach the ring record,
+        not the annotation, which took its own on entry."""
         self.attrs.update(attrs)
 
     def __enter__(self):
@@ -169,32 +212,40 @@ class _Span:
         if stack is None:
             stack = _ctx.stack = []
         stack.append((self.trace_id, self.span_id))
-        self._ts = time.time()             # wall clock: cross-host merge
-        self._t0 = time.perf_counter()     # monotonic: exact duration
+        self._ann = _Annotation(self.name, span_id=self.span_id,
+                                **self.attrs)
+        self._ann.__enter__()
+        self._ts = time.time_ns()          # realtime: the profiler's clock
+        self.t0 = time.perf_counter_ns()   # monotonic: exact duration
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dur = time.perf_counter() - self._t0
+        self.t1 = time.perf_counter_ns()
+        self._ann.__exit__(exc_type, exc, tb)
         stack = getattr(_ctx, "stack", None)
         if stack:
             stack.pop()
-        tracer = _ACTIVE
-        if tracer is not None:             # disabled mid-span: drop it
+        if recording():                    # ended mid-span: drop it
             if exc_type is not None:
                 self.attrs["error"] = exc_type.__name__
-            tracer.record({
-                "name": self.name, "ts": self._ts, "dur": dur,
-                "tid": threading.get_ident(), "trace_id": self.trace_id,
-                "span_id": self.span_id, "parent_id": self.parent_id,
-                "attrs": self.attrs})
+            _record(self.name, self._ts * 1e-9, (self.t1 - self.t0) * 1e-9,
+                    self.trace_id, self.span_id, self.parent_id, self.attrs)
         return False
+
+
+def _record(name: str, ts: float, dur: float, trace_id: str, span_id: str,
+            parent_id: str | None, attrs: dict) -> None:
+    _RING.record({"name": name, "ts": ts, "dur": dur,
+                  "tid": threading.get_ident(), "trace_id": trace_id,
+                  "span_id": span_id, "parent_id": parent_id,
+                  "attrs": attrs})
 
 
 def span(name: str, **attrs: Any):
     """Open a span: ``with trace.span("ckpt/save", step=3): ...``.
-    Returns a shared no-op when tracing is off — safe (and cheap) to
+    Returns a shared no-op while nothing records — safe (and cheap) to
     call unconditionally outside the per-request hot paths."""
-    if _ACTIVE is None:
+    if not recording():
         return _NOOP
     return _Span(name, attrs)
 
@@ -204,32 +255,49 @@ def server_span(name: str, trace_id: str | None, parent_id: str | None,
     """Open a span linked to a remote parent (the server half of a wire
     round-trip). ``trace_id=None`` (untraced client) starts a fresh
     trace, so a traced server still records its side."""
-    if _ACTIVE is None:
+    if not recording():
         return _NOOP
     return _Span(name, attrs, trace_id=trace_id, parent_id=parent_id)
 
 
 def get_spans() -> list[dict]:
-    """Snapshot of the ring buffer (oldest first); [] when disabled."""
-    tracer = _ACTIVE
-    return tracer.spans() if tracer is not None else []
+    """Snapshot of the ring (oldest first), recording or not."""
+    return _RING.spans()
 
 
 def clear() -> None:
-    tracer = _ACTIVE
-    if tracer is not None:
-        tracer.clear()
+    _RING.clear()
 
 
 def snapshot(clear_after: bool = False) -> dict:
     """JSON-safe dump for the wire ``trace_dump`` op and obs_dump."""
-    tracer = _ACTIVE
-    if tracer is None:
-        return {"enabled": False, "spans": []}
-    spans = tracer.spans()
+    doc = {"enabled": recording(), "capacity": _RING.capacity,
+           "dropped": _RING.dropped, "spans": _RING.spans()}
     if clear_after:
-        tracer.clear()
-    return {"enabled": True, "capacity": tracer.capacity, "spans": spans}
+        _RING.clear()
+    return doc
+
+
+# ---------------------------------------------------------------------------
+# compiles, from jax's own events
+# ---------------------------------------------------------------------------
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+def thread_compiles() -> int:
+    """Programs this thread has built so far (compiled, or loaded from
+    the persistent cache). jax compiles on the calling thread, so a
+    change across a call means that call built one."""
+    return getattr(_ctx, "compiles", 0)
+
+
+def _on_duration(event: str, seconds: float, **_) -> None:
+    if event == _BACKEND_COMPILE:
+        _ctx.compiles = thread_compiles() + 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 # ---------------------------------------------------------------------------

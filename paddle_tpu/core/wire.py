@@ -225,7 +225,7 @@ class FrameService:
                             outer._shed_frame(sock, reason)
                             continue
                         try:
-                            if _trace._ACTIVE is not None:
+                            if _trace.flag_on():
                                 keep = outer._traced_dispatch(
                                     sock, op, header, payload)
                             else:
@@ -602,7 +602,7 @@ class FrameClient:
             # client span covers the whole logical request including
             # retries, and its ids ride the header so the server links
             # its span into the same trace.
-            if _trace._ACTIVE is not None:
+            if _trace.flag_on():
                 return self._traced_request(op, opnum, header, payload,
                                             idempotent, timeout)
             return self._request_inner(op, opnum, header, payload,

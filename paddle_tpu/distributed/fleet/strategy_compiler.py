@@ -29,8 +29,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu import amp as amp_mod
 from paddle_tpu.core import flags as flags_mod
+from paddle_tpu.core import monitor
 from paddle_tpu.core import rng
-from paddle_tpu.core.profiler import RecordEvent
+from paddle_tpu.core import trace as _trace
 from paddle_tpu.core.module import apply_updates, trainable_mask
 from paddle_tpu.core.strategy import DistributedStrategy
 from paddle_tpu.nn.stateful import map_modules
@@ -329,7 +330,7 @@ def build_train_step(model, optimizer, loss_fn=None, *,
                     head_loss_fn=pipe_head_loss,
                     loss_denom_fn=pipe_loss_denom)
 
-            with RecordEvent("forward_backward"):
+            with jax.named_scope("forward_backward"):
                 if amp_enabled:
                     # the VJP of cast_model is just the reverse cast
                     # (transpose of convert), applied by hand: grads land
@@ -375,7 +376,7 @@ def build_train_step(model, optimizer, loss_fn=None, *,
                         tape.items()}
                 return grads, loss, tape
 
-            with RecordEvent("forward_backward"):
+            with jax.named_scope("forward_backward"):
                 grads, loss, tape = shard_map(
                     local_grads, mesh=mesh, in_specs=(P(), data_specs),
                     out_specs=(P(), P(), P()), check_vma=False)(model, batch)
@@ -385,7 +386,7 @@ def build_train_step(model, optimizer, loss_fn=None, *,
         else:
             grad_fn = jax.value_and_grad(
                 lambda m: compute_loss(m, batch), has_aux=True)
-            with RecordEvent("forward_backward"):
+            with jax.named_scope("forward_backward"):
                 (_, (loss, tape)), grads = grad_fn(model)
             grads, all_finite = (scaler.unscale(grads, state.scaler)
                                  if use_scaler else
@@ -409,7 +410,7 @@ def build_train_step(model, optimizer, loss_fn=None, *,
             do_apply = jnp.asarray(True)
             eff = grads
 
-        with RecordEvent("optimizer_update"):
+        with jax.named_scope("optimizer_update"):
             updates, new_opt = optimizer.update(eff, state.opt_state, model)
             apply_gate = jnp.logical_and(do_apply, all_finite)
             updates = jax.tree_util.tree_map(
@@ -513,9 +514,11 @@ class CompiledTrainStep:
     def shard_batch(self, batch):
         """Place a host batch onto the mesh (dp+fsdp over the batch dim) —
         the data-feed split of the reference's trainers."""
-        shardings = jax.tree_util.tree_map(
-            lambda x: NamedSharding(self._mesh, self._data_spec_fn(x)), batch)
-        return jax.device_put(batch, shardings)
+        with _trace.span("train/shard_batch"):
+            shardings = jax.tree_util.tree_map(
+                lambda x: NamedSharding(self._mesh, self._data_spec_fn(x)),
+                batch)
+            return jax.device_put(batch, shardings)
 
     def _build_jit(self, state, batch):
         """The production jit wiring (shardings + donation) — shared by
@@ -557,7 +560,15 @@ class CompiledTrainStep:
             key = rng.next_key()
         if self._jitted is None:
             self._jitted = self._build_jit(state, batch)
-        new_state, metrics = self._jitted(state, batch, key)
+        if not _trace.recording():
+            new_state, metrics = self._jitted(state, batch, key)
+        else:
+            # the host side of one step: dispatch, and the compile when
+            # jax built a program during the call (which step recompiled)
+            with _trace.span("train/step") as sp:
+                built = _trace.thread_compiles()
+                new_state, metrics = self._jitted(state, batch, key)
+                sp.set(compiled=int(_trace.thread_compiles() != built))
         if "check/grads_finite" in metrics:
             bad = [name for name in ("loss", "grads", "params")
                    if not bool(metrics[f"check/{name}_finite"])]
@@ -570,7 +581,6 @@ class CompiledTrainStep:
             # FLAGS_benchmark: synchronize every step so host-side timing
             # brackets real device work (reference operator.cc:1123)
             jax.block_until_ready(new_state)
-        from paddle_tpu.core import monitor
         monitor.stat_add("fleet/steps", 1)
         return new_state, metrics
 

@@ -615,7 +615,7 @@ class InferenceClient(FrameClient):
             header["eos_token_id"] = int(eos_token_id)
         if rng_skip:
             header["rng_skip"] = int(rng_skip)
-        if trace_id is None and _trace.enabled():
+        if trace_id is None and _trace.recording():
             trace_id = _trace.new_id()
         if trace_id:
             header["st"] = str(trace_id)

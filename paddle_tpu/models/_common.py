@@ -152,7 +152,8 @@ def apply_cache_writes(cache, payload, index):
         start = zeros + (idx,) + (jnp.zeros((), jnp.int32),) * (buf.ndim - 4)
         return jax.lax.dynamic_update_slice(buf, x.astype(buf.dtype), start)
 
-    return tuple(wr(b, x) for b, x in zip(cache, payload))
+    with jax.named_scope("kv/write"):
+        return tuple(wr(b, x) for b, x in zip(cache, payload))
 
 
 def init_kv_cache(num_layers, batch_size, max_len, num_kv_heads, head_dim,

@@ -141,6 +141,7 @@ def init_paged_cache(proto_cache, num_pages: int, page_tokens: int):
     return tuple(pool)
 
 
+@jax.named_scope("kv/gather")
 def paged_gather(pool, table):
     """Materialize a sequence's contiguous cache view from its page
     table (``table`` [M] int32 physical page ids; entry 0 = null page).
@@ -157,6 +158,7 @@ def paged_gather(pool, table):
     return tuple(out)
 
 
+@jax.named_scope("kv/write")
 def paged_scatter(pool, table, chunk, index, page_tokens: int,
                   length=None):
     """Write a contiguous chunk (leaves ``[L, 1, Hkv, T, *rest]``,

@@ -125,6 +125,7 @@ class LlamaAttention(Module):
         # "none" | "ring" | "ulysses"
         self.seq_mode = "none"
 
+    @jax.named_scope("attn")
     def __call__(self, x, positions=None, cache=None, index=None,
                  layer=0, training: bool = False):
         """Forward. ``cache``/``index``/``layer`` enable incremental

@@ -223,6 +223,7 @@ class MoEMLP(Module):
             return "einsum"
         return "gather"
 
+    @jax.named_scope("moe/experts")
     def _experts(self, expert_in):
         sg = getattr(self, "w_gate_scale", None)
         if sg is not None:
@@ -252,8 +253,11 @@ class MoEMLP(Module):
         tokens = x.reshape(n, h)
         cap = self.capacity(n)
 
-        # router in fp32 for stable softmax (standard MoE practice)
-        logits = tokens.astype(jnp.float32) @ self.router
+        # router in fp32 for stable softmax (standard MoE practice).
+        # The moe/* scopes name the block's four stages in the device
+        # trace, which otherwise shows only fusions.
+        with jax.named_scope("moe/route"):
+            logits = tokens.astype(jnp.float32) @ self.router
 
         mode = self._resolved_mode()
         if mode == "gather":
@@ -267,56 +271,63 @@ class MoEMLP(Module):
         return out.reshape(b, t, h), aux.astype(jnp.float32)
 
     def _call_einsum(self, tokens, logits, n, h, cap):
-        dispatch, combine, aux = top_k_routing(logits, self.top_k, cap)
-        dispatch = dispatch.astype(tokens.dtype)
-        combine = combine.astype(tokens.dtype)
+        with jax.named_scope("moe/route"):
+            dispatch, combine, aux = top_k_routing(logits, self.top_k, cap)
+            dispatch = dispatch.astype(tokens.dtype)
+            combine = combine.astype(tokens.dtype)
 
         # dispatch: [N,H] x [N,E,C] -> [E,C,H]; the sharding constraint
         # makes the XLA partitioner materialize the ep all_to_all here
-        expert_in = jnp.einsum("nh,nec->ech", tokens, dispatch)
-        expert_in = _constrain(expert_in, P("ep", None, None))
+        with jax.named_scope("moe/dispatch"):
+            expert_in = jnp.einsum("nh,nec->ech", tokens, dispatch)
+            expert_in = _constrain(expert_in, P("ep", None, None))
 
         expert_out = self._experts(expert_in)
         expert_out = _constrain(expert_out, P("ep", None, None))
 
         # combine (the return all_to_all): [E,C,H] x [N,E,C] -> [N,H]
-        out = jnp.einsum("ech,nec->nh", expert_out, combine)
+        with jax.named_scope("moe/combine"):
+            out = jnp.einsum("ech,nec->nh", expert_out, combine)
         return out, aux
 
     def _call_gather(self, tokens, logits, n, h, cap):
         e, k = self.num_experts, self.top_k
-        expert, slot, keep, gate, aux = top_k_routing_compact(
-            logits, k, cap)
+        with jax.named_scope("moe/route"):
+            expert, slot, keep, gate, aux = top_k_routing_compact(
+                logits, k, cap)
 
-        # flat destination slot per (token, pick); dropped picks land in
-        # an out-of-bounds trash slot (served by fill-mode gathers below)
-        dest = jnp.where(keep, expert * cap + slot, e * cap)      # [N, k]
-        # inverse map slot→token: a tiny int scatter (destinations are
-        # unique by construction except the shared trash slot); the
-        # out-of-bounds sentinel n marks unfilled slots
-        src = jnp.full((e * cap + 1,), n, jnp.int32)
-        tok_idx = jnp.broadcast_to(
-            jnp.arange(n, dtype=jnp.int32)[:, None], (n, k))
-        src = src.at[dest.reshape(-1)].set(tok_idx.reshape(-1))
+        with jax.named_scope("moe/dispatch"):
+            # flat destination slot per (token, pick); dropped picks land in
+            # an out-of-bounds trash slot (served by fill-mode gathers below)
+            dest = jnp.where(keep, expert * cap + slot, e * cap)      # [N, k]
+            # inverse map slot→token: a tiny int scatter (destinations are
+            # unique by construction except the shared trash slot); the
+            # out-of-bounds sentinel n marks unfilled slots
+            src = jnp.full((e * cap + 1,), n, jnp.int32)
+            tok_idx = jnp.broadcast_to(
+                jnp.arange(n, dtype=jnp.int32)[:, None], (n, k))
+            src = src.at[dest.reshape(-1)].set(tok_idx.reshape(-1))
 
-        # pack expert inputs with one row gather (embedding-lookup
-        # pattern; backward is the scatter-add of embedding grads).
-        # mode="fill" zero-fills the sentinel rows without materializing
-        # a padded copy of the token buffer, and its transpose drops the
-        # out-of-bounds cotangents
-        expert_in = jnp.take(tokens, src[:e * cap], axis=0,
-                             mode="fill", fill_value=0).reshape(e, cap, h)
-        expert_in = _constrain(expert_in, P("ep", None, None))
+            # pack expert inputs with one row gather (embedding-lookup
+            # pattern; backward is the scatter-add of embedding grads).
+            # mode="fill" zero-fills the sentinel rows without materializing
+            # a padded copy of the token buffer, and its transpose drops the
+            # out-of-bounds cotangents
+            expert_in = jnp.take(tokens, src[:e * cap], axis=0,
+                                 mode="fill", fill_value=0).reshape(e, cap, h)
+            expert_in = _constrain(expert_in, P("ep", None, None))
 
         expert_out = self._experts(expert_in)
         expert_out = _constrain(expert_out, P("ep", None, None))
 
-        # combine: k row gathers + gate-weighted sum (the trash slot is
-        # out of bounds → zero-filled, and its gate is already zero)
-        picked = jnp.take(expert_out.reshape(e * cap, h), dest.reshape(-1),
-                          axis=0, mode="fill",
-                          fill_value=0).reshape(n, k, h)
-        out = jnp.sum(picked * gate.astype(tokens.dtype)[..., None], axis=1)
+        with jax.named_scope("moe/combine"):
+            # combine: k row gathers + gate-weighted sum (the trash slot is
+            # out of bounds → zero-filled, and its gate is already zero)
+            picked = jnp.take(expert_out.reshape(e * cap, h), dest.reshape(-1),
+                              axis=0, mode="fill",
+                              fill_value=0).reshape(n, k, h)
+            out = jnp.sum(picked * gate.astype(tokens.dtype)[..., None],
+                          axis=1)
         return out, aux
 
     def _groups(self, n: int) -> int:
@@ -352,41 +363,44 @@ class MoEMLP(Module):
         t_g = tokens.reshape(g, ng, h)
         l_g = logits.reshape(g, ng, e)
 
-        expert, slot, keep, gate, _ = jax.vmap(
-            lambda lg: top_k_routing_compact(lg, k, cg))(l_g)
-        # aux stays GLOBAL (same population as the other modes) — the
-        # grouping only changes capacity quotas, not the balance target
-        aux = _switch_aux_loss(jax.nn.softmax(logits, axis=-1))
+        with jax.named_scope("moe/route"):
+            expert, slot, keep, gate, _ = jax.vmap(
+                lambda lg: top_k_routing_compact(lg, k, cg))(l_g)
+            # aux stays GLOBAL (same population as the other modes) — the
+            # grouping only changes capacity quotas, not the balance target
+            aux = _switch_aux_loss(jax.nn.softmax(logits, axis=-1))
 
-        dest = jnp.where(keep, expert * cg + slot, e * cg)    # [G, ng, k]
-        tok_idx = jnp.broadcast_to(
-            jnp.arange(ng, dtype=jnp.int32)[None, :, None], (g, ng, k))
-        src = jnp.full((g, e * cg + 1), ng, jnp.int32)
-        src = jax.vmap(lambda s, d, t: s.at[d.reshape(-1)]
-                       .set(t.reshape(-1)))(src, dest, tok_idx)
+        with jax.named_scope("moe/dispatch"):
+            dest = jnp.where(keep, expert * cg + slot, e * cg)    # [G, ng, k]
+            tok_idx = jnp.broadcast_to(
+                jnp.arange(ng, dtype=jnp.int32)[None, :, None], (g, ng, k))
+            src = jnp.full((g, e * cg + 1), ng, jnp.int32)
+            src = jax.vmap(lambda s, d, t: s.at[d.reshape(-1)]
+                           .set(t.reshape(-1)))(src, dest, tok_idx)
 
-        packed = jax.vmap(lambda tg, sg: jnp.take(
-            tg, sg[:e * cg], axis=0, mode="fill", fill_value=0))(t_g, src)
-        from paddle_tpu.parallel.mesh import BATCH_AXES
-        packed = packed.reshape(g, e, cg, h)
-        # double-sharded staging block: each (batch-shard, ep) device
-        # holds its (group, expert-shard) tile — the constraint pair
-        # makes the partitioner emit the direct batch→ep exchange. The
-        # group axis must name ALL batch axes (groups come from
-        # dp·fsdp), or an fsdp-sharded batch gets gathered whole
-        packed = _constrain(packed, P(BATCH_AXES, "ep", None, None))
-        expert_in = packed.transpose(1, 0, 2, 3).reshape(e, g * cg, h)
-        expert_in = _constrain(expert_in, P("ep", None, None))
+            packed = jax.vmap(lambda tg, sg: jnp.take(
+                tg, sg[:e * cg], axis=0, mode="fill", fill_value=0))(t_g, src)
+            from paddle_tpu.parallel.mesh import BATCH_AXES
+            packed = packed.reshape(g, e, cg, h)
+            # double-sharded staging block: each (batch-shard, ep) device
+            # holds its (group, expert-shard) tile — the constraint pair
+            # makes the partitioner emit the direct batch→ep exchange. The
+            # group axis must name ALL batch axes (groups come from
+            # dp·fsdp), or an fsdp-sharded batch gets gathered whole
+            packed = _constrain(packed, P(BATCH_AXES, "ep", None, None))
+            expert_in = packed.transpose(1, 0, 2, 3).reshape(e, g * cg, h)
+            expert_in = _constrain(expert_in, P("ep", None, None))
 
         expert_out = self._experts(expert_in)
         expert_out = _constrain(expert_out, P("ep", None, None))
 
-        back = expert_out.reshape(e, g, cg, h).transpose(1, 0, 2, 3)
-        back = _constrain(back, P(BATCH_AXES, "ep", None, None))
-        picked = jax.vmap(lambda rows, d: jnp.take(
-            rows.reshape(e * cg, h), d.reshape(-1), axis=0, mode="fill",
-            fill_value=0))(back, dest)                  # [G, ng*k, H]
-        picked = picked.reshape(g, ng, k, h)
-        out = jnp.sum(picked * gate.astype(tokens.dtype)[..., None],
-                      axis=2)
+        with jax.named_scope("moe/combine"):
+            back = expert_out.reshape(e, g, cg, h).transpose(1, 0, 2, 3)
+            back = _constrain(back, P(BATCH_AXES, "ep", None, None))
+            picked = jax.vmap(lambda rows, d: jnp.take(
+                rows.reshape(e * cg, h), d.reshape(-1), axis=0, mode="fill",
+                fill_value=0))(back, dest)                  # [G, ng*k, H]
+            picked = picked.reshape(g, ng, k, h)
+            out = jnp.sum(picked * gate.astype(tokens.dtype)[..., None],
+                          axis=2)
         return out.reshape(n, h), aux

@@ -166,7 +166,7 @@ class DynamicBatcher:
                                chip_s=time.perf_counter() - t0)
                 return outs
             p = _Pending(inputs, rows, tenant)
-            if _trace._ACTIVE is not None:
+            if _trace.recording():
                 with _trace.span("serving/batch_wait", model=model,
                                  rows=rows):
                     self._submit(q, pred, model, p)

@@ -111,9 +111,20 @@ speculation efficiency), ``gen/tokens`` / ``gen/evictions`` /
 ``gen/prefix_hits`` / ``gen/prefix_tokens_saved`` /
 ``gen/prefix_evictions`` / ``gen/traps`` / ``gen/rebuilds`` /
 ``gen/stuck`` / ``gen/quarantined`` / ``gen/quarantine_rejected`` /
-``gen/expired_polls`` counters, ``gen/prefill`` + ``gen/prefill_chunk``
-+ ``gen/decode_step`` spans, and slot + page-pool occupancy in the
-serving ``health`` op.
+``gen/expired_polls`` counters, and slot + page-pool occupancy in the
+serving ``health`` op. Spans (``core.trace``: recorded while
+``FLAGS_trace`` is on or a ``jax.profiler`` capture is live, each a
+``TraceAnnotation`` on the capture's host plane), all on the loop
+thread and none per token: ``gen/loop`` (one iteration; ``queue``,
+``active``) is the parent of ``gen/idle_wait``, ``gen/admit`` (``gen``,
+``waited_ms``, ``prefix_tokens``, ``pages``; under it ``gen/kv_fetch``),
+``gen/dev_ops``, ``gen/prefill`` / ``gen/prefill_chunk``,
+``gen/decode_step`` (``active``, ``spec``, ``compiled``; under it
+``gen/step_dispatch`` and ``gen/step_wait``, or ``gen/spec_verify``
+around both), ``gen/draft`` and ``gen/emit`` (``emitted``,
+``retired``). One helper, ``_phase``, times each section
+with two clock reads that also feed the section's histogram and goodput
+bucket.
 """
 
 from __future__ import annotations
@@ -157,6 +168,97 @@ def _jittered(base: float) -> float:
     """``base`` scaled by U[0.5, 1.5) — the retry hint synchronized
     shed clients back off by must de-synchronize them."""
     return base * (0.5 + _jitter_rng.random())
+
+
+class _Phase:
+    """One timed section of the loop thread. Two clock reads, on entry
+    and on exit, feed everything that times the section: the span while
+    one records (the reads are then the span's own), the ``observe()``
+    histogram, and the goodput bucket while the ledger is on. ``dt``
+    (seconds) and ``t0``/``t1`` (``perf_counter_ns``) stay readable
+    after the block.
+
+    ``entry=(name, signature)`` marks a call into a compiled entry
+    point: whether THIS call built a program comes from jax's own
+    compile events on this thread (``trace.thread_compiles``), goes on
+    the span as ``compiled``, into the engine's signature book, and
+    books the section under ``recompile`` instead of its own bucket. A
+    section left by an exception records its span (with the error) and
+    feeds nothing else."""
+
+    __slots__ = ("_eng", "_span", "_bucket", "_hist", "_entry", "_built",
+                 "t0", "t1", "dt", "compiled")
+
+    def __init__(self, eng, span, bucket, hist, entry):
+        self._eng, self._span = eng, span
+        self._bucket, self._hist, self._entry = bucket, hist, entry
+        self.compiled = False
+
+    @property
+    def recording(self) -> bool:
+        """Whether this section has a span (attributes worth
+        computing)."""
+        return self._span is not _trace._NOOP
+
+    def set(self, **attrs) -> None:
+        self._span.set(**attrs)
+
+    def __enter__(self):
+        if self._entry is not None:
+            self._built = _trace.thread_compiles()
+        sp = self._span
+        if sp is _trace._NOOP:
+            self.t0 = time.perf_counter_ns()
+        else:
+            sp.__enter__()
+            self.t0 = sp.t0
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        sp = self._span
+        if self._entry is not None:
+            self.compiled = _trace.thread_compiles() != self._built
+            sp.set(compiled=int(self.compiled))
+        if sp is _trace._NOOP:
+            self.t1 = time.perf_counter_ns()
+        else:
+            sp.__exit__(exc_type, exc, tb)
+            self.t1 = sp.t1
+        self.dt = dt = (self.t1 - self.t0) * 1e-9
+        if exc_type is not None:
+            return False
+        eng = self._eng
+        if self._hist is not None:
+            observe(self._hist, dt)
+        if self._entry is not None:
+            eng._note_compile(*self._entry, dt, self.compiled)
+        if self._bucket is not None and eng._goodput is not None:
+            eng._goodput.note(
+                "recompile" if self.compiled else self._bucket, dt)
+        return False
+
+
+class _NoopPhase:
+    """What :meth:`GenerationEngine._phase` returns for a section that
+    has nothing to feed: no span records and it has no histogram, no
+    compiled entry and no goodput bucket to fill, and whose caller
+    asks for no clock read. Shared; allocates nothing, reads no
+    clock."""
+
+    __slots__ = ()
+    recording = False
+
+    def set(self, **attrs) -> None:
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NOOP_PHASE = _NoopPhase()
 
 
 def stream_fingerprint(prompt, temperature: float = 0.0, top_k: int = 0,
@@ -465,23 +567,24 @@ def _sample_slot(logits, key, temperature, top_k, top_p):
     import jax
     import jax.numpy as jnp
 
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    V = logits.shape[-1]
-    lt = logits.astype(jnp.float32) / jnp.maximum(temperature, 1e-6)
-    # top-k via the kth-largest threshold, k traced (take clamps indices)
-    asc = jnp.sort(lt, axis=-1)
-    k_eff = jnp.clip(jnp.where(top_k > 0, top_k, V), 1, V)
-    kth = jnp.take(asc, V - k_eff)
-    lt = jnp.where(lt < kth, -jnp.inf, lt)
-    # nucleus over what survived top-k (the sample_logits ordering)
-    desc = jnp.sort(lt, axis=-1)[::-1]
-    probs = jax.nn.softmax(desc)
-    cum = jnp.cumsum(probs)
-    keep = cum - probs < top_p              # always keeps the top-1
-    thr = jnp.min(jnp.where(keep, desc, jnp.inf))
-    lt = jnp.where(lt < thr, -jnp.inf, lt)
-    sampled = jax.random.categorical(key, lt).astype(jnp.int32)
-    return jnp.where(temperature <= 0.0, greedy, sampled)
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        V = logits.shape[-1]
+        lt = logits.astype(jnp.float32) / jnp.maximum(temperature, 1e-6)
+        # top-k via the kth-largest threshold, k traced (take clamps indices)
+        asc = jnp.sort(lt, axis=-1)
+        k_eff = jnp.clip(jnp.where(top_k > 0, top_k, V), 1, V)
+        kth = jnp.take(asc, V - k_eff)
+        lt = jnp.where(lt < kth, -jnp.inf, lt)
+        # nucleus over what survived top-k (the sample_logits ordering)
+        desc = jnp.sort(lt, axis=-1)[::-1]
+        probs = jax.nn.softmax(desc)
+        cum = jnp.cumsum(probs)
+        keep = cum - probs < top_p              # always keeps the top-1
+        thr = jnp.min(jnp.where(keep, desc, jnp.inf))
+        lt = jnp.where(lt < thr, -jnp.inf, lt)
+        sampled = jax.random.categorical(key, lt).astype(jnp.int32)
+        return jnp.where(temperature <= 0.0, greedy, sampled)
 
 
 class GenerationEngine:
@@ -957,9 +1060,10 @@ class GenerationEngine:
             pidx = jnp.clip(state["pos"] // P, 0, maxp - 1)
             pages = jnp.where(active, pt[jnp.arange(slots), pidx], 0)
             offs = state["pos"] % P
-            pool = tuple(
-                buf.at[pages, :, :, offs].set(n.astype(buf.dtype))
-                for buf, n in zip(pool, new))
+            with jax.named_scope("kv/write"):
+                pool = tuple(
+                    buf.at[pages, :, :, offs].set(n.astype(buf.dtype))
+                    for buf, n in zip(pool, new))
             tok = jnp.where(active, nxt, state["tok"])
             pos = state["pos"] + active.astype(jnp.int32)
             return dict(state, cache=pool, tok=tok, pos=pos,
@@ -1130,10 +1234,11 @@ class GenerationEngine:
             # position of inactive slots, emit 0) go to the null page
             pages = jnp.where(j[None, :] < emit[:, None], pages, 0)
             offs = pos % P
-            pool = tuple(
-                buf.at[pages, :, :, offs].set(
-                    jnp.moveaxis(ch, 3, 1).astype(buf.dtype))
-                for buf, ch in zip(pool, chunks))
+            with jax.named_scope("kv/write"):
+                pool = tuple(
+                    buf.at[pages, :, :, offs].set(
+                        jnp.moveaxis(ch, 3, 1).astype(buf.dtype))
+                    for buf, ch in zip(pool, chunks))
             last = jnp.take_along_axis(
                 out, jnp.maximum(emit - 1, 0)[:, None], axis=1)[:, 0]
             tok = jnp.where(active, last, state["tok"])
@@ -1164,10 +1269,9 @@ class GenerationEngine:
             fn = self._draft_fns[bucket] = self._build_draft_fn(bucket)
         padded = np.full((bucket,), self._pad, np.int32)
         padded[:T] = ctx
-        t0 = time.perf_counter()
-        out = np.asarray(fn(jnp.asarray(padded),
-                            jnp.asarray(T, jnp.int32)))
-        self._note_compile("draft", bucket, time.perf_counter() - t0)
+        with self._phase("gen/draft", entry=("draft", bucket)):
+            out = np.asarray(fn(jnp.asarray(padded),
+                                jnp.asarray(T, jnp.int32)))
         return out[:cap]
 
     def _build_draft_fn(self, bucket: int):
@@ -1220,7 +1324,9 @@ class GenerationEngine:
         executes and nothing is donated; call it on an idle engine (the
         loop thread owns the state). Pallas kernels appear in the text
         under their ``name=``, which is how ``chip_smoke.py`` checks
-        what the engine really dispatches."""
+        what the engine really dispatches; the named scopes
+        (``kv/gather``, ``kv/write``, ``sample``, the model's own) ride
+        in the operations' locations."""
         import jax
         import jax.numpy as jnp
 
@@ -1236,61 +1342,70 @@ class GenerationEngine:
         else:
             prefill = (self._state, i32, padded, i32, *sampling)
             decode = (self._state, active)
-        return {"prefill": self._prefill_fn.lower(*prefill).as_text(),
-                "decode": self._step.lower(*decode).as_text()}
+        return {"prefill": self._prefill_fn.lower(*prefill).as_text(
+                    debug_info=True),
+                "decode": self._step.lower(*decode).as_text(
+                    debug_info=True)}
 
     # -- stream-lifecycle tracing + compile observability -------------------
-    def _gen_span(self, gen: Generation, name: str, **attrs):
-        """Span for per-generation work: linked under the generation's
-        stream trace id when it carries one (the cross-replica stream
-        timeline obs_dump merges), a plain engine-local span otherwise.
-        The shared no-op when tracing is off — the unflagged path pays
-        one module-attribute read."""
-        if _trace._ACTIVE is None:
-            return _trace._NOOP
-        if gen.trace_id is not None:
-            return _trace.server_span(name, gen.trace_id, None,
+    def _phase(self, name: str, goodput: str | None = None,
+               hist: str | None = None, entry: tuple | None = None,
+               gen: Generation | None = None, clock: bool = False,
+               **attrs) -> _Phase | _NoopPhase:
+        """A timed section of the loop (:class:`_Phase`): span ``name``
+        while one records, histogram ``hist``, bucket ``goodput``.
+        ``gen`` links the span under the generation's stream trace id
+        when it carries one (the cross-replica stream timeline obs_dump
+        merges), its parent still the loop's open span. ``clock``: the
+        caller reads the section's ``t0`` / ``t1`` itself. A section
+        with nothing to feed is the shared no-op."""
+        if not _trace.recording():
+            if (not clock and hist is None and entry is None
+                    and (goodput is None or self._goodput is None)):
+                return _NOOP_PHASE
+            span = _trace._NOOP
+        elif gen is not None and gen.trace_id is not None:
+            cur = _trace.current()
+            span = _trace.server_span(name, gen.trace_id,
+                                      cur[1] if cur else None,
                                       gen=gen.gen_id, **attrs)
-        return _trace.span(name, **attrs)
+        else:
+            span = _trace.span(name, **attrs)
+        return _Phase(self, span, goodput, hist, entry)
 
     def _gen_event(self, gen: Generation, name: str, **attrs) -> None:
         """Zero-duration stream-lifecycle event (admitted / retire /
-        decode sample) recorded under the stream trace id. No-op unless
-        tracing is on AND the generation carries a stream id."""
-        if _trace._ACTIVE is None or gen.trace_id is None:
+        spec accept) recorded under the stream trace id. No-op unless
+        spans record AND the generation carries a stream id."""
+        if gen.trace_id is None or not _trace.recording():
             return
         with _trace.server_span(name, gen.trace_id, None,
                                 gen=gen.gen_id, **attrs):
             pass
 
-    def _note_compile(self, entry: str, sig, dt: float) -> bool:
-        """Bookkeep one compiled-entry-point call: the first call with a
-        new (entry, shape-signature) pair is the XLA compile (every
-        later call hits the jit cache), so ``dt`` — that call's wall
-        clock — lands in the ``gen/compile_s`` histogram. A second or
-        later signature on one entry point counts as a recompile; their
-        recent-window count is the recompile-storm gauge in
-        :meth:`stats`. After the first sight this is one set lookup.
-
-        Returns True when THIS call compiled (first sight of the pair):
-        its wall clock was compile-dominated, which the goodput meter
-        attributes to the ``recompile`` bucket instead of device work."""
+    def _note_compile(self, entry: str, sig, dt: float,
+                      compiled: bool) -> None:
+        """Bookkeep one call into a compiled entry point. The book of
+        (entry, shape-signature) pairs is what :meth:`stats` counts as
+        ``compiles``. ``compiled`` — jax built a program during THIS
+        call, by its own events — puts ``dt``, the call's wall clock,
+        into the ``gen/compile_s`` histogram; on an entry point that had
+        a program already it is a recompile, and their recent-window
+        count is the recompile-storm gauge in :meth:`stats`."""
         key = (entry, sig)
-        if key in self._compiled_seen:
-            return False
+        if not compiled and key in self._compiled_seen:
+            return
         with self._cond:
-            if key in self._compiled_seen:
-                return False
-            first = not any(k[0] == entry for k in self._compiled_seen)
+            again = any(k[0] == entry for k in self._compiled_seen)
             self._compiled_seen.add(key)
-            if not first:
+            if compiled and again:
                 self._recompiles += 1
                 self._recompile_ts.append(time.monotonic())
-        observe("gen/compile_s", dt)
-        stat_add("gen/compiles")
-        if not first:
-            stat_add("gen/recompiles")
-        return True
+        if compiled:
+            observe("gen/compile_s", dt)
+            stat_add("gen/compiles")
+            if again:
+                stat_add("gen/recompiles")
 
     def _ledger_finalize(self, gen: Generation, outcome: str) -> None:
         """Finalize the generation's ledger record exactly once (caller
@@ -1712,80 +1827,85 @@ class GenerationEngine:
     def _loop(self) -> None:
         import jax.numpy as jnp
 
-        while True:
-            with self._cond:
-                if self._stopping:
-                    return
-                self._last_beat = time.monotonic()   # watchdog heartbeat
-                if (not self._queue
-                        and not any(g is not None for g in self._slot_gen)):
-                    # idle: wake on new work, and periodically anyway so
-                    # TTL reaping runs while nothing is streaming
-                    t_idle = (time.perf_counter()
-                              if self._goodput is not None else 0.0)
+        stop = False
+        while not stop:
+            with self._phase("gen/loop",
+                             clock=self._goodput is not None) as it:
+                stop = self._iterate(jnp, it)
+            if self._goodput is not None:
+                # close this iteration's taxonomy on the iteration's own
+                # last clock read: the un-noted remainder is host-side
+                # gather/bookkeeping (or the stuck latch, while the
+                # watchdog has it marked)
+                self._goodput.tick("watchdog_stuck" if self._stuck
+                                   else "host_gather", now=it.t1 * 1e-9)
+
+    def _iterate(self, jnp, it: _Phase) -> bool:
+        """One iteration of the scheduler loop, inside its ``gen/loop``
+        span (whose self time is lock waits, reaping and planning).
+        True when the loop has to end."""
+        with self._cond:
+            if self._stopping:
+                return True
+            self._last_beat = time.monotonic()   # watchdog heartbeat
+            if it.recording:
+                it.set(queue=len(self._queue),
+                       active=sum(g is not None for g in self._slot_gen))
+            if (not self._queue
+                    and not any(g is not None for g in self._slot_gen)):
+                # idle: wake on new work, and periodically anyway so
+                # TTL reaping runs while nothing is streaming
+                with self._phase("gen/idle_wait", "admission_idle"):
                     self._cond.wait(timeout=0.25)
-                    if self._goodput is not None:
-                        self._goodput.note("admission_idle",
-                                           time.perf_counter() - t_idle)
-                    if self._stopping:
-                        return
-            try:
-                if self._stuck:
-                    # the watchdog failed this loop's generations while
-                    # a call was (apparently) wedged; whatever state the
-                    # call left behind is garbage — rebuild or break
-                    raise _EpochChanged("watchdog marked the engine "
-                                        "stuck")
-                self._reap_expired()
-                if self._sched is not None:
-                    # one brain, once per iteration: re-order the wait
-                    # queue (class rank + fair tags) and fix this
-                    # iteration's budgets; park victims when an
-                    # interactive head is waiting on a full engine
-                    with self._cond:
-                        self._plan = self._sched.plan(self._queue,
-                                                      self._slot_gen)
-                    if self._plan.preempt:
-                        self._preempt_tick()
-                if self._paged:
-                    progressed = self._admit_paged()
-                    progressed |= self._prefill_tick()
-                    progressed |= self._decode_step(jnp)
-                    if not progressed:
-                        # queue blocked on pages and nothing to step:
-                        # wait for a cancel/TTL/poll to free capacity
-                        # instead of spinning
-                        t_idle = (time.perf_counter()
-                                  if self._goodput is not None else 0.0)
-                        with self._cond:
-                            if not self._stopping:
-                                self._cond.wait(timeout=0.05)
-                        if self._goodput is not None:
-                            self._goodput.note(
-                                "admission_idle",
-                                time.perf_counter() - t_idle)
-                else:
-                    self._admit()
-                    self._decode_step(jnp)
-                if self._goodput is not None:
-                    # close this iteration's taxonomy: the un-noted
-                    # remainder is host-side gather/bookkeeping (or the
-                    # stuck latch, while the watchdog has it marked)
-                    self._goodput.tick("watchdog_stuck" if self._stuck
-                                       else "host_gather")
-            except Exception as e:   # device-side failure: fail loudly
+                if self._stopping:
+                    return True
+        try:
+            if self._stuck:
+                # the watchdog failed this loop's generations while
+                # a call was (apparently) wedged; whatever state the
+                # call left behind is garbage — rebuild or break
+                raise _EpochChanged("watchdog marked the engine "
+                                    "stuck")
+            self._reap_expired()
+            if self._sched is not None:
+                # one brain, once per iteration: re-order the wait
+                # queue (class rank + fair tags) and fix this
+                # iteration's budgets; park victims when an
+                # interactive head is waiting on a full engine
                 with self._cond:
-                    self._consec_traps += 1
-                    consec = self._consec_traps
-                if self._rebuild_max > 0 and consec <= self._rebuild_max:
-                    try:              # self-heal: fail active gens,
-                        self._rebuild(e)   # fresh state, re-admit
-                        continue
-                    except Exception as e2:   # rebuild itself trapped
-                        self._break(e2)
-                        return
-                self._break(e)       # terminal: refuse new work,
-                return               # keep pollers sane
+                    self._plan = self._sched.plan(self._queue,
+                                                  self._slot_gen)
+                if self._plan.preempt:
+                    self._preempt_tick()
+            if self._paged:
+                progressed = self._admit_paged()
+                progressed |= self._prefill_tick()
+                progressed |= self._decode_step(jnp)
+                if not progressed:
+                    # queue blocked on pages and nothing to step:
+                    # wait for a cancel/TTL/poll to free capacity
+                    # instead of spinning
+                    with self._phase("gen/idle_wait", "admission_idle"), \
+                            self._cond:
+                        if not self._stopping:
+                            self._cond.wait(timeout=0.05)
+            else:
+                self._admit()
+                self._decode_step(jnp)
+        except Exception as e:   # device-side failure: fail loudly
+            with self._cond:
+                self._consec_traps += 1
+                consec = self._consec_traps
+            if self._rebuild_max > 0 and consec <= self._rebuild_max:
+                try:              # self-heal: fail active gens,
+                    self._rebuild(e)   # fresh state, re-admit
+                    return False
+                except Exception as e2:   # rebuild itself trapped
+                    self._break(e2)
+                    return True
+            self._break(e)       # terminal: refuse new work,
+            return True          # keep pollers sane
+        return False
 
     def _note_trap(self, gens: list[Generation], e: BaseException, *,
                    exact: bool = False) -> None:
@@ -2050,19 +2170,32 @@ class GenerationEngine:
                 gen = self._queue.popleft()
                 if gen.done:          # cancelled while queued
                     continue
-                slot = free[0]
-                self._slot_gen[slot] = gen
-                gen.slot = slot
-                if self._ledger is not None:
-                    gen.admitted_ts = time.monotonic()
-                    self._ledger.book_admission(gen, gen.admitted_ts)
-                if self._sched is not None:
-                    self._sched.note_admitted(gen)
-                stat_set("gen/slots_active",
-                         sum(g is not None for g in self._slot_gen))
-                self._gen_event(gen, "gen/admitted", slot=slot,
-                                prompt_len=int(gen.prompt.size))
+                with self._phase("gen/admit", gen=gen) as ph:
+                    slot = free[0]
+                    self._slot_gen[slot] = gen
+                    gen.slot = slot
+                    self._note_admitted_locked(gen, ph)
+                    stat_set("gen/slots_active",
+                             sum(g is not None for g in self._slot_gen))
+                    self._gen_event(gen, "gen/admitted", slot=slot,
+                                    prompt_len=int(gen.prompt.size))
             self._prefill(gen, slot)
+
+    def _note_admitted_locked(self, gen: Generation, ph: _Phase) -> None:
+        """A request got its slot: one clock read stamps the ledger's
+        admission and the ``gen/admit`` span's ``waited_ms`` (enqueue →
+        now), the request's share of time to first token that is spent
+        waiting for the loop to come round."""
+        if self._ledger is not None or ph.recording:
+            now = time.monotonic()
+            if self._ledger is not None:
+                gen.admitted_ts = now
+                self._ledger.book_admission(gen, now)
+            if ph.recording:
+                ph.set(gen=gen.gen_id,
+                       waited_ms=round((now - gen.created) * 1e3, 3))
+        if self._sched is not None:
+            self._sched.note_admitted(gen)
 
     def _admit_paged(self) -> bool:
         """Assign free slots + page reservations to queued prompts, in
@@ -2085,90 +2218,90 @@ class GenerationEngine:
                 if gen.done:                # cancelled while queued
                     self._queue.popleft()
                     continue
-                P = self._page_tokens
-                # spec_k extra positions: the verify step's fixed-width
-                # scatter may touch one page past the declared worst
-                # case (rejected offsets are null-page-masked, but the
-                # ACCEPTED prefix must land in owned pages)
-                # a parked (preempted) generation folded its emitted
-                # tokens into the prompt: max_new shrinks by the same
-                # amount, so its reservation never grows past the
-                # original worst case (folded is 0 for fresh requests)
-                need = -(-(gen.prompt.size + gen.max_new_tokens
-                           - gen.folded + self._spec_k) // P)
-                matched: list[int] = []
-                if self._prefix is not None:
-                    matched = self._prefix.match(gen.prompt, self._pool)
-                if (self._kv is not None and self._kv_fetch
-                        and self._prefix is not None):
-                    epoch0 = self._epoch
-                    matched += self._kv_admit_fetch(gen, matched)
-                    if self._epoch != epoch0 or self._stuck:
-                        # the store fetch ran with the lock released
-                        # and a rebuild/watchdog reset landed under it:
-                        # matched pages belong to the replaced pool —
-                        # do NOT release them into the fresh one
-                        return progressed
-                    if gen.done:        # cancelled while fetching
-                        for pid in matched:
-                            self._pool.release(pid)
+                with self._phase("gen/admit", gen=gen,
+                                 clock=True) as ph:
+                    P = self._page_tokens
+                    # spec_k extra positions: the verify step's fixed-width
+                    # scatter may touch one page past the declared worst
+                    # case (rejected offsets are null-page-masked, but the
+                    # ACCEPTED prefix must land in owned pages)
+                    # a parked (preempted) generation folded its emitted
+                    # tokens into the prompt: max_new shrinks by the same
+                    # amount, so its reservation never grows past the
+                    # original worst case (folded is 0 for fresh requests)
+                    need = -(-(gen.prompt.size + gen.max_new_tokens
+                               - gen.folded + self._spec_k) // P)
+                    matched: list[int] = []
+                    if self._prefix is not None:
+                        matched = self._prefix.match(gen.prompt, self._pool)
+                    if (self._kv is not None and self._kv_fetch
+                            and self._prefix is not None):
+                        epoch0 = self._epoch
+                        matched += self._kv_admit_fetch(gen, matched)
+                        if self._epoch != epoch0 or self._stuck:
+                            # the store fetch ran with the lock released
+                            # and a rebuild/watchdog reset landed under it:
+                            # matched pages belong to the replaced pool —
+                            # do NOT release them into the fresh one
+                            return progressed
+                        if gen.done:        # cancelled while fetching
+                            for pid in matched:
+                                self._pool.release(pid)
+                            stat_set("gen/pages_free", self._pool.free_count)
+                            continue        # loop top pops the dead head
+                        if gen.rng_skip:
+                            # a resumed stream's original prompt is
+                            # prompt[:-rng_skip] (replay appended the
+                            # delivered tokens); whatever of it the cache +
+                            # store did not cover is recomputed prefill —
+                            # the debt KV-native failover exists to zero
+                            debt = max(0, (int(gen.prompt.size)
+                                           - int(gen.rng_skip))
+                                       - len(matched) * P)
+                            self._kv_recomputed += debt
+                            if debt:
+                                stat_add("gen/kv_prefill_recomputed", debt)
+                    short = (need - len(matched)) - self._pool.free_count
+                    if short > 0 and self._prefix is not None:
+                        self._prefix.evict(short, self._pool,
+                                           demote=(self._kv_demote
+                                                   if self._kv is not None
+                                                   else None))
+                    if need - len(matched) > self._pool.free_count:
+                        for pid in matched:     # give the hits back; retry
+                            self._pool.release(pid)   # when pages free up
+                        if (self._plan is not None
+                                and self._plan.hol_window > 0
+                                and self._hol_bypass_locked()):
+                            continue        # a smaller request jumped ahead
+                        stat_set("gen/queue_depth", len(self._queue))
                         stat_set("gen/pages_free", self._pool.free_count)
-                        continue        # loop top pops the dead head
-                    if gen.rng_skip:
-                        # a resumed stream's original prompt is
-                        # prompt[:-rng_skip] (replay appended the
-                        # delivered tokens); whatever of it the cache +
-                        # store did not cover is recomputed prefill —
-                        # the debt KV-native failover exists to zero
-                        debt = max(0, (int(gen.prompt.size)
-                                       - int(gen.rng_skip))
-                                   - len(matched) * P)
-                        self._kv_recomputed += debt
-                        if debt:
-                            stat_add("gen/kv_prefill_recomputed", debt)
-                short = (need - len(matched)) - self._pool.free_count
-                if short > 0 and self._prefix is not None:
-                    self._prefix.evict(short, self._pool,
-                                       demote=(self._kv_demote
-                                               if self._kv is not None
-                                               else None))
-                if need - len(matched) > self._pool.free_count:
-                    for pid in matched:     # give the hits back; retry
-                        self._pool.release(pid)   # when pages free up
-                    if (self._plan is not None
-                            and self._plan.hol_window > 0
-                            and self._hol_bypass_locked()):
-                        continue        # a smaller request jumped ahead
-                    stat_set("gen/queue_depth", len(self._queue))
+                        return progressed
+                    self._queue.popleft()
+                    gen.pages = matched + self._pool.alloc(need - len(matched))
+                    gen.shared = len(matched)
+                    slot = free[0]
+                    self._slot_gen[slot] = gen
+                    gen.slot = slot
+                    self._note_admitted_locked(gen, ph)
+                    ph.set(prefix_tokens=len(matched) * P,
+                           pages=len(gen.pages))
+                    gen.prefilling = True
+                    gen.prefill_pos = len(matched) * P
+                    gen.prefill_t0 = ph.t0 * 1e-9
+                    self._pt[slot] = 0
+                    self._pt[slot, :len(gen.pages)] = gen.pages
+                    self._pt_sync_row_locked(slot)
+                    if matched:
+                        stat_add("gen/prefix_hits")
+                        stat_add("gen/prefix_tokens_saved", len(matched) * P)
                     stat_set("gen/pages_free", self._pool.free_count)
-                    return progressed
-                self._queue.popleft()
-                gen.pages = matched + self._pool.alloc(need - len(matched))
-                gen.shared = len(matched)
-                slot = free[0]
-                self._slot_gen[slot] = gen
-                gen.slot = slot
-                if self._ledger is not None:
-                    gen.admitted_ts = time.monotonic()
-                    self._ledger.book_admission(gen, gen.admitted_ts)
-                if self._sched is not None:
-                    self._sched.note_admitted(gen)
-                gen.prefilling = True
-                gen.prefill_pos = len(matched) * P
-                gen.prefill_t0 = time.perf_counter()
-                self._pt[slot] = 0
-                self._pt[slot, :len(gen.pages)] = gen.pages
-                self._pt_sync_row_locked(slot)
-                if matched:
-                    stat_add("gen/prefix_hits")
-                    stat_add("gen/prefix_tokens_saved", len(matched) * P)
-                stat_set("gen/pages_free", self._pool.free_count)
-                stat_set("gen/slots_active",
-                         sum(g is not None for g in self._slot_gen))
-                stat_set("gen/queue_depth", len(self._queue))
-                self._gen_event(gen, "gen/admitted", slot=slot,
-                                prompt_len=int(gen.prompt.size),
-                                pages=len(gen.pages), shared=gen.shared)
+                    stat_set("gen/slots_active",
+                             sum(g is not None for g in self._slot_gen))
+                    stat_set("gen/queue_depth", len(self._queue))
+                    self._gen_event(gen, "gen/admitted", slot=slot,
+                                    prompt_len=int(gen.prompt.size),
+                                    pages=len(gen.pages), shared=gen.shared)
                 progressed = True
 
     # -- scheduler mechanics (FLAGS_gen_sched; never run otherwise) --------
@@ -2318,7 +2451,6 @@ class GenerationEngine:
         start = len(matched)
         if start >= cap:
             return []
-        t0 = time.perf_counter()
         kv_budget = self._kv_admit_s
         if self._plan is not None:
             # scheduler budget: tighten the fetch window under
@@ -2330,46 +2462,44 @@ class GenerationEngine:
                   for pl in self._state["cache"]]
         epoch0 = self._epoch
         self._admitting = gen
-        self._cond.release()
-        frames: list[tuple[tuple, int]] = []   # (validated leaves, nbytes)
-        degraded = False
-        try:
-            for key in keys[start:]:
-                if gen.done or self._stuck or self._stopping:
-                    break
-                if (kv_budget > 0
-                        and time.perf_counter() - t0 > kv_budget):
-                    # admission-level budget across the whole chain:
-                    # the rest is recompute debt, not a wedge
-                    degraded = True
-                    stat_add("gen/kv_admit_timeouts")
-                    break
-                try:
-                    frame, deg = self._kv.fetch(key)
-                except Exception:
-                    frame, deg = None, True
-                if frame is None:
-                    degraded |= deg
-                    break
-                try:
-                    leaves = deserialize_page(frame)
-                except ValueError:
-                    # corrupt/truncated store entry: a miss, but a
-                    # DEGRADED one — the bytes existed and were bad
-                    degraded = True
-                    stat_add("gen/kv_corrupt")
-                    break
-                if (len(leaves) != len(shapes)
-                        or any(l.shape != shp or l.dtype != dt
-                               for l, (shp, dt) in zip(leaves, shapes))):
-                    break                # foreign layout: not our pool
-                frames.append((leaves, len(frame)))
-        finally:
-            self._cond.acquire()
-            self._admitting = None
-        dt = time.perf_counter() - t0
-        if self._goodput is not None:
-            self._goodput.note("kv_fetch", dt)
+        with self._phase("gen/kv_fetch", "kv_fetch", clock=True) as fetch:
+            self._cond.release()
+            frames: list[tuple[tuple, int]] = []   # (validated leaves, nbytes)
+            degraded = False
+            try:
+                for key in keys[start:]:
+                    if gen.done or self._stuck or self._stopping:
+                        break
+                    if (kv_budget > 0 and (time.perf_counter_ns() - fetch.t0)
+                            * 1e-9 > kv_budget):
+                        # admission-level budget across the whole chain:
+                        # the rest is recompute debt, not a wedge
+                        degraded = True
+                        stat_add("gen/kv_admit_timeouts")
+                        break
+                    try:
+                        frame, deg = self._kv.fetch(key)
+                    except Exception:
+                        frame, deg = None, True
+                    if frame is None:
+                        degraded |= deg
+                        break
+                    try:
+                        leaves = deserialize_page(frame)
+                    except ValueError:
+                        # corrupt/truncated store entry: a miss, but a
+                        # DEGRADED one — the bytes existed and were bad
+                        degraded = True
+                        stat_add("gen/kv_corrupt")
+                        break
+                    if (len(leaves) != len(shapes)
+                            or any(l.shape != shp or l.dtype != dt
+                                   for l, (shp, dt) in zip(leaves, shapes))):
+                        break                # foreign layout: not our pool
+                    frames.append((leaves, len(frame)))
+            finally:
+                self._cond.acquire()
+                self._admitting = None
         if degraded:
             self._kv_degraded += 1
             stat_add("gen/kv_fetch_degraded")
@@ -2414,14 +2544,15 @@ class GenerationEngine:
         lifetime, so chunked prefill stops re-materializing four host
         arrays per chunk."""
         if gen.dev_ops is None:
-            key = jax.random.PRNGKey(gen.seed)
-            if gen.rng_skip:
-                from paddle_tpu.models.generation import advance_key
-                key = advance_key(key, gen.rng_skip)
-            gen.dev_ops = (key,
-                           jnp.asarray(gen.temperature, jnp.float32),
-                           jnp.asarray(gen.top_k, jnp.int32),
-                           jnp.asarray(gen.top_p, jnp.float32))
+            with self._phase("gen/dev_ops"):
+                key = jax.random.PRNGKey(gen.seed)
+                if gen.rng_skip:
+                    from paddle_tpu.models.generation import advance_key
+                    key = advance_key(key, gen.rng_skip)
+                gen.dev_ops = (key,
+                               jnp.asarray(gen.temperature, jnp.float32),
+                               jnp.asarray(gen.top_k, jnp.int32),
+                               jnp.asarray(gen.top_p, jnp.float32))
         return gen.dev_ops
 
     def _prefill_tick(self) -> bool:
@@ -2458,10 +2589,12 @@ class GenerationEngine:
             padded = np.full((bucket,), self._pad, np.int32)
             padded[:b - a] = gen.prompt[a:b]
             key, temp, top_k, top_p = self._gen_dev_ops(gen, jax, jnp)
-            t0 = time.perf_counter()
             try:
-                with self._gen_span(gen, "gen/prefill_chunk", slot=slot,
-                                    index=a, tokens=b - a, final=final):
+                with self._phase("gen/prefill_chunk", "prefill",
+                                 "gen/prefill_chunk_s",
+                                 ("paged_prefill", bucket), gen, slot=slot,
+                                 index=a, tokens=b - a,
+                                 final=final) as chunk:
                     _fault.inject("engine.prefill")
                     self._state, tok0 = self._prefill_fn(
                         self._state, pt_dev,
@@ -2473,21 +2606,15 @@ class GenerationEngine:
             except Exception as e:       # a prefill trap implicates
                 self._note_trap([gen], e, exact=True)  # exactly this one
                 raise
-            dt = time.perf_counter() - t0
-            observe("gen/prefill_chunk_s", dt)
-            compiled = self._note_compile("paged_prefill", bucket, dt)
-            if self._goodput is not None:
-                self._goodput.note("recompile" if compiled else "prefill",
-                                   dt)
             if self._ledger is not None:
-                gen.chip_s += dt
+                gen.chip_s += chunk.dt
             self._last_beat = time.monotonic()
             self._consec_traps = 0       # real device work succeeded
             if self._epoch != epoch0:
                 raise _EpochChanged("prefill chunk outlived the "
                                     "watchdog deadline")
             ticked = True
-            with self._cond:
+            with self._phase("gen/emit", emitted=int(final)), self._cond:
                 if self._slot_gen[slot] is not gen:
                     continue                # cancelled/reaped mid-chunk
                 gen.prefill_pos = b
@@ -2495,7 +2622,7 @@ class GenerationEngine:
                     continue
                 gen.prefilling = False
                 observe("gen/prefill_s",
-                        time.perf_counter() - gen.prefill_t0)
+                        chunk.t1 * 1e-9 - gen.prefill_t0)
                 if self._prefix is not None:
                     self._prefix.insert(gen.prompt, gen.pages, self._pool)
                 if self._kv is not None:
@@ -2539,10 +2666,10 @@ class GenerationEngine:
         padded[:T0] = gen.prompt
         key, temp, top_k, top_p = self._gen_dev_ops(gen, jax, jnp)
         epoch0 = self._epoch
-        t0 = time.perf_counter()
         try:
-            with self._gen_span(gen, "gen/prefill", slot=slot,
-                                prompt_len=T0, bucket=bucket):
+            with self._phase("gen/prefill", "prefill", "gen/prefill_s",
+                             ("prefill", bucket), gen, slot=slot,
+                             prompt_len=T0, bucket=bucket) as call:
                 _fault.inject("engine.prefill")
                 self._state, tok0 = self._prefill_fn(
                     self._state, jnp.asarray(slot, jnp.int32),
@@ -2552,13 +2679,8 @@ class GenerationEngine:
         except Exception as e:           # a prefill trap implicates
             self._note_trap([gen], e, exact=True)     # exactly this one
             raise
-        dt = time.perf_counter() - t0
-        observe("gen/prefill_s", dt)
-        compiled = self._note_compile("prefill", bucket, dt)
-        if self._goodput is not None:
-            self._goodput.note("recompile" if compiled else "prefill", dt)
         if self._ledger is not None:
-            gen.chip_s += dt
+            gen.chip_s += call.dt
         self._last_beat = time.monotonic()
         self._consec_traps = 0           # real device work succeeded
         if self._epoch != epoch0:
@@ -2648,46 +2770,46 @@ class GenerationEngine:
             # cheaper (width 1 vs K+1) and byte-identical
             use_spec = bool(dlens.any())
         lookahead = self._async_depth > 0 and not use_spec
-        t0 = time.perf_counter()
+        args = (pt_dev,) if self._paged else ()
         try:
-            with _trace.span("gen/decode_step", active=len(stepped),
-                             spec=int(use_spec)):
+            # gen/step_dispatch: operands and the call until it returns
+            # (the device starts before it does); gen/step_wait: the
+            # host blocked on the device for the tokens
+            with self._phase(
+                    "gen/decode_step",
+                    "spec_verify" if use_spec else "decode",
+                    "gen/decode_step_s",
+                    ("spec_step" if use_spec
+                     else ("paged_step" if self._paged else "step"), 0),
+                    active=len(stepped), spec=int(use_spec)) as call:
                 _fault.inject("engine.decode_step")
                 if use_spec:
-                    with _trace.span("gen/spec_verify",
+                    with self._phase("gen/spec_verify",
+                                     hist="gen/spec_verify_s",
                                      drafted=int(dlens.sum())):
-                        args = (pt_dev,) if self._paged else ()
-                        self._state, out, emit = self._spec_step(
-                            self._state, *args, jnp.asarray(active),
-                            jnp.asarray(drafts), jnp.asarray(dlens))
-                        out = np.asarray(out)
-                        emit = np.asarray(emit)
+                        with self._phase("gen/step_dispatch"):
+                            self._state, out, emit = self._spec_step(
+                                self._state, *args, jnp.asarray(active),
+                                jnp.asarray(drafts), jnp.asarray(dlens))
+                        with self._phase("gen/step_wait"):
+                            out = np.asarray(out)
+                            emit = np.asarray(emit)
                 else:
-                    args = (pt_dev,) if self._paged else ()
-                    self._state, toks = self._step(
-                        self._state, *args, jnp.asarray(active))
+                    with self._phase("gen/step_dispatch"):
+                        self._state, toks = self._step(
+                            self._state, *args, jnp.asarray(active))
                     if not lookahead:
-                        toks = np.asarray(toks)
+                        with self._phase("gen/step_wait"):
+                            toks = np.asarray(toks)
         except Exception as e:
             # the fused step shares one compiled call: every stepped
             # generation is implicated (co-tenant counts — see
             # _note_trap's threshold note)
             self._note_trap([g for _, g in stepped], e)
             raise
-        dt = time.perf_counter() - t0
-        observe("gen/decode_step_s", dt)
-        if use_spec:
-            observe("gen/spec_verify_s", dt)
-        compiled = self._note_compile(
-            "spec_step" if use_spec
-            else ("paged_step" if self._paged else "step"), 0, dt)
-        if self._goodput is not None:
-            self._goodput.note(
-                "recompile" if compiled
-                else ("spec_verify" if use_spec else "decode"), dt)
         # chip-second attribution: one fused step serves every stepped
         # slot — split its device wall evenly across them
-        chip_share = (dt / len(stepped)
+        chip_share = (call.dt / len(stepped)
                       if self._ledger is not None else 0.0)
         self._last_beat = time.monotonic()
         if lookahead:
@@ -2702,23 +2824,14 @@ class GenerationEngine:
             self._pending.append((stepped, toks, epoch0, chip_share))
             while len(self._pending) > self._async_depth:
                 self._drain_pending(1)
-            if self.step_wait_s > 0:
-                time.sleep(self.step_wait_s)
-                if self._goodput is not None:
-                    self._goodput.note("admission_idle",
-                                       self.step_wait_s)
+            self._pace()
             return True
         self._consec_traps = 0           # real device work succeeded
         if self._epoch != epoch0:
             raise _EpochChanged("decode step outlived the watchdog "
                                 "deadline")
-        # per-iteration stream sampling (FLAGS_trace_sample, hard-off):
-        # every Nth emitted token of an id-carrying stream records a
-        # gen/decode_sample event — affordable per-iteration visibility
-        sample_n = (int(flag("trace_sample"))
-                    if _trace._ACTIVE is not None else 0)
-        with self._cond:
-            emitted = 0
+        with self._phase("gen/emit") as emit_ph, self._cond:
+            emitted = retired = 0
             for s, gen in stepped:
                 if self._slot_gen[s] is not gen:   # cancelled mid-step
                     continue
@@ -2738,18 +2851,13 @@ class GenerationEngine:
                         stat_add("gen/spec_accepted", acc)
                         stat_add("gen/spec_rejected", dlen - acc)
                         observe("gen/spec_accept_len", float(acc))
-                        if sample_n > 0:
-                            self._gen_event(gen, "gen/spec_accept",
-                                            slot=s, proposed=dlen,
-                                            accepted=acc)
+                        self._gen_event(gen, "gen/spec_accept", slot=s,
+                                        proposed=dlen, accepted=acc)
                 else:
                     new = [int(toks[s])]
                 for tok in new:
                     gen.tokens.append(tok)
                     emitted += 1
-                    if sample_n > 0 and len(gen.tokens) % sample_n == 0:
-                        self._gen_event(gen, "gen/decode_sample", slot=s,
-                                        token_index=len(gen.tokens))
                     if ((gen.eos_token_id is not None
                          and tok == gen.eos_token_id)
                             or len(gen.tokens) >= gen.max_new_tokens):
@@ -2757,6 +2865,7 @@ class GenerationEngine:
                         # host; the device state past this point is
                         # garbage but the slot is released right here
                         gen.done = True
+                        retired += 1
                         if self._ledger is not None:
                             gen.done_ts = time.monotonic()
                         self._gen_event(gen, "gen/retire",
@@ -2771,12 +2880,16 @@ class GenerationEngine:
             if emitted:
                 stat_add("gen/tokens", emitted)
             self._cond.notify_all()
-        if self.step_wait_s > 0:
-            time.sleep(self.step_wait_s)
-            if self._goodput is not None:
-                # deliberate pacing gap: idle by configuration, not work
-                self._goodput.note("admission_idle", self.step_wait_s)
+            emit_ph.set(emitted=emitted, retired=retired)
+        self._pace()
         return True
+
+    def _pace(self) -> None:
+        """``step_wait_s``: a deliberate pacing gap after a decode step
+        — idle by configuration, not work."""
+        if self.step_wait_s > 0:
+            with self._phase("gen/idle_wait", "admission_idle"):
+                time.sleep(self.step_wait_s)
 
     # -- async dispatch lookahead (gen_async_depth) ------------------------
     def _drain_pending(self, n: int | None = None) -> None:
@@ -2804,14 +2917,12 @@ class GenerationEngine:
         entry's generations exactly like a sync trap. A slot retired
         or reassigned by an earlier entry is skipped by the identity
         guard, so lagged post-EOS tokens are never delivered."""
-        t0 = time.perf_counter()
         try:
-            toks = np.asarray(toks_dev)
+            with self._phase("gen/step_wait", "host_gather"):
+                toks = np.asarray(toks_dev)
         except Exception as e:
             self._note_trap([g for _, g in stepped], e)
             raise
-        if self._goodput is not None:
-            self._goodput.note("host_gather", time.perf_counter() - t0)
         self._last_beat = time.monotonic()
         self._consec_traps = 0           # real device work succeeded
         if self._epoch != epoch0:
@@ -2819,10 +2930,8 @@ class GenerationEngine:
             # in flight — its tokens are garbage; the loop's stuck
             # latch forces the rebuild/break decision
             return
-        sample_n = (int(flag("trace_sample"))
-                    if _trace._ACTIVE is not None else 0)
-        with self._cond:
-            emitted = 0
+        with self._phase("gen/emit") as emit_ph, self._cond:
+            emitted = retired = 0
             for s, gen in stepped:
                 if self._slot_gen[s] is not gen:   # retired/cancelled
                     continue                       # by an earlier entry
@@ -2831,13 +2940,11 @@ class GenerationEngine:
                 tok = int(toks[s])
                 gen.tokens.append(tok)
                 emitted += 1
-                if sample_n > 0 and len(gen.tokens) % sample_n == 0:
-                    self._gen_event(gen, "gen/decode_sample", slot=s,
-                                    token_index=len(gen.tokens))
                 if ((gen.eos_token_id is not None
                      and tok == gen.eos_token_id)
                         or len(gen.tokens) >= gen.max_new_tokens):
                     gen.done = True
+                    retired += 1
                     if self._ledger is not None:
                         gen.done_ts = time.monotonic()
                     self._gen_event(gen, "gen/retire",
@@ -2849,3 +2956,4 @@ class GenerationEngine:
             if emitted:
                 stat_add("gen/tokens", emitted)
             self._cond.notify_all()
+            emit_ph.set(emitted=emitted, retired=retired)

@@ -21,8 +21,9 @@ capacity attribution, SOSP '23). Three books:
   ordinary raw-bucket health path (``MetricsHub.phase_percentiles``).
 - **Goodput taxonomy** (:class:`GoodputMeter`). The engine loop notes
   every device section (prefill / decode / spec-verify, or recompile
-  when the call's wall clock was an XLA compile) and every deliberate
-  wait (admission-idle), then ``tick()`` at each iteration boundary
+  when jax built a program during the call) and every deliberate
+  wait (admission-idle) from the clock reads of the section's span
+  (``engine._Phase``), then ``tick()`` at each iteration boundary
   sweeps the unaccounted remainder into a hint bucket (host-gather
   normally, watchdog-stuck while the engine is marked stuck). Bucket
   seconds therefore sum to 100% of loop wall-clock; ``goodput`` =
@@ -132,10 +133,14 @@ class GoodputMeter:
             self._buckets[bucket] += dt
             self._noted += dt
 
-    def tick(self, hint: str = "host_gather") -> None:
+    def tick(self, hint: str = "host_gather",
+             now: float | None = None) -> None:
         """Close one loop iteration: sweep the un-noted remainder of
-        the wall clock since the last tick into ``hint``."""
-        now = time.perf_counter()
+        the wall clock since the last tick into ``hint``. ``now``: the
+        iteration's own last ``perf_counter`` read, where the caller
+        has one."""
+        if now is None:
+            now = time.perf_counter()
         with self._lock:
             rem = (now - self._t0) - self._noted
             if rem > 0.0:

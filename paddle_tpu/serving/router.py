@@ -714,7 +714,7 @@ class StickySession:
         # resume attempt replays the same id onto its replacement
         # replica — obs_dump then merges the stream's whole life across
         # replicas into one trace. Only minted with tracing on.
-        trace_id = _trace.new_id() if _trace.enabled() else None
+        trace_id = _trace.new_id() if _trace.recording() else None
         # The tenant identity likewise rides every resume attempt, so
         # per-tenant ledger counters keep accruing to the same tenant
         # on whichever replica inherits the stream.
@@ -874,7 +874,7 @@ class StickySession:
                     getattr(last, "endpoint", None) or "?",
                     attempts=attempts) from last
             stat_add("serving/router/stream_resumes")
-            if trace_id is not None and _trace.enabled():
+            if trace_id is not None and _trace.recording():
                 # client-side marker in the SAME stream trace: the
                 # merged dump shows exactly where the replica switch
                 # happened between the dead engine's spans and the

@@ -641,3 +641,16 @@ def test_generate_while_exits_early():
     # old fori_loop would have called forward 10 times regardless
     assert sum(calls) <= 2, f"loop did not exit early: {sum(calls)} calls"
     assert int(out[0, 3]) == EOS and int(out[0, 4]) == 0
+
+
+def test_engine_programs_carry_kv_and_sample_scopes(paged_engine, model):
+    """The step and prefill programs name their KV gather, KV write and
+    sampler (``op_name`` metadata; nothing else about the programs
+    changes), and the model's attention block names itself."""
+    for name, text in paged_engine.lowered_text(6).items():
+        for scope in ("kv/gather", "kv/write", "sample", "attn"):
+            assert scope in text, f"{scope} missing from {name}"
+    with GenerationEngine(model, slots=2, max_len=32, queue_max=4) as eng:
+        for name, text in eng.lowered_text(6).items():
+            for scope in ("kv/write", "sample", "attn"):
+                assert scope in text, f"{scope} missing from {name}"

@@ -405,3 +405,14 @@ def test_generate_under_tensor_parallel_sharding(devices8):
                                    cache_dtype=jnp.int8))(m_sh, ids))
     np.testing.assert_array_equal(out, ref)
     np.testing.assert_array_equal(out8, ref)
+
+
+def test_attention_block_is_a_named_scope(tiny_llama):
+    """``LlamaAttention`` names its operations ``attn`` (metadata only),
+    in the training forward as in the cached one."""
+    ids = jnp.ones((1, 8), jnp.int32)
+    text = jax.jit(lambda m, x: m(x)).lower(tiny_llama, ids).as_text(
+        debug_info=True)
+    assert "/attn/" in text or "attn/" in text, "no attn scope"
+    plain = jax.jit(lambda m, x: m(x)).lower(tiny_llama, ids).as_text()
+    assert "attn" not in plain, "the scope must be metadata only"

@@ -454,3 +454,17 @@ def test_moe_1f1b_aux_gradients_match_reference(devices8):
     gw = np.asarray(stepped.blocks.block.moe.w_gate, np.float32)
     ww = np.asarray(ref_stepped.blocks.block.moe.w_gate, np.float32)
     np.testing.assert_allclose(gw, ww, rtol=2e-3, atol=2e-6)
+
+
+@pytest.mark.parametrize("mode", ["einsum", "gather", "gather_grouped"])
+def test_moe_stages_are_named_scopes_in_the_lowered_block(mode):
+    """The device trace shows an MoE block as fusions; the four stages
+    ride in every operation's ``op_name`` so that a capture opened in
+    XProf or Perfetto says which is which."""
+    paddle_tpu.seed(0)
+    moe = MoEMLP(16, 32, 4, top_k=2, dispatch_mode=mode)
+    text = jax.jit(lambda m, x: m(x)).lower(
+        moe, jnp.ones((2, 8, 16))).as_text(debug_info=True)
+    for scope in ("moe/route", "moe/dispatch", "moe/experts",
+                  "moe/combine"):
+        assert scope in text, f"{scope} missing from the {mode} block"

@@ -7,6 +7,7 @@ tier-1 fast."""
 import json
 import logging
 import threading
+import time
 
 import pytest
 
@@ -24,6 +25,7 @@ def _restore_obs_flags():
     """Tracing/logging must be back at production defaults (off) after
     each test — a leaked tracer would record every other suite."""
     saved = get_flags(_FLAGS)
+    trace.clear()      # a capture in an earlier test leaves its spans
     yield
     set_flags(saved)
     trace.clear()
@@ -46,15 +48,19 @@ class _Echo(FrameService):
 # ---------------------------------------------------------------------------
 
 def test_disabled_mode_is_noop():
-    """Production default: no tracer, span() returns one shared no-op
-    object (no per-call allocation), nothing is recorded."""
-    assert not trace.enabled()
+    """Production default: no flag and no profiler capture, so nothing
+    records; span() returns one shared no-op object (no per-call
+    allocation), nothing is recorded."""
+    assert not trace.recording() and not trace.flag_on()
     s = trace.span("x", k=1)
     assert s is trace.span("y"), "disabled span must be a shared singleton"
+    assert s is trace.server_span("z", "t", None)
     with s:
         assert trace.current() is None
     assert trace.get_spans() == []
-    assert trace.snapshot() == {"enabled": False, "spans": []}
+    snap = trace.snapshot()
+    assert snap["enabled"] is False and snap["spans"] == []
+    assert snap["dropped"] == 0
 
 
 def test_span_nesting_and_linkage():
@@ -145,9 +151,16 @@ def test_wire_round_trip_joins_one_trace():
     c = FrameClient(srv.endpoint, {"echo": 1}, service="test", timeout=5.0)
     assert c._request("echo", {"x": 7})[0]["echo"] == 7
 
-    spans = trace.get_spans()
+    # the server records its span after it has sent the reply, so the
+    # client can be here first: wait for it (bounded)
+    deadline = time.monotonic() + 5.0
+    while True:
+        spans = trace.get_spans()
+        server = [s for s in spans if s["name"] == "wire/_Echo.echo"]
+        if server or time.monotonic() > deadline:
+            break
+        time.sleep(0.005)
     client = [s for s in spans if s["name"] == "wire/test.echo"]
-    server = [s for s in spans if s["name"] == "wire/_Echo.echo"]
     assert len(client) == 1 and len(server) == 1
     assert client[0]["trace_id"] == server[0]["trace_id"]
     assert server[0]["parent_id"] == client[0]["span_id"]
@@ -434,7 +447,7 @@ def _drain_gen(eng, gid):
 
 def test_stream_traces_spec_accept_under_stream_id(_gen_model):
     """A speculating engine's per-generation ``gen/spec_accept`` spans
-    (emitted when drafts are accepted and per-token sampling is on)
+    (emitted when drafts are accepted)
     group under the SAME stream trace id as the lifecycle spans, so
     stream_traces() shows speculation inside the request timeline."""
     import numpy as np
@@ -442,24 +455,19 @@ def test_stream_traces_spec_accept_under_stream_id(_gen_model):
     from paddle_tpu.serving import GenerationEngine
 
     obs_dump = _load_obs_dump()
-    saved = get_flags(["trace_sample"])
     _tracing_on(8192)
-    set_flags({"trace_sample": 1})       # spec/sample spans are per-token
-    try:
-        rs = np.random.RandomState(1)
-        prompts = [rs.randint(1, 96, size=rs.randint(4, 10))
-                   .astype(np.int32) for _ in range(6)]
-        with GenerationEngine(_gen_model, slots=3, max_len=40,
-                              queue_max=8, spec_k=4, spec_mode="ngram",
-                              spec_shed_occupancy=1.0) as eng:
-            gids = [eng.start(p, 12, trace_id=f"t-spec-{i}")
-                    for i, p in enumerate(prompts)]
-            for g in gids:
-                _, err = _drain_gen(eng, g)
-                assert err is None
-            assert eng.stats()["spec"]["accepted"] > 0
-    finally:
-        set_flags(saved)
+    rs = np.random.RandomState(1)
+    prompts = [rs.randint(1, 96, size=rs.randint(4, 10))
+               .astype(np.int32) for _ in range(6)]
+    with GenerationEngine(_gen_model, slots=3, max_len=40,
+                          queue_max=8, spec_k=4, spec_mode="ngram",
+                          spec_shed_occupancy=1.0) as eng:
+        gids = [eng.start(p, 12, trace_id=f"t-spec-{i}")
+                for i, p in enumerate(prompts)]
+        for g in gids:
+            _, err = _drain_gen(eng, g)
+            assert err is None
+        assert eng.stats()["spec"]["accepted"] > 0
     scrape = {"endpoint": "a", "service": "gen",
               "spans": trace.get_spans()}
     streams = obs_dump.stream_traces([scrape])
@@ -523,3 +531,316 @@ def test_stream_traces_ledger_spans_join_failover_resume(_gen_model):
     assert d["endpoints"] == ["a", "b"]
     assert "gen/ledger" in d["names"]
     assert d["retired"] == "complete"    # B's completion wins the join
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock: recording follows a live capture
+# ---------------------------------------------------------------------------
+
+def _host_events(logdir):
+    """``{name: [event, ...]}`` of the capture's ``/host:CPU`` plane."""
+    import pathlib
+
+    import jax
+
+    path = sorted(pathlib.Path(logdir).rglob("*.xplane.pb"))[-1]
+    out = {}
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                out.setdefault(e.name, []).append(e)
+    return out
+
+
+def test_capture_records_spans_on_the_host_plane(tmp_path):
+    """Inside ``jax.profiler.start_trace`` — ``FLAGS_trace`` off — spans
+    record, and the capture's ``/host:CPU`` plane holds an event of each
+    span's name whose ``span_id`` stat is a ring record's and whose
+    duration agrees with the record's to 0.2 ms."""
+    import jax
+
+    assert not trace.recording()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert trace.recording() and not get_flags(["trace"])["trace"]
+        with trace.span("t/outer", queue=3):
+            time.sleep(0.01)
+            with trace.span("t/inner"):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    assert not trace.recording()
+    recs = {s["name"]: s for s in trace.get_spans()}
+    assert set(recs) == {"t/outer", "t/inner"}
+    assert recs["t/inner"]["parent_id"] == recs["t/outer"]["span_id"]
+    events = _host_events(tmp_path)
+    for name, rec in recs.items():
+        (e,) = events[name]
+        stats = dict(e.stats)
+        assert stats["span_id"] == rec["span_id"]
+        assert abs(e.duration_ns * 1e-9 - rec["dur"]) < 2e-4
+    assert dict(events["t/outer"][0].stats)["queue"] == 3
+    # one clock: the inner event starts inside the outer one
+    o, i = events["t/outer"][0], events["t/inner"][0]
+    assert o.start_ns <= i.start_ns
+    assert i.start_ns + i.duration_ns <= o.start_ns + o.duration_ns
+
+
+def test_capture_leaves_the_wire_untraced(tmp_path):
+    """The per-message wire paths follow ``FLAGS_trace`` alone: inside a
+    capture with the flag off a round trip records no ``wire/*`` span
+    and carries no trace keys, while a plain span beside it records."""
+    import jax
+
+    captured = {}
+
+    class _Capture(FrameService):
+        def _dispatch(self, sock, op, header, payload):
+            captured.update(header)
+            send_frame(sock, 0, {})
+            return True
+
+    srv = _Capture().start()
+    c = FrameClient(srv.endpoint, {"go": 1}, service="test", timeout=5.0)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert trace.recording() and not trace.flag_on()
+        with trace.span("t/around"):
+            c._request("go", {"x": 1})
+    finally:
+        jax.profiler.stop_trace()
+        c.close()
+        srv.stop()
+    assert captured["x"] == 1
+    assert "tr" not in captured and "sp" not in captured
+    assert [s["name"] for s in trace.get_spans()] == ["t/around"]
+
+
+def test_ring_outlives_the_capture_and_counts_what_it_drops(tmp_path):
+    """What a capture recorded is read after it has ended, until
+    ``clear()``; a ring too small for it says how much it evicted."""
+    import jax
+
+    set_flags({"trace_buffer": 4})
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for n in range(10):
+            with trace.span(f"s{n}"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    with trace.span("after"):       # no capture, no flag: not recorded
+        pass
+    assert [s["name"] for s in trace.get_spans()] == ["s6", "s7", "s8", "s9"]
+    snap = trace.snapshot()
+    assert snap["enabled"] is False and snap["dropped"] == 6
+    assert snap["capacity"] == 4 and len(snap["spans"]) == 4
+    trace.clear()
+    assert trace.get_spans() == [] and trace.snapshot()["dropped"] == 0
+    set_flags({"trace_buffer": 4096})
+
+
+def test_compiles_are_counted_from_jax_events():
+    """The per-thread count moves when jax builds a program on this
+    thread and only then, recording or not."""
+    import threading
+
+    import jax
+    import jax.numpy as jnp
+
+    def fresh_program(x):
+        return jnp.cos(x) * 3 + 1
+
+    x5, x7 = jnp.ones(5), jnp.ones(7)     # making them compiles too
+    assert not trace.recording()
+    mine = trace.thread_compiles()
+    f = jax.jit(fresh_program)
+    f(x5).block_until_ready()
+    assert trace.thread_compiles() == mine + 1
+    f(x5).block_until_ready()                     # jit cache hit
+    assert trace.thread_compiles() == mine + 1
+    other = threading.Thread(target=lambda: f(x7).block_until_ready())
+    other.start()                                 # new shape, elsewhere
+    other.join()
+    assert trace.thread_compiles() == mine + 1
+    assert trace.get_spans() == []
+
+
+def _self_times(spans):
+    """Span id → duration minus what its recorded children cover."""
+    out = {s["span_id"]: s["dur"] for s in spans}
+    for s in spans:
+        if s["parent_id"] in out:
+            out[s["parent_id"]] -= s["dur"]
+    return out
+
+
+def test_engine_loop_spans_nest_inside_a_capture(_gen_model, tmp_path):
+    """A paged engine driven inside a capture, no flag set: every
+    ``gen/loop`` iteration is the parent of its phases, no self time is
+    negative, decode steps split into dispatch and wait, and each
+    ``gen/admit`` says how long its request waited — no longer than the
+    request took."""
+    import jax
+    import numpy as np
+
+    from paddle_tpu.serving import GenerationEngine
+
+    rs = np.random.RandomState(2)
+    prompts = [rs.randint(1, 96, size=n).astype(np.int32)
+               for n in (5, 9, 12, 7)]
+    with GenerationEngine(_gen_model, slots=2, max_len=48, queue_max=8,
+                          paged=True, page_tokens=8, pages=24) as eng:
+        _drain_gen(eng, eng.start(prompts[0], 3))         # compile first
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            t0 = time.monotonic()
+            gids = [eng.start(p, 6) for p in prompts]
+            latency = {}
+            for g in gids:
+                _, err = _drain_gen(eng, g)
+                assert err is None
+                latency[g] = time.monotonic() - t0
+        finally:
+            jax.profiler.stop_trace()
+    spans = trace.get_spans()
+    assert trace.snapshot()["dropped"] == 0
+    by_id = {s["span_id"]: s for s in spans}
+    loops = [s for s in spans if s["name"] == "gen/loop"]
+    assert loops and all("queue" in s["attrs"] and "active" in s["attrs"]
+                         for s in loops)
+    loop_thread = {s["tid"] for s in loops}
+    assert len(loop_thread) == 1
+    names = {s["name"] for s in spans if s["tid"] in loop_thread}
+    assert names >= {"gen/loop", "gen/admit", "gen/dev_ops",
+                     "gen/prefill_chunk", "gen/decode_step",
+                     "gen/step_dispatch", "gen/step_wait",
+                     "gen/emit"}, names
+
+    def parent(s):
+        return by_id.get(s["parent_id"], {}).get("name")
+
+    for s in spans:
+        if s["tid"] not in loop_thread or s["parent_id"] not in by_id:
+            continue            # its iteration began before the capture
+        if s["name"] in ("gen/step_dispatch", "gen/step_wait"):
+            assert parent(s) == "gen/decode_step"
+        elif s["name"] in ("gen/admit", "gen/prefill_chunk",
+                           "gen/decode_step", "gen/idle_wait",
+                           "gen/dev_ops"):
+            assert parent(s) == "gen/loop", (s["name"], parent(s))
+        p = by_id[s["parent_id"]]
+        assert p["ts"] <= s["ts"] + 1e-4
+        assert s["ts"] + s["dur"] <= p["ts"] + p["dur"] + 1e-4
+    assert min(_self_times(spans).values()) > -1e-6
+    steps = [s for s in spans if s["name"] == "gen/decode_step"]
+    assert all(s["attrs"]["compiled"] == 0 for s in steps)
+    admits = [s for s in spans if s["name"] == "gen/admit"
+              and "waited_ms" in s["attrs"]]
+    assert {s["attrs"]["gen"] for s in admits} == set(gids)
+    for s in admits:
+        assert 0 <= s["attrs"]["waited_ms"] * 1e-3 <= latency[
+            s["attrs"]["gen"]]
+        assert s["attrs"]["pages"] >= 1 and "prefix_tokens" in s["attrs"]
+    # and the capture holds them on the host plane
+    events = _host_events(tmp_path)
+    ids = {dict(e.stats).get("span_id") for e in events["gen/loop"]}
+    assert ids >= {s["span_id"] for s in loops}
+
+
+def test_phase_feeds_span_histogram_and_goodput_from_one_pair_of_reads(
+        _gen_model):
+    """``_phase`` reads the clock on entry and on exit and nowhere else:
+    the span's duration, the histogram's sample and the goodput bucket's
+    seconds are the same number."""
+    from paddle_tpu.serving import GenerationEngine
+
+    _tracing_on()
+    with GenerationEngine(_gen_model, slots=1, max_len=32, queue_max=2,
+                          ledger=True) as eng:
+        monitor.reset_stats("t/")
+        before = eng._goodput.snapshot()["buckets"]["kv_fetch"]
+        with eng._phase("t/phase", "kv_fetch", "t/phase_s", k=1) as ph:
+            time.sleep(0.003)
+        after = eng._goodput.snapshot()["buckets"]["kv_fetch"]
+        # an exception records the span and feeds nothing else
+        with pytest.raises(ValueError):
+            with eng._phase("t/phase", "kv_fetch", "t/phase_s"):
+                raise ValueError("x")
+    assert ph.dt == (ph.t1 - ph.t0) * 1e-9 and ph.dt >= 0.003
+    recs = [s for s in trace.get_spans() if s["name"] == "t/phase"]
+    assert len(recs) == 2 and recs[0]["attrs"] == {"k": 1}
+    assert recs[0]["dur"] == ph.dt
+    assert recs[1]["attrs"]["error"] == "ValueError"
+    hist = monitor.get_histogram("t/phase_s")
+    assert hist["count"] == 1 and hist["sum"] == pytest.approx(ph.dt, abs=0)
+    assert after - before == pytest.approx(ph.dt, rel=1e-9)
+    assert eng._goodput.snapshot()["buckets"]["kv_fetch"] == after
+    monitor.reset_stats("t/")
+
+
+def test_phase_without_a_recorder_still_times(_gen_model):
+    """Nothing records: the section has no span, and histogram and
+    ``dt`` still come from the two reads."""
+    from paddle_tpu.serving import GenerationEngine
+
+    with GenerationEngine(_gen_model, slots=1, max_len=32,
+                          queue_max=2) as eng:
+        monitor.reset_stats("t/")
+        with eng._phase("t/quiet", hist="t/quiet_s") as ph:
+            assert not ph.recording
+        assert monitor.get_histogram("t/quiet_s")["sum"] == ph.dt
+        # nothing to feed at all: the shared no-op, which reads no
+        # clock, unless the caller wants the reads or the ledger a
+        # bucket
+        bare = eng._phase("t/bare", k=1)
+        assert bare is eng._phase("t/other", "decode")
+        with bare as inside:
+            assert not inside.recording
+            inside.set(k=2)
+        assert not hasattr(bare, "t0")
+        with eng._phase("t/clocked", clock=True) as ph:
+            pass
+        assert ph is not bare and ph.t1 >= ph.t0
+    with GenerationEngine(_gen_model, slots=1, max_len=32, queue_max=2,
+                          ledger=True) as eng:
+        assert eng._phase("t/bare") is bare
+        assert eng._phase("t/booked", "decode") is not bare
+    assert trace.get_spans() == []
+    monitor.reset_stats("t/")
+
+
+def test_train_step_span_marks_the_calls_that_compiled():
+    """``train/step`` carries ``compiled=1`` on the first call and on a
+    call with a new batch shape, 0 on every other; ``train/shard_batch``
+    records beside it."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu
+    import paddle_tpu.distributed as dist
+    from paddle_tpu import nn, optimizer as optim
+    from paddle_tpu.parallel import mesh as M
+
+    paddle_tpu.seed(0)
+    model = nn.Linear(4, 1)
+    mesh = M.create_mesh({"dp": 1}, devices=jax.devices()[:1])
+
+    def loss_fn(m, batch, training=True):
+        return jnp.mean((m(batch["x"]) - batch["y"]) ** 2)
+
+    _tracing_on()
+    with M.MeshContext(mesh):
+        step = dist.fleet.build_train_step(
+            model, optimizer=optim.SGD(0.1), loss_fn=loss_fn, mesh=mesh)
+        state = step.init_state(model)
+        for rows in (2, 2, 2, 6, 6):
+            batch = step.shard_batch({"x": jnp.ones((rows, 4)),
+                                      "y": jnp.ones((rows, 1))})
+            state, _ = step(state, batch, jax.random.PRNGKey(0))
+    spans = trace.get_spans()
+    steps = [s for s in spans if s["name"] == "train/step"]
+    assert [s["attrs"]["compiled"] for s in steps] == [1, 0, 0, 1, 0]
+    assert sum(s["name"] == "train/shard_batch" for s in spans) == 5

@@ -162,7 +162,7 @@ def check_defaults_off() -> None:
     check("defaults/injection_off", f["fault_inject"] == ""
           and not fault.enabled(), str(f))
     t = get_flags(["trace", "log_json"])
-    check("defaults/trace_off", not t["trace"] and not trace.enabled()
+    check("defaults/trace_off", not t["trace"] and not trace.recording()
           and not t["log_json"], str(t))
     check("defaults/deadline_finite", f["wire_timeout_s"] > 0, str(f))
     o = get_flags(["wire_max_inflight", "wire_max_conns",
@@ -220,11 +220,11 @@ def check_defaults_off() -> None:
     check("defaults/gen_mesh_off",
           mt["gen_mesh_tp"] == 0,                 # no mesh, identity
           str(mt))                                # layout, plain jit
-    ob = get_flags(["trace_sample", "control_slo_budget",
+    ob = get_flags(["trace", "control_slo_budget",
                     "control_burn_fast_ticks", "control_burn_slow_ticks",
                     "control_burn_threshold"])
     check("defaults/obs_burn_off",
-          ob["trace_sample"] == 0                 # no per-token spans
+          not ob["trace"]                         # spans only in a capture
           and ob["control_slo_budget"] > 0        # sane when opted in
           and 1 <= ob["control_burn_fast_ticks"]
           <= ob["control_burn_slow_ticks"]
