@@ -58,7 +58,9 @@ PREFILL_KERNELS = {
     "contiguous": ("ptpu_flash_fwd", "ptpu_rms_norm_fwd", "ptpu_rope"),
     "paged": ("ptpu_rms_norm_fwd", "ptpu_rope"),
 }
-DECODE_KERNELS = ("ptpu_decode_attn",)
+# the paged step attends through ``generation.PagedCache`` (a per-layer
+# gather and the einsum arm): no decode kernel in it
+DECODE_KERNELS = {"contiguous": ("ptpu_decode_attn",), "paged": ()}
 # per-shard (shard_map) units the four-chip programs must take on the
 # kernel arm (``ops.pallas.partition_stats()`` keys ``<unit>:kernel``)
 TRAIN_UNITS = ("flash_fwd", "flash_bwd", "rms_fwd", "rms_bwd", "rope",
@@ -532,11 +534,11 @@ def serve_phase(model, requests, *, slots: int, max_len: int,
             for name, engine in engines.items():
                 text = engine.lowered_text(longest)
                 absent = (_missing(text["prefill"], PREFILL_KERNELS[name])
-                          + _missing(text["decode"], DECODE_KERNELS))
+                          + _missing(text["decode"], DECODE_KERNELS[name]))
                 check(not absent, f"{name} engine lowered without {absent}")
                 report[name] = {"kernels": {
                     "prefill": list(PREFILL_KERNELS[name]),
-                    "decode": list(DECODE_KERNELS)}}
+                    "decode": list(DECODE_KERNELS[name])}}
         tokens, repeats = {}, {}
         for name in engines:
             t0 = time.monotonic()

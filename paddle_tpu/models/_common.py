@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from paddle_tpu.models.generation import PagedCache
 from paddle_tpu.nn import functional as F
 
 
@@ -62,10 +63,17 @@ def cached_attention(q, k, v, cache, index, layer=0):
     causal for [index, index+T) — the same visibility set as writing
     first and masking j <= index + t.
 
-    Two cache layouts:
+    Three cache forms, told apart by what ``cache`` is:
     - ``(k_buf, v_buf)`` [L, B, Hkv, S, D] — any float dtype.
     - ``(k_q, v_q, k_scale, v_scale)`` — int8 buffers + f32
       per-(head, position) scales [L, B, Hkv, S].
+    - a ``generation.PagedCache`` — the page pool (either leaf set) and
+      one sequence's page-table row, B = 1: this layer's pages are
+      gathered through the row (``PagedCache.read_layer``) and attended
+      by the einsum lines below, so a paged program never holds a
+      sequence's all-layers view. The decode kernel is not tried: under
+      the engine's ``vmap`` its batching rule loops over the slots and
+      slices each slot's whole stacked cache out for every layer.
 
     The [..., Hkv, S, D] layout (heads ahead of sequence) matters on
     TPU: the decode attention contracts D and batches (B, Hkv), so S×D
@@ -78,7 +86,9 @@ def cached_attention(q, k, v, cache, index, layer=0):
     """
     import jax
 
-    quantized = len(cache) == 4
+    paged = isinstance(cache, PagedCache)
+    bufs = cache.pool if paged else cache
+    quantized = len(bufs) == 4
     B, T, Hq, D = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv
@@ -91,7 +101,7 @@ def cached_attention(q, k, v, cache, index, layer=0):
         vq, vs = _quant_chunk(vt)
         payload = (kq, vq, ks, vs)
     else:
-        payload = (kt.astype(cache[0].dtype), vt.astype(cache[1].dtype))
+        payload = (kt.astype(bufs[0].dtype), vt.astype(bufs[1].dtype))
 
     if index is None or (isinstance(index, int) and index == 0):
         # prefill: nothing behind us — plain causal over the raw chunk
@@ -100,19 +110,22 @@ def cached_attention(q, k, v, cache, index, layer=0):
         return out, payload
 
     idx = jnp.asarray(index, jnp.int32)
-    from paddle_tpu.ops.pallas import decode_attention as _dk
-    if _dk.supported(q, cache):
-        out = _dk.decode_attention(q, kt, vt, cache, layer, idx,
-                                   scale=scale)
-        return out, payload
+    if paged:
+        sl = cache.read_layer(layer)
+    else:
+        from paddle_tpu.ops.pallas import decode_attention as _dk
+        if _dk.supported(q, cache):
+            out = _dk.decode_attention(q, kt, vt, cache, layer, idx,
+                                       scale=scale)
+            return out, payload
+        # einsum fallback (CPU / unsupported shapes): slice this layer
+        sl = (tuple(c[layer] for c in cache) if isinstance(layer, int) else
+              tuple(jax.lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
+                    for c in cache))
 
-    # einsum fallback (CPU / unsupported shapes): slice this layer, then
     # two-piece softmax — prefix logits against the buffer + fresh-chunk
     # causal logits, normalized jointly. GQA maps q-head (g, h) to
     # kv-head h with no repeat of the cache.
-    sl = (tuple(c[layer] for c in cache) if isinstance(layer, int) else
-          tuple(jax.lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
-                for c in cache))
     if quantized:
         k_c, v_c, k_s, v_s = sl
         dt = q.dtype
@@ -142,9 +155,13 @@ def apply_cache_writes(cache, payload, index):
     """Write the stacked per-layer chunk payloads ([L, B, Hkv, T, ...])
     into the static cache at position ``index`` — one
     ``dynamic_update_slice`` per buffer per step, in place under the
-    decode loop's donation."""
+    decode loop's donation. A ``generation.PagedCache`` has no buffer of
+    its own to write: the payload goes back as it is and the paged
+    program puts it into the pool (``generation.paged_write``)."""
     import jax
 
+    if isinstance(cache, PagedCache):
+        return payload
     idx = jnp.asarray(0 if index is None else index, jnp.int32)
 
     def wr(buf, x):

@@ -31,7 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["generate", "sample_logits", "beam_search", "init_paged_cache",
-           "paged_gather", "paged_scatter", "advance_key", "ngram_propose",
+           "PagedCache", "paged_gather", "paged_scatter", "paged_write",
+           "advance_key", "ngram_propose",
            "speculative_generate", "serialize_page", "deserialize_page",
            "STACKED_KV_SPEC", "POOL_KV_SPEC", "PAGE_TABLE_SPEC"]
 
@@ -113,12 +114,19 @@ def sample_logits(logits, key=None, *, temperature: float = 1.0,
 # layer of the cache contract. A model's ``init_cache`` proto defines the
 # per-sequence leaf layout ([L, 1, Hkv, S, D] buffers — scales
 # [L, 1, Hkv, S] in the int8 layout); these helpers re-express it as a
-# pool of fixed-size pages plus a per-sequence page table, and translate
-# between the two so ``forward_with_cache`` keeps its contiguous view:
-# gather pages -> contiguous cache -> forward -> scatter the written
-# chunk back. Physical page 0 is reserved as the null page: unmapped
-# table entries and masked (padding) writes land there, never on a live
-# page. Exactness contract: a gather of pages holding positions
+# pool of fixed-size pages plus a per-sequence page table. A paged
+# program hands ``forward_with_cache`` a :class:`PagedCache` (the pool
+# and ONE sequence's table row) in the cache's place: attention reads
+# each layer's pages through the row (``PagedCache.read_layer`` — no
+# all-layers contiguous view ever materializes), the chunk's new k/v
+# come back as the payload, and :func:`paged_write` puts them into the
+# donated pool with in-place slice updates. ``paged_gather`` /
+# ``paged_scatter`` are the whole-sequence translations between the two
+# layouts — what the tests compare the per-layer path against, and the
+# host-side tools' way to read a sequence out of a pool. Physical page 0
+# is reserved as the null page: unmapped table entries and masked
+# (padding, rejected-draft, inactive-slot) writes land there, never on a
+# live page. Exactness contract: a read of pages holding positions
 # [0, index) reproduces the contiguous buffer bit-for-bit over those
 # positions, so paged decode logits equal contiguous decode logits.
 # ---------------------------------------------------------------------------
@@ -141,14 +149,52 @@ def init_paged_cache(proto_cache, num_pages: int, page_tokens: int):
     return tuple(pool)
 
 
+@jax.tree_util.register_pytree_node_class
+class PagedCache:
+    """One sequence's cache as a paged program sees it: the pool leaves
+    (``init_paged_cache`` layout, two or the int8 four) and the
+    sequence's page-table row (``table`` [M] int32 physical page ids;
+    entry 0 = null page). ``forward_with_cache`` takes it where it takes
+    the contiguous tuple; ``_common.cached_attention`` tells the two
+    apart by type. Under the engine's ``jax.vmap`` over slots the pool
+    is unmapped and the row is the mapped operand."""
+
+    def __init__(self, pool, table):
+        self.pool, self.table = tuple(pool), table
+
+    def tree_flatten(self):
+        return (self.pool, self.table), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
+
+    @jax.named_scope("kv/gather")
+    def read_layer(self, layer):
+        """Layer ``layer``'s (python int or traced scalar) contiguous
+        view: leaves ``[1, Hkv, M * page_tokens, *rest]`` — what
+        ``paged_gather(pool, table)`` holds at ``[layer]``, bit for bit,
+        from one gather of this layer's pages. Unmapped (null) regions
+        hold garbage; attention masks them (the fill position bounds
+        every read)."""
+        out = []
+        for leaf in self.pool:
+            g = leaf[self.table, layer]           # [M, Hkv, P, *rest]
+            g = jnp.moveaxis(g, 0, 1)             # [Hkv, M, P, *rest]
+            s = g.shape
+            out.append(g.reshape(s[0], s[1] * s[2], *s[3:])[None])
+        return tuple(out)
+
+
 @jax.named_scope("kv/gather")
 def paged_gather(pool, table):
     """Materialize a sequence's contiguous cache view from its page
     table (``table`` [M] int32 physical page ids; entry 0 = null page).
     Returns leaves ``[L, 1, Hkv, M * page_tokens, *rest]`` — position
     ``p`` reads ``pool[table[p // page_tokens]][..., p % page_tokens]``.
-    Unmapped (null) regions hold garbage; attention masks them (the
-    fill position bounds every read)."""
+    Unmapped (null) regions hold garbage. All layers at once: the
+    compiled programs read per layer (``PagedCache.read_layer``), this is
+    their oracle."""
     out = []
     for leaf in pool:
         g = leaf[table]                       # [M, L, Hkv, P, *rest]
@@ -159,13 +205,46 @@ def paged_gather(pool, table):
 
 
 @jax.named_scope("kv/write")
+def paged_write(pool, pages, offs, rows):
+    """Put ``n`` single positions into the pool, in place: position
+    ``i`` (``rows`` leaves ``[n, L, Hkv, *rest]``) goes to page
+    ``pages[i]`` at in-page offset ``offs[i]``, one after the other:
+    read the page, replace the row, write the whole page back
+    (``dynamic_update_slice`` of ``[1, L, Hkv, P, *rest]``, dynamic on
+    axis 0 only). Updating the row alone — by a scatter on axes 0 and 3
+    or by a one-row slice update — touches half a packed ``(P, D)`` bf16
+    tile, and XLA:TPU then gives the whole pool a position-major layout
+    for the program: a copy of every leaf in and out per call. The
+    caller sends what must not land (padding, rejected drafts, inactive
+    slots) to the null page 0; later updates win where two positions
+    name one target, which only happens there."""
+    n = pages.shape[0]
+    zero = jnp.zeros((), jnp.int32)
+
+    def body(i, pool):
+        out = []
+        for leaf, r in zip(pool, rows):
+            start = (pages[i],) + (zero,) * (leaf.ndim - 1)
+            page = jax.lax.dynamic_slice(leaf, start, (1,) + leaf.shape[1:])
+            x = jax.lax.dynamic_index_in_dim(r, i, 0)    # [1, L, Hkv, *rest]
+            x = jnp.expand_dims(x, 3).astype(leaf.dtype)
+            here = (jnp.arange(leaf.shape[3]) == offs[i]).reshape(
+                (1, 1, 1, -1) + (1,) * (leaf.ndim - 4))
+            out.append(jax.lax.dynamic_update_slice(
+                leaf, jnp.where(here, x, page), start))
+        return tuple(out)
+
+    return jax.lax.fori_loop(0, n, body, tuple(pool))
+
+
 def paged_scatter(pool, table, chunk, index, page_tokens: int,
                   length=None):
     """Write a contiguous chunk (leaves ``[L, 1, Hkv, T, *rest]``,
     covering positions ``[index, index + T)``) into the pool through
-    ``table``. Positions at or past ``length`` (the chunk's true token
-    count — padding) are redirected to the null page so a right-padded
-    chunk can never clobber a live page."""
+    ``table`` (:func:`paged_write`, one position at a time). Positions
+    at or past ``length`` (the chunk's true token count — padding) are
+    redirected to the null page so a right-padded chunk can never
+    clobber a live page."""
     T = chunk[0].shape[3]
     j = jnp.arange(T)
     pos = jnp.asarray(index, jnp.int32) + j
@@ -173,12 +252,8 @@ def paged_scatter(pool, table, chunk, index, page_tokens: int,
     pages = table[pidx]
     if length is not None:
         pages = jnp.where(j < length, pages, 0)
-    offs = pos % page_tokens
-    out = []
-    for leaf, ch in zip(pool, chunk):
-        data = jnp.moveaxis(ch[:, 0], 2, 0)   # [T, L, Hkv, *rest]
-        out.append(leaf.at[pages, :, :, offs].set(data.astype(leaf.dtype)))
-    return tuple(out)
+    rows = tuple(jnp.moveaxis(ch[:, 0], 2, 0) for ch in chunk)
+    return paged_write(pool, pages, pos % page_tokens, rows)
 
 
 _PAGE_MAGIC = b"KVPG1"

@@ -1,20 +1,22 @@
 """Page-table-aware single-token decode attention over the paged pool.
 
-The paged serving engine's decode step today materializes a contiguous
-per-slot cache view with ``models.generation.paged_gather`` — a full
-copy of every live page, every layer, every step — and only then runs
-attention over the copy. This kernel deletes the copy the same way
-``decode_attention`` deleted the per-layer ``lax.scan`` slice: the page
-indirection moves INTO the pallas index maps. The scalar-prefetch row
-carries ``[layer, index, table...]``, and the page-block index map
+The paged serving engine's decode step reads K/V through the page table
+one layer at a time (``models.generation.PagedCache.read_layer``): a
+gather of every page of the slot's table row — capacity, not fill —
+into a per-layer contiguous view, which the einsum arm of
+``cached_attention`` then attends over. This kernel is the next step
+down: it deletes that per-layer copy the same way ``decode_attention``
+deleted the per-layer ``lax.scan`` slice — the page indirection moves
+INTO the pallas index maps. The scalar-prefetch row carries
+``[layer, index, table...]``, and the page-block index map
 
     page id = sp_ref[b, 2 + min(max(j - 1, 0), last_live_page)]
 
 reads the slot's device-resident page table directly — grid step ``j``
 DMAs physical page ``table[j - 1]`` of the pool, so the persistent HBM
-(the pool) is the only cache the kernel ever touches. Blocks past the
-filled prefix repeat the last live page id and Mosaic elides the
-repeated DMA, exactly the stacked-layer clamp trick.
+(the pool) is the only cache the kernel ever touches, and only its live
+pages. Blocks past the filled prefix repeat the last live page id and
+Mosaic elides the repeated DMA, exactly the stacked-layer clamp trick.
 
 Everything else is the ``decode_attention`` recipe on a page-shaped
 block: the fresh token's raw k/v joins the streaming softmax as grid
@@ -35,13 +37,19 @@ zero-upload statement end to end.
 Status: interpreter-mode tests (``tests/test_paged_decode_attention.py``)
 pin the kernel bit-exact to ``paged_gather`` + masked attention per
 slot, under ``jax.vmap``, and for the int8 4-leaf layout — the
-hardware-independent result. Wiring it under the engine's compiled
-step (replacing the gather inside ``forward_with_cache``) and the TPU
-timing run are the honest remaining caveat; off-TPU callers take the
-``paged_reference`` einsum fallback under the same ``supported()`` gate
-as the stacked kernel. Multi-device meshes fall back too (no
-``_partition`` unit yet — the pool's KV-head shard would need a
-per-shard grid).
+hardware-independent result. It is NOT on the engine's path: the paged
+programs attend through ``PagedCache`` and the einsum arm (a joint f32
+softmax; this kernel's online softmax orders every sum differently).
+Whether a kernel that reads only live pages beats the per-layer gather
+of capacity is ROADMAP C2, to be decided on the serving cell; note that
+under ``jax.vmap`` a pallas call with a batched scalar-prefetch operand
+becomes a loop over the mapped axis that slices every other operand per
+iteration, so the engine has to call it over the slot axis itself
+(``paged_decode_attention`` takes ``[B, ...]`` rows and tables), not
+under its vmap. Off-TPU callers take the ``paged_reference`` einsum
+fallback under the same ``supported()`` gate as the stacked kernel.
+Multi-device meshes fall back too (no ``_partition`` unit yet — the
+pool's KV-head shard would need a per-shard grid).
 """
 
 from __future__ import annotations
@@ -242,6 +250,8 @@ def paged_reference(q, k_new, v_new, pool, table, layer, index, *,
     ``layer``, dequantize, mask positions ``>= index``, softmax over
     [cache, fresh] in f32, combine. Shapes as
     :func:`paged_decode_attention`."""
+    from paddle_tpu.models.generation import PagedCache
+
     B, T, Hq, D = q.shape
     Hkv = k_new.shape[1]
     G = Hq // Hkv
@@ -250,16 +260,12 @@ def paged_reference(q, k_new, v_new, pool, table, layer, index, *,
     M = table.shape[1]
 
     def one(qb, knb, vnb, row, idx):
-        # paged_gather, restricted to one layer: [Hkv, M·P, D]
-        def view(leaf):
-            g = leaf[row, layer]                  # [M, Hkv, P, *rest]
-            g = jnp.moveaxis(g, 0, 1)             # [Hkv, M, P, *rest]
-            s = g.shape
-            return g.reshape(s[0], s[1] * s[2], *s[3:])
-        k_c, v_c = view(pool[0]), view(pool[1])
+        # paged_gather, restricted to one layer: [Hkv, M·P, *rest]
+        view = [v[0] for v in PagedCache(pool, row).read_layer(layer)]
+        k_c, v_c = view[:2]
         if quantized:
-            k_c = k_c.astype(qb.dtype) * view(pool[2])[..., None]
-            v_c = v_c.astype(qb.dtype) * view(pool[3])[..., None]
+            k_c = k_c.astype(qb.dtype) * view[2][..., None]
+            v_c = v_c.astype(qb.dtype) * view[3][..., None]
         qh = qb.reshape(Hkv, G, D)                # [Hkv, G, D]
         s_c = jnp.einsum("hgd,hsd->hgs", qh, k_c) * scale
         mask = jnp.arange(M * P) < idx

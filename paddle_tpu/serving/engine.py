@@ -16,8 +16,9 @@ paged cache, SOSP '23) to the framework's autoregressive path:
 - **Iteration-level scheduling.** A background loop admits queued
   prompts into free slots (bucketed prefill), steps *all* active slots
   through ONE fused decode (``jax.vmap`` over
-  ``model.forward_with_cache`` with per-slot positions — the einsum
-  decode path batches exactly), and retires slots on EOS,
+  ``model.forward_with_cache`` with per-slot positions — row-
+  independent compute, so co-tenants never change a row), and retires
+  slots on EOS,
   ``max_new_tokens``, cancel, or poll-TTL expiry (client disconnect).
   A request admitted mid-flight shares the very next decode step with
   the requests already running.
@@ -31,8 +32,14 @@ paged cache, SOSP '23) to the framework's autoregressive path:
   ``max_len`` positions; paged mode (vLLM PagedAttention, SOSP '23)
   replaces them with a pool of ``FLAGS_gen_pages`` physical pages of
   ``FLAGS_gen_page_tokens`` tokens plus per-slot page tables
-  (``models.generation.init_paged_cache`` / ``paged_gather`` /
-  ``paged_scatter``). A generation reserves pages for its *declared*
+  (``models.generation.init_paged_cache``). The three paged programs
+  (step, prefill chunk, speculative verify) hand the model a
+  ``generation.PagedCache`` — the pool and the slot's table row — in
+  the cache's place: attention gathers ONE layer's pages through the
+  row, so no slot's all-layers view is ever built, and the chunk's new
+  k/v go into the donated pool by in-place page updates
+  (``generation.paged_write``): a step moves the pages it reads, never
+  the pool. A generation reserves pages for its *declared*
   worst case (prompt + ``max_new_tokens``) at admission — capacity
   becomes ``pool / actual-need`` instead of ``slots`` — and admission
   stalls on page-pool exhaustion, not slot count. A radix prefix cache
@@ -1024,31 +1031,27 @@ class GenerationEngine:
                                       paged=False, n_in=7, n_out=1)
 
     def _build_paged_step(self):
-        """ONE fused decode for all slots in paged mode: each slot
-        gathers its page table into a contiguous cache view, runs the
-        same single-token cached forward as the contiguous step, and
-        the freshly written position is scattered back into its page
-        outside the vmap (inactive/masked slots scatter to the null
-        page). The gathered view is a step-local temporary — the
-        persistent HBM is the page pool."""
+        """ONE fused decode for all slots in paged mode: each slot runs
+        the same single-token cached forward as the contiguous step on
+        a ``PagedCache`` (the pool and its page-table row), so
+        attention gathers one layer's pages at a time and no slot's
+        all-layers view exists; the new position's k/v come back as the
+        payload and go into the donated pool in place, outside the vmap
+        (inactive/masked slots write to the null page)."""
         import jax
         import jax.numpy as jnp
 
-        from paddle_tpu.models.generation import paged_gather
+        from paddle_tpu.models.generation import PagedCache, paged_write
 
         P, maxp = self._page_tokens, self._maxp
         slots = self.slots
 
         def one(model, pt_row, tok, idx, key, temp, top_k, top_p, pool):
-            cache = paged_gather(pool, pt_row)
-            logits, cache = model.forward_with_cache(
-                tok[None, None], cache, index=idx)
-            new = tuple(
-                jax.lax.dynamic_slice_in_dim(c, idx, 1, axis=3)[:, 0, :, 0]
-                for c in cache)                       # [L, Hkv, *rest]
+            logits, new = model.forward_with_cache(
+                tok[None, None], PagedCache(pool, pt_row), index=idx)
             key, sub = jax.random.split(key)
             nxt = _sample_slot(logits[0, -1], sub, temp, top_k, top_p)
-            return nxt, key, new
+            return nxt, key, tuple(n[:, 0, :, 0] for n in new)
 
         def step(model, state, pt, active):
             pool = state["cache"]
@@ -1059,11 +1062,7 @@ class GenerationEngine:
                 state["temp"], state["top_k"], state["top_p"], pool)
             pidx = jnp.clip(state["pos"] // P, 0, maxp - 1)
             pages = jnp.where(active, pt[jnp.arange(slots), pidx], 0)
-            offs = state["pos"] % P
-            with jax.named_scope("kv/write"):
-                pool = tuple(
-                    buf.at[pages, :, :, offs].set(n.astype(buf.dtype))
-                    for buf, n in zip(pool, new))
+            pool = paged_write(pool, pages, state["pos"] % P, new)
             tok = jnp.where(active, nxt, state["tok"])
             pos = state["pos"] + active.astype(jnp.int32)
             return dict(state, cache=pool, tok=tok, pos=pos,
@@ -1074,9 +1073,10 @@ class GenerationEngine:
 
     def _build_paged_prefill(self):
         """Prefill ONE chunk of one slot's prompt (compiled per padded
-        chunk length): gather the slot's pages, forward the chunk at its
-        absolute index against the shared-prefix context already in
-        those pages, scatter the written positions back (padding
+        chunk length): forward the chunk at its absolute index on the
+        slot's ``PagedCache`` — attention reads the shared-prefix
+        context already in the slot's pages, one layer at a time —
+        write the chunk's k/v into those pages in place (padding
         redirected to the null page), and record the slot state as if
         this were the final chunk — a later chunk simply overwrites it,
         so the last chunk's sample/key/position land without a traced
@@ -1084,7 +1084,7 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        from paddle_tpu.models.generation import paged_gather, paged_scatter
+        from paddle_tpu.models.generation import PagedCache, paged_scatter
 
         P = self._page_tokens
 
@@ -1092,13 +1092,8 @@ class GenerationEngine:
                     temp, top_k, top_p):
             pool = state["cache"]
             row = pt[slot]
-            cache = paged_gather(pool, row)
-            logits, cache = model.forward_with_cache(padded[None], cache,
-                                                     index=index)
-            chunk = tuple(
-                jax.lax.dynamic_slice_in_dim(c, index, padded.shape[0],
-                                             axis=3)
-                for c in cache)
+            logits, chunk = model.forward_with_cache(
+                padded[None], PagedCache(pool, row), index=index)
             pool = paged_scatter(pool, row, chunk, index, P,
                                  length=true_len)
             key, sub = jax.random.split(key)
@@ -1189,33 +1184,31 @@ class GenerationEngine:
                                       paged=False, n_in=3, n_out=2)
 
     def _build_paged_spec_step(self):
-        """Speculative verify in paged mode: gather each slot's pages,
-        forward the K+1-token window, then scatter ONLY the emitted
-        positions back through the page table — the rejected tail is
+        """Speculative verify in paged mode: forward each slot's
+        K+1-token window on its ``PagedCache``, then write ONLY the
+        emitted positions through the page table — the rejected tail is
         redirected to the null page (page-refcount-safe truncation:
         rejected drafts never land in a live page, so rollback cannot
         interact with prefix-shared pages or refcounts)."""
         import jax
         import jax.numpy as jnp
 
-        from paddle_tpu.models.generation import paged_gather
+        from paddle_tpu.models.generation import PagedCache, paged_write
 
         P, maxp = self._page_tokens, self._maxp
         K = self._spec_k
 
         def one(model, pt_row, tok, idx, key, temp, top_k, top_p, draft,
                 dlen, pool):
-            cache = paged_gather(pool, pt_row)
             ids = jnp.concatenate([tok[None], draft])[None]
-            logits, cache = model.forward_with_cache(ids, cache,
-                                                     index=idx)
-            chunk = tuple(
-                jax.lax.dynamic_slice_in_dim(c, idx, K + 1, axis=3)[:, 0]
-                for c in cache)               # [L, Hkv, K+1, *rest]
+            logits, chunk = model.forward_with_cache(
+                ids, PagedCache(pool, pt_row), index=idx)
             out, emit, new_key = self._spec_pick_accept(
                 jax, jnp, logits[0], key, temp, top_k, top_p, draft,
                 dlen)
-            return out, emit, new_key, chunk
+            # [K+1, L, Hkv, *rest]: one row a position
+            return out, emit, new_key, tuple(
+                jnp.moveaxis(c[:, 0], 2, 0) for c in chunk)
 
         def step(model, state, pt, active, drafts, dlens):
             pool = state["cache"]
@@ -1233,12 +1226,9 @@ class GenerationEngine:
             # truncation: positions past the accept point (and every
             # position of inactive slots, emit 0) go to the null page
             pages = jnp.where(j[None, :] < emit[:, None], pages, 0)
-            offs = pos % P
-            with jax.named_scope("kv/write"):
-                pool = tuple(
-                    buf.at[pages, :, :, offs].set(
-                        jnp.moveaxis(ch, 3, 1).astype(buf.dtype))
-                    for buf, ch in zip(pool, chunks))
+            pool = paged_write(
+                pool, pages.reshape(-1), (pos % P).reshape(-1),
+                tuple(c.reshape((-1,) + c.shape[2:]) for c in chunks))
             last = jnp.take_along_axis(
                 out, jnp.maximum(emit - 1, 0)[:, None], axis=1)[:, 0]
             tok = jnp.where(active, last, state["tok"])
@@ -1316,17 +1306,16 @@ class GenerationEngine:
             b *= 2
         return min(b, self.max_len)
 
-    def lowered_text(self, prompt_len: int) -> dict[str, str]:
-        """StableHLO text of the two compiled entry points a request of
-        ``prompt_len`` tokens runs — ``{"prefill": ..., "decode": ...}``
-        (the prefill at that length's bucket, the fused decode step) —
-        lowered from the live state's shapes and shardings. Nothing
-        executes and nothing is donated; call it on an idle engine (the
-        loop thread owns the state). Pallas kernels appear in the text
-        under their ``name=``, which is how ``chip_smoke.py`` checks
-        what the engine really dispatches; the named scopes
-        (``kv/gather``, ``kv/write``, ``sample``, the model's own) ride
-        in the operations' locations."""
+    def lowered(self, prompt_len: int) -> dict[str, Any]:
+        """The two compiled entry points a request of ``prompt_len``
+        tokens runs — ``{"prefill": ..., "decode": ...}`` (the prefill
+        at that length's bucket, the fused decode step) — as
+        ``jax.stages.Lowered``, from the live state's shapes and
+        shardings. Nothing executes and nothing is donated; call it on
+        an idle engine (the loop thread owns the state). ``.compile()``
+        gives the optimized program: its ``memory_analysis()`` and the
+        buffers it holds are how the tests see that a paged step keeps
+        no copy of the pool."""
         import jax
         import jax.numpy as jnp
 
@@ -1342,10 +1331,17 @@ class GenerationEngine:
         else:
             prefill = (self._state, i32, padded, i32, *sampling)
             decode = (self._state, active)
-        return {"prefill": self._prefill_fn.lower(*prefill).as_text(
-                    debug_info=True),
-                "decode": self._step.lower(*decode).as_text(
-                    debug_info=True)}
+        return {"prefill": self._prefill_fn.lower(*prefill),
+                "decode": self._step.lower(*decode)}
+
+    def lowered_text(self, prompt_len: int) -> dict[str, str]:
+        """StableHLO text of :meth:`lowered`'s two programs. Pallas
+        kernels appear in the text under their ``name=``, which is how
+        ``chip_smoke.py`` checks what the engine really dispatches; the
+        named scopes (``kv/gather``, ``kv/write``, ``sample``, the
+        model's own) ride in the operations' locations."""
+        return {name: low.as_text(debug_info=True)
+                for name, low in self.lowered(prompt_len).items()}
 
     # -- stream-lifecycle tracing + compile observability -------------------
     def _phase(self, name: str, goodput: str | None = None,
