@@ -11,4 +11,7 @@ from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 from paddle_tpu.models.mamba import MambaConfig, MambaForCausalLM
 from paddle_tpu.models.mlp import MLP, MNISTClassifier
 from paddle_tpu.models.moe import MoEConfig, MoEForCausalLM
+from paddle_tpu.models.deepseek_v3 import (
+    DeepseekV3Config, DeepseekV3ForCausalLM,
+)
 from paddle_tpu.models.ernie import ErnieConfig, ErnieForPretraining, ErnieModel

@@ -151,6 +151,115 @@ def cached_attention(q, k, v, cache, index, layer=0):
     return out, payload
 
 
+def init_latent_cache(num_layers, batch_size, max_len, width, dtype):
+    """The cache of a latent-attention (MLA) model in the shared leaf
+    layout: ONE leaf ``[L, B, 1, S, width]`` — a token's compressed
+    K/V row and its shared rope key side by side, nothing per head. The
+    ``1`` stands where the other families keep KV heads, so the page
+    pool, the page table and every write go through the functions that
+    serve them (``init_paged_cache``, ``PagedCache.read_layer``,
+    ``paged_write``, ``apply_cache_writes``) unchanged. The row is
+    padded with zeros to whole 128-lane tiles (576 -> 640), which is what
+    the TPU's tiled layout allocates for it anyway: stated as it is
+    allocated, a page update is whole tiles and the pool keeps its
+    layout (``generation.paged_write``)."""
+    dtype = jnp.dtype(dtype)
+    width = -(-width // 128) * 128
+    if not jnp.issubdtype(dtype, jnp.floating):
+        raise ValueError(
+            f"latent (MLA) cache in {dtype}: int8 latent cache leaves are "
+            "not implemented (the quantized layout is per KV head); use a "
+            "float dtype")
+    return (jnp.zeros((num_layers, batch_size, 1, max_len, width), dtype),)
+
+
+def latent_attention(q_nope, q_rope, c_kv, k_rope, w_kc, w_vc, scale,
+                     cache=None, index=None, layer=0):
+    """Attention core of a latent-attention (MLA, DeepSeek-V2/V3) layer,
+    in the two arithmetic forms it needs.
+
+    ``q_nope`` [B, T, H, N] and ``q_rope`` [B, T, H, R] (rotated);
+    ``c_kv`` [B, T, C] the chunk's compressed K/V after its norm and
+    ``k_rope`` [B, T, R] its one rope key a token (rotated), shared by
+    all heads; ``w_kc`` [C, H, N] and ``w_vc`` [C, H, V] the two halves
+    of the K/V up-projection. Returns ``(out [B, T, H, V], payload)``;
+    the payload is the chunk's cache rows ``[B, 1, T, C + R + pad]``
+    (absent with no cache).
+
+    *Expanded* (nothing behind the chunk — no cache, or a static index
+    0): per-head keys ``[c_kv·w_kc | k_rope]`` and values ``c_kv·w_vc``
+    are made for the chunk and attended causally; per position that is
+    C·H·(N+V) multiply-adds once.
+
+    *Absorbed* (a cache behind the chunk: decode, and a prefill chunk
+    after a cached prefix): the up-projection moves onto the query and
+    the output — ``q~ = q_nope·w_kc^T`` [C], score ``= q~·c_kv(s) +
+    q_rope·k_rope(s)``, ``u = sum_s p·c_kv(s)``, ``o = u·w_vc`` — so a
+    cached position is read as its C + R numbers and never expanded
+    (expanding costs C·H·(N+V) multiply-adds a cached position a
+    layer for every call). The chunk's own rows attend in the same
+    form, jointly normalised with the cached ones (two-piece softmax in
+    float32, as ``cached_attention``)."""
+    import jax
+
+    B, T, H, _ = q_nope.shape
+    C, R_ = c_kv.shape[-1], k_rope.shape[-1]
+    payload = None
+    if cache is not None:
+        paged = isinstance(cache, PagedCache)
+        buf = (cache.pool if paged else cache)[0]
+        pad = jnp.zeros(c_kv.shape[:-1] + (buf.shape[-1] - C - R_,),
+                        c_kv.dtype)
+        payload = (jnp.concatenate([c_kv, k_rope, pad], axis=-1)[:, None]
+                   .astype(buf.dtype),)
+
+    if cache is None or index is None or (isinstance(index, int)
+                                          and index == 0):
+        with jax.named_scope("mla/attend"):
+            k_nope = jnp.einsum("btc,chn->bthn", c_kv, w_kc)
+            v = jnp.einsum("btc,chv->bthv", c_kv, w_vc)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_rope[:, :, None],
+                                          (B, T, H, R_))], -1)
+            q = jnp.concatenate([q_nope, q_rope], -1)
+            out = F.scaled_dot_product_attention(q, k, v, causal=True,
+                                                 scale=scale)
+        return out, payload
+
+    idx = jnp.asarray(index, jnp.int32)
+    if paged:
+        (lat,) = cache.read_layer(layer)                # [1, 1, S, C+R]
+    else:
+        lat = (buf[layer] if isinstance(layer, int) else
+               jax.lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False))
+    lat = lat[:, 0].astype(q_nope.dtype)                # [B, S, C+R]
+    c_c, r_c = lat[..., :C], lat[..., C:C + R_]
+    S = lat.shape[1]
+    neg = jnp.finfo(jnp.float32).min
+    with jax.named_scope("mla/absorb"):
+        q_lat = jnp.einsum("bthn,chn->bthc", q_nope, w_kc)
+    with jax.named_scope("mla/attend"):
+        # scores come out of the products in float32 (they are cast for
+        # the softmax anyway): a score of ~10 rounded to bf16 is off by
+        # 0.03 in the exponent
+        f32 = dict(preferred_element_type=jnp.float32)
+        s_c = (jnp.einsum("bthc,bsc->bhts", q_lat, c_c, **f32)
+               + jnp.einsum("bthr,bsr->bhts", q_rope, r_c, **f32)) * scale
+        s_c = jnp.where((jnp.arange(S) < idx)[None, None, None, :], s_c, neg)
+        s_n = (jnp.einsum("bthc,buc->bhtu", q_lat, c_kv, **f32)
+               + jnp.einsum("bthr,bur->bhtu", q_rope, k_rope, **f32)) * scale
+        chunk_causal = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
+        s_n = jnp.where(chunk_causal[None, None], s_n, neg)
+        probs = jax.nn.softmax(jnp.concatenate([s_c, s_n], -1), axis=-1)
+        p_c = probs[..., :S].astype(q_nope.dtype)
+        p_n = probs[..., S:].astype(q_nope.dtype)
+        u = (jnp.einsum("bhts,bsc->bthc", p_c, c_c)
+             + jnp.einsum("bhtu,buc->bthc", p_n, c_kv))
+    with jax.named_scope("mla/absorb"):
+        out = jnp.einsum("bthc,chv->bthv", u, w_vc)
+    return out, payload
+
+
 def apply_cache_writes(cache, payload, index):
     """Write the stacked per-layer chunk payloads ([L, B, Hkv, T, ...])
     into the static cache at position ``index`` — one

@@ -48,6 +48,22 @@ ample-capacity three-way agreement.
 
 Load-balancing auxiliary loss follows Switch/GShard:
 ``aux = E * sum_e(frac_tokens_e * mean_gate_e)``.
+
+Routing rule (``route``) is a parameter of the one expert layer:
+``"softmax"`` (Switch/Mixtral/OLMoE: softmax over all experts,
+sequential top-k, gates not renormalised) or ``"sigmoid_group"``
+(DeepSeek-V3: sigmoid scores, a selection bias that moves picks and not
+gates, group-limited top-k, gates normalised over the chosen and
+scaled; no aux loss). A layer may also carry one shared expert
+(``shared_size``) that every token passes through, and may be told
+which experts it holds (``held = (first, count)``): it then routes over
+all ``num_experts``, allocates and computes only its own, and returns
+their part of the result — one chip's share of an expert-parallel
+layer, without the exchange. The held form is dropless by
+construction: its capacity is the chunk's token count, at which a
+pick's slot is its token's own index, so the expert buffer is the token
+block itself and the gate matrix (zero where an expert was not picked)
+is the combine.
 """
 
 from __future__ import annotations
@@ -63,7 +79,8 @@ from paddle_tpu.core.module import Module
 from paddle_tpu.nn import functional as F
 from paddle_tpu.nn.initializer import Normal
 
-__all__ = ["MoEMLP", "top_k_routing", "top_k_routing_compact"]
+__all__ = ["MoEMLP", "top_k_routing", "top_k_routing_compact",
+           "sigmoid_group_picks"]
 
 
 def _constrain(x, spec: P):
@@ -168,6 +185,38 @@ def top_k_routing_compact(logits, k: int, capacity: int):
     return expert, slot, keep, gate, aux_loss
 
 
+def softmax_picks(logits, k: int):
+    """The softmax rule's picks with no capacity: ``(expert, gate)``
+    [N, k] — the k largest probabilities in order, gates as they are."""
+    gate, expert = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    return expert, gate
+
+
+def sigmoid_group_picks(logits, bias, k: int, n_group: int,
+                        topk_group: int, scale: float):
+    """DeepSeek-V3 routing. Scores ``s = sigmoid(logits)``; selection
+    scores ``s' = s + bias``; experts lie in ``n_group`` groups of
+    consecutive ids, a group scores the sum of its two largest ``s'``,
+    the ``topk_group`` best groups stay and the ``k`` largest ``s'``
+    among their experts are picked. Gates are ``s`` (not ``s'``) at the
+    picks, divided by their sum and multiplied by ``scale``. Returns
+    ``(expert, gate)`` [N, k], float32 gates."""
+    n, e = logits.shape
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    sel = s + bias.astype(jnp.float32)
+    per = sel.reshape(n, n_group, e // n_group)
+    group_score = jnp.sum(jax.lax.top_k(per, 2)[0], axis=-1)   # [N, G]
+    _, best = jax.lax.top_k(group_score, topk_group)
+    keep = jnp.sum(jax.nn.one_hot(best, n_group, dtype=jnp.int32),
+                   axis=1) > 0                                 # [N, G]
+    sel = jnp.where(jnp.repeat(keep, e // n_group, axis=1), sel,
+                    -jnp.inf)
+    _, expert = jax.lax.top_k(sel, k)
+    gate = jnp.take_along_axis(s, expert, axis=1)
+    gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20) * scale
+    return expert, gate
+
+
 class MoEMLP(Module):
     """Top-k routed SwiGLU expert MLPs (drop-in for a dense LlamaMLP).
 
@@ -179,31 +228,78 @@ class MoEMLP(Module):
                  num_experts: int, *, top_k: int = 2,
                  capacity_factor: float = 1.25, init_std: float = 0.02,
                  num_layers: int = 1, dtype=jnp.float32,
-                 dispatch_mode: str = "auto", key=None):
+                 dispatch_mode: str = "auto", route: str = "softmax",
+                 n_group: int = 1, topk_group: int = 1,
+                 routed_scale: float = 1.0, shared_size: int = 0,
+                 held: tuple[int, int] | None = None, key=None):
         if dispatch_mode not in ("auto", "einsum", "gather",
                                  "gather_grouped"):
             raise ValueError(
                 f"dispatch_mode must be auto|einsum|gather|gather_grouped,"
                 f" got {dispatch_mode!r}")
-        keys = rng.split_key(key, 4)
+        if route not in ("softmax", "sigmoid_group"):
+            raise ValueError(
+                f"route must be softmax|sigmoid_group, got {route!r}")
         E, H, I_ = num_experts, hidden_size, intermediate_size
+        if route == "sigmoid_group":
+            if E % n_group or not 1 <= topk_group <= n_group:
+                raise ValueError(
+                    f"sigmoid_group routing: {n_group} groups must divide "
+                    f"{E} experts and topk_group {topk_group} lie in "
+                    f"1..{n_group}")
+        if held is None and (route == "sigmoid_group" or shared_size):
+            # the capacity dispatch forms are the plain softmax layer's
+            held = (0, E)
+        if held is not None:
+            first, count = (int(v) for v in held)
+            if not (0 <= first and 0 < count and first + count <= E):
+                raise ValueError(f"held {held!r} is not a range of the "
+                                 f"{E} experts")
+            held = (first, count)
+        keys = rng.split_key(key, 4)
+        n_held = E if held is None else held[1]
         init = Normal(0.0, init_std)
         down_init = Normal(0.0, init_std / math.sqrt(2 * num_layers))
-        # router replicated (tiny); experts stacked on a leading ep axis
+        # router replicated (tiny); experts stacked on a leading ep axis.
+        # A layer that holds a share allocates its own experts only; the
+        # router keeps its full width.
         self.router = init(keys[0], (H, E), jnp.float32)
-        self.w_gate = init(keys[1], (E, H, I_), dtype)
-        self.w_up = init(keys[2], (E, H, I_), dtype)
-        self.w_down = down_init(keys[3], (E, I_, H), dtype)
-        self._pspecs = (
+        self.w_gate = init(keys[1], (n_held, H, I_), dtype)
+        self.w_up = init(keys[2], (n_held, H, I_), dtype)
+        self.w_down = down_init(keys[3], (n_held, I_, H), dtype)
+        pspecs = [
             ("router", P()),
             ("w_gate", P("ep", "fsdp", "tp")),
             ("w_up", P("ep", "fsdp", "tp")),
             ("w_down", P("ep", "tp", "fsdp")),
-        )
+        ]
+        if route == "sigmoid_group":
+            # e_score_correction_bias: moves picks, never gates
+            self.select_bias = jnp.zeros((E,), jnp.float32)
+            pspecs.append(("select_bias", P()))
+        if shared_size:
+            ks = rng.split_key(keys[0], 3)
+            S_ = int(shared_size)
+            self.shared_gate = init(ks[0], (H, S_), dtype)
+            self.shared_up = init(ks[1], (H, S_), dtype)
+            self.shared_down = down_init(ks[2], (S_, H), dtype)
+            pspecs += [("shared_gate", P("fsdp", "tp")),
+                       ("shared_up", P("fsdp", "tp")),
+                       ("shared_down", P("tp", "fsdp"))]
+        self._pspecs = tuple(pspecs)
         self.num_experts = E
         self.top_k = int(top_k)
         self.capacity_factor = float(capacity_factor)
         self.dispatch_mode = dispatch_mode
+        if held is not None:
+            # static fields only where they are used: the plain softmax
+            # layer keeps the tree structure it had
+            from paddle_tpu.nn.stateful import new_uid
+            self.route = route
+            self.n_group, self.topk_group = int(n_group), int(topk_group)
+            self.routed_scale = float(routed_scale)
+            self.held = held
+            self._uid = new_uid()
 
     def capacity(self, n_tokens: int) -> int:
         c = int(math.ceil(n_tokens * self.top_k * self.capacity_factor
@@ -256,6 +352,15 @@ class MoEMLP(Module):
         # router in fp32 for stable softmax (standard MoE practice).
         # The moe/* scopes name the block's four stages in the device
         # trace, which otherwise shows only fusions.
+        if getattr(self, "held", None) is not None:
+            out, aux = self._call_held(tokens, b, t)
+            if hasattr(self, "shared_gate"):
+                with jax.named_scope("moe/shared"):
+                    out = out + (F.swiglu(tokens @ self.shared_up,
+                                          tokens @ self.shared_gate)
+                                 @ self.shared_down)
+            return out.reshape(b, t, h), aux
+
         with jax.named_scope("moe/route"):
             logits = tokens.astype(jnp.float32) @ self.router
 
@@ -269,6 +374,44 @@ class MoEMLP(Module):
         else:
             raise ValueError(f"unknown dispatch_mode {mode!r}")
         return out.reshape(b, t, h), aux.astype(jnp.float32)
+
+    def _call_held(self, tokens, b, t):
+        """The dropless form of a layer that holds experts ``[first,
+        first + count)``: route every token over all experts, run the
+        held ones on the whole token block (capacity = token count, slot
+        = token index) and combine with the gate matrix, which is zero
+        wherever a held expert was not picked. What the experts held
+        elsewhere would add is left out. Records, for whoever sums over
+        live positions, each token's picks and those that fell here."""
+        from paddle_tpu.nn.stateful import record_count
+
+        first, count = self.held
+        with jax.named_scope("moe/route"):
+            # float32 throughout: a pick decided in bf16 is another pick
+            logits = jnp.matmul(tokens.astype(jnp.float32), self.router,
+                                precision=jax.lax.Precision.HIGHEST)
+            if self.route == "sigmoid_group":
+                expert, gate = sigmoid_group_picks(
+                    logits, self.select_bias, self.top_k, self.n_group,
+                    self.topk_group, self.routed_scale)
+            else:
+                expert, gate = softmax_picks(logits, self.top_k)
+            # one_hot of an id outside [0, count) is a zero row
+            here = jax.nn.one_hot(expert - first, count, dtype=gate.dtype)
+            gates = jnp.einsum("nk,nkc->nc", gate, here)       # [N, held]
+            record_count(
+                self._uid,
+                moe_picks=jnp.full((b, t), self.top_k, jnp.int32),
+                moe_picks_held=jnp.sum(here, axis=(1, 2)).astype(
+                    jnp.int32).reshape(b, t))
+        with jax.named_scope("moe/dispatch"):
+            expert_in = jnp.broadcast_to(tokens[None],
+                                         (count,) + tokens.shape)
+        expert_out = self._experts(expert_in)                  # [held, N, H]
+        with jax.named_scope("moe/combine"):
+            out = jnp.einsum("cnh,nc->nh", expert_out,
+                             gates.astype(tokens.dtype))
+        return out, jnp.zeros((), jnp.float32)
 
     def _call_einsum(self, tokens, logits, n, h, cap):
         with jax.named_scope("moe/route"):

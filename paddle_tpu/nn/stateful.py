@@ -96,6 +96,39 @@ def collect_aux(tape: dict):
     return total
 
 
+# Reserved tape entry name for per-position COUNTS a layer makes of its
+# own live work (an expert layer's routed picks). Like the aux loss they
+# ride the per-layer tape out of a scan and are never merged into module
+# state; whoever knows which positions are live (the serving engine:
+# active slots, unpadded prefill positions) sums them.
+COUNT_KEY = "live_counts"
+
+
+def record_count(uid: int, **counts) -> bool:
+    """Record integer count arrays (one value a position, ``[B, T]``)
+    under ``COUNT_KEY``. False with no tape active: nothing is kept and
+    XLA drops the arithmetic."""
+    tape = _tape_var.get()
+    if tape is None:
+        return False
+    tape.setdefault(uid, {})[COUNT_KEY] = counts
+    return True
+
+
+def collect_counts(tape: dict) -> dict:
+    """Sum every ``COUNT_KEY`` entry on the tape by name over modules
+    and over leading layer axes (leaves may be ``[L, B, T]``): one
+    ``[B, T]`` int32 array a name."""
+    import jax.numpy as jnp
+
+    out: dict = {}
+    for updates in tape.values():
+        for name, v in updates.get(COUNT_KEY, {}).items():
+            v = jnp.sum(v.reshape((-1,) + v.shape[-2:]), axis=0)
+            out[name] = out[name] + v if name in out else v
+    return out
+
+
 def map_modules(fn, tree):
     """Bottom-up map over every Module in a pytree (children first)."""
 
@@ -138,8 +171,8 @@ def merge_state(model, tape: dict):
         if uid is not None and uid in tape:
             updates = {}
             for k, v in tape[uid].items():
-                if k == AUX_LOSS_KEY:
-                    # loss contribution, not module state
+                if k in (AUX_LOSS_KEY, COUNT_KEY):
+                    # loss contribution / live counts, not module state
                     continue
                 cur = getattr(m, k, None)
                 if (hasattr(v, "astype") and hasattr(cur, "dtype")

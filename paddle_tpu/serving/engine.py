@@ -732,6 +732,19 @@ class GenerationEngine:
         # HERE only, never on the decode hot path). Sharded params are
         # committed before any cache/entry-point construction so the
         # partitioner sees one consistent layout.
+        if getattr(model, "latent_cache", False) and draft_model is not None:
+            raise ValueError(
+                "a draft model beside a latent (MLA) cache is not "
+                "implemented: the lookahead keeps a per-head K/V scratch "
+                "cache of its own bucket; use spec_mode='ngram' or none")
+        # counts the model's layers record per position on a state tape
+        # (an expert layer's routed picks): summed over LIVE positions
+        # on the device, in the donated state, and fetched by stats().
+        # A model that names none compiles the programs it always did.
+        self._count_names = tuple(getattr(model, "live_counts", ()))
+        self._counts_host = dict.fromkeys(self._count_names, 0)
+        self._counts_base = dict(self._counts_host)
+        self._counts_req: threading.Event | None = None
         from paddle_tpu.serving.layout import DeviceLayout
         self._layout = DeviceLayout(int(flag("gen_mesh_tp")
                                         if mesh_tp is None else mesh_tp))
@@ -879,9 +892,16 @@ class GenerationEngine:
         # topology for stats()/health: static for the engine's lifetime
         # (the cache pool never resizes), so computed once here
         import jax
-        kv_bytes = sum(int(x.nbytes) for x in
-                       jax.tree_util.tree_leaves(self._state["cache"]))
+        leaves = jax.tree_util.tree_leaves(self._state["cache"])
+        kv_bytes = sum(int(x.nbytes) for x in leaves)
         self._device_info = self._layout.describe(kv_bytes)
+        # bytes one token position takes in the cache leaves as they are
+        # allocated (every layer, every leaf): pool pages x page tokens,
+        # or slots x max_len
+        self._kv_bytes_per_token = sum(
+            x.nbytes / (x.shape[0] * (self._page_tokens if self._paged
+                                      else self.max_len))
+            for x in leaves)
         if self._paged:
             self._step = self._build_paged_step()
             self._prefill_fn = self._build_paged_prefill()
@@ -962,12 +982,48 @@ class GenerationEngine:
             "top_k": jnp.zeros((self.slots,), jnp.int32),
             "top_p": jnp.ones((self.slots,), jnp.float32),
         }
+        if self._count_names:
+            # (hi, lo) words of 30 bits a name: exact past 2**31
+            state["counts"] = jnp.zeros((len(self._count_names), 2),
+                                        jnp.int32)
         # commit to the device layout (identity at gen_mesh_tp=0): KV
         # leaves land sharded on the KV-head axis, scalars replicated,
         # matching the explicit shardings every entry point compiles with
         return self._layout.place_state(state, paged=self._paged)
 
     # -- compiled pieces ---------------------------------------------------
+    def _forward(self, model, ids, cache, index):
+        """``forward_with_cache`` and, for a model that names
+        ``live_counts``, what its layers recorded on the tape: ``[T,
+        names]`` int32, one row a position of the chunk (else None)."""
+        if not self._count_names:
+            return (*model.forward_with_cache(ids, cache, index=index),
+                    None)
+        import jax.numpy as jnp
+
+        from paddle_tpu.nn.stateful import collect_counts, tape_call
+        (logits, cache), tape = tape_call(model.forward_with_cache, ids,
+                                          cache, index=index)
+        got = collect_counts(tape)
+        return logits, cache, jnp.stack(
+            [got[n][0] for n in self._count_names], axis=-1)
+
+    def _counted(self, state, counts, live):
+        """``state`` with the counts of the live positions added
+        (``counts`` [..., names], ``live`` broadcastable to its
+        positions): padding, rejected drafts and idle slots route too
+        and are left out."""
+        if counts is None:
+            return state
+        import jax.numpy as jnp
+
+        add = jnp.sum(jnp.where(live, counts, 0).reshape(
+            -1, counts.shape[-1]), axis=0)
+        lo = state["counts"][:, 1] + add
+        return dict(state, counts=jnp.stack(
+            [state["counts"][:, 0] + (lo >> 30), lo & ((1 << 30) - 1)],
+            axis=1))
+
     def _build_step(self):
         """ONE fused decode for all slots: vmap the model's single-token
         cached forward over the slot axis with per-slot positions/keys/
@@ -978,18 +1034,20 @@ class GenerationEngine:
         import jax.numpy as jnp
 
         def one(model, cache, tok, idx, key, temp, top_k, top_p):
-            logits, cache = model.forward_with_cache(
-                tok[None, None], cache, index=idx)
+            logits, cache, cnt = self._forward(model, tok[None, None],
+                                               cache, idx)
             key, sub = jax.random.split(key)
             nxt = _sample_slot(logits[0, -1], sub, temp, top_k, top_p)
-            return cache, nxt, key
+            return cache, nxt, key, cnt
 
         def step(model, state, active):
-            cache, nxt, keys = jax.vmap(functools.partial(one, model))(
+            cache, nxt, keys, cnt = jax.vmap(
+                functools.partial(one, model))(
                 state["cache"], state["tok"], state["pos"], state["keys"],
                 state["temp"], state["top_k"], state["top_p"])
             tok = jnp.where(active, nxt, state["tok"])
             pos = state["pos"] + active.astype(jnp.int32)
+            state = self._counted(state, cnt, active[:, None, None])
             return dict(state, cache=cache, tok=tok, pos=pos,
                         keys=keys), tok
 
@@ -1009,15 +1067,16 @@ class GenerationEngine:
         def prefill(model, state, slot, padded, true_len, key, temp, top_k,
                     top_p):
             b1 = model.init_cache(1, S, dtype=cache_dtype)
-            logits, b1 = model.forward_with_cache(padded[None], b1,
-                                                  index=0)
+            logits, b1, cnt = self._forward(model, padded[None], b1, 0)
             key, sub = jax.random.split(key)
             tok0 = _sample_slot(logits[0, true_len - 1], sub, temp, top_k,
                                 top_p)
             cache = jax.tree_util.tree_map(
                 lambda big, sm: big.at[slot].set(sm), state["cache"], b1)
+            state = self._counted(
+                state, cnt, (jnp.arange(padded.shape[0]) < true_len)[:, None])
             return dict(
-                cache=cache,
+                state, cache=cache,
                 tok=state["tok"].at[slot].set(tok0),
                 pos=state["pos"].at[slot].set(true_len),
                 keys=state["keys"].at[slot].set(key),
@@ -1047,15 +1106,15 @@ class GenerationEngine:
         slots = self.slots
 
         def one(model, pt_row, tok, idx, key, temp, top_k, top_p, pool):
-            logits, new = model.forward_with_cache(
-                tok[None, None], PagedCache(pool, pt_row), index=idx)
+            logits, new, cnt = self._forward(
+                model, tok[None, None], PagedCache(pool, pt_row), idx)
             key, sub = jax.random.split(key)
             nxt = _sample_slot(logits[0, -1], sub, temp, top_k, top_p)
-            return nxt, key, tuple(n[:, 0, :, 0] for n in new)
+            return nxt, key, tuple(n[:, 0, :, 0] for n in new), cnt
 
         def step(model, state, pt, active):
             pool = state["cache"]
-            nxt, keys, new = jax.vmap(
+            nxt, keys, new, cnt = jax.vmap(
                 functools.partial(one, model),
                 in_axes=(0, 0, 0, 0, 0, 0, 0, None))(
                 pt, state["tok"], state["pos"], state["keys"],
@@ -1065,6 +1124,7 @@ class GenerationEngine:
             pool = paged_write(pool, pages, state["pos"] % P, new)
             tok = jnp.where(active, nxt, state["tok"])
             pos = state["pos"] + active.astype(jnp.int32)
+            state = self._counted(state, cnt, active[:, None, None])
             return dict(state, cache=pool, tok=tok, pos=pos,
                         keys=keys), tok
 
@@ -1092,15 +1152,17 @@ class GenerationEngine:
                     temp, top_k, top_p):
             pool = state["cache"]
             row = pt[slot]
-            logits, chunk = model.forward_with_cache(
-                padded[None], PagedCache(pool, row), index=index)
+            logits, chunk, cnt = self._forward(
+                model, padded[None], PagedCache(pool, row), index)
             pool = paged_scatter(pool, row, chunk, index, P,
                                  length=true_len)
             key, sub = jax.random.split(key)
             tok0 = _sample_slot(logits[0, true_len - 1], sub, temp, top_k,
                                 top_p)
+            state = self._counted(
+                state, cnt, (jnp.arange(padded.shape[0]) < true_len)[:, None])
             return dict(
-                cache=pool,
+                state, cache=pool,
                 tok=state["tok"].at[slot].set(tok0),
                 pos=state["pos"].at[slot].set(index + true_len),
                 keys=state["keys"].at[slot].set(key),
@@ -1160,19 +1222,22 @@ class GenerationEngine:
         def one(model, cache, tok, idx, key, temp, top_k, top_p, draft,
                 dlen):
             ids = jnp.concatenate([tok[None], draft])[None]   # [1, K+1]
-            logits, cache = model.forward_with_cache(ids, cache,
-                                                     index=idx)
+            logits, cache, cnt = self._forward(model, ids, cache, idx)
             out, emit, new_key = self._spec_pick_accept(
                 jax, jnp, logits[0], key, temp, top_k, top_p, draft,
                 dlen)
-            return cache, out, emit, new_key
+            return cache, out, emit, new_key, cnt
 
         def step(model, state, active, drafts, dlens):
-            cache, out, emit, keys = jax.vmap(functools.partial(one, model))(
+            cache, out, emit, keys, cnt = jax.vmap(
+                functools.partial(one, model))(
                 state["cache"], state["tok"], state["pos"], state["keys"],
                 state["temp"], state["top_k"], state["top_p"], drafts,
                 dlens)
             emit = jnp.where(active, emit, 0)
+            state = self._counted(
+                state, cnt,
+                (jnp.arange(self._spec_k + 1)[None] < emit[:, None])[..., None])
             last = jnp.take_along_axis(
                 out, jnp.maximum(emit - 1, 0)[:, None], axis=1)[:, 0]
             tok = jnp.where(active, last, state["tok"])
@@ -1201,18 +1266,18 @@ class GenerationEngine:
         def one(model, pt_row, tok, idx, key, temp, top_k, top_p, draft,
                 dlen, pool):
             ids = jnp.concatenate([tok[None], draft])[None]
-            logits, chunk = model.forward_with_cache(
-                ids, PagedCache(pool, pt_row), index=idx)
+            logits, chunk, cnt = self._forward(
+                model, ids, PagedCache(pool, pt_row), idx)
             out, emit, new_key = self._spec_pick_accept(
                 jax, jnp, logits[0], key, temp, top_k, top_p, draft,
                 dlen)
             # [K+1, L, Hkv, *rest]: one row a position
             return out, emit, new_key, tuple(
-                jnp.moveaxis(c[:, 0], 2, 0) for c in chunk)
+                jnp.moveaxis(c[:, 0], 2, 0) for c in chunk), cnt
 
         def step(model, state, pt, active, drafts, dlens):
             pool = state["cache"]
-            out, emit, keys, chunks = jax.vmap(
+            out, emit, keys, chunks, cnt = jax.vmap(
                 functools.partial(one, model),
                 in_axes=(0,) * 9 + (None,))(
                 pt, state["tok"], state["pos"], state["keys"],
@@ -1220,6 +1285,8 @@ class GenerationEngine:
                 dlens, pool)
             emit = jnp.where(active, emit, 0)
             j = jnp.arange(K + 1)
+            state = self._counted(
+                state, cnt, (j[None, :] < emit[:, None])[..., None])
             pos = state["pos"][:, None] + j[None, :]      # [slots, K+1]
             pidx = jnp.clip(pos // P, 0, maxp - 1)
             pages = jnp.take_along_axis(pt, pidx, axis=1)
@@ -1624,6 +1691,7 @@ class GenerationEngine:
         ``health`` op — routers/probes see generation capacity AND, in
         paged mode, how much of the page pool and prefix cache is
         live)."""
+        counts = self._live_counts()
         with self._cond:
             active = sum(g is not None for g in self._slot_gen)
             doc = {"slots": self.slots, "active": active,
@@ -1672,7 +1740,11 @@ class GenerationEngine:
                    # bench/chaos harnesses can assert which loop ran
                    "device_pt": self._device_pt,
                    "async_depth": self._async_depth,
-                   "pending_steps": len(self._pending)}
+                   "pending_steps": len(self._pending),
+                   "kv_bytes_per_token": self._kv_bytes_per_token}
+            # the model's live counts (absent for a model that names
+            # none): monotone, summed on the device over live positions
+            doc.update(counts)
             if self._spec_k > 0:
                 prop = self._spec_proposed
                 doc["spec"] = {
@@ -1720,6 +1792,36 @@ class GenerationEngine:
                                  prefill_recomputed=self._kv_recomputed,
                                  fetch_degraded=self._kv_degraded)
             return doc
+
+    def _live_counts(self) -> dict:
+        """The model's ``live_counts`` as of the loop's next iteration
+        boundary: the device words live in the donated state, which only
+        the loop thread may read, so a reader asks and waits for the
+        loop to fetch them (one small readback a ``stats()`` call, none
+        a step). With the loop gone, the last fetch stands."""
+        if not self._count_names:
+            return {}
+        asked = None
+        with self._cond:
+            if (self._thread.is_alive() and not self._stopping
+                    and threading.current_thread() is not self._thread):
+                asked = self._counts_req = (self._counts_req
+                                            or threading.Event())
+                self._cond.notify_all()
+        if asked is not None:
+            asked.wait(timeout=2.0)
+        return dict(self._counts_host)
+
+    def _fetch_counts_locked(self) -> None:
+        """Loop thread, between compiled calls: read the count words."""
+        words = np.asarray(self._state["counts"]).astype(np.int64)
+        for name, (hi, lo) in zip(self._count_names, words):
+            total = self._counts_base[name] + (int(hi) << 30) + int(lo)
+            self._counts_host[name] = total
+            stat_set(f"gen/{name}", total)
+        if self._counts_req is not None:
+            self._counts_req.set()
+            self._counts_req = None
 
     def ledger_dump(self, limit: int | None = None) -> dict | None:
         """Finalized per-request phase records + tenant book + goodput
@@ -1828,6 +1930,10 @@ class GenerationEngine:
             with self._phase("gen/loop",
                              clock=self._goodput is not None) as it:
                 stop = self._iterate(jnp, it)
+            if self._counts_req is not None or (stop and self._count_names
+                                                and not self._broken):
+                with self._cond:
+                    self._fetch_counts_locked()
             if self._goodput is not None:
                 # close this iteration's taxonomy on the iteration's own
                 # last clock read: the un-noted remainder is host-side
@@ -2015,6 +2121,9 @@ class GenerationEngine:
                     self._prefix = _PrefixCache(self._page_tokens)
                 stat_set("gen/pages_free", self._pool.free_count)
             self._state = fresh
+            # the words start again at nought: what stats() last saw
+            # stays counted, the tail since then is lost with the state
+            self._counts_base = dict(self._counts_host)
             self._stuck = False
             self._cond.notify_all()
 
