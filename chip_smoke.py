@@ -58,9 +58,14 @@ PREFILL_KERNELS = {
     "contiguous": ("ptpu_flash_fwd", "ptpu_rms_norm_fwd", "ptpu_rope"),
     "paged": ("ptpu_rms_norm_fwd", "ptpu_rope"),
 }
-# the paged step attends through ``generation.PagedCache`` (a per-layer
-# gather and the einsum arm): no decode kernel in it
-DECODE_KERNELS = {"contiguous": ("ptpu_decode_attn",), "paged": ()}
+# the contiguous step runs the stacked-cache kernel under the engine's
+# vmap; the paged step on one chip attends through the page table with
+# the paged kernel, one call a layer for all slots. Under a mesh of
+# several devices the paged layout has no per-shard unit: that step
+# gathers a layer's pages for the einsum arm and holds no decode kernel
+# (``serve_phase`` expects none there).
+DECODE_KERNELS = {"contiguous": ("ptpu_decode_attn",),
+                  "paged": ("ptpu_paged_decode_attn",)}
 # per-shard (shard_map) units the four-chip programs must take on the
 # kernel arm (``ops.pallas.partition_stats()`` keys ``<unit>:kernel``)
 TRAIN_UNITS = ("flash_fwd", "flash_bwd", "rms_fwd", "rms_bwd", "rope",
@@ -85,6 +90,9 @@ SERVE_UNITS = ("flash_fwd", "rms_fwd", "rope", "decode_attn")
 # or a dropped term lands far outside them.
 LOGIT_RMS_RTOL = 5e-2
 LOGIT_MAX_RTOL = 8e-2
+# page size of the parity phase's paged decode: the engine's default
+# (``FLAGS_gen_page_tokens``)
+PARITY_PAGE_TOKENS = 16
 
 
 class SmokeFailure(AssertionError):
@@ -314,9 +322,10 @@ def _check_units(what: str, units) -> dict:
 # ---------------------------------------------------------------------------
 
 def _parity_programs(seq: int):
-    """Fresh jits of the four compared programs (the interpret flag is
+    """Fresh jits of the five compared programs (the interpret flag is
     read while tracing, so each mode traces its own)."""
     import jax
+    import jax.numpy as jnp
 
     def prefill(m, x):
         cache = m.init_cache(1, 2 * seq)
@@ -329,6 +338,24 @@ def _parity_programs(seq: int):
             return logits[0, -1]
         return jax.vmap(one)(caches, toks, fills)
 
+    def paged_decode(m, cache, toks, fills):
+        # the same slots read through a page table: the cache scattered
+        # over a pool's pages in reverse order, the pool unmapped under
+        # the vmap as in the engine's paged step
+        from paddle_tpu.models.generation import (
+            PagedCache, init_paged_cache, paged_scatter,
+        )
+        page = PARITY_PAGE_TOKENS
+        row = jnp.arange(2 * seq // page, 0, -1, dtype=jnp.int32)
+        pool = paged_scatter(init_paged_cache(cache, row.size, page), row,
+                             cache, 0, page)
+
+        def one(tok, fill):
+            logits, _ = m.forward_with_cache(
+                tok[None, None], PagedCache(pool, row), index=fill)
+            return logits[0, -1]
+        return jax.vmap(one)(toks, fills)
+
     def chunked_prefill(m, x):
         # the prompt's tail forwarded against its already-cached head:
         # what a prefix-cache hit and chunked prefill run
@@ -339,7 +366,7 @@ def _parity_programs(seq: int):
         return logits
 
     return (jax.jit(lambda m, x: m(x)), jax.jit(prefill), jax.jit(decode),
-            jax.jit(chunked_prefill))
+            jax.jit(paged_decode), jax.jit(chunked_prefill))
 
 
 def parity_phase(model, *, seq: int, rms_rtol: float = LOGIT_RMS_RTOL,
@@ -347,10 +374,12 @@ def parity_phase(model, *, seq: int, rms_rtol: float = LOGIT_RMS_RTOL,
     """Logits of one training forward, one prefill, one chunked prefill
     and one engine-style cached decode step (vmapped over two slots with
     different fill positions — the batched scalar-prefetch form the
-    engine sends the decode kernel through), compiled vs traced under
-    ``force_interpret()`` (both decode variants read the SAME cache);
-    and, across paths, the chunked prefill and the decode step against
-    the one-shot prefill's logits at the same positions."""
+    engine sends the decode kernel through) on the contiguous cache and
+    on the same cache behind a page table (the paged kernel over the
+    slot axis), compiled vs traced under ``force_interpret()`` (all
+    decode variants read the SAME cache); and, across paths, the chunked
+    prefill and both decode steps against the one-shot prefill's logits
+    at the same positions."""
     import jax
     import jax.numpy as jnp
 
@@ -365,15 +394,16 @@ def parity_phase(model, *, seq: int, rms_rtol: float = LOGIT_RMS_RTOL,
     fills = jnp.asarray([seq - 1, seq - 1 - seq // 4], jnp.int32)
 
     def run(cache=None):
-        forward, prefill, decode, chunked = _parity_programs(seq)
+        forward, prefill, decode, paged, chunked = _parity_programs(seq)
         full = forward(model, ids)
         pre, own_cache = prefill(model, ids)
         cache = own_cache if cache is None else cache
         slots = jax.tree_util.tree_map(lambda c: jnp.stack([c, c]), cache)
         dec = decode(model, slots, ids[0, fills], fills)
+        pag = paged(model, cache, ids[0, fills], fills)
         tail = chunked(model, ids)
         return {"train_forward": full, "prefill": pre, "decode": dec,
-                "chunked_prefill": tail}, cache
+                "paged_decode": pag, "chunked_prefill": tail}, cache
 
     compiled, cache = run()
     with pk.force_interpret():
@@ -386,8 +416,9 @@ def parity_phase(model, *, seq: int, rms_rtol: float = LOGIT_RMS_RTOL,
     # reproduce the one-shot prefill's logits at the same positions
     pairs.append(("chunked vs one-shot prefill",
                   compiled["chunked_prefill"], compiled["prefill"][:, head:]))
-    pairs.append(("decode vs one-shot prefill",
-                  compiled["decode"], compiled["prefill"][0, fills]))
+    for name in ("decode", "paged_decode"):
+        pairs.append((f"{name} vs one-shot prefill",
+                      compiled[name], compiled["prefill"][0, fills]))
     report = {}
     for name, got, ref in pairs:
         got = np.asarray(got, np.float32)
@@ -533,12 +564,20 @@ def serve_phase(model, requests, *, slots: int, max_len: int,
             longest = max(len(r.prompt) for r in requests)
             for name, engine in engines.items():
                 text = engine.lowered_text(longest)
+                decode = DECODE_KERNELS[name]
+                if name == "paged":
+                    arm = "gather" if mesh_tp > 1 else "paged_kernel"
+                    got = engine.stats()["decode_attn"]
+                    check(got == arm, f"paged step attends by {got}, "
+                                      f"expected {arm}")
+                    if arm == "gather":
+                        decode = ()
                 absent = (_missing(text["prefill"], PREFILL_KERNELS[name])
-                          + _missing(text["decode"], DECODE_KERNELS[name]))
+                          + _missing(text["decode"], decode))
                 check(not absent, f"{name} engine lowered without {absent}")
                 report[name] = {"kernels": {
                     "prefill": list(PREFILL_KERNELS[name]),
-                    "decode": list(DECODE_KERNELS[name])}}
+                    "decode": list(decode)}}
         tokens, repeats = {}, {}
         for name in engines:
             t0 = time.monotonic()

@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+import collections
+
 import jax.numpy as jnp
 
 from paddle_tpu.models.generation import PagedCache
@@ -27,6 +29,15 @@ def causal_lm_loss(model, head_weight, input_ids, labels,
     return F.cross_entropy(
         logits[:, :-1].astype(jnp.float32), labels[:, 1:],
         ignore_index=ignore_index)
+
+
+# How a paged program's attention read its cache, decided when the
+# program is traced and counted there (as ``ops.pallas.partition_stats``
+# counts its units): ``"paged_kernel"`` — ``ptpu_paged_decode_attn``
+# through the page table — or ``"gather"`` — one layer's pages gathered
+# and the einsum lines. The engine reads the difference around the trace
+# of its step (``GenerationEngine.stats()["decode_attn"]``).
+paged_attn_arms: collections.Counter = collections.Counter()
 
 
 def _quant_chunk(x):
@@ -68,12 +79,18 @@ def cached_attention(q, k, v, cache, index, layer=0):
     - ``(k_q, v_q, k_scale, v_scale)`` — int8 buffers + f32
       per-(head, position) scales [L, B, Hkv, S].
     - a ``generation.PagedCache`` — the page pool (either leaf set) and
-      one sequence's page-table row, B = 1: this layer's pages are
-      gathered through the row (``PagedCache.read_layer``) and attended
-      by the einsum lines below, so a paged program never holds a
-      sequence's all-layers view. The decode kernel is not tried: under
-      the engine's ``vmap`` its batching rule loops over the slots and
-      slices each slot's whole stacked cache out for every layer.
+      one sequence's page-table row, B = 1. A one-token chunk the paged
+      kernel supports (``paged_decode_attention.supported``: one TPU
+      chip, lane-aligned pages) is attended by ``ptpu_paged_decode_attn``
+      through the row, live pages only; under the engine's ``vmap`` over
+      slots that is one call for all slots (the kernel's own batching
+      rule). Everything else — a prefill chunk or verify window, the
+      CPU, a multi-device mesh, other shapes — gathers this layer's
+      pages through the row (``PagedCache.read_layer``) for the einsum
+      lines below, which are also what the tests hold the kernel to.
+      Either way a paged program never holds a sequence's all-layers
+      view. Which of the two a trace took is counted in
+      ``paged_attn_arms``.
 
     The [..., Hkv, S, D] layout (heads ahead of sequence) matters on
     TPU: the decode attention contracts D and batches (B, Hkv), so S×D
@@ -111,6 +128,13 @@ def cached_attention(q, k, v, cache, index, layer=0):
 
     idx = jnp.asarray(index, jnp.int32)
     if paged:
+        from paddle_tpu.ops.pallas import paged_decode_attention as _pk
+        if _pk.supported(q, bufs, cache.table[None]):
+            paged_attn_arms["paged_kernel"] += 1
+            out = _pk.paged_decode_attention(
+                q, kt, vt, bufs, cache.table[None], layer, idx, scale=scale)
+            return out, payload
+        paged_attn_arms["gather"] += 1
         sl = cache.read_layer(layer)
     else:
         from paddle_tpu.ops.pallas import decode_attention as _dk

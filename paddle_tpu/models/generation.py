@@ -117,10 +117,11 @@ def sample_logits(logits, key=None, *, temperature: float = 1.0,
 # pool of fixed-size pages plus a per-sequence page table. A paged
 # program hands ``forward_with_cache`` a :class:`PagedCache` (the pool
 # and ONE sequence's table row) in the cache's place: attention reads
-# each layer's pages through the row (``PagedCache.read_layer`` — no
-# all-layers contiguous view ever materializes), the chunk's new k/v
-# come back as the payload, and :func:`paged_write` puts them into the
-# donated pool with in-place slice updates. ``paged_gather`` /
+# each layer's pages through the row — the paged decode kernel's index
+# maps on one TPU chip, ``PagedCache.read_layer``'s gather everywhere
+# else; no all-layers contiguous view ever materializes — the chunk's
+# new k/v come back as the payload, and :func:`paged_write` puts them
+# into the donated pool with in-place slice updates. ``paged_gather`` /
 # ``paged_scatter`` are the whole-sequence translations between the two
 # layouts — what the tests compare the per-layer path against, and the
 # host-side tools' way to read a sequence out of a pool. Physical page 0
@@ -156,8 +157,12 @@ class PagedCache:
     sequence's page-table row (``table`` [M] int32 physical page ids;
     entry 0 = null page). ``forward_with_cache`` takes it where it takes
     the contiguous tuple; ``_common.cached_attention`` tells the two
-    apart by type. Under the engine's ``jax.vmap`` over slots the pool
-    is unmapped and the row is the mapped operand."""
+    apart by type, and reads the pool either through
+    ``ops.pallas.paged_decode_attention`` (a one-token chunk on one TPU
+    chip: the kernel takes ``pool`` and ``table`` as they are) or
+    through :meth:`read_layer`. Under the engine's ``jax.vmap`` over
+    slots the pool is unmapped and the row is the mapped operand; the
+    kernel folds that axis into its grid."""
 
     def __init__(self, pool, table):
         self.pool, self.table = tuple(pool), table

@@ -1,55 +1,67 @@
 """Page-table-aware single-token decode attention over the paged pool.
 
-The paged serving engine's decode step reads K/V through the page table
-one layer at a time (``models.generation.PagedCache.read_layer``): a
-gather of every page of the slot's table row — capacity, not fill —
-into a per-layer contiguous view, which the einsum arm of
-``cached_attention`` then attends over. This kernel is the next step
-down: it deletes that per-layer copy the same way ``decode_attention``
-deleted the per-layer ``lax.scan`` slice — the page indirection moves
-INTO the pallas index maps. The scalar-prefetch row carries
-``[layer, index, table...]``, and the page-block index map
+What the paged serving engine's decode step attends with on one TPU
+chip (``models._common.cached_attention`` dispatches here for a
+one-token chunk on a ``PagedCache`` when :func:`supported` holds). The
+other arm gathers every page of a slot's table row — capacity, not
+fill — into a per-layer contiguous view
+(``models.generation.PagedCache.read_layer``) for the einsum lines of
+``cached_attention``. This kernel deletes that per-layer copy the same
+way ``decode_attention`` deleted the per-layer ``lax.scan`` slice — the
+page indirection moves INTO the pallas index maps. The scalar-prefetch
+row carries ``[layer, index, table...]``, and a page block's index map
 
-    page id = sp_ref[b, 2 + min(max(j - 1, 0), last_live_page)]
+    page id = sp_ref[b, 2 + min((j - 1) * K + i, last_live_page)]
 
 reads the slot's device-resident page table directly — grid step ``j``
-DMAs physical page ``table[j - 1]`` of the pool, so the persistent HBM
-(the pool) is the only cache the kernel ever touches, and only its live
-pages. Blocks past the filled prefix repeat the last live page id and
-Mosaic elides the repeated DMA, exactly the stacked-layer clamp trick.
+DMAs the K physical pages ``table[(j - 1) * K : j * K]`` of the pool
+(every pool leaf is an operand K times over, operand ``i`` mapped to
+the step's ``i``-th page; K from :func:`_pages_per_step`), so the
+persistent HBM (the pool) is the only cache the kernel ever touches,
+and only its live pages. Pages past the filled prefix repeat the last
+live page id and Mosaic elides the repeated DMA, exactly the
+stacked-layer clamp trick. A step's K pages are K independent dots on
+either side of one softmax update, which is what lets the core's
+matrix units work side by side: one page a step — the first form, a
+chain of dot, update, dot — kept one unit busy at 0.55 µs a page where
+the page's bytes take 0.16.
 
 Everything else is the ``decode_attention`` recipe on a page-shaped
 block: the fresh token's raw k/v joins the streaming softmax as grid
-step 0; pages stream as steps 1..M with positions ``>= index`` masked
+step 0; pages stream K a step after it with positions ``>= index`` masked
 (position ``p`` lives in page ``p // P`` at offset ``p % P``, matching
 ``paged_gather``'s view); one block-diagonal all-heads dot per page;
 int8 pool scales fold into the logit/prob planes so HBM traffic stays
-the int8 bytes.
+the int8 bytes (interpreter only so far: compiled for the TPU the gate
+sends the int8 pool to the gather arm, see :func:`supported`).
 
 Pool layout contract matches ``models.generation.init_paged_cache``:
 k/v leaves ``[num_pages + 1, L, Hkv, P, D]`` (page id 0 = the reserved
 null page), int8 layout adds f32 scale leaves
 ``[num_pages + 1, L, Hkv, P]``. ``table`` is one slot's int32 page-id
-row — the same row the ``FLAGS_gen_device_pt`` engine keeps device-
-resident, which is what makes "index maps read the page table" a
-zero-upload statement end to end.
+row. The kernel only reads the pool: the step's new k/v go in
+afterwards by whole-page updates (``generation.paged_write``), so the
+donated pool keeps its layout and is never copied.
+
+The slot axis. The engine calls the model under ``jax.vmap`` over slots,
+and jax's batching rule for a pallas call with a mapped scalar-prefetch
+operand is a loop over the mapped axis that slices every other operand
+per iteration. The call therefore carries a batching rule of its own
+(:func:`_over_rows`, ``jax.custom_batching.custom_vmap``): a mapped axis
+of S slots joins the rows, and the step holds ONE call a layer with grid
+``(S, 1 + ceil(M / K))`` on the unmapped pool.
 
 Status: interpreter-mode tests (``tests/test_paged_decode_attention.py``)
-pin the kernel bit-exact to ``paged_gather`` + masked attention per
-slot, under ``jax.vmap``, and for the int8 4-leaf layout — the
-hardware-independent result. It is NOT on the engine's path: the paged
-programs attend through ``PagedCache`` and the einsum arm (a joint f32
-softmax; this kernel's online softmax orders every sum differently).
-Whether a kernel that reads only live pages beats the per-layer gather
-of capacity is ROADMAP C2, to be decided on the serving cell; note that
-under ``jax.vmap`` a pallas call with a batched scalar-prefetch operand
-becomes a loop over the mapped axis that slices every other operand per
-iteration, so the engine has to call it over the slot axis itself
-(``paged_decode_attention`` takes ``[B, ...]`` rows and tables), not
-under its vmap. Off-TPU callers take the ``paged_reference`` einsum
-fallback under the same ``supported()`` gate as the stacked kernel.
-Multi-device meshes fall back too (no ``_partition`` unit yet — the
-pool's KV-head shard would need a per-shard grid).
+pin the kernel to ``paged_gather`` + masked attention per slot, under
+``jax.vmap``, and for the int8 4-leaf layout;
+``tests/test_paged_kernel_step.py`` holds the engine's step on this arm
+to the gather arm (tokens in float32, logits in bf16: the online softmax
+orders every sum differently from the einsum arm's joint f32 softmax)
+and compiles it for the v5e. Off-TPU callers take the gather arm
+(``dispatch_mode()`` is ``"off"``). Multi-device meshes do too (no
+``_partition`` unit yet — the pool's KV-head shard would need a
+per-shard grid), as do prefill chunks and speculative verify windows
+(``T > 1``).
 """
 
 from __future__ import annotations
@@ -73,7 +85,8 @@ def supported(q, pool, table) -> bool:
     leaves ([N, L, Hkv, P, D], int8 adds [N, L, Hkv, P] scales);
     ``table`` [B, M] int32 page rows. Raw dispatch only — a
     multi-device mesh has no partitioned wrapper for the paged layout
-    yet, so it stays on the gather+einsum path."""
+    yet, so it stays on the gather+einsum path; so does the int8 pool
+    where the kernel would be compiled (float leaves only there)."""
     if _support.dispatch_mode() != "raw":
         return False
     if q.ndim != 4 or q.shape[1] != 1:
@@ -87,11 +100,17 @@ def supported(q, pool, table) -> bool:
         return False
     if P % 8 or table.ndim != 2 or table.shape[0] != B:
         return False
-    if _support.on_tpu() and not _support.interpret() and (Hkv * P) % LANES:
-        return False                  # lane-aligned page blocks only
+    quantized = len(pool) == 4
+    if _support.on_tpu() and not _support.interpret():
+        if (Hkv * P) % LANES:
+            return False              # lane-aligned page blocks only
+        if quantized:
+            # Mosaic refuses the scale planes' [Hkv, P] -> [1, Hkv * P]
+            # reshape ("unsupported shape cast", v5e): the int8 pool is
+            # the interpreter's only, compiled it takes the gather arm
+            return False
     if q.dtype not in (jnp.float32, jnp.bfloat16):
         return False
-    quantized = len(pool) == 4
     if quantized and k.dtype != jnp.int8:
         return False
     if not quantized and k.dtype not in (jnp.float32, jnp.bfloat16):
@@ -99,12 +118,13 @@ def supported(q, pool, table) -> bool:
     return True
 
 
-def _kernel(sp_ref, q_ref, kn_ref, vn_ref, kp_ref, vp_ref, *rest,
-            scale, P, M, G, Hkv, quantized, out_dtype):
-    if quantized:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        o_ref, acc_ref, m_ref, l_ref = rest
+def _kernel(sp_ref, q_ref, kn_ref, vn_ref, *rest,
+            scale, P, K, steps, G, Hkv, quantized, out_dtype):
+    # ``rest``: K page refs of k, K of v (int8: K of each scale plane
+    # after them), then the output and the scratch
+    n = (4 if quantized else 2) * K
+    leaves = [rest[i * K:(i + 1) * K] for i in range(n // K)]
+    o_ref, acc_ref, m_ref, l_ref = rest[n:]
     b = pl.program_id(0)
     j = pl.program_id(1)
     idx = sp_ref[b, 1]
@@ -126,107 +146,128 @@ def _kernel(sp_ref, q_ref, kn_ref, vn_ref, kp_ref, vp_ref, *rest,
 
     last_page = jnp.maximum(idx - 1, 0) // P
 
-    @pl.when((j > 0) & (j - 1 <= last_page))
-    def _page_block():
-        jb = j - 1
-        # ONE block-diagonal dot for ALL heads over the page (the
+    @pl.when((j > 0) & ((j - 1) * K <= last_page))
+    def _page_blocks():
+        # ONE block-diagonal dot for ALL heads over each page (the
         # decode_attention trick at page granularity): q [Hq, D]
         # against the whole [Hkv·P, D] page computes every cross-head
-        # product, the mask kills the wrong-head logits exactly.
+        # product, the mask kills the wrong-head logits exactly. The
+        # step's K pages are K independent dots on either side of ONE
+        # softmax update over [Hq, K·Hkv·P] — nothing orders them among
+        # themselves, so the matrix units take them side by side (a
+        # page after a page, each through the scratch, kept one unit
+        # busy: 0.55 µs a page where its bytes take 0.16). A page past
+        # the fill (the last step's tail) is masked whole.
         q = q_ref[0]                                # [Hq, D], model dtype
         Hq, D = q.shape
-        cdt = q.dtype if kp_ref.dtype == jnp.int8 else kp_ref.dtype
-        if q.dtype != cdt:
-            q = q.astype(cdt)
-        kb = kp_ref[0, 0]                           # [Hkv, P, D]
-        if kb.dtype != cdt:
-            kb = kb.astype(cdt)
-        kb = kb.reshape(Hkv * P, D)
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [Hq, Hkv·P]
+        W = Hkv * P
+        kp_refs, vp_refs = leaves[0], leaves[1]
+        cdt = q.dtype if kp_refs[0].dtype == jnp.int8 else kp_refs[0].dtype
+        q = q.astype(cdt)
+
+        def page(ref):                              # [Hkv, P, D] -> [W, D]
+            return ref[0, 0].astype(cdt).reshape(W, D)
+
+        def plane(refs):                            # K x [Hkv, P] -> [1, K·W]
+            return jnp.concatenate([r[0, 0].reshape(1, W) for r in refs], 1)
+
+        s = jnp.concatenate([
+            jax.lax.dot_general(q, page(r), (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            for r in kp_refs], axis=1) * scale      # [Hq, K·W]
         if quantized:
             # per-position scale folds into the logit plane (per column)
-            s = s * ks_ref[0, 0].reshape(1, Hkv * P)
-        row_h = jax.lax.broadcasted_iota(
-            jnp.int32, (Hq, Hkv * P), 0) // G
-        col = jax.lax.broadcasted_iota(jnp.int32, (Hq, Hkv * P), 1)
-        pos = jb * P + col % P       # paged_gather's view coordinate
-        valid = (row_h == col // P) & (pos < idx)
+            s = s * plane(leaves[2])
+        row_h = jax.lax.broadcasted_iota(jnp.int32, (Hq, K * W), 0) // G
+        col = jax.lax.broadcasted_iota(jnp.int32, (Hq, K * W), 1)
+        # paged_gather's view coordinate of column (page i, head, offset)
+        pos = ((j - 1) * K + col // W) * P + col % P
+        valid = (row_h == col % W // P) & (pos < idx)
         s = jnp.where(valid, s, NEG_INF)
         m_prev = m_ref[:, :1]
         l_prev = l_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                      # [Hq, Hkv·P]
+        p = jnp.exp(s - m_new)                      # [Hq, K·W]
         alpha = jnp.exp(m_prev - m_new)
         l_ref[:, :1] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
         m_ref[:, :1] = m_new
         if quantized:
             # v scale folds into the prob plane
-            p = p * vs_ref[0, 0].reshape(1, Hkv * P)
-        vb = vp_ref[0, 0]
-        if vb.dtype != cdt:
-            vb = vb.astype(cdt)
-        pv = jax.lax.dot_general(
-            p.astype(cdt), vb.reshape(Hkv * P, D),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)     # [Hq, D]
+            p = p * plane(leaves[3])
+        p = p.astype(cdt)
+        pv = sum(
+            jax.lax.dot_general(p[:, i * W:(i + 1) * W], page(r),
+                                (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            for i, r in enumerate(vp_refs))         # [Hq, D]
         acc_ref[:, :] = acc_ref[:, :] * alpha + pv
 
-    @pl.when(j == M)
+    @pl.when(j == steps)
     def _finalize():
         l = l_ref[:, :1]
         o_ref[0] = (acc_ref[:, :] / jnp.where(l == 0.0, 1.0, l)).astype(
             out_dtype)
 
 
+def _pages_per_step(M: int, page_bytes: int) -> int:
+    """Pages one grid step streams: up to 8, within 1 MiB a leaf a step
+    (double-buffered in VMEM). Several pages a step are what gives the
+    kernel independent dots to run side by side (``_kernel``); on the
+    v5e 16 pages read the same as 8, and one page a step — a page's two
+    dots and its softmax update in a chain — took 5.9 ms a decode step
+    of the OLMoE cell where 8 take 3.1."""
+    return max(1, min(8, M, (1 << 20) // page_bytes))
+
+
 def raw_call(sp, q2, kn2, vn2, *pool, scale: float):
     """The pallas_call on local shapes: sp int32 [B, 2 + M] rows of
     ``[layer, index, table...]``; q2 [B, Hq, D]; kn2/vn2 [B, Hkv, D];
-    ``pool`` the paged leaves. Returns [B, Hq, D]."""
+    ``pool`` the paged leaves. Returns [B, Hq, D]. Grid ``(B, 1 +
+    ceil(M / K))``: step 0 the fresh token, then K pages a step — every
+    pool leaf is passed K times, operand ``i`` of a step mapped to the
+    step's ``i``-th page, so one step's K pages (anywhere in the pool)
+    arrive by K block DMAs of the ordinary pipeline."""
     B, Hq, D = q2.shape
     Hkv = kn2.shape[1]
     G = Hq // Hkv
     quantized = len(pool) == 4
-    kp, vp = pool[0], pool[1]
+    kp = pool[0]
     P = kp.shape[3]
     M = sp.shape[1] - 2
+    K = _pages_per_step(M, Hkv * P * D * kp.dtype.itemsize)
+    steps = -(-M // K)
 
-    def page_map(b, j, sp_ref):
+    def page_map(i, ndim):
         # THE point of this kernel: the block's pool coordinate is read
-        # straight out of the slot's page-table row. Steps past the
+        # straight out of the slot's page-table row. Pages past the
         # filled prefix clamp to the last live page (repeated DMA
         # elided), mirroring the stacked kernel's fill clamp.
-        last = jnp.maximum(sp_ref[b, 1] - 1, 0) // P
-        jp = jnp.minimum(jnp.maximum(j - 1, 0), last)
-        return (sp_ref[b, 2 + jp], sp_ref[b, 0], 0, 0, 0)
-
-    def scale_map(b, j, sp_ref):
-        last = jnp.maximum(sp_ref[b, 1] - 1, 0) // P
-        jp = jnp.minimum(jnp.maximum(j - 1, 0), last)
-        return (sp_ref[b, 2 + jp], sp_ref[b, 0], 0, 0)
+        def index(b, j, sp_ref):
+            last = jnp.maximum(sp_ref[b, 1] - 1, 0) // P
+            jp = jnp.minimum(jnp.maximum(j - 1, 0) * K + i, last)
+            return (sp_ref[b, 2 + jp], sp_ref[b, 0]) + (0,) * (ndim - 2)
+        return index
 
     in_specs = [
         pl.BlockSpec((1, Hq, D), lambda b, j, s: (b, 0, 0)),
         pl.BlockSpec((1, Hkv, D), lambda b, j, s: (b, 0, 0)),
         pl.BlockSpec((1, Hkv, D), lambda b, j, s: (b, 0, 0)),
-        pl.BlockSpec((1, 1, Hkv, P, D), page_map),
-        pl.BlockSpec((1, 1, Hkv, P, D), page_map),
     ]
-    args = [q2, kn2, vn2, kp, vp]
-    if quantized:
-        in_specs += [pl.BlockSpec((1, 1, Hkv, P), scale_map),
-                     pl.BlockSpec((1, 1, Hkv, P), scale_map)]
-        args += [pool[2], pool[3]]
+    args = [q2, kn2, vn2]
+    for leaf in pool:
+        block = (1, 1) + leaf.shape[2:]
+        in_specs += [pl.BlockSpec(block, page_map(i, leaf.ndim))
+                     for i in range(K)]
+        args += [leaf] * K
 
     kernel = functools.partial(
-        _kernel, scale=scale, P=P, M=M, G=G, Hkv=Hkv,
+        _kernel, scale=scale, P=P, K=K, steps=steps, G=G, Hkv=Hkv,
         quantized=quantized, out_dtype=q2.dtype)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, M + 1),
+            grid=(B, steps + 1),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, Hq, D), lambda b, j, s: (b, 0, 0)),
             scratch_shapes=[
@@ -287,6 +328,41 @@ def paged_reference(q, k_new, v_new, pool, table, layer, index, *,
     return out.reshape(B, 1, Hq, D)
 
 
+def _over_rows(scale: float):
+    """The kernel call on row operands — ``layer`` [B], ``index`` [B],
+    ``table`` [B, M], q2 [B, Hq, D], kn2/vn2 [B, Hkv, D], then the pool
+    leaves — with a batching rule of its own. jax's rule for a pallas
+    call whose scalar-prefetch operand is mapped is a ``while`` over the
+    mapped axis that slices every operand per iteration; here a mapped
+    axis is folded into the rows instead, so ``vmap`` over S slots of
+    one row each is ONE ``raw_call`` with S rows in its grid, on the
+    unmapped pool."""
+
+    def call(layer, index, table, q2, kn2, vn2, pool):
+        sp = jnp.concatenate([layer[:, None], index[:, None], table],
+                             axis=1)
+        return raw_call(sp, q2, kn2, vn2, *pool, scale=scale)
+
+    rows_call = jax.custom_batching.custom_vmap(call)
+
+    @rows_call.def_vmap
+    def _fold(axis_size, in_batched, *args):
+        *rows, pool = args
+        *rows_batched, pool_batched = in_batched
+        if any(pool_batched):
+            # a mapped pool has no row to fold into: jax's own rule
+            axes = [0 if b else None for b in rows_batched]
+            axes.append(tuple(0 if b else None for b in pool_batched))
+            return jax.vmap(call, in_axes=axes)(*args), True
+        rows = [x if b else jnp.broadcast_to(x, (axis_size,) + x.shape)
+                for x, b in zip(rows, rows_batched)]
+        out = rows_call(*(x.reshape((-1,) + x.shape[2:]) for x in rows),
+                        pool)
+        return out.reshape((axis_size, -1) + out.shape[1:]), True
+
+    return rows_call
+
+
 def paged_decode_attention(q, k_new, v_new, pool, table, layer, index, *,
                            scale: float):
     """q [B, 1, Hq, D]; k_new/v_new [B, Hkv, 1, D] (this step's raw
@@ -295,18 +371,17 @@ def paged_decode_attention(q, k_new, v_new, pool, table, layer, index, *,
     table); ``layer`` this block's layer id; ``index`` int32 fill
     position(s) — scalar or [B] (each slot's pool pages hold tokens
     [0, index)). Returns [B, 1, Hq, D]. Dispatches the kernel when
-    :func:`supported`, else :func:`paged_reference`."""
+    :func:`supported`, else :func:`paged_reference`. Under ``jax.vmap``
+    with the pool unmapped the mapped axis joins B (:func:`_over_rows`):
+    still one kernel call."""
     if not supported(q, pool, table):
         return paged_reference(q, k_new, v_new, pool, table, layer,
                                index, scale=scale)
     B, T, Hq, D = q.shape
     Hkv = k_new.shape[1]
-    q2 = q.reshape(B, Hq, D)
-    kn2 = k_new.reshape(B, Hkv, D)
-    vn2 = v_new.reshape(B, Hkv, D)
     idx = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (B,))
     lay = jnp.broadcast_to(jnp.asarray(layer, jnp.int32), (B,))
-    sp = jnp.concatenate([lay[:, None], idx[:, None],
-                          jnp.asarray(table, jnp.int32)], axis=1)
-    out = raw_call(sp, q2, kn2, vn2, *pool, scale=scale)
+    out = _over_rows(scale)(
+        lay, idx, jnp.asarray(table, jnp.int32), q.reshape(B, Hq, D),
+        k_new.reshape(B, Hkv, D), v_new.reshape(B, Hkv, D), tuple(pool))
     return out.reshape(B, 1, Hq, D)

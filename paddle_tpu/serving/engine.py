@@ -35,12 +35,17 @@ paged cache, SOSP '23) to the framework's autoregressive path:
   (``models.generation.init_paged_cache``). The three paged programs
   (step, prefill chunk, speculative verify) hand the model a
   ``generation.PagedCache`` — the pool and the slot's table row — in
-  the cache's place: attention gathers ONE layer's pages through the
-  row, so no slot's all-layers view is ever built, and the chunk's new
-  k/v go into the donated pool by in-place page updates
-  (``generation.paged_write``): a step moves the pages it reads, never
-  the pool. A generation reserves pages for its *declared*
-  worst case (prompt + ``max_new_tokens``) at admission — capacity
+  the cache's place. The decode step on one TPU chip attends through
+  ``ptpu_paged_decode_attn``: one call a layer for all slots, whose
+  index maps read each slot's live pages out of the pool through the
+  row; a prefill chunk, a verify window, the CPU and a multi-device
+  mesh gather ONE layer's pages through the row for the einsum arm
+  (``models._common.cached_attention`` picks; ``stats()["decode_attn"]``
+  says which the step took). Either way no slot's all-layers view is
+  ever built, and the chunk's new k/v go into the donated pool by
+  in-place page updates (``generation.paged_write``): a step moves the
+  pages it reads, never the pool. A generation reserves pages for its
+  *declared* worst case (prompt + ``max_new_tokens``) at admission — capacity
   becomes ``pool / actual-need`` instead of ``slots`` — and admission
   stalls on page-pool exhaustion, not slot count. A radix prefix cache
   over full prompt pages maps generations sharing a prompt prefix onto
@@ -126,12 +131,12 @@ thread and none per token: ``gen/loop`` (one iteration; ``queue``,
 ``active``) is the parent of ``gen/idle_wait``, ``gen/admit`` (``gen``,
 ``waited_ms``, ``prefix_tokens``, ``pages``; under it ``gen/kv_fetch``),
 ``gen/dev_ops``, ``gen/prefill`` / ``gen/prefill_chunk``,
-``gen/decode_step`` (``active``, ``spec``, ``compiled``; under it
-``gen/step_dispatch`` and ``gen/step_wait``, or ``gen/spec_verify``
-around both), ``gen/draft`` and ``gen/emit`` (``emitted``,
-``retired``). One helper, ``_phase``, times each section
-with two clock reads that also feed the section's histogram and goodput
-bucket.
+``gen/decode_step`` (``active``, ``spec``, ``compiled``, a plain paged
+step's ``decode_attn``; under it ``gen/step_dispatch`` and
+``gen/step_wait``, or ``gen/spec_verify`` around both), ``gen/draft``
+and ``gen/emit`` (``emitted``, ``retired``). One helper, ``_phase``,
+times each section with two clock reads that also feed the section's
+histogram and goodput bucket.
 """
 
 from __future__ import annotations
@@ -902,6 +907,9 @@ class GenerationEngine:
             x.nbytes / (x.shape[0] * (self._page_tokens if self._paged
                                       else self.max_len))
             for x in leaves)
+        # how the paged step's attention reads the pool, "paged_kernel"
+        # or "gather": decided where the step is traced, None until then
+        self._decode_attn: str | None = None
         if self._paged:
             self._step = self._build_paged_step()
             self._prefill_fn = self._build_paged_prefill()
@@ -1093,13 +1101,16 @@ class GenerationEngine:
         """ONE fused decode for all slots in paged mode: each slot runs
         the same single-token cached forward as the contiguous step on
         a ``PagedCache`` (the pool and its page-table row), so
-        attention gathers one layer's pages at a time and no slot's
+        attention reads one layer's pages at a time — the paged kernel
+        over the slot axis, or a gather (``cached_attention``; the arm
+        this trace took is kept for :meth:`stats`) — and no slot's
         all-layers view exists; the new position's k/v come back as the
         payload and go into the donated pool in place, outside the vmap
         (inactive/masked slots write to the null page)."""
         import jax
         import jax.numpy as jnp
 
+        from paddle_tpu.models._common import paged_attn_arms
         from paddle_tpu.models.generation import PagedCache, paged_write
 
         P, maxp = self._page_tokens, self._maxp
@@ -1114,11 +1125,16 @@ class GenerationEngine:
 
         def step(model, state, pt, active):
             pool = state["cache"]
+            kernel_arms = paged_attn_arms["paged_kernel"]
             nxt, keys, new, cnt = jax.vmap(
                 functools.partial(one, model),
                 in_axes=(0, 0, 0, 0, 0, 0, 0, None))(
                 pt, state["tok"], state["pos"], state["keys"],
                 state["temp"], state["top_k"], state["top_p"], pool)
+            self._decode_attn = (
+                "paged_kernel"
+                if paged_attn_arms["paged_kernel"] > kernel_arms
+                else "gather")
             pidx = jnp.clip(state["pos"] // P, 0, maxp - 1)
             pages = jnp.where(active, pt[jnp.arange(slots), pidx], 0)
             pool = paged_write(pool, pages, state["pos"] % P, new)
@@ -1742,6 +1758,8 @@ class GenerationEngine:
                    "async_depth": self._async_depth,
                    "pending_steps": len(self._pending),
                    "kv_bytes_per_token": self._kv_bytes_per_token}
+            if self._paged:
+                doc["decode_attn"] = self._decode_attn
             # the model's live counts (absent for a model that names
             # none): monotone, summed on the device over live positions
             doc.update(counts)
@@ -2903,6 +2921,8 @@ class GenerationEngine:
                     with self._phase("gen/step_dispatch"):
                         self._state, toks = self._step(
                             self._state, *args, jnp.asarray(active))
+                    if self._paged:
+                        call.set(decode_attn=self._decode_attn)
                     if not lookahead:
                         with self._phase("gen/step_wait"):
                             toks = np.asarray(toks)

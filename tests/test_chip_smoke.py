@@ -47,6 +47,29 @@ def test_train_and_serve_phases_tiny():
 
 
 @pytest.mark.slow
+@pytest.mark.parametrize("kernels", ["off", "interpreted"])
+def test_parity_phase_tiny(kernels):
+    """The parity phase off the chip, float32: every compared pair — the
+    contiguous and the paged decode step against the one-shot prefill
+    among them — agrees to rounding, on the jnp arms and with the
+    kernels (``ptpu_paged_decode_attn`` included: head size 64) forced
+    through the interpreter."""
+    import contextlib
+
+    import paddle_tpu
+    from paddle_tpu.models import LlamaForCausalLM
+    from paddle_tpu.ops import pallas as pk
+
+    paddle_tpu.seed(3)
+    model = LlamaForCausalLM(LlamaConfig.tiny(hidden_size=256))
+    with (pk.force_dispatch() if kernels == "interpreted"
+          else contextlib.nullcontext()):
+        report = cs.parity_phase(model, seq=32, rms_rtol=1e-5, max_rtol=1e-5)
+    assert {"paged_decode: compiled vs interpreted",
+            "paged_decode vs one-shot prefill"} <= set(report)
+    assert all(r["argmax_agree"] == 1.0 for r in report.values())
+
+
 def test_four_device_phases_tiny():
     """The >= 4-chip branch (ZeRO-3 x4 train, mesh_tp=4 serve) on the
     virtual CPU mesh."""

@@ -20,6 +20,23 @@ from paddle_tpu.ops.pallas import _support
 from paddle_tpu.ops.pallas import paged_decode_attention as pdk
 
 
+@pytest.fixture(autouse=True, params=["default", 3])
+def pages_per_step(request, monkeypatch):
+    """Every test at the kernel's own choice of pages a grid step (all
+    of these tables' 4 in one) and at 3: two steps of pages, the second
+    with a tail past the table that clamps to the last live page."""
+    if request.param != "default":
+        monkeypatch.setattr(pdk, "_pages_per_step",
+                            lambda M, page_bytes: request.param)
+    return request.param
+
+
+def _steps(M):
+    """Grid steps along a slot's pages: the fresh token's, then
+    ``_pages_per_step`` pages each."""
+    return 1 - (-M // pdk._pages_per_step(M, 1))
+
+
 def _mk(B=2, Hq=4, Hkv=2, P=8, M=4, D=64, L=2, N=16, quant=False,
         dtype=jnp.float32, seed=0):
     rs = np.random.RandomState(seed)
@@ -225,6 +242,70 @@ def test_kernel_under_vmap_matches_per_slot(quant):
             np.asarray(_via_paged_gather(qs[s], kns[s], vns[s], pool,
                                          tabs[s], 1, idxs[s], 0.125)),
             rtol=2e-5, atol=2e-5, err_msg=f"slot {s}")
+
+
+def walk_eqns(jaxpr, path=()):
+    """``(eqn, names of the primitives enclosing it)`` for every
+    equation of a jaxpr, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn, path
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from walk_eqns(sub, path + (eqn.primitive.name,))
+
+
+def _pallas_calls(closed):
+    return [(e.params["grid_mapping"].grid, path)
+            for e, path in walk_eqns(closed.jaxpr)
+            if e.primitive.name == "pallas_call"]
+
+
+def test_vmap_over_slots_is_one_call_with_the_slots_in_its_grid():
+    """The call's own batching rule: a mapped axis (and a second one
+    around it) joins the rows of ONE pallas_call on the unmapped pool —
+    jax's rule for a mapped scalar-prefetch operand would wrap the call
+    in a ``while`` over the axis and slice every operand."""
+    q, kn, vn, pool, table = _mk(B=3, seed=8)
+    M = table.shape[1]
+    idx = jnp.asarray([2, 15, 31], jnp.int32)
+
+    def one(q, kn, vn, tab, i):
+        return pdk.paged_decode_attention(q[None], kn[None], vn[None], pool,
+                                          tab[None], jnp.int32(1), i,
+                                          scale=0.125)
+
+    def two(*a):
+        return jax.vmap(one)(*a)
+
+    twice = tuple(jnp.stack([x, x]) for x in (q, kn, vn, table, idx))
+    with _support.force_dispatch():
+        assert _pallas_calls(jax.make_jaxpr(jax.vmap(one))(
+            q, kn, vn, table, idx)) == [((3, _steps(M)), ("custom_vmap_call",))]
+        assert _pallas_calls(jax.make_jaxpr(jax.vmap(two))(*twice)) == [
+            ((6, _steps(M)), ("custom_vmap_call",))]
+        got = jax.vmap(two)(*twice)
+        want = pdk.paged_decode_attention(q, kn, vn, pool, table,
+                                          jnp.int32(1), idx, scale=0.125)
+    for half in got:
+        np.testing.assert_array_equal(np.asarray(half[:, 0]),
+                                      np.asarray(want))
+
+
+def test_vmap_with_a_mapped_pool_falls_back_to_jax_rule():
+    """A pool that is itself mapped has no row to fold into: the rule
+    hands that case to jax's own batching and stays correct."""
+    a = _mk(B=2, seed=40)
+    b = _mk(B=2, seed=41)
+    stack = jax.tree_util.tree_map(lambda x, y: jnp.stack([x, y]), a, b)
+
+    def call(q, kn, vn, pool, table):
+        return pdk.paged_decode_attention(q, kn, vn, pool, table,
+                                          jnp.int32(0), jnp.int32(20),
+                                          scale=0.125)
+
+    with _support.force_dispatch():
+        got = jax.vmap(call)(*stack)
+        want = jnp.stack([call(*a), call(*b)])
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_supported_gates():

@@ -1,0 +1,359 @@
+"""The paged decode step on its kernel arm: ``cached_attention`` hands a
+one-token chunk on a ``PagedCache`` to ``ptpu_paged_decode_attn`` where
+the kernel's gate holds, and the engine's ``vmap`` over slots folds into
+ONE call a layer (the kernel's own batching rule). Everything here runs
+the kernel through the interpreter (``_support.force_dispatch``) and
+holds it to the gather + einsum arm — the same step with the gate shut,
+every other kernel dispatched alike on both sides.
+
+Pinned: tokens (float32, and over the int8 pool) and logits (bf16,
+within ``chip_smoke``'s tolerance for two evaluations of one
+mathematics) agree on slots at different fills, an idle slot on the
+null page, a fill on a page edge and a template shared through the real
+prefix cache; the step's jaxpr holds one ``pallas_call`` a layer body
+whose grid holds the slots, no loop over slots and no gathered view;
+the programs that must stay on the gather arm (prefill chunk, verify
+window, the latent model) lower to the same text whether or not the
+gate is open; and the step compiled for the v5e keeps the pool in place.
+"""
+
+import contextlib
+import dataclasses
+import hashlib
+import os
+import sys
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu
+from paddle_tpu.core.monitor import get_stat
+from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.models.generation import PagedCache
+from paddle_tpu.ops.pallas import _support
+from paddle_tpu.ops.pallas import paged_decode_attention as pdk
+from paddle_tpu.serving import GenerationEngine
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+import chip_smoke  # noqa: E402
+from test_paged_decode_attention import walk_eqns  # noqa: E402
+from test_paged_view import _random_pool  # noqa: E402
+
+pytestmark = pytest.mark.gen
+
+VOCAB = 96
+L, HQ, HKV, D, P = 2, 4, 2, 64, 8
+SLOTS, MAXLEN = 4, 64
+M = MAXLEN // P
+STEPS = 34
+
+
+def _llama(dtype, seed=11, **kw):
+    paddle_tpu.seed(seed)
+    args = dict(vocab_size=VOCAB, hidden_size=HQ * D, num_layers=L,
+                num_heads=HQ, num_kv_heads=HKV, max_seq_len=MAXLEN)
+    args.update(kw)
+    return LlamaForCausalLM(dataclasses.replace(LlamaConfig.tiny(**args),
+                                                dtype=dtype))
+
+
+@pytest.fixture(scope="module")
+def model():
+    return _llama("float32")
+
+
+@contextlib.contextmanager
+def arm(name):
+    """Trace under it: every kernel dispatched (interpreted), the paged
+    one refused for ``"gather"``."""
+    with _support.force_dispatch():
+        if name == "paged_kernel":
+            yield
+            return
+        real = pdk.supported
+        pdk.supported = lambda *a, **k: False
+        try:
+            yield
+        finally:
+            pdk.supported = real
+
+
+# two slots on one template's pages (1, 2) with tails of their own, a
+# slot whose fill sits on a page edge, and an idle slot mapped nowhere
+# (the null page)
+TABLE = np.zeros((SLOTS, M), np.int32)
+TABLE[0] = [1, 2, 3, 4, 5, 6, 7, 8]
+TABLE[1] = [1, 2, 9, 10, 11, 12, 13, 14]
+TABLE[2] = [15, 16, 17, 18, 19, 20, 21, 22]
+POS = [2 * P + 3, 2 * P + 5, 3 * P, 0]
+ACTIVE = [True, True, True, False]
+PAGES = 24
+
+
+def _hand_state(eng, seed):
+    state = eng._init_state()
+    proto = eng._model.init_cache(1, MAXLEN, dtype=eng._cache_dtype)
+    state["cache"] = _random_pool(proto, PAGES, P, seed)
+    state["pos"] = jnp.asarray(POS, jnp.int32)
+    state["tok"] = jnp.asarray([5, 9, 2, 7], jnp.int32)
+    return state
+
+
+def _steps(model, which, quant):
+    with arm(which), GenerationEngine(
+            model, slots=SLOTS, max_len=MAXLEN, paged=True, page_tokens=P,
+            pages=PAGES, cache_dtype=jnp.int8 if quant else None,
+            queue_max=4) as eng:
+        state = _hand_state(eng, seed=3)
+        pt, active = jnp.asarray(TABLE), jnp.asarray(ACTIVE)
+        toks = []
+        for _ in range(STEPS):
+            state, tok = eng._step(state, pt, active)
+            toks.append(np.asarray(tok))
+        assert eng.stats()["decode_attn"] == which
+        return np.stack(toks), np.asarray(state["pos"])
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["f32", "int8"])
+def test_step_tokens_equal_on_both_arms(model, quant):
+    """34 steps from one hand-built pool: every live slot crosses page
+    edges (and slot 2 starts on one), the idle slot reads the null page
+    and keeps its token, and both arms pick the same tokens."""
+    got, pos = _steps(model, "paged_kernel", quant)
+    want, _ = _steps(model, "gather", quant)
+    np.testing.assert_array_equal(got, want)
+    assert list(pos) == [p + STEPS * a for p, a in zip(POS, ACTIVE)]
+    assert (got[:, 3] == 7).all()
+    assert len({tuple(t) for t in got[:, :3]}) > 8      # not one fixed point
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_bf16_logits_within_chip_smoke_tolerance(quant):
+    """The two arms are two evaluations of one mathematics (a joint
+    float32 softmax against an online one): in bf16 their logits differ
+    by what ``chip_smoke`` allows such a pair."""
+    model = _llama("bfloat16")
+    proto = model.init_cache(1, MAXLEN,
+                             dtype=jnp.int8 if quant else jnp.bfloat16)
+    pool = _random_pool(proto, PAGES, P, seed=4)
+    rows = jnp.asarray(TABLE[:3])
+    idx = jnp.asarray(POS[:3], jnp.int32) + jnp.asarray([0, P, 4 * P])
+    ids = jnp.asarray([[[5]], [[9]], [[2]]], jnp.int32)
+
+    def logits(which):
+        def one(r, i, x):
+            return model.forward_with_cache(x, PagedCache(pool, r),
+                                            index=i)[0]
+        with arm(which):
+            return np.asarray(jax.jit(jax.vmap(one))(rows, idx, ids),
+                              np.float32)
+
+    got, ref = logits("paged_kernel"), logits("gather")
+    assert np.isfinite(got).all()
+    diff = got - ref
+    assert diff.any()                       # the arms do differ in bf16
+    rms = np.sqrt(np.mean(diff ** 2)) / np.sqrt(np.mean(ref ** 2))
+    assert rms <= chip_smoke.LOGIT_RMS_RTOL, rms
+    assert (np.abs(diff).max() / np.abs(ref).max()
+            <= chip_smoke.LOGIT_MAX_RTOL)
+
+
+def _serve_shared_template(model, which):
+    """A first request leaves the template's pages in the prefix cache;
+    two more then decode side by side on those pages."""
+    rs = np.random.RandomState(9)
+    template = rs.randint(1, VOCAB, 2 * P + 1).astype(np.int32)
+    out = {}
+
+    def run(eng, key, item, n):
+        gid = eng.start(np.concatenate([template, item]), n)
+        while True:
+            r = eng.poll(gid, wait_s=0.5)
+            if r["done"]:
+                break
+        assert r["error"] is None, r["error"]
+        out[key] = r["tokens"]
+
+    with arm(which), GenerationEngine(
+            model, slots=3, max_len=MAXLEN, paged=True, page_tokens=P,
+            prefix_cache=True, queue_max=4) as eng:
+        run(eng, "first", np.asarray([7], np.int32), 2)
+        hits = get_stat("gen/prefix_hits") or 0
+        threads = [threading.Thread(target=run, args=(
+            eng, i, np.asarray([11 + i, 3], np.int32), 4 * P))
+            for i in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+        assert not any(t.is_alive() for t in threads)
+        assert get_stat("gen/prefix_hits") - hits == 2
+        assert eng.stats()["decode_attn"] == which
+    return out
+
+
+def test_shared_template_through_prefix_cache(model):
+    got = _serve_shared_template(model, "paged_kernel")
+    want = _serve_shared_template(model, "gather")
+    assert got == want and len(got[0]) == 4 * P
+
+
+def test_cpu_engine_stays_on_gather_arm(model):
+    """No force context: ``dispatch_mode()`` is ``"off"`` off the TPU,
+    and the stat says so once the step has been traced."""
+    with GenerationEngine(model, slots=2, max_len=MAXLEN, paged=True,
+                          page_tokens=P, queue_max=4) as eng:
+        assert eng.stats()["decode_attn"] is None
+        eng.lowered(6)
+        assert eng.stats()["decode_attn"] == "gather"
+    with GenerationEngine(model, slots=2, max_len=MAXLEN,
+                          queue_max=4) as eng:
+        assert "decode_attn" not in eng.stats()
+
+
+# -- structure of the traced step ----------------------------------------------
+
+def _step_eqns(model, which):
+    with arm(which), GenerationEngine(
+            model, slots=SLOTS, max_len=MAXLEN, paged=True, page_tokens=P,
+            pages=PAGES, queue_max=4) as eng:
+        jaxpr = jax.make_jaxpr(eng._step._jitted)(
+            model, eng._state, jnp.asarray(TABLE), jnp.asarray(ACTIVE))
+    return list(walk_eqns(jaxpr.jaxpr))
+
+
+def _attn_calls(eqns):
+    return [(e, path) for e, path in eqns
+            if e.primitive.name == "pallas_call"
+            and e.params["name"] == "ptpu_paged_decode_attn"]
+
+
+def _shapes(eqns):
+    return {tuple(v.aval.shape) for e, _ in eqns for v in e.outvars
+            if hasattr(v.aval, "shape")}
+
+
+def test_step_holds_one_kernel_call_a_layer_over_all_slots(model):
+    eqns = _step_eqns(model, "paged_kernel")
+    calls = _attn_calls(eqns)
+    assert len(calls) == 1                      # the layer scan's body
+    call, path = calls[0]
+    # the fresh token's step, then the row's pages a few a step
+    assert call.params["grid_mapping"].grid == (
+        SLOTS, 1 - (-M // pdk._pages_per_step(M, HKV * P * D * 4)))
+    assert "scan" in path and "while" not in path, path
+    # the views a gather builds: pages through the row, and the
+    # contiguous [Hkv, M * P, D] it reshapes them to
+    pages, view = (SLOTS, M, HKV, P, D), (SLOTS, 1, HKV, M * P, D)
+    assert not {pages, view} & _shapes(eqns)
+    gather = _step_eqns(model, "gather")
+    assert not _attn_calls(gather)
+    assert {pages, view} <= _shapes(gather)     # the check sees them
+
+
+# -- what must not move --------------------------------------------------------
+
+def _sha(lowered):
+    return hashlib.sha256(lowered.as_text().encode()).hexdigest()
+
+
+def _paged_programs(model, **kw):
+    with GenerationEngine(model, slots=SLOTS, max_len=MAXLEN, paged=True,
+                          page_tokens=P, pages=PAGES, queue_max=4,
+                          **kw) as eng:
+        low = eng.lowered(6)
+        out = {"prefill": _sha(low["prefill"]), "step": _sha(low["decode"])}
+        if eng._spec_step is not None:
+            i32 = jnp.zeros((SLOTS,), jnp.int32)
+            out["spec_step"] = _sha(eng._spec_step.lower(
+                eng._state, jnp.asarray(eng._pt), jnp.zeros((SLOTS,), bool),
+                jnp.zeros((SLOTS, eng._spec_k), jnp.int32), i32))
+        return out
+
+
+def test_only_the_plain_step_changes_with_the_gate(model):
+    """With the gate open or shut the paged prefill chunk and the paged
+    speculative verify lower to the same text (``T > 1`` never reaches
+    the kernel), as do both programs of the latent model (its
+    ``latent_attention`` is not touched); the plain step does change."""
+    from paddle_tpu.models.deepseek_v3 import (
+        DeepseekV3Config, DeepseekV3ForCausalLM,
+    )
+    paddle_tpu.seed(12)
+    latent = DeepseekV3ForCausalLM(DeepseekV3Config.tiny(
+        vocab_size=VOCAB, max_seq_len=MAXLEN))
+    sides = {}
+    for which in ("paged_kernel", "gather"):
+        with arm(which):
+            sides[which] = {
+                "llama": _paged_programs(model, spec_k=3, spec_mode="ngram"),
+                "latent": _paged_programs(latent)}
+    a, b = sides["paged_kernel"], sides["gather"]
+    assert a["latent"] == b["latent"]
+    assert a["llama"]["prefill"] == b["llama"]["prefill"]
+    assert a["llama"]["spec_step"] == b["llama"]["spec_step"]
+    assert a["llama"]["step"] != b["llama"]["step"]
+
+
+# -- compiled for the chip (no chip needed: libtpu compiles for a described
+# -- v5e); the topology is described inside the fixture, never at import ----
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("pool_dtype", ["bf16", "int8"])
+def test_step_compiled_for_v5e_keeps_the_pool_in_place(one_chip, monkeypatch,
+                                                       pool_dtype):
+    """Trap 2: the kernel only reads the pool, the write after the vmap
+    updates whole pages, so Mosaic's operand is the donated pool as it
+    lies — the temporaries stay under one pool leaf and no buffer but
+    the pool is pool-sized, as ``test_paged_view`` bounds the gather arm.
+    The int8 pool compiles too, on the gather arm: Mosaic refuses the
+    kernel's reshape of the scale planes, and the gate knows."""
+    # hkv * p is one lane tile and a row a whole one; the pool is too
+    # large for XLA to stage a copy of it in fast memory
+    hkv, d, p, slots, maxlen = 8, 128, 16, 4, 512
+    model = _llama("bfloat16", seed=13, hidden_size=hkv * d, num_layers=4,
+                   num_heads=hkv, num_kv_heads=hkv, max_seq_len=maxlen)
+    monkeypatch.setattr(_support, "on_tpu", lambda: True)
+    which = "paged_kernel" if pool_dtype == "bf16" else "gather"
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    with GenerationEngine(
+            model, slots=slots, max_len=maxlen, paged=True, page_tokens=p,
+            cache_dtype=jnp.int8 if pool_dtype == "int8" else None,
+            queue_max=4) as eng:
+        pool = eng._state["cache"]
+        lowered = eng._step._jitted.trace(
+            abstract(model), abstract(eng._state),
+            abstract(jnp.asarray(eng._pt)),
+            abstract(jnp.zeros((slots,), bool))).lower(
+                lowering_platforms=("tpu",))
+        assert eng.stats()["decode_attn"] == which
+    assert (("ptpu_paged_decode_attn" in lowered.as_text())
+            == (which == "paged_kernel"))
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(int(x.nbytes) for x in pool)
+    assert mem.temp_size_in_bytes < int(pool[0].nbytes)
+    leaf = ",".join(str(d) for d in pool[0].shape)
+    for line in hlo.splitlines():
+        if " copy(" in line:
+            assert f"[{leaf}]" not in line, line
