@@ -3,9 +3,9 @@
     python chip_smoke.py
 
 Drives the two hot paths through the entry points users call, at the
-full width of the headline 953M Llama-shaped decoder (``bench.py``'s
-non-smoke config; only step and request counts are small, weights are
-random from a seed), in ONE process that holds the chip throughout:
+full width of the 953M Llama-shaped decoder (:func:`headline_config`;
+only step and request counts are small, weights are random from a
+seed), in ONE process that holds the chip throughout:
 
 1. train — ``fleet.build_train_step`` -> ``init_state`` -> ``shard_batch``
    -> a few AdamW steps on one repeated batch (finite loss/grad-norm,
@@ -109,8 +109,7 @@ def log(message: str) -> None:
 
 
 def headline_config():
-    """The 953M Llama-shaped decoder of ``bench.py``'s non-smoke branch,
-    every width as there."""
+    """The 953M Llama-shaped decoder the smoke trains and serves."""
     from paddle_tpu.models import LlamaConfig
 
     return LlamaConfig(
@@ -236,7 +235,7 @@ def _check_spread(what: str, devices, tree=None) -> list[int]:
 
 def train_phase(cfg, *, batch: int, seq: int, steps: int, chips: int = 1,
                 on_chip: bool = True):
-    """``steps`` AdamW steps of ``bench.py``'s train-step wiring on one
+    """``steps`` AdamW steps through ``fleet.build_train_step`` on one
     repeated batch over ``chips`` devices (ZeRO-3 when > 1). Returns
     ``(trained model, report)``; the optimizer state is dropped."""
     import jax

@@ -3,10 +3,9 @@
 Every chip-tool call is a fresh machine and a ~1B-parameter train step
 plus the serving engine's bucket programs take minutes to compile, so
 every entry point that compiles for the chip (``chip_smoke.py``,
-``bench.py``, ``bench_extra.py``, ``serving/replica_main.py``) calls
-:func:`enable_compile_cache` first. It is NOT called at
-``import paddle_tpu``: library users and the CPU test suite keep JAX's
-own default (no persistent cache).
+``serving/replica_main.py``) calls :func:`enable_compile_cache` first.
+It is NOT called at ``import paddle_tpu``: library users and the CPU
+test suite keep JAX's own default (no persistent cache).
 
 The directory can be placed from outside: when
 ``JAX_COMPILATION_CACHE_DIR`` is set JAX itself reads it and this module

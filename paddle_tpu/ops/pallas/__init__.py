@@ -1,6 +1,5 @@
 """Pallas TPU kernels for the hot-op set (reference: CUDA kernels under
-``paddle/fluid/operators/fused/``, ``operators/math/``,
-``operators/optimizers/``).
+``paddle/fluid/operators/fused/``, ``operators/math/``).
 
 - ``flash_attention`` — fused attention, never materializes [T, T]
   (ref ``fused/multihead_matmul_op.cu``)
@@ -12,7 +11,6 @@
   logits never stored (ref fuses only softmax+xent; this also folds the
   preceding FC — the memory lever at real vocab sizes)
 - ``apply_rotary`` — fused RoPE rotation
-- ``adamw_update`` — fused optimizer update (ref ``optimizers/adam_op.cu``)
 
 All kernels run compiled on TPU and interpreted elsewhere
 (``_support.interpret()``); all are differentiable via ``jax.custom_vjp``.
@@ -27,7 +25,6 @@ from paddle_tpu.ops.pallas.softmax_xent import softmax_cross_entropy
 from paddle_tpu.ops.pallas.linear_xent import (
     chunked_linear_cross_entropy, fused_linear_cross_entropy,
 )
-from paddle_tpu.ops.pallas.adamw import adamw_update
 from paddle_tpu.ops.pallas.selective_scan import (
     selective_scan, supported as selective_scan_supported,
 )
@@ -55,7 +52,7 @@ def reset_partition_stats() -> None:
 __all__ = [
     "flash_attention", "flash_attention_supported", "rms_norm", "layer_norm",
     "softmax_cross_entropy", "fused_linear_cross_entropy",
-    "chunked_linear_cross_entropy", "apply_rotary", "adamw_update",
+    "chunked_linear_cross_entropy", "apply_rotary",
     "selective_scan", "selective_scan_supported",
     "force_interpret", "force_dispatch", "on_tpu", "dispatch_mode",
     "partition_stats", "reset_partition_stats",

@@ -123,10 +123,7 @@ class AdamW(Optimizer):
 
     Kernel note: inside a jitted train step XLA fuses this pure-jnp
     update chain into one elementwise kernel per parameter, so no custom
-    kernel is dispatched here. The fused single-pass Pallas variant
-    (``paddle_tpu.ops.pallas.adamw_update``, buffer-donating — the
-    ``adam_op.cu`` analogue) is for eager/out-of-step use where each
-    jnp op would otherwise round-trip HBM."""
+    kernel is dispatched here."""
 
     def __init__(self, learning_rate=0.001, beta1: float = 0.9,
                  beta2: float = 0.999, epsilon: float = 1e-8,

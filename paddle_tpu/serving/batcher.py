@@ -17,9 +17,8 @@ Mechanics:
   FIFO prefix that fits, executes it, and distributes results. Followers
   just wait; leftover requests elect the next leader immediately.
 - **Load watermark.** Coalescing taxes idle traffic: a lone request
-  paid the full ``serving_batch_timeout_s`` window for a batch that was
-  never coming (measured 0.57x vs unbatched at concurrency 1,
-  BENCH_serving.json r5). A request that finds fewer than
+  pays the full ``serving_batch_timeout_s`` window for a batch that is
+  never coming. A request that finds fewer than
   ``FLAGS_serving_batch_min_queue`` concurrent submits for its model —
   and no batch already forming — bypasses the queue and runs
   immediately (``serving/batch_bypass``); under real concurrency the

@@ -2678,6 +2678,75 @@ class GenerationEngine:
                                jnp.asarray(gen.top_p, jnp.float32))
         return gen.dev_ops
 
+    # -- delivery: the one place a token reaches a stream ---------------------
+    def _deliver_locked(self, gen: Generation, toks) -> tuple[int, int]:
+        """Append ``toks`` in order; at EOS or ``max_new_tokens`` retire
+        the stream and stop: accepted drafts past the end are dropped
+        on the host (the device state behind them is garbage, but the
+        slot is released right here). Returns ``(emitted, retired)``;
+        ``_cond`` held."""
+        emitted = 0
+        for tok in toks:
+            gen.tokens.append(tok)
+            emitted += 1
+            if ((gen.eos_token_id is not None
+                 and tok == gen.eos_token_id)
+                    or len(gen.tokens) >= gen.max_new_tokens):
+                gen.done = True
+                if self._ledger is not None:
+                    gen.done_ts = time.monotonic()
+                self._gen_event(gen, "gen/retire", reason="complete",
+                                tokens=len(gen.tokens))
+                self._release_slot_locked(gen)
+                return emitted, 1
+        return emitted, 0
+
+    def _first_token_locked(self, gen: Generation, tok0: int) -> None:
+        """A prefill's sampled token. TTFT = enqueue -> first token
+        (queue wait included): the latency an interactive SLO is about
+        and the signal the control plane autoscales on. A parked
+        stream's resume-prefill is NOT a first token (its TTFT was
+        observed before the preemption), hence the two guards; a
+        contiguous stream is never parked (``_preempt_tick`` returns at
+        ``not self._paged``), so both always pass there. ``_cond``
+        held."""
+        if self._ledger is not None and gen.first_tok_ts == 0.0:
+            gen.first_tok_ts = time.monotonic()
+        if gen.folded == 0:
+            observe("gen/ttft_s", time.monotonic() - gen.created)
+            if self._sched is not None and gen.tenant:
+                # per-tenant split: the fairness input
+                # MetricsHub.burn_rates(tenant=) reads
+                observe(f"gen/ttft_s/{gen.tenant}",
+                        time.monotonic() - gen.created)
+        stat_add("gen/tokens")
+        self._deliver_locked(gen, (tok0,))
+
+    def _emit_step_locked(self, emit_ph, stepped, chip_share, toks,
+                          accepted=None) -> None:
+        """Body of a decode step's ``gen/emit``, sync or lagged: every
+        stepped slot its generation still holds (not cancelled
+        mid-step, not retired by an earlier lagged entry) takes its
+        share of the step's chip-seconds and its token ``toks[slot]``
+        — of a speculative step, the tokens ``accepted(slot, gen)``.
+        ``_cond`` held."""
+        emitted = retired = 0
+        for s, gen in stepped:
+            if self._slot_gen[s] is not gen:
+                continue
+            if self._ledger is not None:
+                gen.chip_s += chip_share
+            e, r = self._deliver_locked(
+                gen, (toks[s],) if accepted is None else accepted(s, gen))
+            emitted += e
+            retired += r
+        self._emit_total += emitted
+        self._decode_iters += 1
+        if emitted:
+            stat_add("gen/tokens", emitted)
+        self._cond.notify_all()
+        emit_ph.set(emitted=emitted, retired=retired)
+
     def _prefill_tick(self) -> bool:
         """Advance every prefilling slot by ONE chunk (then the loop
         runs a decode step — chunked prefill interleaves with decode
@@ -2750,32 +2819,7 @@ class GenerationEngine:
                     self._prefix.insert(gen.prompt, gen.pages, self._pool)
                 if self._kv is not None:
                     self._kv_publish(gen)
-                gen.tokens.append(tok0)
-                if self._ledger is not None and gen.first_tok_ts == 0.0:
-                    gen.first_tok_ts = time.monotonic()
-                if gen.folded == 0:
-                    # TTFT = enqueue -> first token (queue wait
-                    # included): the latency an interactive SLO is
-                    # actually about, and the signal the serving
-                    # control plane autoscales on. A parked stream's
-                    # resume-prefill is NOT a first token — its TTFT
-                    # was observed before the preemption.
-                    observe("gen/ttft_s", time.monotonic() - gen.created)
-                    if self._sched is not None and gen.tenant:
-                        # per-tenant split: the fairness input
-                        # MetricsHub.burn_rates(tenant=) reads
-                        observe(f"gen/ttft_s/{gen.tenant}",
-                                time.monotonic() - gen.created)
-                stat_add("gen/tokens")
-                if ((gen.eos_token_id is not None
-                     and tok0 == gen.eos_token_id)
-                        or len(gen.tokens) >= gen.max_new_tokens):
-                    gen.done = True
-                    if self._ledger is not None:
-                        gen.done_ts = time.monotonic()
-                    self._gen_event(gen, "gen/retire", reason="complete",
-                                    tokens=len(gen.tokens))
-                    self._release_slot_locked(gen)
+                self._first_token_locked(gen, tok0)
                 self._cond.notify_all()
         return ticked
 
@@ -2811,23 +2855,7 @@ class GenerationEngine:
         with self._cond:
             if self._slot_gen[slot] is not gen:   # cancelled mid-prefill
                 return
-            gen.tokens.append(tok0)
-            if self._ledger is not None:
-                gen.first_tok_ts = time.monotonic()
-            observe("gen/ttft_s", time.monotonic() - gen.created)
-            if self._sched is not None and gen.tenant:
-                observe(f"gen/ttft_s/{gen.tenant}",
-                        time.monotonic() - gen.created)
-            stat_add("gen/tokens")
-            if ((gen.eos_token_id is not None
-                 and tok0 == gen.eos_token_id)
-                    or len(gen.tokens) >= gen.max_new_tokens):
-                gen.done = True
-                if self._ledger is not None:
-                    gen.done_ts = time.monotonic()
-                self._gen_event(gen, "gen/retire", reason="complete",
-                                tokens=len(gen.tokens))
-                self._release_slot_locked(gen)
+            self._first_token_locked(gen, tok0)
             self._cond.notify_all()
 
     def _decode_step(self, jnp) -> bool:
@@ -2955,57 +2983,32 @@ class GenerationEngine:
         if self._epoch != epoch0:
             raise _EpochChanged("decode step outlived the watchdog "
                                 "deadline")
+
+        def accepted(s, gen):
+            n = int(emit[s])
+            dlen = int(dlens[s])
+            if dlen:
+                acc = n - 1
+                gen.spec_proposed += dlen
+                gen.spec_accepted += acc
+                self._spec_proposed += dlen
+                self._spec_accepted += acc
+                stat_add("gen/spec_proposed", dlen)
+                stat_add("gen/spec_accepted", acc)
+                stat_add("gen/spec_rejected", dlen - acc)
+                observe("gen/spec_accept_len", float(acc))
+                self._gen_event(gen, "gen/spec_accept", slot=s,
+                                proposed=dlen, accepted=acc)
+            return [int(t) for t in out[s, :n]]
+
         with self._phase("gen/emit") as emit_ph, self._cond:
-            emitted = retired = 0
-            for s, gen in stepped:
-                if self._slot_gen[s] is not gen:   # cancelled mid-step
-                    continue
-                if self._ledger is not None:
-                    gen.chip_s += chip_share
-                if use_spec:
-                    n = int(emit[s])
-                    new = [int(t) for t in out[s, :n]]
-                    dlen = int(dlens[s])
-                    if dlen:
-                        acc = n - 1
-                        gen.spec_proposed += dlen
-                        gen.spec_accepted += acc
-                        self._spec_proposed += dlen
-                        self._spec_accepted += acc
-                        stat_add("gen/spec_proposed", dlen)
-                        stat_add("gen/spec_accepted", acc)
-                        stat_add("gen/spec_rejected", dlen - acc)
-                        observe("gen/spec_accept_len", float(acc))
-                        self._gen_event(gen, "gen/spec_accept", slot=s,
-                                        proposed=dlen, accepted=acc)
-                else:
-                    new = [int(toks[s])]
-                for tok in new:
-                    gen.tokens.append(tok)
-                    emitted += 1
-                    if ((gen.eos_token_id is not None
-                         and tok == gen.eos_token_id)
-                            or len(gen.tokens) >= gen.max_new_tokens):
-                        # accepted tokens past EOS are discarded on the
-                        # host; the device state past this point is
-                        # garbage but the slot is released right here
-                        gen.done = True
-                        retired += 1
-                        if self._ledger is not None:
-                            gen.done_ts = time.monotonic()
-                        self._gen_event(gen, "gen/retire",
-                                        reason="complete",
-                                        tokens=len(gen.tokens))
-                        self._release_slot_locked(gen)
-                        break
             if use_spec:
                 self._spec_verify_steps += 1
-            self._emit_total += emitted
-            self._decode_iters += 1
-            if emitted:
-                stat_add("gen/tokens", emitted)
-            self._cond.notify_all()
-            emit_ph.set(emitted=emitted, retired=retired)
+                self._emit_step_locked(emit_ph, stepped, chip_share, None,
+                                       accepted)
+            else:
+                self._emit_step_locked(emit_ph, stepped, chip_share,
+                                       toks.tolist())
         self._pace()
         return True
 
@@ -3056,29 +3059,5 @@ class GenerationEngine:
             # latch forces the rebuild/break decision
             return
         with self._phase("gen/emit") as emit_ph, self._cond:
-            emitted = retired = 0
-            for s, gen in stepped:
-                if self._slot_gen[s] is not gen:   # retired/cancelled
-                    continue                       # by an earlier entry
-                if self._ledger is not None:
-                    gen.chip_s += chip_share
-                tok = int(toks[s])
-                gen.tokens.append(tok)
-                emitted += 1
-                if ((gen.eos_token_id is not None
-                     and tok == gen.eos_token_id)
-                        or len(gen.tokens) >= gen.max_new_tokens):
-                    gen.done = True
-                    retired += 1
-                    if self._ledger is not None:
-                        gen.done_ts = time.monotonic()
-                    self._gen_event(gen, "gen/retire",
-                                    reason="complete",
-                                    tokens=len(gen.tokens))
-                    self._release_slot_locked(gen)
-            self._emit_total += emitted
-            self._decode_iters += 1
-            if emitted:
-                stat_add("gen/tokens", emitted)
-            self._cond.notify_all()
-            emit_ph.set(emitted=emitted, retired=retired)
+            self._emit_step_locked(emit_ph, stepped, chip_share,
+                                   toks.tolist())

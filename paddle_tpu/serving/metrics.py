@@ -381,8 +381,8 @@ class MetricsHub:
         """Fleet KV-store rollup: every (endpoint, model) engine's ``kv``
         gauge block (``serving/kvstore.py`` snapshot + engine counters)
         summed, with the derived fleet hit rate over all lookups — the
-        disaggregated-serving scoreboard (`tools/perf_report.py`).  None
-        when no engine reports one (store off fleet-wide)."""
+        disaggregated-serving scoreboard.  None when no engine reports
+        one (store off fleet-wide)."""
         counters: dict[str, float] = {}
         roles: dict[str, int] = {}
         engines = 0

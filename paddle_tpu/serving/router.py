@@ -507,8 +507,9 @@ class RoutedClient:
         goodput snapshots — see ``serving/ledger.py``). Unreachable
         replicas map to ``{"status": "unreachable", ...}`` like
         :meth:`health`; replicas running with ``FLAGS_gen_ledger`` off
-        contribute empty dumps. ``tools/perf_report.py`` turns this +
-        :meth:`health` into the fleet attribution report."""
+        contribute empty dumps. ``MetricsHub.fleet_goodput()`` /
+        ``tenants()`` / ``fleet_kv()`` roll :meth:`health` up across
+        replicas; this is the per-request half."""
         out: dict[str, dict] = {}
         for r in list(self._replicas):
             ok, err = self._probe_one(r.endpoint)

@@ -102,20 +102,6 @@ def test_main_refuses_to_run_without_a_tpu():
     assert proc.stdout == ""          # no result line off the chip
 
 
-def test_bench_refuses_a_device_without_a_recorded_peak():
-    import bench
-
-    def device(kind, platform):
-        return type("Device", (), {"device_kind": kind,
-                                   "platform": platform})()
-
-    assert bench.detect_peak_flops(device("TPU v5 lite", "tpu")) == 197e12
-    assert bench.detect_peak_bandwidth(device("TPU v5 lite", "tpu")) == 819e9
-    for detect in (bench.detect_peak_flops, bench.detect_peak_bandwidth):
-        with pytest.raises(ValueError, match="device_kind 'cpu'"):
-            detect(device("cpu", "cpu"))
-
-
 def test_compile_cache_placement(monkeypatch):
     dir_before = jax.config.jax_compilation_cache_dir
     secs_before = jax.config.jax_persistent_cache_min_compile_time_secs

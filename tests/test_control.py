@@ -615,13 +615,15 @@ def _cum_hist(values):
     return h.summary(raw=True)
 
 
-def test_controller_burn_rate_pressure_signals():
+@pytest.mark.parametrize("max_replicas", [1, 2])
+def test_controller_burn_rate_pressure_signals(max_replicas):
     """TTFT pressure is the multi-window burn rate, not a raw p99
     breach: the first scrape is a baseline (burn 0), a violating window
     trips BOTH windows past the threshold, and the resulting decision
-    carries the burn evidence in its signals."""
+    — a hold at ``max_replicas``, a scale-up below it — cites the burn
+    and carries the evidence in its signals."""
     ctl = ServingController(InProcSpawner(_mlp_factory), interval_s=0,
-                            max_replicas=1, breach_ticks=1,
+                            max_replicas=max_replicas, breach_ticks=1,
                             cooldown_s=0.0, target_ttft_s=0.5,
                             slo_budget=0.1, burn_fast_ticks=2,
                             burn_slow_ticks=4, burn_threshold=1.0)
@@ -643,8 +645,10 @@ def test_controller_burn_rate_pressure_signals():
         assert s2["ttft_p99_s"] is not None and s2["ttft_p99_s"] > 0.5
         reasons = ctl._pressure(s2)
         assert any("burn rate" in r for r in reasons), reasons
-        d = ctl._decide(s2)                     # at max_replicas: holds,
-        assert d.action == "hold"               # but evidence is logged
+        d = ctl._decide(s2)      # at max_replicas it holds, and the
+        assert d.action == ("hold" if max_replicas == 1   # evidence is
+                            else "scale_up")              # logged still
+        assert "burn rate" in d.reason
         assert d.signals["ttft_burn_fast"] == pytest.approx(10.0)
         assert d.signals["ttft_burn_slow"] == pytest.approx(10.0)
         # two clean ticks push the violation out of the fast window: the

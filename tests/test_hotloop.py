@@ -19,7 +19,9 @@ import numpy as np
 import pytest
 
 import paddle_tpu
-from paddle_tpu.core.flags import flag
+from paddle_tpu.core import trace
+from paddle_tpu.core.flags import flag, get_flags, set_flags
+from paddle_tpu.core.monitor import get_histogram
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.models.generation import generate
 from paddle_tpu.serving import GenerationEngine
@@ -106,6 +108,62 @@ def test_byte_identity_grid_matches_solo_generate(model):
             # the trailing lagged step (pad writes only) drains on the
             # next idle loop pass
             assert _wait(eng, lambda s: s["pending_steps"] == 0), tag
+
+
+# -- delivery: a token reaches a stream, and a stream ends, in one place ------
+
+LAYOUTS = {"contiguous": dict(),
+           "paged_chunked": dict(paged=True, page_tokens=8, pages=24,
+                                 prefill_chunk=4)}
+
+
+@pytest.fixture
+def tracing():
+    saved = get_flags(["trace", "trace_buffer"])
+    trace.clear()
+    set_flags({"trace_buffer": 4096, "trace": True})
+    yield
+    set_flags(saved)
+    trace.clear()
+
+
+@pytest.mark.parametrize("depth", (0, 1))
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_delivery_ends_each_stream_once(model, tracing, layout, depth):
+    """A stream that ends at its first token (the prefill's), one that
+    ends on EOS mid-stream and one that runs to ``max_new_tokens``:
+    each delivers solo ``generate()``'s tokens up to its end and not
+    one past it (a lagged step's post-EOS token included), retires
+    with exactly one ``gen/retire`` carrying its token count, observes
+    ``gen/ttft_s`` once, and leaves its slot and pages free."""
+    prompt = np.random.RandomState(3).randint(0, VOCAB, (6,)).astype(
+        np.int32)
+    ref = [int(t) for t in np.asarray(
+        generate(model, prompt[None], 6))[0, 6:]]
+    k = next(i for i in range(1, 6) if ref[i] not in ref[:i])
+    ends = {"first_token": (dict(max_new_tokens=1), ref[:1]),
+            "eos": (dict(max_new_tokens=6, eos_token_id=ref[k]),
+                    ref[:k + 1]),
+            "max_new": (dict(max_new_tokens=5), ref[:5])}
+    with GenerationEngine(model, slots=2, max_len=32, queue_max=8,
+                          async_depth=depth, **LAYOUTS[layout]) as eng:
+        for end, (kw, want) in ends.items():
+            tag = f"{layout} depth={depth} {end}"
+            ttft0 = (get_histogram("gen/ttft_s") or {"count": 0})["count"]
+            toks, err = _drain(eng, eng.start(prompt, trace_id=tag, **kw))
+            assert err is None and toks == want, tag
+            assert get_histogram("gen/ttft_s")["count"] == ttft0 + 1, tag
+            retires = [sp["attrs"] for sp in trace.get_spans()
+                       if sp["name"] == "gen/retire"
+                       and sp["trace_id"] == tag]
+            assert [(a["reason"], a["tokens"]) for a in retires] == [
+                ("complete", len(want))], tag
+            assert _wait(eng, lambda s: s["active"] == 0
+                         and s["pending_steps"] == 0), tag
+            st = eng.stats()
+            if st["paged"]:
+                assert (st["pages_free"] + st["prefix_entries"]
+                        == st["pages"]), tag
 
 
 # -- cancel / TTL under lookahead -------------------------------------------

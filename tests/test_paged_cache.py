@@ -334,6 +334,31 @@ def test_start_rejects_request_larger_than_pool(model):
         assert err is None and len(toks) == 2
 
 
+def test_paged_engine_holds_2x_streams_at_equal_cache_memory(model):
+    """Equal cache memory (2 slots x 64 positions == 8 pages x 16
+    tokens), streams of prompt 8 + 8 new = exactly one page: the
+    contiguous engine holds 2 and queues 6, the paged one holds all 8
+    at once — and every stream is still solo ``generate()``."""
+    N = 8
+    prompts = np.random.RandomState(35).randint(
+        0, VOCAB, (N, 8)).astype(np.int32)
+    ref = np.asarray(generate(model, prompts, 8))[:, 8:]
+    for mode, kw in (("contiguous", dict(slots=2)),
+                     ("paged", dict(slots=N, paged=True, page_tokens=16,
+                                    pages=N, prefix_cache=False))):
+        with GenerationEngine(model, max_len=64, queue_max=16,
+                              step_wait_s=0.05, **kw) as eng:
+            gids = [eng.start(p, 8) for p in prompts]
+            want = kw["slots"]          # 2 held against 8: 4x (floor 2x)
+            assert _wait(lambda: eng.stats()["active"] == want
+                         and eng.stats()["queued"] == N - want), mode
+            for i, g in enumerate(gids):
+                toks, err = _drain(eng, g)
+                assert err is None, mode
+                np.testing.assert_array_equal(
+                    np.asarray(toks, np.int32), ref[i], err_msg=mode)
+
+
 @pytest.mark.slow
 def test_admission_stalls_then_resumes_when_pages_free(model):
     """When live generations hold the whole pool the queue head waits

@@ -24,7 +24,6 @@ FA = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 NORM = importlib.import_module("paddle_tpu.ops.pallas.norm")
 SX = importlib.import_module("paddle_tpu.ops.pallas.softmax_xent")
 ROPE = importlib.import_module("paddle_tpu.ops.pallas.rope")
-AW = importlib.import_module("paddle_tpu.ops.pallas.adamw")
 
 
 def ref_attention(q, k, v, causal):
@@ -181,34 +180,6 @@ def test_rope_kernel():
         x, cos, sin))))(x)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
                                rtol=1e-4, atol=1e-5)
-
-
-def test_adamw_kernel_matches_optimizer_math():
-    rs = np.random.RandomState(8)
-    p = jnp.asarray(rs.randn(33, 7).astype(np.float32))  # padding path
-    m = jnp.zeros_like(p)
-    v = jnp.zeros_like(p)
-    g = jnp.asarray(rs.randn(33, 7).astype(np.float32))
-    lr, b1, b2, eps, wd = 1e-2, 0.9, 0.999, 1e-8, 0.01
-    p1, m1, v1 = p, m, v
-    for step in (1, 2, 3):
-        p1, m1, v1 = AW.adamw_update(p1, m1, v1, g, lr=lr, beta1=b1,
-                                     beta2=b2, eps=eps, weight_decay=wd,
-                                     step=step)
-    # plain-jnp reference
-    p2, m2, v2 = p, m, v
-    for step in (1, 2, 3):
-        m2 = b1 * m2 + (1 - b1) * g
-        v2 = b2 * v2 + (1 - b2) * g * g
-        mh = m2 / (1 - b1 ** step)
-        vh = v2 / (1 - b2 ** step)
-        p2 = p2 - lr * (mh / (jnp.sqrt(vh) + eps) + wd * p2)
-    np.testing.assert_allclose(np.asarray(p1), np.asarray(p2),
-                               rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(m1), np.asarray(m2),
-                               rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(v1), np.asarray(v2),
-                               rtol=1e-5, atol=1e-6)
 
 
 def test_dispatch_wrappers_forced(monkeypatch):
