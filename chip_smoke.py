@@ -66,6 +66,9 @@ PREFILL_KERNELS = {
 # (``serve_phase`` expects none there).
 DECODE_KERNELS = {"contiguous": ("ptpu_decode_attn",),
                   "paged": ("ptpu_paged_decode_attn",)}
+# the paged step of a latent-attention (MLA) model: the same dispatch,
+# the latent arm of the same module
+LATENT_DECODE_KERNEL = "ptpu_paged_latent_decode_attn"
 # per-shard (shard_map) units the four-chip programs must take on the
 # kernel arm (``ops.pallas.partition_stats()`` keys ``<unit>:kernel``)
 TRAIN_UNITS = ("flash_fwd", "flash_bwd", "rms_fwd", "rms_bwd", "rope",
@@ -620,6 +623,55 @@ def serve_phase(model, requests, *, slots: int, max_len: int,
     return report
 
 
+def latent_phase(requests, *, slots: int, max_len: int,
+                 on_chip: bool = True) -> dict:
+    """A small latent-attention model (``DeepseekV3ForCausalLM``, a
+    cache row of whole lane tiles: 128 + 64 -> 256) behind a paged,
+    prefix-cached generator: every request streamed concurrently. On
+    the chip its step has to attend through the latent paged kernel —
+    ``stats()["decode_attn"]`` and the lowered step both say so — as
+    :func:`serve_phase` holds the K/V step to its kernel."""
+    import jax
+
+    import paddle_tpu
+    from paddle_tpu import io
+    from paddle_tpu.models.deepseek_v3 import (
+        DeepseekV3Config, DeepseekV3ForCausalLM,
+    )
+
+    paddle_tpu.seed(5)
+    cfg = DeepseekV3Config.tiny(
+        hidden_size=256, num_heads=8, kv_lora_rank=128, qk_nope_head_dim=64,
+        qk_rope_head_dim=64, v_head_dim=64, max_seq_len=max_len,
+        dtype="bfloat16" if on_chip else "float32")
+    model = DeepseekV3ForCausalLM(cfg)
+    server = io.InferenceServer(port=0).start()
+    try:
+        engine = server.add_generator(
+            "latent", model, slots=slots, max_len=max_len, paged=True,
+            page_tokens=PARITY_PAGE_TOKENS, prefix_cache=True)
+        text = engine.lowered_text(max(len(r.prompt) for r in requests))
+        arm = engine.stats()["decode_attn"]
+        if on_chip:
+            check(arm == "paged_kernel",
+                  f"latent paged step attends by {arm}, expected "
+                  "paged_kernel")
+            absent = _missing(text["decode"], (LATENT_DECODE_KERNEL,))
+            check(not absent, f"latent engine lowered without {absent}")
+        _, repeat = _drive(server.endpoint, "latent", engine, requests,
+                           cfg.vocab_size)
+        with io.InferenceClient(server.endpoint) as client:
+            g = client.health()["generators"]["latent"]
+        _check_generator("latent", g, platform=jax.devices()[0].platform,
+                         devices=1)
+        return {"decode_attn": arm, "streams": len(requests),
+                "probe_repeat": repeat, "compiles": g["compiles"],
+                "kernels": {"decode": [LATENT_DECODE_KERNEL]
+                            if arm == "paged_kernel" else []}}
+    finally:
+        server.stop()
+
+
 # ---------------------------------------------------------------------------
 # main
 # ---------------------------------------------------------------------------
@@ -651,6 +703,9 @@ def main() -> int:
     model, report["train"] = train_phase(cfg, batch=4, seq=2048, steps=8)
     report["parity"] = parity_phase(model, seq=512)
     report["serve"] = serve_phase(model, requests, slots=8, max_len=2048)
+    report["serve_latent"] = latent_phase(
+        make_requests(256, (150, 140, 170, 130), (24, 16, 24, 16),
+                      shared_prefix=96), slots=4, max_len=256)
     if n_dev >= 4:
         del model
         gc.collect()

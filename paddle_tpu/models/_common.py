@@ -33,10 +33,12 @@ def causal_lm_loss(model, head_weight, input_ids, labels,
 
 # How a paged program's attention read its cache, decided when the
 # program is traced and counted there (as ``ops.pallas.partition_stats``
-# counts its units): ``"paged_kernel"`` — ``ptpu_paged_decode_attn``
-# through the page table — or ``"gather"`` — one layer's pages gathered
-# and the einsum lines. The engine reads the difference around the trace
-# of its step (``GenerationEngine.stats()["decode_attn"]``).
+# counts its units), by ``cached_attention`` and ``latent_attention``
+# alike: ``"paged_kernel"`` — ``ptpu_paged_decode_attn`` or, over the
+# latent leaf, ``ptpu_paged_latent_decode_attn``, through the page table
+# — or ``"gather"`` — one layer's pages gathered and the einsum lines.
+# The engine reads the difference around the trace of its step
+# (``GenerationEngine.stats()["decode_attn"]``).
 paged_attn_arms: collections.Counter = collections.Counter()
 
 
@@ -222,8 +224,24 @@ def latent_attention(q_nope, q_rope, c_kv, k_rope, w_kc, w_vc, scale,
     cached position is read as its C + R numbers and never expanded
     (expanding costs C·H·(N+V) multiply-adds a cached position a
     layer for every call). The chunk's own rows attend in the same
-    form, jointly normalised with the cached ones (two-piece softmax in
-    float32, as ``cached_attention``)."""
+    form, jointly normalised with the cached ones.
+
+    The absorbed form has two arms, picked as ``cached_attention`` picks
+    and counted in ``paged_attn_arms``. A one-token chunk on a
+    ``PagedCache`` that the latent kernel's gate takes
+    (``paged_decode_attention.latent_supported``: one TPU chip, a float
+    leaf, a row of whole lane tiles) is attended by
+    ``ptpu_paged_latent_decode_attn``: ``[q~ | q_rope | 0]`` against the
+    slot's live pages read once through the table row, each cached row
+    key and value at once, an online softmax with float32 state; under
+    the engine's ``vmap`` over slots one call a layer. Everything else —
+    a prefill chunk, the CPU, a mesh, the contiguous cache of
+    ``generate()`` — reads the layer (``PagedCache.read_layer`` gathers
+    a slot's pages, capacity not fill) for the einsum lines below: a
+    two-piece softmax in float32, as ``cached_attention``. Both arms
+    feed the products operands in the model's dtype, accumulate in
+    float32 and cast the probabilities to the model's dtype before the
+    value product."""
     import jax
 
     B, T, H, _ = q_nope.shape
@@ -234,8 +252,8 @@ def latent_attention(q_nope, q_rope, c_kv, k_rope, w_kc, w_vc, scale,
         buf = (cache.pool if paged else cache)[0]
         pad = jnp.zeros(c_kv.shape[:-1] + (buf.shape[-1] - C - R_,),
                         c_kv.dtype)
-        payload = (jnp.concatenate([c_kv, k_rope, pad], axis=-1)[:, None]
-                   .astype(buf.dtype),)
+        row = jnp.concatenate([c_kv, k_rope, pad], axis=-1)
+        payload = (row[:, None].astype(buf.dtype),)
 
     if cache is None or index is None or (isinstance(index, int)
                                           and index == 0):
@@ -252,6 +270,22 @@ def latent_attention(q_nope, q_rope, c_kv, k_rope, w_kc, w_vc, scale,
 
     idx = jnp.asarray(index, jnp.int32)
     if paged:
+        from paddle_tpu.ops.pallas import paged_decode_attention as _pk
+        if _pk.latent_supported(q_nope, cache.pool, cache.table[None], C):
+            paged_attn_arms["paged_kernel"] += 1
+            with jax.named_scope("mla/absorb"):
+                q_lat = jnp.einsum("bthn,chn->bthc", q_nope, w_kc)
+            with jax.named_scope("mla/attend"):
+                q_full = jnp.concatenate(
+                    [q_lat, q_rope.astype(q_lat.dtype),
+                     jnp.zeros((B, T, H, pad.shape[-1]), q_lat.dtype)], -1)
+                u = _pk.paged_latent_decode_attention(
+                    q_full, row[:, 0].astype(q_lat.dtype), cache.pool,
+                    cache.table[None], layer, idx, scale=scale, C=C)
+            with jax.named_scope("mla/absorb"):
+                out = jnp.einsum("bthc,chv->bthv", u, w_vc)
+            return out, payload
+        paged_attn_arms["gather"] += 1
         (lat,) = cache.read_layer(layer)                # [1, 1, S, C+R]
     else:
         lat = (buf[layer] if isinstance(layer, int) else

@@ -2,7 +2,9 @@
 
 What the paged serving engine's decode step attends with on one TPU
 chip (``models._common.cached_attention`` dispatches here for a
-one-token chunk on a ``PagedCache`` when :func:`supported` holds). The
+one-token chunk on a ``PagedCache`` when :func:`supported` holds,
+``latent_attention`` when :func:`latent_supported` does: two kernel
+bodies, one batching rule, told apart by the pool's leaves). The
 other arm gathers every page of a slot's table row — capacity, not
 fill — into a per-layer contiguous view
 (``models.generation.PagedCache.read_layer``) for the einsum lines of
@@ -51,17 +53,44 @@ per iteration. The call therefore carries a batching rule of its own
 of S slots joins the rows, and the step holds ONE call a layer with grid
 ``(S, 1 + ceil(M / K))`` on the unmapped pool.
 
-Status: interpreter-mode tests (``tests/test_paged_decode_attention.py``)
-pin the kernel to ``paged_gather`` + masked attention per slot, under
-``jax.vmap``, and for the int8 4-leaf layout;
-``tests/test_paged_kernel_step.py`` holds the engine's step on this arm
-to the gather arm (tokens in float32, logits in bf16: the online softmax
-orders every sum differently from the einsum arm's joint f32 softmax)
-and compiles it for the v5e. Off-TPU callers take the gather arm
-(``dispatch_mode()`` is ``"off"``). Multi-device meshes do too (no
-``_partition`` unit yet — the pool's KV-head shard would need a
-per-shard grid), as do prefill chunks and speculative verify windows
-(``T > 1``).
+The latent arm (``ptpu_paged_latent_decode_attn``, behind
+``models._common.latent_attention``). A latent (MLA) pool is ONE leaf
+``[num_pages + 1, L, 1, P, W]`` — a token's compressed K/V row and its
+shared rope key side by side, padded to whole lane tiles
+(``init_latent_cache``) — and a page of one layer is ``[16, 640]`` =
+20 KB where a K/V page is 64 KB a leaf. The K/V form would read every
+page twice (the row is key AND value), take 8x the grid steps and hand
+the matrix units 16-token stationary operands. So the second kernel
+body leaves the pool unblocked in HBM (``pl.ANY``) and copies pages
+itself: a block of :func:`_latent_pages_per_block` live pages, one
+``make_async_copy`` a page into its 16-row place of a ``[KP * 16, W]``
+VMEM buffer — contiguous BEFORE the dot — double-buffered one block
+ahead across grid steps and across slots; then ONE ``[H, W] x [KP * 16,
+W]^T`` product for the scores of all heads (the query is ``[q~ | q_rope
+| 0]``, the pad zeros on both sides), one online-softmax update in
+float32, one ``[H, KP * 16] x [KP * 16, C]`` product over the block's
+first C columns, a lane-aligned slice of the same buffer. Pages past
+the fill are not copied, positions ``>= index`` are masked, blocks
+past the fill skipped; the step's own row is grid step 0. The slot axis
+joins the rows through the same :func:`_over_rows`. Read on the v5e at
+the latent cell's shapes (64 slots x 6.3-7.0 k rows, 5 layers): 5.4 ms
+a decode step in the cell's trace where the gather and the einsum lines
+took 22.7; chained alone in one program 6.0 — the copies alone 4.1
+(2.7 GB at 650 GB/s, whatever the page placement), the products alone
+3.3.
+
+Status: interpreter-mode tests (``tests/test_paged_decode_attention.py``,
+``tests/test_paged_latent_attention.py``) pin each kernel to its gather
+arm per slot, under ``jax.vmap``, and the K/V one for the int8 4-leaf
+layout; ``tests/test_paged_kernel_step.py`` holds the engine's step on
+this arm to the gather arm for both model families (tokens in float32,
+logits in bf16: the online softmax orders every sum differently from
+the einsum arm's joint f32 softmax) and compiles both for the v5e.
+Off-TPU callers take the gather arm (``dispatch_mode()`` is ``"off"``).
+Multi-device meshes do too (no ``_partition`` unit yet — the pool's
+KV-head shard would need a per-shard grid), as do prefill chunks and
+speculative verify windows (``T > 1``), and an int8 latent leaf does
+not exist (``init_latent_cache`` refuses it).
 """
 
 from __future__ import annotations
@@ -79,26 +108,33 @@ LANES = 128
 NEG_INF = -1e30
 
 
+def _one_token_on_one_chip(q, table) -> bool:
+    """What both gates ask first, from what a trace can see: a
+    one-token chunk ``q`` [B, 1, H, *] of a float dtype with a page row
+    a slot (``table`` [B, M]), dispatched raw — one TPU chip; a
+    multi-device mesh has no partitioned wrapper for the paged layouts
+    yet and stays on the gather + einsum lines."""
+    return (_support.dispatch_mode() == "raw"
+            and q.ndim == 4 and q.shape[1] == 1
+            and q.dtype in (jnp.float32, jnp.bfloat16)
+            and table.ndim == 2 and table.shape[0] == q.shape[0])
+
+
 def supported(q, pool, table) -> bool:
     """Kernel gate; callers fall back to :func:`paged_reference` when
     False. ``q`` [B, 1, Hq, D] (decode chunks only); ``pool`` the paged
     leaves ([N, L, Hkv, P, D], int8 adds [N, L, Hkv, P] scales);
-    ``table`` [B, M] int32 page rows. Raw dispatch only — a
-    multi-device mesh has no partitioned wrapper for the paged layout
-    yet, so it stays on the gather+einsum path; so does the int8 pool
-    where the kernel would be compiled (float leaves only there)."""
-    if _support.dispatch_mode() != "raw":
-        return False
-    if q.ndim != 4 or q.shape[1] != 1:
+    ``table`` [B, M] int32 page rows. Raw dispatch only
+    (:func:`_one_token_on_one_chip`); the int8 pool stays on the gather
+    arm where the kernel would be compiled (float leaves only there)."""
+    if not _one_token_on_one_chip(q, table):
         return False
     B, T, Hq, D = q.shape
     k = pool[0]
     if k.ndim != 5:
         return False
     _, _, Hkv, P, Dk = k.shape
-    if Dk != D or D not in (64, 128, 256) or Hq % Hkv:
-        return False
-    if P % 8 or table.ndim != 2 or table.shape[0] != B:
+    if Dk != D or D not in (64, 128, 256) or Hq % Hkv or P % 8:
         return False
     quantized = len(pool) == 4
     if _support.on_tpu() and not _support.interpret():
@@ -109,8 +145,6 @@ def supported(q, pool, table) -> bool:
             # reshape ("unsupported shape cast", v5e): the int8 pool is
             # the interpreter's only, compiled it takes the gather arm
             return False
-    if q.dtype not in (jnp.float32, jnp.bfloat16):
-        return False
     if quantized and k.dtype != jnp.int8:
         return False
     if not quantized and k.dtype not in (jnp.float32, jnp.bfloat16):
@@ -284,6 +318,211 @@ def raw_call(sp, q2, kn2, vn2, *pool, scale: float):
     )(sp, *args)
 
 
+def latent_supported(q, pool, table, C: int) -> bool:
+    """Gate of the latent kernel; ``latent_attention`` stays on its
+    einsum lines when False. ``q`` [B, T, H, *] the chunk's queries;
+    ``pool`` the latent layout — ONE float leaf [N, L, 1, P, W] with a
+    lane-tiled row (``init_latent_cache`` pads it), which is what tells
+    it from the K/V leaves; ``table`` [B, M]; ``C`` the row's leading
+    value columns. Raw dispatch and one-token chunks only
+    (:func:`_one_token_on_one_chip`). Compiled, a page must be whole
+    tiles of the leaf's dtype (16 rows of bf16) and the value slice
+    whole lane tiles."""
+    if not _one_token_on_one_chip(q, table) or len(pool) != 1:
+        return False
+    leaf = pool[0]
+    if leaf.ndim != 5 or leaf.shape[2] != 1:
+        return False
+    P, W = leaf.shape[3:]
+    if W % LANES or P % 8 or not 0 < C <= W:
+        return False
+    if leaf.dtype not in (jnp.float32, jnp.bfloat16):
+        return False
+    if _support.on_tpu() and not _support.interpret():
+        if C % LANES or (P * leaf.dtype.itemsize) % 32:
+            return False
+    return True
+
+
+def _latent_pages_per_block(M: int, P: int) -> int:
+    """Pages one block of the latent kernel holds contiguous in VMEM:
+    1024 tokens (64 pages of 16), within the table. The block is the
+    stationary side of both products, so it has to be many pages before
+    the dot — a 16-token page alone fills 16 of a tile's 128 columns.
+    On the v5e, at the latent cell's shapes, 64 pages a block read
+    5.9-6.0 ms a decode step and 128 read 5.7-6.2 (twice the VMEM, and
+    more masked columns computed on a short context); 32 read 6 % more
+    than 64 did."""
+    return max(1, min(M, 1024 // P))
+
+
+def _latent_kernel(sp_ref, q_ref, new_ref, pool_ref, o_ref,
+                   buf, sem, slot_ref, acc_ref, m_ref, l_ref, *,
+                   scale, P, KP, steps, C, rows, out_dtype):
+    # one latent row is key AND value: the score is q_full . row over
+    # the whole W-wide row (the rope part rides along, the pad is zeros
+    # on both sides), the value its first C columns — a lane-aligned
+    # slice of the same VMEM block, so a page crosses HBM once.
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    idx = sp_ref[b, 1]
+    T = KP * P
+
+    def blocks(row):                      # live blocks of a slot
+        return (sp_ref[row, 1] + T - 1) // T
+
+    def page_copy(row, blk, slot, i):
+        return pltpu.make_async_copy(
+            pool_ref.at[sp_ref[row, 2 + blk * KP + i], sp_ref[row, 0], 0],
+            buf.at[slot, pl.ds(pl.multiple_of(i * P, P), P)], sem.at[slot])
+
+    def each_block(row, blk, whole, page):
+        """A block's live pages, one [P, W] copy each into its P-row
+        place of the buffer: contiguous in VMEM before the dot. Pages
+        past the fill are not copied (what the buffer holds there is
+        masked: an earlier block's rows, or the first step's zeros).
+        A full block is KP copies in a straight line — a loop over
+        them costs 14 ns a page more, a branch a group of eight 0.4 µs
+        a block — and only a slot's last block loops over its count."""
+        n = jnp.minimum(KP, (sp_ref[row, 1] + P - 1) // P - blk * KP)
+        pl.when(n == KP)(whole)
+
+        @pl.when(n < KP)
+        def _some():
+            def body(i, carry):
+                page(i)
+                return carry
+            jax.lax.fori_loop(0, n, body, 0)
+
+    def start(row, blk, slot):
+        def page(i):
+            page_copy(row, blk, slot, i).start()
+
+        def whole():
+            for i in range(KP):
+                page(i)
+
+        each_block(row, blk, whole, page)
+
+    def wait(row, blk, slot):
+        def page(i):
+            page_copy(row, blk, slot, i).wait()
+
+        def whole():
+            # the semaphore counts bytes: one wait for a whole block
+            full = buf.at[slot]
+            pltpu.make_async_copy(full, full, sem.at[slot]).wait()
+
+        each_block(row, blk, whole, page)
+
+    @pl.when((b == 0) & (j == 0))
+    def _first():
+        buf[...] = jnp.zeros_like(buf)
+        slot_ref[0] = 0
+
+    nb = blocks(b)
+
+    @pl.when(j <= nb)
+    def _prefetch():
+        # every live block is started exactly once, one block ahead of
+        # its use and across slots: a slot's later blocks by the step
+        # before them, its first by the last live step of the slot
+        # before (slot 0's by the call's first step)
+        cur = slot_ref[0]
+        using = j >= 1
+        own = j < nb
+        row = jnp.where(own, b, jnp.minimum(b + 1, rows - 1))
+        go = jnp.where(own, using | (b == 0),
+                       (b + 1 < rows) & (blocks(row) >= 1))
+
+        @pl.when(go)
+        def _start():
+            start(row, jnp.where(own, j, 0), jnp.where(using, 1 - cur, cur))
+
+    @pl.when(j == 0)
+    def _fresh():
+        # the step's own row: p = exp(s - m) = 1, l = 1, acc = its value
+        q = q_ref[0].astype(jnp.float32)                   # [H, W]
+        new = new_ref[0].astype(jnp.float32)               # [1, W]
+        s = jnp.sum(q * new, axis=1, keepdims=True) * scale
+        m_ref[:, :] = jnp.broadcast_to(s, m_ref.shape)
+        l_ref[:, :] = jnp.ones_like(l_ref)
+        acc_ref[:, :] = jnp.broadcast_to(new[:, :C], acc_ref.shape)
+
+    @pl.when((j >= 1) & (j <= nb))
+    def _block():
+        cur = slot_ref[0]
+        wait(b, j - 1, cur)
+        slot_ref[0] = 1 - cur
+        q = q_ref[0]                                       # model dtype
+        blk = buf[cur].astype(q.dtype)                     # [T, W]
+        s = jax.lax.dot_general(q, blk, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        pos = (j - 1) * T + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < idx, s, NEG_INF)               # [H, T]
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[:, :1] = alpha * l_ref[:, :1] + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+        m_ref[:, :1] = m_new
+        pv = jax.lax.dot_general(p.astype(q.dtype), blk[:, :C],
+                                 (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        acc_ref[:, :] = acc_ref[:, :] * alpha + pv
+
+    @pl.when(j == steps)
+    def _finalize():
+        o_ref[0] = (acc_ref[:, :] / l_ref[:, :1]).astype(out_dtype)
+
+
+def raw_latent_call(sp, q_full, new, leaf, *, scale: float, C: int):
+    """The latent pallas_call on local shapes: sp int32 [B, 2 + M] rows
+    of ``[layer, index, table...]``; q_full [B, H, W]; new [B, 1, W]
+    (the step's own row); ``leaf`` the pool's one leaf [N, L, 1, P, W],
+    unblocked in HBM and only read. Returns u [B, H, C]. Grid ``(B, 1 +
+    ceil(M / KP))``: step 0 the fresh row, then one block of KP pages a
+    step, double-buffered across steps and slots by the kernel's own
+    copies."""
+    B, H, W = q_full.shape
+    P = leaf.shape[3]
+    M = sp.shape[1] - 2
+    KP = _latent_pages_per_block(M, P)
+    steps = -(-M // KP)
+    kernel = functools.partial(
+        _latent_kernel, scale=scale, P=P, KP=KP, steps=steps, C=C, rows=B,
+        out_dtype=q_full.dtype)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, steps + 1),
+            in_specs=[
+                pl.BlockSpec((1, H, W), lambda b, j, s: (b, 0, 0)),
+                pl.BlockSpec((1, 1, W), lambda b, j, s: (b, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, H, C), lambda b, j, s: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, KP * P, W), leaf.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((H, C), jnp.float32),
+                pltpu.VMEM((H, LANES), jnp.float32),
+                pltpu.VMEM((H, LANES), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, H, C), q_full.dtype),
+        # the copies of one step are waited in a later one: the grid
+        # has to run in order
+        compiler_params=_support.compiler_params(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=_support.interpret(),
+        name="ptpu_paged_latent_decode_attn",
+    )(sp, q_full, new, leaf)
+
+
 def paged_reference(q, k_new, v_new, pool, table, layer, index, *,
                     scale: float):
     """The gather+einsum semantics the kernel must match, and the
@@ -328,20 +567,22 @@ def paged_reference(q, k_new, v_new, pool, table, layer, index, *,
     return out.reshape(B, 1, Hq, D)
 
 
-def _over_rows(scale: float):
-    """The kernel call on row operands — ``layer`` [B], ``index`` [B],
-    ``table`` [B, M], q2 [B, Hq, D], kn2/vn2 [B, Hkv, D], then the pool
-    leaves — with a batching rule of its own. jax's rule for a pallas
-    call whose scalar-prefetch operand is mapped is a ``while`` over the
-    mapped axis that slices every operand per iteration; here a mapped
-    axis is folded into the rows instead, so ``vmap`` over S slots of
-    one row each is ONE ``raw_call`` with S rows in its grid, on the
-    unmapped pool."""
+def _over_rows(raw, scale: float):
+    """``raw`` (:func:`raw_call` or :func:`raw_latent_call`) on row
+    operands — ``layer`` [B], ``index`` [B], ``table`` [B, M], then the
+    call's per-row arrays (leading axis B), then the pool leaves — with
+    a batching rule of its own. jax's rule for a pallas call whose
+    scalar-prefetch operand is mapped is a ``while`` over the mapped
+    axis that slices every operand per iteration; here a mapped axis is
+    folded into the rows instead, so ``vmap`` over S slots of one row
+    each is ONE ``raw`` call with S rows in its grid, on the unmapped
+    pool."""
 
-    def call(layer, index, table, q2, kn2, vn2, pool):
+    def call(layer, index, table, *rest):
+        *rows, pool = rest
         sp = jnp.concatenate([layer[:, None], index[:, None], table],
                              axis=1)
-        return raw_call(sp, q2, kn2, vn2, *pool, scale=scale)
+        return raw(sp, *rows, *pool, scale=scale)
 
     rows_call = jax.custom_batching.custom_vmap(call)
 
@@ -381,7 +622,26 @@ def paged_decode_attention(q, k_new, v_new, pool, table, layer, index, *,
     Hkv = k_new.shape[1]
     idx = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (B,))
     lay = jnp.broadcast_to(jnp.asarray(layer, jnp.int32), (B,))
-    out = _over_rows(scale)(
+    out = _over_rows(raw_call, scale)(
         lay, idx, jnp.asarray(table, jnp.int32), q.reshape(B, Hq, D),
         k_new.reshape(B, Hkv, D), v_new.reshape(B, Hkv, D), tuple(pool))
     return out.reshape(B, 1, Hq, D)
+
+
+def paged_latent_decode_attention(q_full, new, pool, table, layer, index, *,
+                                  scale: float, C: int):
+    """The absorbed latent decode step through the page table. q_full
+    [B, 1, H, W] = ``[q_lat | q_rope | 0]``; ``new`` [B, W] the step's
+    own row ``[c_kv | k_rope | 0]`` (not in the pool yet); ``pool`` the
+    one-leaf latent pool; ``table`` [B, M]; ``layer``; ``index`` scalar
+    or [B]. Returns u [B, 1, H, C] — the probabilities' sum of the
+    rows' first ``C`` columns, what ``w_vc`` then expands. The caller
+    has asked :func:`latent_supported`. Under ``jax.vmap`` with the pool
+    unmapped the mapped axis joins B (:func:`_over_rows`)."""
+    B, _, H, W = q_full.shape
+    idx = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (B,))
+    lay = jnp.broadcast_to(jnp.asarray(layer, jnp.int32), (B,))
+    out = _over_rows(functools.partial(raw_latent_call, C=C), scale)(
+        lay, idx, jnp.asarray(table, jnp.int32), q_full.reshape(B, H, W),
+        new.reshape(B, 1, W), tuple(pool))
+    return out.reshape(B, 1, H, C)

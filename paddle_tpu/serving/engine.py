@@ -36,12 +36,14 @@ paged cache, SOSP '23) to the framework's autoregressive path:
   (step, prefill chunk, speculative verify) hand the model a
   ``generation.PagedCache`` — the pool and the slot's table row — in
   the cache's place. The decode step on one TPU chip attends through
-  ``ptpu_paged_decode_attn``: one call a layer for all slots, whose
-  index maps read each slot's live pages out of the pool through the
-  row; a prefill chunk, a verify window, the CPU and a multi-device
-  mesh gather ONE layer's pages through the row for the einsum arm
-  (``models._common.cached_attention`` picks; ``stats()["decode_attn"]``
-  says which the step took). Either way no slot's all-layers view is
+  ``ptpu_paged_decode_attn`` — over a latent (MLA) pool through
+  ``ptpu_paged_latent_decode_attn`` — one call a layer for all slots,
+  which reads each slot's live pages out of the pool through the row;
+  a prefill chunk, a verify window, the CPU and a multi-device mesh
+  gather ONE layer's pages through the row for the einsum arm
+  (``models._common.cached_attention`` / ``latent_attention`` pick;
+  ``stats()["decode_attn"]`` says which the step took, for either
+  family). Either way no slot's all-layers view is
   ever built, and the chunk's new k/v go into the donated pool by
   in-place page updates (``generation.paged_write``): a step moves the
   pages it reads, never the pool. A generation reserves pages for its
@@ -1102,9 +1104,9 @@ class GenerationEngine:
         the same single-token cached forward as the contiguous step on
         a ``PagedCache`` (the pool and its page-table row), so
         attention reads one layer's pages at a time — the paged kernel
-        over the slot axis, or a gather (``cached_attention``; the arm
-        this trace took is kept for :meth:`stats`) — and no slot's
-        all-layers view exists; the new position's k/v come back as the
+        over the slot axis, or a gather (``cached_attention`` or
+        ``latent_attention`` picks; the arm this trace took is kept for
+        :meth:`stats`) — and no slot's all-layers view exists; the new position's k/v come back as the
         payload and go into the donated pool in place, outside the vmap
         (inactive/masked slots write to the null page)."""
         import jax

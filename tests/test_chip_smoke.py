@@ -46,6 +46,19 @@ def test_train_and_serve_phases_tiny():
         "contiguous": "4/4 tokens", "paged": "4/4 tokens"}
 
 
+def test_latent_phase_off_the_chip():
+    """The latent engine's phase on the CPU (float32): the streams
+    finish, the pool drains, and the step reports the gather arm — the
+    kernel arm is the chip's."""
+    latent = cs.latent_phase(
+        cs.make_requests(256, (40, 36, 44, 34), (4, 3, 4, 3),
+                         shared_prefix=32), slots=4, max_len=64,
+        on_chip=False)
+    assert latent["decode_attn"] == "gather" and latent["streams"] == 4
+    assert latent["kernels"] == {"decode": []}
+    assert latent["probe_repeat"]["same_programs_identical"]
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("kernels", ["off", "interpreted"])
 def test_parity_phase_tiny(kernels):

@@ -1,10 +1,12 @@
 """The paged decode step on its kernel arm: ``cached_attention`` hands a
 one-token chunk on a ``PagedCache`` to ``ptpu_paged_decode_attn`` where
-the kernel's gate holds, and the engine's ``vmap`` over slots folds into
-ONE call a layer (the kernel's own batching rule). Everything here runs
-the kernel through the interpreter (``_support.force_dispatch``) and
-holds it to the gather + einsum arm — the same step with the gate shut,
-every other kernel dispatched alike on both sides.
+the kernel's gate holds — ``latent_attention`` to
+``ptpu_paged_latent_decode_attn`` where the latent one's does — and the
+engine's ``vmap`` over slots folds into ONE call a layer (the kernels'
+shared batching rule). Everything here runs the kernels through the
+interpreter (``_support.force_dispatch``) and holds them to the gather +
+einsum arm — the same step with the gates shut, every other kernel
+dispatched alike on both sides.
 
 Pinned: tokens (float32, and over the int8 pool) and logits (bf16,
 within ``chip_smoke``'s tolerance for two evaluations of one
@@ -13,8 +15,11 @@ null page, a fill on a page edge and a template shared through the real
 prefix cache; the step's jaxpr holds one ``pallas_call`` a layer body
 whose grid holds the slots, no loop over slots and no gathered view;
 the programs that must stay on the gather arm (prefill chunk, verify
-window, the latent model) lower to the same text whether or not the
-gate is open; and the step compiled for the v5e keeps the pool in place.
+window — of the latent model too) lower to the same text whether or not
+the gates are open, and the K/V step does with the latent arm there or
+not; and the step compiled for the v5e keeps the pool in place. The
+latent model's step (``DeepseekV3ForCausalLM`` at the tiny preset) is
+held the same way.
 """
 
 import contextlib
@@ -60,25 +65,44 @@ def _llama(dtype, seed=11, **kw):
                                                 dtype=dtype))
 
 
+def _latent(dtype, seed=12, **kw):
+    from paddle_tpu.models.deepseek_v3 import (
+        DeepseekV3Config, DeepseekV3ForCausalLM,
+    )
+    paddle_tpu.seed(seed)
+    args = dict(vocab_size=VOCAB, max_seq_len=MAXLEN, dtype=dtype)
+    args.update(kw)
+    return DeepseekV3ForCausalLM(DeepseekV3Config.tiny(**args))
+
+
+FAMILIES = {"llama": _llama, "latent": _latent}
+
+
 @pytest.fixture(scope="module")
 def model():
     return _llama("float32")
 
 
+@pytest.fixture(scope="module")
+def models(model):
+    return {"llama": model, "latent": _latent("float32")}
+
+
+GATES = {"paged_kernel": (), "gather": ("supported", "latent_supported"),
+         "kv_kernel_only": ("latent_supported",)}
+
+
 @contextlib.contextmanager
 def arm(name):
     """Trace under it: every kernel dispatched (interpreted), the paged
-    one refused for ``"gather"``."""
-    with _support.force_dispatch():
-        if name == "paged_kernel":
-            yield
-            return
-        real = pdk.supported
-        pdk.supported = lambda *a, **k: False
-        try:
-            yield
-        finally:
-            pdk.supported = real
+    ones refused for ``"gather"``, the latent one alone for
+    ``"kv_kernel_only"``."""
+    with _support.force_dispatch(), contextlib.ExitStack() as stack:
+        for gate in GATES[name]:
+            real = getattr(pdk, gate)
+            setattr(pdk, gate, lambda *a, **k: False)
+            stack.callback(setattr, pdk, gate, real)
+        yield
 
 
 # two slots on one template's pages (1, 2) with tails of their own, a
@@ -117,11 +141,16 @@ def _steps(model, which, quant):
         return np.stack(toks), np.asarray(state["pos"])
 
 
-@pytest.mark.parametrize("quant", [False, True], ids=["f32", "int8"])
-def test_step_tokens_equal_on_both_arms(model, quant):
+@pytest.mark.parametrize("family,quant", [
+    ("llama", False), ("llama", True), ("latent", False)],
+    ids=["f32", "int8", "latent-f32"])
+def test_step_tokens_equal_on_both_arms(models, family, quant):
     """34 steps from one hand-built pool: every live slot crosses page
     edges (and slot 2 starts on one), the idle slot reads the null page
-    and keeps its token, and both arms pick the same tokens."""
+    and keeps its token, and both arms pick the same tokens. (The
+    latent pool's pad columns hold noise here: a zero pad on the query
+    is what keeps them out of the score.)"""
+    model = models[family]
     got, pos = _steps(model, "paged_kernel", quant)
     want, _ = _steps(model, "gather", quant)
     np.testing.assert_array_equal(got, want)
@@ -130,12 +159,14 @@ def test_step_tokens_equal_on_both_arms(model, quant):
     assert len({tuple(t) for t in got[:, :3]}) > 8      # not one fixed point
 
 
-@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
-def test_bf16_logits_within_chip_smoke_tolerance(quant):
+@pytest.mark.parametrize("family,quant", [
+    ("llama", False), ("llama", True), ("latent", False)],
+    ids=["bf16", "int8", "latent-bf16"])
+def test_bf16_logits_within_chip_smoke_tolerance(family, quant):
     """The two arms are two evaluations of one mathematics (a joint
     float32 softmax against an online one): in bf16 their logits differ
     by what ``chip_smoke`` allows such a pair."""
-    model = _llama("bfloat16")
+    model = FAMILIES[family]("bfloat16")
     proto = model.init_cache(1, MAXLEN,
                              dtype=jnp.int8 if quant else jnp.bfloat16)
     pool = _random_pool(proto, PAGES, P, seed=4)
@@ -195,9 +226,10 @@ def _serve_shared_template(model, which):
     return out
 
 
-def test_shared_template_through_prefix_cache(model):
-    got = _serve_shared_template(model, "paged_kernel")
-    want = _serve_shared_template(model, "gather")
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_shared_template_through_prefix_cache(models, family):
+    got = _serve_shared_template(models[family], "paged_kernel")
+    want = _serve_shared_template(models[family], "gather")
     assert got == want and len(got[0]) == 4 * P
 
 
@@ -225,10 +257,10 @@ def _step_eqns(model, which):
     return list(walk_eqns(jaxpr.jaxpr))
 
 
-def _attn_calls(eqns):
+def _attn_calls(eqns, name="ptpu_paged_decode_attn"):
     return [(e, path) for e, path in eqns
             if e.primitive.name == "pallas_call"
-            and e.params["name"] == "ptpu_paged_decode_attn"]
+            and e.params["name"] == name]
 
 
 def _shapes(eqns):
@@ -254,6 +286,26 @@ def test_step_holds_one_kernel_call_a_layer_over_all_slots(model):
     assert {pages, view} <= _shapes(gather)     # the check sees them
 
 
+def test_latent_step_holds_one_kernel_call_a_layer_body(models):
+    """The latent model scans two stacks (dense layers, expert layers):
+    one call in each body, all slots in its grid, no loop over slots
+    and none of the views the gather arm builds of the latent leaf."""
+    name = "ptpu_paged_latent_decode_attn"
+    eqns = _step_eqns(models["latent"], "paged_kernel")
+    calls = _attn_calls(eqns, name)
+    assert len(calls) == 2 and not _attn_calls(eqns)
+    for call, path in calls:
+        assert call.params["grid_mapping"].grid == (
+            SLOTS, 1 - (-M // pdk._latent_pages_per_block(M, P)))
+        assert "scan" in path and "while" not in path, path
+    width = models["latent"].init_cache(1, MAXLEN)[0].shape[-1]
+    pages, view = (SLOTS, M, 1, P, width), (SLOTS, 1, 1, M * P, width)
+    assert not {pages, view} & _shapes(eqns)
+    gather = _step_eqns(models["latent"], "gather")
+    assert not _attn_calls(gather, name)
+    assert {pages, view} <= _shapes(gather)     # the check sees them
+
+
 # -- what must not move --------------------------------------------------------
 
 def _sha(lowered):
@@ -274,28 +326,29 @@ def _paged_programs(model, **kw):
         return out
 
 
-def test_only_the_plain_step_changes_with_the_gate(model):
-    """With the gate open or shut the paged prefill chunk and the paged
-    speculative verify lower to the same text (``T > 1`` never reaches
-    the kernel), as do both programs of the latent model (its
-    ``latent_attention`` is not touched); the plain step does change."""
-    from paddle_tpu.models.deepseek_v3 import (
-        DeepseekV3Config, DeepseekV3ForCausalLM,
-    )
-    paddle_tpu.seed(12)
-    latent = DeepseekV3ForCausalLM(DeepseekV3Config.tiny(
-        vocab_size=VOCAB, max_seq_len=MAXLEN))
+def test_only_the_plain_step_changes_with_the_gate(models):
+    """With the gates open or shut the paged prefill chunk and the paged
+    speculative verify lower to the same text (``T > 1`` never reaches a
+    kernel), the latent model's prefill chunk too; the plain step of
+    either family does change; and the K/V step lowers to the same text
+    with the latent arm there or refused — the latent kernel shares the
+    module and the batching rule, and moves nothing of the K/V arm."""
     sides = {}
-    for which in ("paged_kernel", "gather"):
+    for which in GATES:
         with arm(which):
             sides[which] = {
-                "llama": _paged_programs(model, spec_k=3, spec_mode="ngram"),
-                "latent": _paged_programs(latent)}
-    a, b = sides["paged_kernel"], sides["gather"]
-    assert a["latent"] == b["latent"]
+                "llama": _paged_programs(models["llama"], spec_k=3,
+                                         spec_mode="ngram"),
+                "latent": _paged_programs(models["latent"])}
+    a, b, c = (sides[k] for k in ("paged_kernel", "gather",
+                                  "kv_kernel_only"))
+    assert a["latent"]["prefill"] == b["latent"]["prefill"]
+    assert a["latent"]["step"] != b["latent"]["step"]
     assert a["llama"]["prefill"] == b["llama"]["prefill"]
     assert a["llama"]["spec_step"] == b["llama"]["spec_step"]
     assert a["llama"]["step"] != b["llama"]["step"]
+    assert c["llama"] == a["llama"]
+    assert c["latent"] == b["latent"]
 
 
 # -- compiled for the chip (no chip needed: libtpu compiles for a described
@@ -313,7 +366,7 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("pool_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("pool_dtype", ["bf16", "int8", "latent-bf16"])
 def test_step_compiled_for_v5e_keeps_the_pool_in_place(one_chip, monkeypatch,
                                                        pool_dtype):
     """Trap 2: the kernel only reads the pool, the write after the vmap
@@ -321,14 +374,25 @@ def test_step_compiled_for_v5e_keeps_the_pool_in_place(one_chip, monkeypatch,
     lies — the temporaries stay under one pool leaf and no buffer but
     the pool is pool-sized, as ``test_paged_view`` bounds the gather arm.
     The int8 pool compiles too, on the gather arm: Mosaic refuses the
-    kernel's reshape of the scale planes, and the gate knows."""
+    kernel's reshape of the scale planes, and the gate knows. The latent
+    step compiles with its own kernel (manual copies out of the pool
+    left unblocked in HBM) under the same bounds."""
     # hkv * p is one lane tile and a row a whole one; the pool is too
     # large for XLA to stage a copy of it in fast memory
     hkv, d, p, slots, maxlen = 8, 128, 16, 4, 512
-    model = _llama("bfloat16", seed=13, hidden_size=hkv * d, num_layers=4,
-                   num_heads=hkv, num_kv_heads=hkv, max_seq_len=maxlen)
+    which = "gather" if pool_dtype == "int8" else "paged_kernel"
+    kernel, kw = "ptpu_paged_decode_attn", {}
+    if pool_dtype == "latent-bf16":
+        # a latent row of whole lane tiles (128 + 64 -> 256) whose value
+        # part is one: what the compiled gate asks for
+        model = _latent("bfloat16", seed=13, kv_lora_rank=128,
+                        qk_rope_head_dim=64, max_seq_len=maxlen)
+        kernel, kw = "ptpu_paged_latent_decode_attn", {"pages": 2048}
+    else:
+        model = _llama("bfloat16", seed=13, hidden_size=hkv * d,
+                       num_layers=4, num_heads=hkv, num_kv_heads=hkv,
+                       max_seq_len=maxlen)
     monkeypatch.setattr(_support, "on_tpu", lambda: True)
-    which = "paged_kernel" if pool_dtype == "bf16" else "gather"
 
     def abstract(tree):
         return jax.tree_util.tree_map(
@@ -338,7 +402,7 @@ def test_step_compiled_for_v5e_keeps_the_pool_in_place(one_chip, monkeypatch,
     with GenerationEngine(
             model, slots=slots, max_len=maxlen, paged=True, page_tokens=p,
             cache_dtype=jnp.int8 if pool_dtype == "int8" else None,
-            queue_max=4) as eng:
+            queue_max=4, **kw) as eng:
         pool = eng._state["cache"]
         lowered = eng._step._jitted.trace(
             abstract(model), abstract(eng._state),
@@ -346,8 +410,7 @@ def test_step_compiled_for_v5e_keeps_the_pool_in_place(one_chip, monkeypatch,
             abstract(jnp.zeros((slots,), bool))).lower(
                 lowering_platforms=("tpu",))
         assert eng.stats()["decode_attn"] == which
-    assert (("ptpu_paged_decode_attn" in lowered.as_text())
-            == (which == "paged_kernel"))
+    assert ((kernel in lowered.as_text()) == (which == "paged_kernel"))
     compiled = lowered.compile()
     hlo = compiled.as_text()
     mem = compiled.memory_analysis()
