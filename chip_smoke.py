@@ -672,6 +672,80 @@ def latent_phase(requests, *, slots: int, max_len: int,
         server.stop()
 
 
+def window_phase(requests, *, slots: int, max_len: int, window: int,
+                 chunk: int, on_chip: bool = True) -> dict:
+    """A small two-period SmallThinker model (full and window layers
+    mixed) behind a paged, prefix-cached generator whose pool has a
+    group a layer kind: every request streamed concurrently, the last
+    two through a shared prefix longer than the window. The window
+    group has to slide (``gen/kv_pages_slid``), both pools have to come
+    back whole, and on the chip every layer of the step attends through
+    ``ptpu_paged_decode_attn``. The probe's tokens are held to solo
+    ``generate()`` off the chip (float32); in bf16 programs of other
+    shapes round differently, so the chip reports the agreement."""
+    import jax
+
+    import paddle_tpu
+    from paddle_tpu import io
+    from paddle_tpu.core import monitor
+    from paddle_tpu.models.generation import generate
+    from paddle_tpu.models.smallthinker import (
+        SmallThinkerConfig, SmallThinkerForCausalLM,
+    )
+
+    paddle_tpu.seed(6)
+    cfg = SmallThinkerConfig.tiny(
+        hidden_size=256, num_heads=4, num_kv_heads=2, head_dim=64,
+        moe_intermediate_size=128, sliding_window=window,
+        max_seq_len=max_len, dtype="bfloat16" if on_chip else "float32")
+    model = SmallThinkerForCausalLM(cfg)
+    slid0 = monitor.get_stat("gen/kv_pages_slid") or 0
+    server = io.InferenceServer(port=0).start()
+    try:
+        engine = server.add_generator(
+            "window", model, slots=slots, max_len=max_len, paged=True,
+            page_tokens=PARITY_PAGE_TOKENS, prefill_chunk=chunk,
+            prefix_cache=True)
+        text = engine.lowered_text(max(len(r.prompt) for r in requests))
+        arm = engine.stats()["decode_attn"]
+        if on_chip:
+            check(arm == "paged_kernel",
+                  f"window paged step attends by {arm}, expected "
+                  "paged_kernel")
+            absent = _missing(text["decode"], DECODE_KERNELS["paged"])
+            check(not absent, f"window engine lowered without {absent}")
+        tokens, repeat = _drive(server.endpoint, "window", engine, requests,
+                                cfg.vocab_size)
+        probe = requests[-1]          # behind the shared prefix
+        solo = np.asarray(jax.jit(
+            lambda m, x: generate(m, x, probe.new_tokens))(
+                model, probe.prompt[None]))[0, probe.prompt.size:]
+        agree = _agree(tokens[-1], solo.tolist())
+        check(on_chip or agree == probe.new_tokens,
+              f"window: {agree}/{probe.new_tokens} tokens of the stream "
+              "behind the shared prefix agree with solo generate()")
+        slid = (monitor.get_stat("gen/kv_pages_slid") or 0) - slid0
+        check(slid > 0, "window: no window-group page was let go")
+        engine.clear_prefix_cache()
+        st = engine.stats()
+        check(all(g["pages_free"] == g["pages"] for g in st["groups"])
+              and st["active"] == 0 and st["broken"] is None,
+              f"window: a pool did not come back whole: {st['groups']}")
+        check(st["groups"][1]["stream_pages_peak"]
+              <= st["groups"][1]["row_pages"],
+              f"window: a stream mapped more than its row: {st['groups']}")
+        return {"decode_attn": arm, "streams": len(requests),
+                "probe_repeat": repeat, "pages_slid": slid,
+                "row_pages": st["groups"][1]["row_pages"],
+                "stream_pages_peak": st["groups"][1]["stream_pages_peak"],
+                "engine_agrees_with_solo_generate_for":
+                    f"{agree}/{probe.new_tokens} tokens",
+                "kernels": {"decode": list(DECODE_KERNELS["paged"])
+                            if arm == "paged_kernel" else []}}
+    finally:
+        server.stop()
+
+
 # ---------------------------------------------------------------------------
 # main
 # ---------------------------------------------------------------------------
@@ -706,6 +780,10 @@ def main() -> int:
     report["serve_latent"] = latent_phase(
         make_requests(256, (150, 140, 170, 130), (24, 16, 24, 16),
                       shared_prefix=96), slots=4, max_len=256)
+    report["serve_window"] = window_phase(
+        make_requests(256, (300, 280, 330, 310), (40, 24, 40, 24),
+                      shared_prefix=256), slots=4, max_len=512, window=64,
+        chunk=64)
     if n_dev >= 4:
         del model
         gc.collect()

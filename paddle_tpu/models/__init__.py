@@ -14,4 +14,7 @@ from paddle_tpu.models.moe import MoEConfig, MoEForCausalLM
 from paddle_tpu.models.deepseek_v3 import (
     DeepseekV3Config, DeepseekV3ForCausalLM,
 )
+from paddle_tpu.models.smallthinker import (
+    SmallThinkerConfig, SmallThinkerForCausalLM,
+)
 from paddle_tpu.models.ernie import ErnieConfig, ErnieForPretraining, ErnieModel

@@ -51,7 +51,17 @@ def _quant_chunk(x):
     return xq, s
 
 
-def cached_attention(q, k, v, cache, index, layer=0):
+def window_mask(T: int, window):
+    """``[T, T]`` mask of a chunk with nothing behind it under a sliding
+    window (query ``t`` sees key ``j`` iff ``t - j < window``; causality
+    is the caller's), or None where the window cannot bite: no window,
+    or a chunk no longer than it."""
+    if window is None or T <= window:
+        return None
+    return (jnp.arange(T)[:, None] - jnp.arange(T)[None, :]) < window
+
+
+def cached_attention(q, k, v, cache, index, layer=0, window=None):
     """Static-KV-cache attention core shared by every attention family
     (llama GQA, GPT fused-MHA, MoE). ``cache`` holds the FULL stacked
     read-only buffers ([L, B, Hkv, S, D] — see ``init_kv_cache``) and
@@ -100,6 +110,14 @@ def cached_attention(q, k, v, cache, index, layer=0):
     [..., S, Hkv, D] layout made XLA physically transpose both buffers
     every step (measured ~0.9 ms/step extra on the bench geometry).
 
+    ``window`` (static; None = every earlier position): a window layer's
+    query at position ``t`` sees key ``j`` iff ``0 <= t - j < window``.
+    The einsum lines take one more ``where`` on the cached and on the
+    chunk-local scores, the paged kernel a lower edge; the contiguous
+    decode kernel is not asked. A window layer's ``PagedCache`` row holds
+    only the live pages from logical page ``cache.base`` on, and cached
+    positions count from there.
+
     Returns ``(out [B, T, Hq, D], payload)`` where payload leaves are the
     chunk k/v in buffer layout ([B, Hkv, T, D], scales [B, Hkv, T]).
     """
@@ -124,8 +142,10 @@ def cached_attention(q, k, v, cache, index, layer=0):
 
     if index is None or (isinstance(index, int) and index == 0):
         # prefill: nothing behind us — plain causal over the raw chunk
-        # (flash-kernel eligible)
-        out = F.scaled_dot_product_attention(q, k, v, causal=True)
+        # (flash-kernel eligible); a chunk no longer than the window
+        # never meets its lower edge
+        out = F.scaled_dot_product_attention(q, k, v, window_mask(T, window),
+                                             causal=True)
         return out, payload
 
     idx = jnp.asarray(index, jnp.int32)
@@ -133,14 +153,17 @@ def cached_attention(q, k, v, cache, index, layer=0):
         from paddle_tpu.ops.pallas import paged_decode_attention as _pk
         if _pk.supported(q, bufs, cache.table[None]):
             paged_attn_arms["paged_kernel"] += 1
+            edge = ({} if window is None
+                    else {"window": window, "base": cache.base})
             out = _pk.paged_decode_attention(
-                q, kt, vt, bufs, cache.table[None], layer, idx, scale=scale)
+                q, kt, vt, bufs, cache.table[None], layer, idx, scale=scale,
+                **edge)
             return out, payload
         paged_attn_arms["gather"] += 1
         sl = cache.read_layer(layer)
     else:
         from paddle_tpu.ops.pallas import decode_attention as _dk
-        if _dk.supported(q, cache):
+        if window is None and _dk.supported(q, cache):
             out = _dk.decode_attention(q, kt, vt, cache, layer, idx,
                                        scale=scale)
             return out, payload
@@ -163,10 +186,23 @@ def cached_attention(q, k, v, cache, index, layer=0):
     qh = q.transpose(0, 2, 1, 3).reshape(B, Hkv, G, T, D)
     neg = jnp.finfo(jnp.float32).min
     s_c = jnp.einsum("bkgtd,bksd->bkgts", qh, kc) * scale
-    s_c = jnp.where((jnp.arange(S) < idx)[None, None, None, None, :],
-                    s_c.astype(jnp.float32), neg)
+    if window is None:
+        seen = (jnp.arange(S) < idx)[None, None, None, None, :]
+    else:
+        # view position s is absolute position first + s; query t of the
+        # chunk stands at idx + t and sees what lies under ``window`` back
+        first = 0
+        if paged and cache.base is not None:
+            first = cache.base * bufs[0].shape[3]
+        at = first + jnp.arange(S)[None, :]
+        seen = ((at < idx) & (idx + jnp.arange(T)[:, None] - at < window)
+                )[None, None, None]
+    s_c = jnp.where(seen, s_c.astype(jnp.float32), neg)
     s_n = jnp.einsum("bkgtd,bkud->bkgtu", qh, kt) * scale
     chunk_causal = (jnp.arange(T)[None, :] <= jnp.arange(T)[:, None])
+    if window is not None:
+        chunk_causal = chunk_causal & (
+            jnp.arange(T)[:, None] - jnp.arange(T)[None, :] < window)
     s_n = jnp.where(chunk_causal[None, None, None],
                     s_n.astype(jnp.float32), neg)
     probs = jax.nn.softmax(jnp.concatenate([s_c, s_n], axis=-1), axis=-1)

@@ -162,13 +162,20 @@ class PagedCache:
     chip: the kernel takes ``pool`` and ``table`` as they are) or
     through :meth:`read_layer`. Under the engine's ``jax.vmap`` over
     slots the pool is unmapped and the row is the mapped operand; the
-    kernel folds that axis into its grid."""
+    kernel folds that axis into its grid.
 
-    def __init__(self, pool, table):
-        self.pool, self.table = tuple(pool), table
+    ``base`` (None everywhere but on a window layer group's row): the
+    logical page the row's first entry stands for. Such a row holds
+    only a stream's live pages — the window's and the chunk's — so
+    ``table[i]`` is logical page ``base + i`` and view position ``v``
+    of :meth:`read_layer` is absolute position ``base * page_tokens +
+    v``."""
+
+    def __init__(self, pool, table, base=None):
+        self.pool, self.table, self.base = tuple(pool), table, base
 
     def tree_flatten(self):
-        return (self.pool, self.table), None
+        return (self.pool, self.table, self.base), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):

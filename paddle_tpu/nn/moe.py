@@ -63,7 +63,11 @@ layer, without the exchange. The held form is dropless by
 construction: its capacity is the chunk's token count, at which a
 pick's slot is its token's own index, so the expert buffer is the token
 block itself and the gate matrix (zero where an expert was not picked)
-is the combine.
+is the combine. On that form the layer may also read its routing
+logits from another tensor than the one its experts multiply
+(``route_from=``: SmallThinker routes from the block's input, ahead of
+the norm and of attention), divide the softmax gates at the picks by
+their sum (``norm_topk``), and gate with relu (``act="relu"``: ReGLU).
 """
 
 from __future__ import annotations
@@ -231,7 +235,8 @@ class MoEMLP(Module):
                  dispatch_mode: str = "auto", route: str = "softmax",
                  n_group: int = 1, topk_group: int = 1,
                  routed_scale: float = 1.0, shared_size: int = 0,
-                 held: tuple[int, int] | None = None, key=None):
+                 held: tuple[int, int] | None = None,
+                 norm_topk: bool = False, act: str = "silu", key=None):
         if dispatch_mode not in ("auto", "einsum", "gather",
                                  "gather_grouped"):
             raise ValueError(
@@ -247,7 +252,10 @@ class MoEMLP(Module):
                     f"sigmoid_group routing: {n_group} groups must divide "
                     f"{E} experts and topk_group {topk_group} lie in "
                     f"1..{n_group}")
-        if held is None and (route == "sigmoid_group" or shared_size):
+        if act not in ("silu", "relu"):
+            raise ValueError(f"act must be silu|relu, got {act!r}")
+        if held is None and (route == "sigmoid_group" or shared_size
+                             or norm_topk or act != "silu"):
             # the capacity dispatch forms are the plain softmax layer's
             held = (0, E)
         if held is not None:
@@ -300,6 +308,10 @@ class MoEMLP(Module):
             self.routed_scale = float(routed_scale)
             self.held = held
             self._uid = new_uid()
+            if norm_topk:
+                self.norm_topk = True
+            if act != "silu":
+                self.act = act
 
     def capacity(self, n_tokens: int) -> int:
         c = int(math.ceil(n_tokens * self.top_k * self.capacity_factor
@@ -340,10 +352,15 @@ class MoEMLP(Module):
                 * self.w_down_scale.astype(dt)[:, None, :]
         gate = jnp.einsum("ech,ehi->eci", expert_in, self.w_gate)
         up = jnp.einsum("ech,ehi->eci", expert_in, self.w_up)
-        act = F.swiglu(up, gate)
+        if getattr(self, "act", "silu") == "relu":
+            act = jax.nn.relu(gate) * up
+        else:
+            act = F.swiglu(up, gate)
         return jnp.einsum("eci,eih->ech", act, self.w_down)
 
-    def __call__(self, x):
+    def __call__(self, x, route_from=None):
+        """``route_from`` [b, t, h] (the held form only): the tensor
+        the router reads, where it is not the experts' input ``x``."""
         b, t, h = x.shape
         n = b * t
         tokens = x.reshape(n, h)
@@ -353,13 +370,18 @@ class MoEMLP(Module):
         # The moe/* scopes name the block's four stages in the device
         # trace, which otherwise shows only fusions.
         if getattr(self, "held", None) is not None:
-            out, aux = self._call_held(tokens, b, t)
+            out, aux = self._call_held(
+                tokens, b, t,
+                None if route_from is None else route_from.reshape(n, h))
             if hasattr(self, "shared_gate"):
                 with jax.named_scope("moe/shared"):
                     out = out + (F.swiglu(tokens @ self.shared_up,
                                           tokens @ self.shared_gate)
                                  @ self.shared_down)
             return out.reshape(b, t, h), aux
+        if route_from is not None:
+            raise ValueError("route_from= is the held (dropless) form's: "
+                             "give the layer held=(0, num_experts)")
 
         with jax.named_scope("moe/route"):
             logits = tokens.astype(jnp.float32) @ self.router
@@ -375,7 +397,7 @@ class MoEMLP(Module):
             raise ValueError(f"unknown dispatch_mode {mode!r}")
         return out.reshape(b, t, h), aux.astype(jnp.float32)
 
-    def _call_held(self, tokens, b, t):
+    def _call_held(self, tokens, b, t, route_tokens=None):
         """The dropless form of a layer that holds experts ``[first,
         first + count)``: route every token over all experts, run the
         held ones on the whole token block (capacity = token count, slot
@@ -388,14 +410,18 @@ class MoEMLP(Module):
         first, count = self.held
         with jax.named_scope("moe/route"):
             # float32 throughout: a pick decided in bf16 is another pick
-            logits = jnp.matmul(tokens.astype(jnp.float32), self.router,
-                                precision=jax.lax.Precision.HIGHEST)
+            logits = jnp.matmul(
+                (tokens if route_tokens is None
+                 else route_tokens).astype(jnp.float32), self.router,
+                precision=jax.lax.Precision.HIGHEST)
             if self.route == "sigmoid_group":
                 expert, gate = sigmoid_group_picks(
                     logits, self.select_bias, self.top_k, self.n_group,
                     self.topk_group, self.routed_scale)
             else:
                 expert, gate = softmax_picks(logits, self.top_k)
+                if getattr(self, "norm_topk", False):
+                    gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
             # one_hot of an id outside [0, count) is a zero row
             here = jax.nn.one_hot(expert - first, count, dtype=gate.dtype)
             gates = jnp.einsum("nk,nkc->nc", gate, here)       # [N, held]

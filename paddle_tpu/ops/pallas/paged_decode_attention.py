@@ -138,8 +138,13 @@ def supported(q, pool, table) -> bool:
         return False
     quantized = len(pool) == 4
     if _support.on_tpu() and not _support.interpret():
-        if (Hkv * P) % LANES:
-            return False              # lane-aligned page blocks only
+        K = _pages_per_step(table.shape[1],
+                            Hkv * P * D * k.dtype.itemsize)
+        if (Hkv * P) % LANES and ((K * Hkv * P) % LANES or quantized):
+            # a page narrower than a lane tile (4 KV heads x 16) is
+            # served by the row-joined form, whose K pages together
+            # have to fill whole tiles
+            return False
         if quantized:
             # Mosaic refuses the scale planes' [Hkv, P] -> [1, Hkv * P]
             # reshape ("unsupported shape cast", v5e): the int8 pool is
@@ -153,7 +158,8 @@ def supported(q, pool, table) -> bool:
 
 
 def _kernel(sp_ref, q_ref, kn_ref, vn_ref, *rest,
-            scale, P, K, steps, G, Hkv, quantized, out_dtype):
+            scale, P, K, steps, G, Hkv, quantized, out_dtype,
+            windowed=False):
     # ``rest``: K page refs of k, K of v (int8: K of each scale plane
     # after them), then the output and the scratch
     n = (4 if quantized else 2) * K
@@ -179,8 +185,15 @@ def _kernel(sp_ref, q_ref, kn_ref, vn_ref, *rest,
         l_ref[:, :] = jnp.ones_like(l_ref)
 
     last_page = jnp.maximum(idx - 1, 0) // P
+    live = (j > 0) & ((j - 1) * K <= last_page)
+    if windowed:
+        # a window layer's row: positions before ``lo`` have slid out;
+        # steps wholly before its page are skipped, the page itself is
+        # masked from inside
+        lo = sp_ref[b, 2]
+        live = live & (j * K > lo // P)
 
-    @pl.when((j > 0) & ((j - 1) * K <= last_page))
+    @pl.when(live)
     def _page_blocks():
         # ONE block-diagonal dot for ALL heads over each page (the
         # decode_attention trick at page granularity): q [Hq, D]
@@ -205,10 +218,20 @@ def _kernel(sp_ref, q_ref, kn_ref, vn_ref, *rest,
         def plane(refs):                            # K x [Hkv, P] -> [1, K·W]
             return jnp.concatenate([r[0, 0].reshape(1, W) for r in refs], 1)
 
-        s = jnp.concatenate([
-            jax.lax.dot_general(q, page(r), (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-            for r in kp_refs], axis=1) * scale      # [Hq, K·W]
+        joined = bool(W % LANES)
+        if joined:
+            # a page narrower than a lane tile: the K pages join along
+            # the rows (whole tiles) before ONE dot a side, so no
+            # [Hq, W] piece is ever cut or joined inside a tile
+            s = jax.lax.dot_general(
+                q, jnp.concatenate([page(r) for r in kp_refs], axis=0),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+        else:
+            s = jnp.concatenate([
+                jax.lax.dot_general(q, page(r), (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+                for r in kp_refs], axis=1) * scale  # [Hq, K·W]
         if quantized:
             # per-position scale folds into the logit plane (per column)
             s = s * plane(leaves[2])
@@ -217,6 +240,8 @@ def _kernel(sp_ref, q_ref, kn_ref, vn_ref, *rest,
         # paged_gather's view coordinate of column (page i, head, offset)
         pos = ((j - 1) * K + col // W) * P + col % P
         valid = (row_h == col % W // P) & (pos < idx)
+        if windowed:
+            valid = valid & (pos >= lo)
         s = jnp.where(valid, s, NEG_INF)
         m_prev = m_ref[:, :1]
         l_prev = l_ref[:, :1]
@@ -229,11 +254,17 @@ def _kernel(sp_ref, q_ref, kn_ref, vn_ref, *rest,
             # v scale folds into the prob plane
             p = p * plane(leaves[3])
         p = p.astype(cdt)
-        pv = sum(
-            jax.lax.dot_general(p[:, i * W:(i + 1) * W], page(r),
-                                (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-            for i, r in enumerate(vp_refs))         # [Hq, D]
+        if joined:
+            pv = jax.lax.dot_general(
+                p, jnp.concatenate([page(r) for r in vp_refs], axis=0),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        else:
+            pv = sum(
+                jax.lax.dot_general(p[:, i * W:(i + 1) * W], page(r),
+                                    (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+                for i, r in enumerate(vp_refs))     # [Hq, D]
         acc_ref[:, :] = acc_ref[:, :] * alpha + pv
 
     @pl.when(j == steps)
@@ -253,21 +284,25 @@ def _pages_per_step(M: int, page_bytes: int) -> int:
     return max(1, min(8, M, (1 << 20) // page_bytes))
 
 
-def raw_call(sp, q2, kn2, vn2, *pool, scale: float):
+def raw_call(sp, q2, kn2, vn2, *pool, scale: float, windowed: bool = False):
     """The pallas_call on local shapes: sp int32 [B, 2 + M] rows of
     ``[layer, index, table...]``; q2 [B, Hq, D]; kn2/vn2 [B, Hkv, D];
     ``pool`` the paged leaves. Returns [B, Hq, D]. Grid ``(B, 1 +
     ceil(M / K))``: step 0 the fresh token, then K pages a step — every
     pool leaf is passed K times, operand ``i`` of a step mapped to the
     step's ``i``-th page, so one step's K pages (anywhere in the pool)
-    arrive by K block DMAs of the ordinary pipeline."""
+    arrive by K block DMAs of the ordinary pipeline. ``windowed``: the
+    rows are ``[layer, index, lo, table...]`` and positions before
+    ``lo`` (the first a window layer's query still sees, in the row's
+    own coordinates) are masked, the pages wholly before it skipped."""
     B, Hq, D = q2.shape
     Hkv = kn2.shape[1]
     G = Hq // Hkv
     quantized = len(pool) == 4
     kp = pool[0]
     P = kp.shape[3]
-    M = sp.shape[1] - 2
+    HDR = 3 if windowed else 2
+    M = sp.shape[1] - HDR
     K = _pages_per_step(M, Hkv * P * D * kp.dtype.itemsize)
     steps = -(-M // K)
 
@@ -279,7 +314,9 @@ def raw_call(sp, q2, kn2, vn2, *pool, scale: float):
         def index(b, j, sp_ref):
             last = jnp.maximum(sp_ref[b, 1] - 1, 0) // P
             jp = jnp.minimum(jnp.maximum(j - 1, 0) * K + i, last)
-            return (sp_ref[b, 2 + jp], sp_ref[b, 0]) + (0,) * (ndim - 2)
+            if windowed:
+                jp = jnp.maximum(jp, jnp.minimum(sp_ref[b, 2] // P, last))
+            return (sp_ref[b, HDR + jp], sp_ref[b, 0]) + (0,) * (ndim - 2)
         return index
 
     in_specs = [
@@ -296,7 +333,8 @@ def raw_call(sp, q2, kn2, vn2, *pool, scale: float):
 
     kernel = functools.partial(
         _kernel, scale=scale, P=P, K=K, steps=steps, G=G, Hkv=Hkv,
-        quantized=quantized, out_dtype=q2.dtype)
+        quantized=quantized, out_dtype=q2.dtype,
+        **({"windowed": True} if windowed else {}))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -523,13 +561,24 @@ def raw_latent_call(sp, q_full, new, leaf, *, scale: float, C: int):
     )(sp, q_full, new, leaf)
 
 
+def _row_edges(index, window, base, P: int, B: int):
+    """``(index, lo)`` [B] in a window row's own coordinates: the row's
+    first entry is logical page ``base``, the query at absolute position
+    ``index`` sees cached positions ``index - window < p < index``."""
+    idx = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (B,))
+    off = jnp.broadcast_to(jnp.asarray(0 if base is None else base,
+                                       jnp.int32), (B,)) * P
+    lo = jnp.maximum(idx - (window - 1), 0)
+    return idx - off, jnp.maximum(lo - off, 0)
+
+
 def paged_reference(q, k_new, v_new, pool, table, layer, index, *,
-                    scale: float):
+                    scale: float, window: int | None = None, base=None):
     """The gather+einsum semantics the kernel must match, and the
     off-TPU fallback arm: ``paged_gather`` the slot's pages at
-    ``layer``, dequantize, mask positions ``>= index``, softmax over
-    [cache, fresh] in f32, combine. Shapes as
-    :func:`paged_decode_attention`."""
+    ``layer``, dequantize, mask positions ``>= index`` (and, with a
+    ``window``, those that have slid out), softmax over [cache, fresh]
+    in f32, combine. Shapes as :func:`paged_decode_attention`."""
     from paddle_tpu.models.generation import PagedCache
 
     B, T, Hq, D = q.shape
@@ -539,7 +588,7 @@ def paged_reference(q, k_new, v_new, pool, table, layer, index, *,
     P = pool[0].shape[3]
     M = table.shape[1]
 
-    def one(qb, knb, vnb, row, idx):
+    def one(qb, knb, vnb, row, idx, lo):
         # paged_gather, restricted to one layer: [Hkv, M·P, *rest]
         view = [v[0] for v in PagedCache(pool, row).read_layer(layer)]
         k_c, v_c = view[:2]
@@ -549,6 +598,8 @@ def paged_reference(q, k_new, v_new, pool, table, layer, index, *,
         qh = qb.reshape(Hkv, G, D)                # [Hkv, G, D]
         s_c = jnp.einsum("hgd,hsd->hgs", qh, k_c) * scale
         mask = jnp.arange(M * P) < idx
+        if window is not None:
+            mask = mask & (jnp.arange(M * P) >= lo)
         s_c = jnp.where(mask[None, None, :], s_c, NEG_INF)
         s_n = jnp.sum(qh * knb[:, None, :], axis=-1,
                       keepdims=True) * scale      # [Hkv, G, 1]
@@ -561,9 +612,12 @@ def paged_reference(q, k_new, v_new, pool, table, layer, index, *,
     q2 = q.reshape(B, Hq, D)
     kn2 = k_new.reshape(B, Hkv, D)
     vn2 = v_new.reshape(B, Hkv, D)
-    out = jax.vmap(one)(q2, kn2, vn2, table,
-                        jnp.broadcast_to(jnp.asarray(index, jnp.int32),
-                                         (B,)))
+    if window is None:
+        idx = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (B,))
+        lo = jnp.zeros((B,), jnp.int32)
+    else:
+        idx, lo = _row_edges(index, window, base, P, B)
+    out = jax.vmap(one)(q2, kn2, vn2, table, idx, lo)
     return out.reshape(B, 1, Hq, D)
 
 
@@ -605,7 +659,8 @@ def _over_rows(raw, scale: float):
 
 
 def paged_decode_attention(q, k_new, v_new, pool, table, layer, index, *,
-                           scale: float):
+                           scale: float, window: int | None = None,
+                           base=None):
     """q [B, 1, Hq, D]; k_new/v_new [B, Hkv, 1, D] (this step's raw
     k/v, not yet in the pool); ``pool`` the paged leaves; ``table``
     [B, M] int32 per-slot page rows (the engine's device-resident
@@ -614,16 +669,32 @@ def paged_decode_attention(q, k_new, v_new, pool, table, layer, index, *,
     [0, index)). Returns [B, 1, Hq, D]. Dispatches the kernel when
     :func:`supported`, else :func:`paged_reference`. Under ``jax.vmap``
     with the pool unmapped the mapped axis joins B (:func:`_over_rows`):
-    still one kernel call."""
+    still one kernel call.
+
+    ``window`` (static; None = every cached position, today's program):
+    a window layer's query at ``index`` sees cached positions ``index -
+    window < p < index``. Its ``table`` rows then hold only the live
+    logical pages, entry 0 being logical page ``base`` (scalar or [B];
+    None = 0): the kernel works in the row's coordinates, reads its live
+    pages from the first still seen, and masks what has slid out inside
+    that page."""
     if not supported(q, pool, table):
         return paged_reference(q, k_new, v_new, pool, table, layer,
-                               index, scale=scale)
+                               index, scale=scale, window=window, base=base)
     B, T, Hq, D = q.shape
     Hkv = k_new.shape[1]
-    idx = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (B,))
+    table = jnp.asarray(table, jnp.int32)
+    raw = raw_call
+    if window is None:
+        idx = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (B,))
+    else:
+        # ``lo`` rides as the first column of the rows' table
+        idx, lo = _row_edges(index, window, base, pool[0].shape[3], B)
+        table = jnp.concatenate([lo[:, None], table], axis=1)
+        raw = functools.partial(raw_call, windowed=True)
     lay = jnp.broadcast_to(jnp.asarray(layer, jnp.int32), (B,))
-    out = _over_rows(raw_call, scale)(
-        lay, idx, jnp.asarray(table, jnp.int32), q.reshape(B, Hq, D),
+    out = _over_rows(raw, scale)(
+        lay, idx, table, q.reshape(B, Hq, D),
         k_new.reshape(B, Hkv, D), v_new.reshape(B, Hkv, D), tuple(pool))
     return out.reshape(B, 1, Hq, D)
 

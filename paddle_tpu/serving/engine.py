@@ -340,7 +340,8 @@ class Generation:
                  "rng_skip", "spec_proposed", "spec_accepted", "trace_id",
                  "tenant", "admitted_ts", "first_tok_ts", "done_ts",
                  "chip_s", "ledgered", "dev_ops", "pclass", "folded",
-                 "queue_booked", "sched_seq", "sched_vft", "sched_ts")
+                 "queue_booked", "sched_seq", "sched_vft", "sched_ts",
+                 "win")
 
     def __init__(self, gen_id: str, prompt: np.ndarray,
                  max_new_tokens: int, temperature: float, top_k: int,
@@ -404,6 +405,9 @@ class Generation:
         # priority class, tokens already folded into the prompt by a
         # preemption park, queue wait booked live at admission, and the
         # fair-queue tag/sequence/admission-stamp the scheduler assigns
+        # a layer-group engine's window-group row (a _WinRow) while the
+        # generation holds a slot; None everywhere else
+        self.win = None
         self.pclass = "batch"
         self.folded = 0
         self.queue_booked = 0.0
@@ -451,12 +455,168 @@ class _PagePool:
         return self._ref[pid]
 
 
-class _PrefixEntry:
-    __slots__ = ("key", "page", "parent_page", "children", "last_used")
+class _WinRow:
+    """One stream's row in a window layer group (host side): the live
+    logical pages ``base .. base + len(pages)``, which of them the
+    stream drew fresh from the pool and still answers for (``own``),
+    how many more it may draw before it lets one go (``need``), and the
+    position its next decode step writes (``pos``)."""
 
-    def __init__(self, key, page: int, parent_page: int):
+    __slots__ = ("base", "pages", "own", "need", "pos", "total")
+
+    def __init__(self, base: int, pages: list[int], need: int, total: int):
+        self.base, self.pages, self.need = base, pages, need
+        self.total = total       # logical pages of the declared worst case
+        self.own: set[int] = set()
+        self.pos = 0
+
+
+class _WindowGroup:
+    """Host-side books of a window layer group: its pool, its table
+    (``pt`` [slots, 1 + row_pages]: a row's base, then the page ids of
+    logical pages ``base, base + 1, ...``; 0 = null page) and the
+    promise that keeps a running stream from ever finding the pool
+    empty.
+
+    A stream holds only the pages a program may still read: those
+    covering ``[first - window + 1, end)`` for a program that starts at
+    position ``first`` and writes up to ``end`` — never more than
+    ``row_pages``. :meth:`cover` lets go of what lies wholly behind and
+    maps fresh pages ahead, before the program that needs them is
+    dispatched. ``debt`` is the sum over live streams of the fresh pages
+    each may still draw (``_WinRow.need``); ``pool.free_count >= debt``
+    always holds: admission reserves a stream's most (:meth:`budget`),
+    a draw and a page given back move both sides alike, and a fresh
+    page handed to the prefix cache (which then pins it) is paid for
+    from :meth:`spare` pages or not handed over at all. All methods run
+    under the engine's condition lock."""
+
+    def __init__(self, window: int, num_pages: int, page_tokens: int,
+                 slots: int, chunk: int, maxp: int):
+        self.window, self.P = int(window), int(page_tokens)
+        # pages covering [first - window + 1, first + chunk) at any
+        # alignment: window/P + 1 + chunk/P where P divides both
+        self.row_pages = min(
+            maxp, -(-(self.window - 1 + chunk) // self.P) + 1)
+        if num_pages <= 0:           # every slot its fullest row
+            num_pages = slots * self.row_pages
+        self.pool = _PagePool(num_pages)
+        self.pt = np.zeros((slots, 1 + self.row_pages), np.int32)
+        self.debt = 0
+        self.slid = 0            # pages streams let go behind the window
+        self.peak = 0            # most pages one stream ever held
+
+    def reset(self) -> None:
+        """Fresh books over a replaced device pool: no row is mapped,
+        nothing is promised (``slid`` and ``peak`` stay: they count)."""
+        self.pool = _PagePool(self.pool.num_pages)
+        self.pt[:] = 0
+        self.debt = 0
+
+    def spare(self) -> int:
+        """Free pages no live stream has been promised."""
+        return self.pool.free_count - self.debt
+
+    def budget(self, total_pages: int, matched: int) -> int:
+        """The most fresh pages a stream of ``total_pages`` logical
+        pages, ``matched`` of them cached, holds at once."""
+        return min(total_pages - matched, self.row_pages)
+
+    def first_page(self, first: int) -> int:
+        """The logical page of the first position a program starting at
+        position ``first`` still reads."""
+        return max(first - self.window + 1, 0) // self.P
+
+    def admit(self, slot: int, matched_wpages: list[int], total: int):
+        """A stream of ``total`` logical pages takes its slot behind
+        ``len(matched_wpages)`` cached prompt pages: it maps those its
+        first chunk still reads, and is promised its budget of fresh
+        ones."""
+        m = len(matched_wpages)
+        base = self.first_page(m * self.P)
+        need = self.budget(total, m)
+        row = _WinRow(base, matched_wpages[base:], need, total)
+        for pid in row.pages:
+            self.pool.retain(pid)
+        self.debt += need
+        self._write(slot, row)
+        return row
+
+    def cover(self, row: _WinRow, slot: int, first: int, end: int) -> int:
+        """Before a program that starts at position ``first`` and writes
+        ``[first, end)``: let go of the pages wholly behind its window,
+        map fresh ones up to ``end``. Returns the pages let go."""
+        keep = self.first_page(first)
+        let_go = min(max(keep - row.base, 0), len(row.pages))
+        for pid in row.pages[:let_go]:
+            if pid in row.own:
+                self._disown(row, pid)
+            self.pool.release(pid)
+        if let_go:
+            del row.pages[:let_go]
+            self.slid += let_go
+        row.base = max(row.base, keep)
+        # (a lookahead step past a stream's end writes to the null page)
+        fresh = ((min(end, row.total * self.P) - 1) // self.P
+                 - (row.base + len(row.pages)) + 1)
+        if fresh > 0:
+            got = self.pool.alloc(fresh)
+            row.pages += got
+            row.own.update(got)
+            row.need -= fresh
+            self.debt -= fresh
+        if let_go or fresh > 0:
+            self.peak = max(self.peak, len(row.pages))
+            self._write(slot, row)
+        return let_go
+
+    def _disown(self, row: _WinRow, pid: int) -> None:
+        """A fresh page leaves the stream's account (back to the pool,
+        or pinned by the cache): the stream may draw one more."""
+        row.own.discard(pid)
+        row.need += 1
+        self.debt += 1
+
+    def hand_to_cache(self, row: _WinRow, i: int) -> int:
+        """The stream's page for logical page ``i``, retained for the
+        prefix cache, or 0 where the stream no longer holds it or the
+        pool cannot spare the page the cache would pin."""
+        j = i - row.base
+        if not 0 <= j < len(row.pages):
+            return 0
+        pid = row.pages[j]
+        if pid in row.own:
+            if self.spare() < 1:
+                return 0
+            self._disown(row, pid)
+        self.pool.retain(pid)
+        return pid
+
+    def release(self, row: _WinRow) -> None:
+        """The stream is gone (retired, cancelled, reaped): every page
+        it holds, and what it was still promised. Its table row is the
+        caller's to zero, with the full group's."""
+        for pid in row.pages:
+            self.pool.release(pid)
+        self.debt -= row.need
+        row.pages, row.need = [], 0
+        row.own.clear()
+
+    def _write(self, slot: int, row: _WinRow) -> None:
+        self.pt[slot] = 0
+        self.pt[slot, 0] = row.base
+        self.pt[slot, 1:1 + len(row.pages)] = row.pages
+
+
+class _PrefixEntry:
+    __slots__ = ("key", "page", "parent_page", "children", "last_used",
+                 "wpage")
+
+    def __init__(self, key, page: int, parent_page: int, wpage: int = 0):
         self.key = key
         self.page = page
+        # the same prompt page in a layer-group engine's window pool
+        self.wpage = wpage
         self.parent_page = parent_page
         self.children = 0
         self.last_used = 0
@@ -472,10 +632,16 @@ class _PrefixCache:
     The cache holds its own +1 refcount per registered page, so shared
     pages outlive their last generation until LRU-evicted under pool
     pressure (leaf entries first — a parent is only evictable once its
-    children are gone)."""
+    children are gone).
 
-    def __init__(self, page_tokens: int):
+    ``second`` (a layer-group engine's window pool): every entry then
+    holds one page of EACH pool for its prompt page, with a refcount of
+    its own in both, and leaves with both — the key stays the full
+    group's page id."""
+
+    def __init__(self, page_tokens: int, second: _PagePool | None = None):
         self._P = int(page_tokens)
+        self._second = second
         self._entries: dict[tuple, _PrefixEntry] = {}
         self._by_page: dict[int, _PrefixEntry] = {}
         self._clock = 0
@@ -505,19 +671,34 @@ class _PrefixCache:
             parent = e.page
         return pages
 
+    def wpages(self, pages: list[int]) -> list[int]:
+        """The window-pool pages of matched entries, by their full-pool
+        page ids (as :meth:`match` returned them)."""
+        return [self._by_page[p].wpage for p in pages]
+
     def insert(self, prompt: np.ndarray, gen_pages: list[int],
-               pool: _PagePool) -> None:
+               pool: _PagePool, second=None) -> None:
         """Register a finished prefill's full prompt pages. Pages whose
         chain key is already cached (matched, or raced by a concurrent
         identical prompt) are touched, not replaced — the generation
-        keeps its private copy in that case."""
+        keeps its private copy in that case. ``second(i)`` (a
+        layer-group engine): the window-pool page the caller hands over
+        for prompt page ``i``, already retained for the cache, or 0 —
+        the chain then ends at ``i`` (the stream let that page go, or
+        the pool has none to spare)."""
         P = self._P
         parent = 0
         for i in range(int(prompt.size) // P):
             key = (parent, prompt[i * P:(i + 1) * P].tobytes())
             e = self._entries.get(key)
             if e is None:
-                e = _PrefixEntry(key, gen_pages[i], parent_page=parent)
+                wpage = 0
+                if second is not None:
+                    wpage = second(i)
+                    if not wpage:
+                        break
+                e = _PrefixEntry(key, gen_pages[i], parent_page=parent,
+                                 wpage=wpage)
                 self._entries[key] = e
                 self._by_page[e.page] = e
                 pool.retain(e.page)
@@ -532,11 +713,16 @@ class _PrefixCache:
         generation references (page refcount 1 = cache-only). With a
         ``demote`` callback (the KV-store hook), each victim is handed
         over — still registered, page still live — before release, so
-        eviction demotes the page to the store instead of dropping it."""
+        eviction demotes the page to the store instead of dropping it.
+        With a second pool an entry leaves only when no stream holds
+        either of its pages, and frees one page in each."""
         freed = 0
+        second = self._second
         while freed < n:
             cands = [e for e in self._entries.values()
-                     if e.children == 0 and pool.refcount(e.page) == 1]
+                     if e.children == 0 and pool.refcount(e.page) == 1
+                     and (second is None
+                          or second.refcount(e.wpage) == 1)]
             if not cands:
                 break
             e = min(cands, key=lambda c: c.last_used)
@@ -548,6 +734,8 @@ class _PrefixCache:
             if pe is not None:
                 pe.children -= 1
             pool.release(e.page)
+            if second is not None:
+                second.release(e.wpage)
             freed += 1
         if freed:
             stat_add("gen/prefix_evictions", freed)
@@ -860,6 +1048,12 @@ class GenerationEngine:
         # the scheduler's decision for the CURRENT loop iteration
         # (None whenever gen_sched is off — hot paths gate on it)
         self._plan = None
+        # layer groups (a model whose cache is one entry a layer kind,
+        # ``cache_groups``: SmallThinker's full and window layers): a
+        # paged engine gives each group a pool and a table of its own.
+        # None for every other model, whose programs stay as they were.
+        self._groups = self._layer_groups(model)
+        self._win: _WindowGroup | None = None
 
         if self._paged:
             P = int(flag("gen_page_tokens") if page_tokens is None
@@ -868,12 +1062,29 @@ class GenerationEngine:
                 raise ValueError(f"page_tokens must be >= 1, got {P}")
             self._page_tokens = P
             self._maxp = -(-self.max_len // P)       # pages per table
-            npages = int(flag("gen_pages") if pages is None else pages)
+            if pages is None:
+                pages = int(flag("gen_pages"))
+            # ``pages``: one count, or one a layer group
+            n_groups = len(self._groups or (None,))
+            per_group = (tuple(int(n) for n in pages)
+                         if isinstance(pages, (list, tuple))
+                         else (int(pages),) * n_groups)
+            if len(per_group) != n_groups:
+                raise ValueError(
+                    f"pages={pages!r}: the model's cache has {n_groups} "
+                    "layer group(s); give one count, or one a group")
+            npages = per_group[0]
             if npages <= 0:
                 # equal HBM to the contiguous layout by default
                 npages = self.slots * self._maxp
             self._pool = _PagePool(npages)
-            self._prefix = (_PrefixCache(P)
+            if self._groups is not None:
+                chunk = (self._prefill_chunk if self._prefill_chunk > 0
+                         else self.max_len)
+                self._win = _WindowGroup(
+                    self._groups[1][1], per_group[1], P, self.slots, chunk,
+                    self._maxp)
+            self._prefix = (_PrefixCache(P, self._win and self._win.pool)
                             if (flag("gen_prefix_cache")
                                 if prefix_cache is None else prefix_cache)
                             else None)
@@ -892,8 +1103,7 @@ class GenerationEngine:
         # schedule change (_sched_pt) so an unchanged table is not
         # re-shipped every iteration — prefill chunks, plain steps and
         # the spec step's second upload all share it.
-        self._pt_dev = (self._layout.place_pt(self._pt)
-                        if self._device_pt else None)
+        self._pt_dev = (self._pt_place() if self._device_pt else None)
         self._sched_pt = None
         self._state: dict[str, Any] = self._init_state()
         # topology for stats()/health: static for the engine's lifetime
@@ -965,6 +1175,40 @@ class GenerationEngine:
                                               name="gen-watchdog")
             self._watchdog.start()
 
+    def _layer_groups(self, model):
+        """The model's cache groups (``cache_groups``: ``(layers, window
+        or None)`` each, in ``init_cache``'s order) where it names more
+        than one kind of layer, else None. What a pool of two groups
+        does not carry is refused here, by name."""
+        groups = getattr(model, "cache_groups", None)
+        if groups is None:
+            return None
+        groups = tuple((int(n), None if w is None else int(w))
+                       for n, w in groups)
+        if not self._paged:
+            return groups            # contiguous: every position, masked
+        if (len(groups) != 2 or groups[0][1] is not None
+                or groups[1][1] is None):
+            raise ValueError(
+                f"cache groups {groups!r}: the paged engine holds one full "
+                "group followed by one window group; other mixes are not "
+                "implemented")
+        refused = {
+            "gen_spec_k (speculation)": self._spec_k > 0,
+            "gen_kv_store / gen_role (the KV store's page frames)":
+                self._kv is not None or self._role != "both",
+            "gen_sched (preemption parks a stream by folding its pages)":
+                self._sched is not None,
+        }
+        for what, on in refused.items():
+            if on:
+                raise ValueError(
+                    f"{what} with layer groups (a window group that frees "
+                    "pages behind a stream) is not implemented: a page id "
+                    "no longer covers every layer; serve this model "
+                    "without it")
+        return groups
+
     def _init_state(self) -> dict[str, Any]:
         """Fresh device-side engine state (the batched KV cache/page
         pool plus per-slot token/position/key/sampling arrays). Called
@@ -975,7 +1219,12 @@ class GenerationEngine:
 
         proto = self._model.init_cache(1, self.max_len,
                                        dtype=self._cache_dtype)
-        if self._paged:
+        if self._win is not None:
+            from paddle_tpu.models.generation import init_paged_cache
+            cache = tuple(
+                init_paged_cache(g, pool.num_pages, self._page_tokens)
+                for g, pool in zip(proto, (self._pool, self._win.pool)))
+        elif self._paged:
             from paddle_tpu.models.generation import init_paged_cache
             cache = init_paged_cache(proto, self._pool.num_pages,
                                      self._page_tokens)
@@ -1099,6 +1348,34 @@ class GenerationEngine:
         return self._layout.jit_entry(prefill, self._model, self._state,
                                       paged=False, n_in=7, n_out=1)
 
+    @staticmethod
+    def _group_caches(pool, row):
+        """One slot's cache as a layer-group model reads it: a
+        ``PagedCache`` a group, the window group's with its row's base
+        (``row[1]`` is ``[base, page ids...]``)."""
+        from paddle_tpu.models.generation import PagedCache
+        return (PagedCache(pool[0], row[0]),
+                PagedCache(pool[1], row[1][1:], row[1][0]))
+
+    def _group_step_writes(self, pool, pt, pos, active, new):
+        """A decode step's new position into both groups' pools: the
+        full group's page as ever, the window group's counted from its
+        row's base (a row that does not hold the page — an idle slot, a
+        lookahead step past a stream's end — writes to the null page)."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.models.generation import paged_write
+        P, slot = self._page_tokens, jnp.arange(self.slots)
+        pages = jnp.where(
+            active, pt[0][slot, jnp.clip(pos // P, 0, self._maxp - 1)], 0)
+        at = pos // P - pt[1][:, 0]
+        held = active & (at >= 0) & (at < self._win.row_pages)
+        wpages = jnp.where(
+            held, pt[1][slot, 1 + jnp.clip(at, 0, self._win.row_pages - 1)],
+            0)
+        return (paged_write(pool[0], pages, pos % P, new[0]),
+                paged_write(pool[1], wpages, pos % P, new[1]))
+
     def _build_paged_step(self):
         """ONE fused decode for all slots in paged mode: each slot runs
         the same single-token cached forward as the contiguous step on
@@ -1117,12 +1394,18 @@ class GenerationEngine:
 
         P, maxp = self._page_tokens, self._maxp
         slots = self.slots
+        grouped = self._win is not None
 
         def one(model, pt_row, tok, idx, key, temp, top_k, top_p, pool):
+            cache = (self._group_caches(pool, pt_row) if grouped
+                     else PagedCache(pool, pt_row))
             logits, new, cnt = self._forward(
-                model, tok[None, None], PagedCache(pool, pt_row), idx)
+                model, tok[None, None], cache, idx)
             key, sub = jax.random.split(key)
             nxt = _sample_slot(logits[0, -1], sub, temp, top_k, top_p)
+            if grouped:
+                return nxt, key, jax.tree_util.tree_map(
+                    lambda n: n[:, 0, :, 0], new), cnt
             return nxt, key, tuple(n[:, 0, :, 0] for n in new), cnt
 
         def step(model, state, pt, active):
@@ -1137,9 +1420,13 @@ class GenerationEngine:
                 "paged_kernel"
                 if paged_attn_arms["paged_kernel"] > kernel_arms
                 else "gather")
-            pidx = jnp.clip(state["pos"] // P, 0, maxp - 1)
-            pages = jnp.where(active, pt[jnp.arange(slots), pidx], 0)
-            pool = paged_write(pool, pages, state["pos"] % P, new)
+            if grouped:
+                pool = self._group_step_writes(pool, pt, state["pos"],
+                                               active, new)
+            else:
+                pidx = jnp.clip(state["pos"] // P, 0, maxp - 1)
+                pages = jnp.where(active, pt[jnp.arange(slots), pidx], 0)
+                pool = paged_write(pool, pages, state["pos"] % P, new)
             tok = jnp.where(active, nxt, state["tok"])
             pos = state["pos"] + active.astype(jnp.int32)
             state = self._counted(state, cnt, active[:, None, None])
@@ -1169,11 +1456,23 @@ class GenerationEngine:
         def prefill(model, state, pt, slot, padded, index, true_len, key,
                     temp, top_k, top_p):
             pool = state["cache"]
-            row = pt[slot]
-            logits, chunk, cnt = self._forward(
-                model, padded[None], PagedCache(pool, row), index)
-            pool = paged_scatter(pool, row, chunk, index, P,
-                                 length=true_len)
+            if self._win is not None:
+                row = tuple(t[slot] for t in pt)
+                logits, chunk, cnt = self._forward(
+                    model, padded[None], self._group_caches(pool, row),
+                    index)
+                # the window row counts its pages from its base
+                starts = (index, index - row[1][0] * P)
+                pool = tuple(
+                    paged_scatter(g, r, c, at, P, length=true_len)
+                    for g, r, c, at in zip(pool, (row[0], row[1][1:]),
+                                           chunk, starts))
+            else:
+                row = pt[slot]
+                logits, chunk, cnt = self._forward(
+                    model, padded[None], PagedCache(pool, row), index)
+                pool = paged_scatter(pool, row, chunk, index, P,
+                                     length=true_len)
             key, sub = jax.random.split(key)
             tok0 = _sample_slot(logits[0, true_len - 1], sub, temp, top_k,
                                 top_p)
@@ -1410,7 +1709,7 @@ class GenerationEngine:
         padded = jnp.zeros((self._bucket(int(prompt_len)),), jnp.int32)
         active = jnp.zeros((self.slots,), bool)
         if self._paged:
-            pt = jnp.asarray(self._pt)
+            pt = self._pt_upload(jnp)
             prefill = (self._state, pt, i32, padded, i32, i32, *sampling)
             decode = (self._state, pt, active)
         else:
@@ -1572,6 +1871,12 @@ class GenerationEngine:
                 raise ValueError(
                     f"request needs {need} pages but the pool only has "
                     f"{self._pool.num_pages}; raise FLAGS_gen_pages")
+            if (self._win is not None and self._win.budget(need, 0)
+                    > self._win.pool.num_pages):
+                raise ValueError(
+                    f"request needs {self._win.budget(need, 0)} window-"
+                    "group pages at once but that pool only has "
+                    f"{self._win.pool.num_pages}; raise FLAGS_gen_pages")
         eos = self._eos_default if eos_token_id is _UNSET else eos_token_id
         gen = Generation(uuid.uuid4().hex[:16], prompt, max_new_tokens,
                          float(temperature), int(top_k), float(top_p),
@@ -1785,6 +2090,27 @@ class GenerationEngine:
                     pages_free=self._pool.free_count,
                     prefix_entries=(0 if self._prefix is None
                                     else len(self._prefix)))
+            if self._win is not None:
+                # a pool a layer group: ``pages`` / ``pages_free`` are
+                # the totals, a prefix entry holds a page of each group
+                win = self._win
+                live = [g for g in self._slot_gen if g is not None]
+                doc["groups"] = [
+                    {"name": "full", "layers": self._groups[0][0],
+                     "pages": doc["pages"], "pages_free": doc["pages_free"],
+                     "stream_pages_max": max(
+                         (len(g.pages) for g in live), default=0)},
+                    {"name": "window", "layers": self._groups[1][0],
+                     "window": win.window, "row_pages": win.row_pages,
+                     "pages": win.pool.num_pages,
+                     "pages_free": win.pool.free_count,
+                     "stream_pages_max": max(
+                         (len(g.win.pages) for g in live
+                          if g.win is not None), default=0),
+                     "stream_pages_peak": win.peak,
+                     "pages_slid": win.slid}]
+                doc["pages"] += win.pool.num_pages
+                doc["pages_free"] += win.pool.free_count
             # performance attribution (FLAGS_gen_ledger only): the loop
             # goodput taxonomy and per-tenant books ride health's
             # generators block, so MetricsHub rolls them up fleet-wide
@@ -1866,7 +2192,7 @@ class GenerationEngine:
                                        demote=(self._kv_demote
                                                if self._kv is not None
                                                else None))
-            stat_set("gen/pages_free", self._pool.free_count)
+            stat_set("gen/pages_free", self._pages_free())
             return freed
 
     def canary(self, timeout_s: float = 5.0, prompt_token: int = 1) -> dict:
@@ -1928,8 +2254,7 @@ class GenerationEngine:
             self._queue.clear()
             self._pending.clear()
             if self._paged:
-                self._pt[:] = 0
-                self._pt_sync_full_locked()
+                self._pt_clear_locked()
             self._cond.notify_all()
         if self._kv is not None and self._kv_owned:
             self._kv.close()   # shared stores outlive their engines
@@ -2071,16 +2396,46 @@ class GenerationEngine:
         path's cached whole-table upload. Caller holds the lock. The
         functional ``.at`` update leaves any snapshot an in-flight
         dispatch captured untouched."""
-        if self._pt_dev is not None:
+        if self._pt_dev is not None and self._win is not None:
+            self._pt_dev = tuple(
+                d.at[slot].set(h[slot])
+                for d, h in zip(self._pt_dev, (self._pt, self._win.pt)))
+        elif self._pt_dev is not None:
             self._pt_dev = self._pt_dev.at[slot].set(self._pt[slot])
         self._sched_pt = None
+
+    def _pt_place(self):
+        """The host table(s) as a committed device-resident operand."""
+        if self._win is not None:
+            return (self._layout.place_pt(self._pt),
+                    self._layout.place_pt(self._win.pt))
+        return self._layout.place_pt(self._pt)
+
+    def _pt_upload(self, jnp):
+        """One upload of the host table — of both tables for a
+        layer-group engine: the page-table operand of a compiled call."""
+        if self._win is not None:
+            # copies: a window row changes every few steps, and a CPU
+            # backend's operand may alias the host array it was made
+            # from while the program that reads it is still in flight
+            return (jnp.asarray(self._pt.copy()),
+                    jnp.asarray(self._win.pt.copy()))
+        return jnp.asarray(self._pt)
+
+    def _pt_clear_locked(self) -> None:
+        """No slot is mapped any more (reset/break/close): zero the
+        host table(s) and what mirrors them. Caller holds the lock."""
+        self._pt[:] = 0
+        if self._win is not None:
+            self._win.pt[:] = 0
+        self._pt_sync_full_locked()
 
     def _pt_sync_full_locked(self) -> None:
         """The whole host table changed (reset/rebuild/break): rebuild
         the device-resident table wholesale and drop the cached
         upload. Caller holds the lock."""
         if self._pt_dev is not None:
-            self._pt_dev = self._layout.place_pt(self._pt)
+            self._pt_dev = self._pt_place()
         self._sched_pt = None
 
     def _pt_device_locked(self, jnp):
@@ -2094,7 +2449,7 @@ class GenerationEngine:
         if self._pt_dev is not None:
             return self._pt_dev
         if self._sched_pt is None:
-            self._sched_pt = jnp.asarray(self._pt)
+            self._sched_pt = self._pt_upload(jnp)
         return self._sched_pt
 
     def _fail_active_locked(self, msg: str) -> list[Generation]:
@@ -2114,10 +2469,10 @@ class GenerationEngine:
             g.slot = None
             g.prefilling = False
             g.pages = []
+            g.win = None
         self._slot_gen = [None] * self.slots
         if self._paged:
-            self._pt[:] = 0
-            self._pt_sync_full_locked()
+            self._pt_clear_locked()
         self._pending.clear()         # deferred readbacks die with the
         self._epoch += 1              # epoch: in-flight compiled results
         stat_set("gen/slots_active", 0)   # are garbage from here on
@@ -2136,10 +2491,8 @@ class GenerationEngine:
             self._rebuilds += 1
             self._fail_active_locked(msg)
             if self._paged:
-                self._pool = _PagePool(self._pool.num_pages)
-                if self._prefix is not None:
-                    self._prefix = _PrefixCache(self._page_tokens)
-                stat_set("gen/pages_free", self._pool.free_count)
+                self._reset_pools_locked()
+                stat_set("gen/pages_free", self._pages_free())
             self._state = fresh
             # the words start again at nought: what stats() last saw
             # stays counted, the tail since then is lost with the state
@@ -2208,16 +2561,31 @@ class GenerationEngine:
                                     tokens=len(gen.tokens))
                 self._ledger_finalize(gen, "broken")
                 gen.pages = []
+                gen.win = None
             self._slot_gen = [None] * self.slots
             self._queue.clear()
             if self._paged:           # nothing runs on a broken engine;
-                self._pt[:] = 0       # reset the books for stats() sanity
-                self._pt_sync_full_locked()
-                self._pool = _PagePool(self._pool.num_pages)
-                if self._prefix is not None:
-                    self._prefix = _PrefixCache(self._page_tokens)
+                self._pt_clear_locked()   # reset the books for stats()
+                self._reset_pools_locked()
             self._pending.clear()
             self._cond.notify_all()
+
+    def _reset_pools_locked(self) -> None:
+        """Fresh page books (rebuild / break): nothing of the old device
+        state is mapped any more."""
+        self._pool = _PagePool(self._pool.num_pages)
+        if self._win is not None:
+            self._win.reset()
+        if self._prefix is not None:
+            self._prefix = _PrefixCache(self._page_tokens,
+                                        self._win and self._win.pool)
+
+    def _pages_free(self) -> int:
+        """Free pages of the pool — of both groups' pools together."""
+        free = self._pool.free_count
+        if self._win is not None:
+            free += self._win.pool.free_count
+        return free
 
     def _release_slot_locked(self, gen: Generation,
                              evicted: bool = False) -> None:
@@ -2225,16 +2593,21 @@ class GenerationEngine:
             self._slot_gen[gen.slot] = None
             if self._paged:
                 self._pt[gen.slot] = 0
+                if gen.win is not None:
+                    self._win.pt[gen.slot] = 0
                 self._pt_sync_row_locked(gen.slot)
             if evicted:
                 stat_add("gen/evictions")
+        if gen.win is not None:
+            self._win.release(gen.win)
+            gen.win = None
         if self._paged and gen.pages:
             # drop this generation's references; pages the prefix cache
             # also holds stay allocated (shareable) until evicted
             for pid in gen.pages:
                 self._pool.release(pid)
             gen.pages = []
-            stat_set("gen/pages_free", self._pool.free_count)
+            stat_set("gen/pages_free", self._pages_free())
         gen.slot = None
         gen.prefilling = False
         stat_set("gen/slots_active",
@@ -2387,12 +2760,19 @@ class GenerationEngine:
                             if debt:
                                 stat_add("gen/kv_prefill_recomputed", debt)
                     short = (need - len(matched)) - self._pool.free_count
+                    win = self._win
+                    if win is not None:
+                        # the window group promises the stream the most
+                        # fresh pages it can hold at once
+                        wneed = win.budget(need, len(matched))
+                        short = max(short, wneed - win.spare())
                     if short > 0 and self._prefix is not None:
                         self._prefix.evict(short, self._pool,
                                            demote=(self._kv_demote
                                                    if self._kv is not None
                                                    else None))
-                    if need - len(matched) > self._pool.free_count:
+                    if (need - len(matched) > self._pool.free_count
+                            or (win is not None and wneed > win.spare())):
                         for pid in matched:     # give the hits back; retry
                             self._pool.release(pid)   # when pages free up
                         if (self._plan is not None
@@ -2400,12 +2780,16 @@ class GenerationEngine:
                                 and self._hol_bypass_locked()):
                             continue        # a smaller request jumped ahead
                         stat_set("gen/queue_depth", len(self._queue))
-                        stat_set("gen/pages_free", self._pool.free_count)
+                        stat_set("gen/pages_free", self._pages_free())
                         return progressed
                     self._queue.popleft()
                     gen.pages = matched + self._pool.alloc(need - len(matched))
                     gen.shared = len(matched)
                     slot = free[0]
+                    if win is not None:
+                        gen.win = win.admit(
+                            slot, self._prefix.wpages(matched)
+                            if matched else [], need)
                     self._slot_gen[slot] = gen
                     gen.slot = slot
                     self._note_admitted_locked(gen, ph)
@@ -2420,7 +2804,7 @@ class GenerationEngine:
                     if matched:
                         stat_add("gen/prefix_hits")
                         stat_add("gen/prefix_tokens_saved", len(matched) * P)
-                    stat_set("gen/pages_free", self._pool.free_count)
+                    stat_set("gen/pages_free", self._pages_free())
                     stat_set("gen/slots_active",
                              sum(g is not None for g in self._slot_gen))
                     stat_set("gen/queue_depth", len(self._queue))
@@ -2749,6 +3133,44 @@ class GenerationEngine:
         self._cond.notify_all()
         emit_ph.set(emitted=emitted, retired=retired)
 
+    def _slide_locked(self, rows) -> None:
+        """Before the upload of the tables for the programs about to be
+        dispatched: every ``(slot, gen, first, end)`` row's window group
+        lets go of the pages wholly behind the window of a program that
+        starts at ``first`` and maps fresh ones up to ``end``
+        (:meth:`_WindowGroup.cover`). A ``gen/kv_slide`` span where a
+        page is due to go, the counter ``gen/kv_pages_slid``; ``_cond``
+        held."""
+        win = self._win
+        due = any(win.first_page(first) > g.win.base
+                  for _, g, first, _ in rows)
+        with (self._phase("gen/kv_slide") if due else _NOOP_PHASE) as ph:
+            slid = 0
+            for slot, gen, first, end in rows:
+                before = gen.win.base, len(gen.win.pages)
+                slid += win.cover(gen.win, slot, first, end)
+                if before != (gen.win.base, len(gen.win.pages)):
+                    self._pt_sync_row_locked(slot)
+            if slid:
+                ph.set(pages=slid)
+                stat_add("gen/kv_pages_slid", slid)
+                stat_set("gen/pages_free", self._pages_free())
+
+    def _prefix_insert_groups_locked(self, gen: Generation, a: int,
+                                     b: int) -> None:
+        """Register the whole prompt pages below ``b`` (a chunk ``[a,
+        b)`` has just been dispatched), both groups' page each. The
+        cache pins the window page it takes, so pages are evicted first
+        where the pool has none to spare, and the chain ends where that
+        does not help. ``_cond`` held."""
+        P, win = self._page_tokens, self._win
+        short = (b // P - a // P) - win.spare()
+        if short > 0:
+            self._prefix.evict(short, self._pool)
+        self._prefix.insert(
+            gen.prompt[:b], gen.pages, self._pool,
+            second=lambda i: win.hand_to_cache(gen.win, i))
+
     def _prefill_tick(self) -> bool:
         """Advance every prefilling slot by ONE chunk (then the loop
         runs a decode step — chunked prefill interleaves with decode
@@ -2758,13 +3180,7 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        with self._cond:
-            work = [(s, g) for s, g in enumerate(self._slot_gen)
-                    if g is not None and g.prefilling]
-            pt_dev = None if not work else self._pt_device_locked(jnp)
-            epoch0 = self._epoch
-        ticked = False
-        for slot, gen in work:
+        def chunk_of(gen):
             T0 = gen.prompt.size
             a = gen.prefill_pos
             C = self._prefill_chunk if self._prefill_chunk > 0 else T0 - a
@@ -2773,7 +3189,20 @@ class GenerationEngine:
                 # long batch prefill cannot monopolize the loop while
                 # interactive work waits
                 C = min(C, self._plan.prefill_chunk)
-            b = min(T0, a + C)
+            return T0, a, min(T0, a + C)
+
+        with self._cond:
+            work = [(s, g) for s, g in enumerate(self._slot_gen)
+                    if g is not None and g.prefilling]
+            if self._win is not None and work:
+                # each chunk's window-group pages, before the upload
+                self._slide_locked([(s, g, *chunk_of(g)[1:])
+                                    for s, g in work])
+            pt_dev = None if not work else self._pt_device_locked(jnp)
+            epoch0 = self._epoch
+        ticked = False
+        for slot, gen in work:
+            T0, a, b = chunk_of(gen)
             final = b >= T0
             smax = self._maxp * self._page_tokens
             # cap the padded length so the traced write window stays in
@@ -2812,12 +3241,19 @@ class GenerationEngine:
                 if self._slot_gen[slot] is not gen:
                     continue                # cancelled/reaped mid-chunk
                 gen.prefill_pos = b
+                if self._win is not None and self._prefix is not None:
+                    # a window row holds a prompt page only until the
+                    # stream has passed it: the cache takes both pages
+                    # of every whole page as soon as a chunk filled it
+                    self._prefix_insert_groups_locked(gen, a, b)
                 if not final:
                     continue
                 gen.prefilling = False
                 observe("gen/prefill_s",
                         chunk.t1 * 1e-9 - gen.prefill_t0)
-                if self._prefix is not None:
+                if gen.win is not None:
+                    gen.win.pos = int(T0)
+                elif self._prefix is not None:
                     self._prefix.insert(gen.prompt, gen.pages, self._pool)
                 if self._kv is not None:
                     self._kv_publish(gen)
@@ -2874,6 +3310,12 @@ class GenerationEngine:
             active = np.zeros((self.slots,), bool)
             for s, _ in stepped:
                 active[s] = True
+            if self._win is not None and stepped:
+                # the step writes each stream's next position
+                self._slide_locked([(s, g, g.win.pos, g.win.pos + 1)
+                                    for s, g in stepped])
+                for _, g in stepped:
+                    g.win.pos += 1
             pt_dev = (self._pt_device_locked(jnp)
                       if self._paged and stepped else None)
             epoch0 = self._epoch

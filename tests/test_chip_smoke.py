@@ -59,6 +59,22 @@ def test_latent_phase_off_the_chip():
     assert latent["probe_repeat"]["same_programs_identical"]
 
 
+def test_window_phase_off_the_chip():
+    """The layer-group engine's phase on the CPU (float32): the streams
+    finish, the window group slides and never outgrows its row, both
+    pools come back whole, and the stream behind a shared prefix three
+    windows long equals solo ``generate()``."""
+    got = cs.window_phase(
+        cs.make_requests(256, (60, 52, 70, 64), (12, 8, 12, 8),
+                         shared_prefix=48), slots=4, max_len=128, window=16,
+        chunk=16, on_chip=False)
+    assert got["decode_attn"] == "gather" and got["streams"] == 4
+    assert got["pages_slid"] > 0
+    assert got["stream_pages_peak"] <= got["row_pages"] == 3
+    assert got["engine_agrees_with_solo_generate_for"] == "8/8 tokens"
+    assert got["probe_repeat"]["same_programs_identical"]
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("kernels", ["off", "interpreted"])
 def test_parity_phase_tiny(kernels):
