@@ -454,6 +454,20 @@ def _agree(a, b) -> int:
     return n
 
 
+def _solo(model, r: Request) -> list:
+    """Solo ``generate()`` of one request, its sampling included."""
+    import jax
+
+    from paddle_tpu.models.generation import generate
+
+    kw = dict(r.sampling)
+    if kw:
+        kw["key"] = jax.random.PRNGKey(kw.pop("seed"))
+    out = jax.jit(lambda m, x: generate(m, x, r.new_tokens, **kw))(
+        model, r.prompt[None])
+    return np.asarray(out)[0, r.prompt.size:].tolist()
+
+
 def _drive(endpoint: str, name: str, engine, requests, vocab: int):
     """All requests as concurrent client streams (one thread and one
     connection each), then the greedy probe again — which must return
@@ -461,7 +475,10 @@ def _drive(endpoint: str, name: str, engine, requests, vocab: int):
     cache it does not: the repeat prefills only the tail past its cached
     pages, a differently shaped program that may round differently in
     bf16, so that repeat is reported and the gated one follows
-    ``clear_prefix_cache()``. Returns ``(token lists, repeat report)``."""
+    ``clear_prefix_cache()``. The step's sampler has to sort while the
+    sampled request (top-k, top-p) is live — a decode step for each of
+    its tokens past the prefill's — and not once for the greedy probe
+    alone. Returns ``(token lists, repeat report)``."""
     from paddle_tpu import io
 
     results: list = [None] * len(requests)
@@ -491,6 +508,11 @@ def _drive(endpoint: str, name: str, engine, requests, vocab: int):
         check(all(0 <= t < vocab for t in toks),
               f"{name}: stream {i} returned an out-of-vocab token")
     probe = requests[0]
+    sorted_steps = engine.stats()["sample_sorted_steps"]
+    longest = max(r.new_tokens for r in requests if r.sampling)
+    check(sorted_steps >= longest - 1,
+          f"{name}: the sampler sorted on {sorted_steps} steps, the "
+          f"sampled stream took {longest - 1}")
 
     def repeat() -> list:
         with io.InferenceClient(endpoint) as client:
@@ -504,6 +526,12 @@ def _drive(endpoint: str, name: str, engine, requests, vocab: int):
         engine.clear_prefix_cache()
     again = repeat()
     info["same_programs_identical"] = again == results[0]
+    alone = engine.stats()["sample_sorted_steps"] - sorted_steps
+    info.update(sample_sorted_steps=sorted_steps,
+                greedy_probe_sorted_steps=alone)
+    check(alone == 0,
+          f"{name}: the sampler sorted on {alone} steps of the greedy "
+          "probe alone")
     check(again == results[0],
           f"{name}: the greedy probe repeated after the others returned "
           f"different tokens (first {_agree(again, results[0])} agree)")
@@ -534,13 +562,13 @@ def serve_phase(model, requests, *, slots: int, max_len: int,
     """``model`` behind an ``InferenceServer`` on a loopback port with a
     default (contiguous) and a paged+prefix-cache generator; every
     request streamed concurrently against each. Also reports — without
-    gating on it — whether the engine's greedy probe equals solo
-    ``generate()`` (unsharded engines only)."""
+    gating on it on the chip — whether the engine's greedy probe and
+    its sampled stream equal solo ``generate()`` (unsharded engines
+    only; held off the chip, where the programs are float32)."""
     import jax
 
     from paddle_tpu import io
     from paddle_tpu.core import monitor
-    from paddle_tpu.models.generation import generate
     from paddle_tpu.ops import pallas as pk
 
     vocab = model.config.vocab_size
@@ -610,14 +638,15 @@ def serve_phase(model, requests, *, slots: int, max_len: int,
         report["paged_equals_contiguous"] = (
             tokens["paged"][0] == tokens["contiguous"][0])
         if not mesh_tp:
-            probe = requests[0]
-            solo = np.asarray(jax.jit(
-                lambda m, x: generate(m, x, probe.new_tokens))(
-                    model, probe.prompt[None]))[0, probe.prompt.size:]
-            report["engine_agrees_with_solo_generate_for"] = {
-                name: f"{_agree(solo.tolist(), toks[0])}/"
-                      f"{probe.new_tokens} tokens"
-                for name, toks in tokens.items()}
+            for key, i in (("engine_agrees_with_solo_generate_for", 0),
+                           ("sampled_agrees_with_solo_generate_for", 1)):
+                solo = _solo(model, requests[i])
+                agree = {name: _agree(solo, toks[i])
+                         for name, toks in tokens.items()}
+                check(on_chip or set(agree.values()) == {len(solo)},
+                      f"{key}: {agree} of {len(solo)} tokens")
+                report[key] = {name: f"{n}/{len(solo)} tokens"
+                               for name, n in agree.items()}
     finally:
         server.stop()
     return report
@@ -688,7 +717,6 @@ def window_phase(requests, *, slots: int, max_len: int, window: int,
     import paddle_tpu
     from paddle_tpu import io
     from paddle_tpu.core import monitor
-    from paddle_tpu.models.generation import generate
     from paddle_tpu.models.smallthinker import (
         SmallThinkerConfig, SmallThinkerForCausalLM,
     )
@@ -717,10 +745,7 @@ def window_phase(requests, *, slots: int, max_len: int, window: int,
         tokens, repeat = _drive(server.endpoint, "window", engine, requests,
                                 cfg.vocab_size)
         probe = requests[-1]          # behind the shared prefix
-        solo = np.asarray(jax.jit(
-            lambda m, x: generate(m, x, probe.new_tokens))(
-                model, probe.prompt[None]))[0, probe.prompt.size:]
-        agree = _agree(tokens[-1], solo.tolist())
+        agree = _agree(tokens[-1], _solo(model, probe))
         check(on_chip or agree == probe.new_tokens,
               f"window: {agree}/{probe.new_tokens} tokens of the stream "
               "behind the shared prefix agree with solo generate()")

@@ -133,7 +133,9 @@ thread and none per token: ``gen/loop`` (one iteration; ``queue``,
 ``active``) is the parent of ``gen/idle_wait``, ``gen/admit`` (``gen``,
 ``waited_ms``, ``prefix_tokens``, ``pages``; under it ``gen/kv_fetch``),
 ``gen/dev_ops``, ``gen/prefill`` / ``gen/prefill_chunk``,
-``gen/decode_step`` (``active``, ``spec``, ``compiled``, a plain paged
+``gen/decode_step`` (``active``, ``spec``, ``compiled``, ``sort_slots``
+— the live slots whose request restricts its sampling, the steps that
+have one counted under ``gen/sample_sorted_steps`` — a plain paged
 step's ``decode_attn``; under it ``gen/step_dispatch`` and
 ``gen/step_wait``, or ``gen/spec_verify`` around both), ``gen/draft``
 and ``gen/emit`` (``emitted``, ``retired``). One helper, ``_phase``,
@@ -341,7 +343,7 @@ class Generation:
                  "tenant", "admitted_ts", "first_tok_ts", "done_ts",
                  "chip_s", "ledgered", "dev_ops", "pclass", "folded",
                  "queue_booked", "sched_seq", "sched_vft", "sched_ts",
-                 "win")
+                 "win", "sorts")
 
     def __init__(self, gen_id: str, prompt: np.ndarray,
                  max_new_tokens: int, temperature: float, top_k: int,
@@ -352,6 +354,10 @@ class Generation:
         self.temperature = temperature
         self.top_k = top_k
         self.top_p = top_p
+        # the request restricts its sampling: live, it pulls the steps'
+        # sampler into the sort (:func:`_sample`, on the float32 it sees)
+        self.sorts = bool(temperature > 0.0 and (
+            top_k > 0 or np.float32(top_p) < 1.0))
         self.eos_token_id = eos_token_id
         self.seed = seed
         self.tokens: list[int] = []
@@ -760,33 +766,90 @@ class _PrefixCache:
         return chain
 
 
-def _sample_slot(logits, key, temperature, top_k, top_p):
-    """Per-slot next-token pick with fully-traced sampling params (one
-    compiled step serves every request mix): greedy argmax where
-    ``temperature <= 0`` — bit-equal to ``sample_logits``'s greedy path —
-    else temperature / top-k / nucleus sampling with traced ``top_k``
-    (``<= 0`` keeps all) and ``top_p`` (``1.0`` keeps all)."""
+def _sample(logits, keys, temperature, top_k, top_p, live):
+    """Next-token picks for ``[N, V]`` logits with fully-traced per-row
+    sampling params (one compiled program serves every request mix), the
+    work following what the ``live`` rows of THIS call ask for — a
+    ``lax.switch`` on scalars reduced over them (a retired slot keeps
+    its last occupant's params in the state and arms nothing):
+
+    0. no live row has ``temperature > 0``: ``argmax`` alone — bit-equal
+       to ``sample_logits``'s greedy path;
+    1. some live row samples, none restricts (``top_k <= 0`` keeps all,
+       ``top_p >= 1`` keeps all): ``categorical(key, logits /
+       temperature)``, what ``sample_logits`` does for such a request;
+    2. some live row restricts: ONE descending sort a row. The top-k
+       cut applies in the sorted row (ties with the k-th value kept),
+       which is the row the nucleus reads.
+
+    A row's pick does not depend on the arm its co-tenants pulled the
+    call into: in every arm a greedy row yields its ``argmax`` and an
+    unrestricted sampling row ``categorical(key, logits / temperature)``
+    — the masks of arm 2 apply only where the row itself asks.
+    Returns ``(tokens [N] int32, arm)``."""
     import jax
     import jax.numpy as jnp
 
-    with jax.named_scope("sample"):
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        V = logits.shape[-1]
-        lt = logits.astype(jnp.float32) / jnp.maximum(temperature, 1e-6)
-        # top-k via the kth-largest threshold, k traced (take clamps indices)
-        asc = jnp.sort(lt, axis=-1)
+    def greedy(logits, *_):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def scaled(logits, temperature):
+        return (logits.astype(jnp.float32)
+                / jnp.maximum(temperature, 1e-6)[:, None])
+
+    def drawn(logits, keys, temperature, lt):
+        sampled = jax.vmap(jax.random.categorical)(keys, lt)
+        return jnp.where(temperature <= 0.0, greedy(logits),
+                         sampled.astype(jnp.int32))
+
+    def arm_plain(logits, keys, temperature, top_k, top_p):
+        return drawn(logits, keys, temperature, scaled(logits, temperature))
+
+    def arm_sorted(logits, keys, temperature, top_k, top_p):
+        lt = scaled(logits, temperature)
+        V = lt.shape[-1]
+        top_p = top_p[:, None]
+        # values alone: stability orders nothing a pick can see, and the
+        # unstable sort moves no index operand beside them
+        desc = jnp.sort(lt, axis=-1, stable=False)[:, ::-1]
+        # top-k via the kth-largest threshold, k traced
         k_eff = jnp.clip(jnp.where(top_k > 0, top_k, V), 1, V)
-        kth = jnp.take(asc, V - k_eff)
-        lt = jnp.where(lt < kth, -jnp.inf, lt)
+        kth = jnp.take_along_axis(desc, (k_eff - 1)[:, None], axis=-1)
+        desc = jnp.where(desc < kth, -jnp.inf, desc)
         # nucleus over what survived top-k (the sample_logits ordering)
-        desc = jnp.sort(lt, axis=-1)[::-1]
-        probs = jax.nn.softmax(desc)
-        cum = jnp.cumsum(probs)
+        probs = jax.nn.softmax(desc, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
         keep = cum - probs < top_p              # always keeps the top-1
-        thr = jnp.min(jnp.where(keep, desc, jnp.inf))
-        lt = jnp.where(lt < thr, -jnp.inf, lt)
-        sampled = jax.random.categorical(key, lt).astype(jnp.int32)
-        return jnp.where(temperature <= 0.0, greedy, sampled)
+        thr = jnp.min(jnp.where(keep, desc, jnp.inf), axis=-1,
+                      keepdims=True)
+        # the float32 cumulative sum rounds to 1.0 before the row ends:
+        # a row that sets no top_p keeps its whole tail
+        thr = jnp.where(top_p >= 1.0, -jnp.inf, thr)
+        lt = jnp.where((lt < kth) | (lt < thr), -jnp.inf, lt)
+        return drawn(logits, keys, temperature, lt)
+
+    with jax.named_scope("sample"):
+        sampling = live & (temperature > 0.0)
+        restricted = sampling & ((top_k > 0) | (top_p < 1.0))
+        arm = (jnp.any(sampling).astype(jnp.int32)
+               + jnp.any(restricted).astype(jnp.int32))
+        tokens = jax.lax.switch(
+            arm, (greedy, arm_plain, arm_sorted), logits, keys,
+            temperature, top_k, top_p)
+        return tokens, arm
+
+
+def _sample_one(logits, key, temperature, top_k, top_p):
+    """:func:`_sample` for one row with the request's own scalars (a
+    prefill's first token)."""
+    import jax.numpy as jnp
+
+    tokens, _ = _sample(
+        logits[None], key[None],
+        jnp.asarray(temperature, jnp.float32)[None],
+        jnp.asarray(top_k, jnp.int32)[None],
+        jnp.asarray(top_p, jnp.float32)[None], jnp.ones((1,), bool))
+    return tokens[0]
 
 
 class GenerationEngine:
@@ -955,6 +1018,8 @@ class GenerationEngine:
         # from batching wins; spec acceptance totals ride along
         self._emit_total = 0
         self._decode_iters = 0
+        # decode steps whose sampler sorted (a live request restricts)
+        self._sample_sorted_steps = 0
         self._spec_proposed = 0
         self._spec_accepted = 0
         self._spec_verify_steps = 0
@@ -1292,18 +1357,19 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        def one(model, cache, tok, idx, key, temp, top_k, top_p):
+        def one(model, cache, tok, idx, key):
             logits, cache, cnt = self._forward(model, tok[None, None],
                                                cache, idx)
             key, sub = jax.random.split(key)
-            nxt = _sample_slot(logits[0, -1], sub, temp, top_k, top_p)
-            return cache, nxt, key, cnt
+            return cache, logits[0, -1], key, sub, cnt
 
         def step(model, state, active):
-            cache, nxt, keys, cnt = jax.vmap(
+            cache, logits, keys, subs, cnt = jax.vmap(
                 functools.partial(one, model))(
-                state["cache"], state["tok"], state["pos"], state["keys"],
-                state["temp"], state["top_k"], state["top_p"])
+                state["cache"], state["tok"], state["pos"], state["keys"])
+            # the pick stands outside the vmap: its arm is one scalar
+            nxt, _ = _sample(logits, subs, state["temp"], state["top_k"],
+                             state["top_p"], active)
             tok = jnp.where(active, nxt, state["tok"])
             pos = state["pos"] + active.astype(jnp.int32)
             state = self._counted(state, cnt, active[:, None, None])
@@ -1328,8 +1394,8 @@ class GenerationEngine:
             b1 = model.init_cache(1, S, dtype=cache_dtype)
             logits, b1, cnt = self._forward(model, padded[None], b1, 0)
             key, sub = jax.random.split(key)
-            tok0 = _sample_slot(logits[0, true_len - 1], sub, temp, top_k,
-                                top_p)
+            tok0 = _sample_one(logits[0, true_len - 1], sub, temp, top_k,
+                               top_p)
             cache = jax.tree_util.tree_map(
                 lambda big, sm: big.at[slot].set(sm), state["cache"], b1)
             state = self._counted(
@@ -1396,26 +1462,22 @@ class GenerationEngine:
         slots = self.slots
         grouped = self._win is not None
 
-        def one(model, pt_row, tok, idx, key, temp, top_k, top_p, pool):
+        def one(model, pt_row, tok, idx, key, pool):
             cache = (self._group_caches(pool, pt_row) if grouped
                      else PagedCache(pool, pt_row))
             logits, new, cnt = self._forward(
                 model, tok[None, None], cache, idx)
             key, sub = jax.random.split(key)
-            nxt = _sample_slot(logits[0, -1], sub, temp, top_k, top_p)
-            if grouped:
-                return nxt, key, jax.tree_util.tree_map(
-                    lambda n: n[:, 0, :, 0], new), cnt
-            return nxt, key, tuple(n[:, 0, :, 0] for n in new), cnt
+            new = jax.tree_util.tree_map(lambda n: n[:, 0, :, 0], new)
+            return logits[0, -1], key, sub, new, cnt
 
         def step(model, state, pt, active):
             pool = state["cache"]
             kernel_arms = paged_attn_arms["paged_kernel"]
-            nxt, keys, new, cnt = jax.vmap(
+            logits, keys, subs, new, cnt = jax.vmap(
                 functools.partial(one, model),
-                in_axes=(0, 0, 0, 0, 0, 0, 0, None))(
-                pt, state["tok"], state["pos"], state["keys"],
-                state["temp"], state["top_k"], state["top_p"], pool)
+                in_axes=(0, 0, 0, 0, None))(
+                pt, state["tok"], state["pos"], state["keys"], pool)
             self._decode_attn = (
                 "paged_kernel"
                 if paged_attn_arms["paged_kernel"] > kernel_arms
@@ -1427,6 +1489,8 @@ class GenerationEngine:
                 pidx = jnp.clip(state["pos"] // P, 0, maxp - 1)
                 pages = jnp.where(active, pt[jnp.arange(slots), pidx], 0)
                 pool = paged_write(pool, pages, state["pos"] % P, new)
+            nxt, _ = _sample(logits, subs, state["temp"], state["top_k"],
+                             state["top_p"], active)
             tok = jnp.where(active, nxt, state["tok"])
             pos = state["pos"] + active.astype(jnp.int32)
             state = self._counted(state, cnt, active[:, None, None])
@@ -1474,8 +1538,8 @@ class GenerationEngine:
                 pool = paged_scatter(pool, row, chunk, index, P,
                                      length=true_len)
             key, sub = jax.random.split(key)
-            tok0 = _sample_slot(logits[0, true_len - 1], sub, temp, top_k,
-                                top_p)
+            tok0 = _sample_one(logits[0, true_len - 1], sub, temp, top_k,
+                               top_p)
             state = self._counted(
                 state, cnt, (jnp.arange(padded.shape[0]) < true_len)[:, None])
             return dict(
@@ -1492,34 +1556,50 @@ class GenerationEngine:
         return self._layout.jit_entry(prefill, self._model, self._state,
                                       paged=True, n_in=9, n_out=1)
 
-    def _spec_pick_accept(self, jax, jnp, logits, key, temp, top_k, top_p,
-                          draft, dlen):
-        """Shared verify core of both spec steps (traced, per slot):
-        compute the target's pick at every one of the K+1 forwarded
-        positions — position ``i``'s pick drawing from the subkey of the
-        ``i+1``-th split past the slot key, the exact per-emitted-token
-        schedule — then accept the longest draft prefix matching those
-        picks. Returns ``(out [K+1], emit, new_key)`` where
-        ``out[:emit]`` are the emitted tokens (accepted drafts + the
-        target's pick at the first mismatch) and ``new_key`` is the slot
-        key advanced by exactly ``emit`` splits, so a slot's key
-        schedule is indistinguishable from ``emit`` plain steps."""
-        K = self._spec_k
+    def _spec_keys(self, jax, jnp, key):
+        """One slot's key schedule over the K+1 forwarded positions:
+        ``keys[i]`` is the slot key after ``i+1`` splits and ``subs[i]``
+        the subkey position ``i``'s pick draws from — the exact
+        per-emitted-token schedule of plain steps."""
         keys, subs, cur = [], [], key
-        for _ in range(K + 1):
+        for _ in range(self._spec_k + 1):
             cur, sub = jax.random.split(cur)
             keys.append(cur)
             subs.append(sub)
-        picks = jnp.stack([
-            _sample_slot(logits[i], subs[i], temp, top_k, top_p)
-            for i in range(K + 1)])                          # [K+1]
-        good = (picks[:K] == draft) & (jnp.arange(K) < dlen)
-        acc = jnp.sum(jnp.cumprod(good.astype(jnp.int32)))
-        j = jnp.arange(K + 1)
-        out = jnp.where(j < acc, jnp.concatenate([draft, draft[-1:]]),
-                        picks)
-        new_key = jnp.stack(keys)[acc]       # acc+1 = emit splits in
-        return out, acc + 1, new_key
+        return jnp.stack(keys), jnp.stack(subs)
+
+    def _spec_pick_accept(self, jax, jnp, logits, keys, subs, state,
+                          active, drafts, dlens):
+        """Shared verify core of both spec steps, over all slots:
+        compute the target's pick at every one of the K+1 forwarded
+        positions (``logits [slots, K+1, V]``, keys of
+        :meth:`_spec_keys`) in one :func:`_sample` call outside the
+        vmap, then accept the longest draft prefix matching those
+        picks. Returns ``(out [slots, K+1], emit, new_keys)`` where
+        ``out[s, :emit[s]]`` are the emitted tokens (accepted drafts +
+        the target's pick at the first mismatch) and ``new_keys[s]`` is
+        the slot key advanced by exactly ``emit[s]`` splits, so a slot's
+        key schedule is indistinguishable from ``emit`` plain steps."""
+        K = self._spec_k
+
+        def rows(a):                      # a slot's value at each position
+            return jnp.repeat(a, K + 1, axis=0)
+
+        picks, _ = _sample(
+            logits.reshape((-1, logits.shape[-1])),
+            subs.reshape((-1,) + subs.shape[2:]), rows(state["temp"]),
+            rows(state["top_k"]), rows(state["top_p"]), rows(active))
+
+        def accept(picks, keys, draft, dlen):
+            good = (picks[:K] == draft) & (jnp.arange(K) < dlen)
+            acc = jnp.sum(jnp.cumprod(good.astype(jnp.int32)))
+            j = jnp.arange(K + 1)
+            out = jnp.where(j < acc, jnp.concatenate([draft, draft[-1:]]),
+                            picks)
+            return out, acc + 1, keys[acc]   # acc+1 = emit splits in
+
+        return jax.vmap(accept)(picks.reshape((-1, K + 1)), keys, drafts,
+                                dlens)
 
     def _build_spec_step(self):
         """ONE fused speculative verify for all slots (contiguous mode):
@@ -1536,21 +1616,18 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        def one(model, cache, tok, idx, key, temp, top_k, top_p, draft,
-                dlen):
+        def one(model, cache, tok, idx, key, draft):
             ids = jnp.concatenate([tok[None], draft])[None]   # [1, K+1]
             logits, cache, cnt = self._forward(model, ids, cache, idx)
-            out, emit, new_key = self._spec_pick_accept(
-                jax, jnp, logits[0], key, temp, top_k, top_p, draft,
-                dlen)
-            return cache, out, emit, new_key, cnt
+            return (cache, logits[0], *self._spec_keys(jax, jnp, key), cnt)
 
         def step(model, state, active, drafts, dlens):
-            cache, out, emit, keys, cnt = jax.vmap(
+            cache, logits, keys, subs, cnt = jax.vmap(
                 functools.partial(one, model))(
                 state["cache"], state["tok"], state["pos"], state["keys"],
-                state["temp"], state["top_k"], state["top_p"], drafts,
-                dlens)
+                drafts)
+            out, emit, keys = self._spec_pick_accept(
+                jax, jnp, logits, keys, subs, state, active, drafts, dlens)
             emit = jnp.where(active, emit, 0)
             state = self._counted(
                 state, cnt,
@@ -1580,26 +1657,22 @@ class GenerationEngine:
         P, maxp = self._page_tokens, self._maxp
         K = self._spec_k
 
-        def one(model, pt_row, tok, idx, key, temp, top_k, top_p, draft,
-                dlen, pool):
+        def one(model, pt_row, tok, idx, key, draft, pool):
             ids = jnp.concatenate([tok[None], draft])[None]
             logits, chunk, cnt = self._forward(
                 model, ids, PagedCache(pool, pt_row), idx)
-            out, emit, new_key = self._spec_pick_accept(
-                jax, jnp, logits[0], key, temp, top_k, top_p, draft,
-                dlen)
             # [K+1, L, Hkv, *rest]: one row a position
-            return out, emit, new_key, tuple(
-                jnp.moveaxis(c[:, 0], 2, 0) for c in chunk), cnt
+            return (logits[0], *self._spec_keys(jax, jnp, key), tuple(
+                jnp.moveaxis(c[:, 0], 2, 0) for c in chunk), cnt)
 
         def step(model, state, pt, active, drafts, dlens):
             pool = state["cache"]
-            out, emit, keys, chunks, cnt = jax.vmap(
+            logits, keys, subs, chunks, cnt = jax.vmap(
                 functools.partial(one, model),
-                in_axes=(0,) * 9 + (None,))(
-                pt, state["tok"], state["pos"], state["keys"],
-                state["temp"], state["top_k"], state["top_p"], drafts,
-                dlens, pool)
+                in_axes=(0,) * 5 + (None,))(
+                pt, state["tok"], state["pos"], state["keys"], drafts, pool)
+            out, emit, keys = self._spec_pick_accept(
+                jax, jnp, logits, keys, subs, state, active, drafts, dlens)
             emit = jnp.where(active, emit, 0)
             j = jnp.arange(K + 1)
             state = self._counted(
@@ -2040,6 +2113,7 @@ class GenerationEngine:
                    "tokens_per_step": (
                        self._emit_total / self._decode_iters
                        if self._decode_iters else 0.0),
+                   "sample_sorted_steps": self._sample_sorted_steps,
                    # XLA compile observability: total distinct compiled
                    # (entry, shape) signatures, how many were re-compiles
                    # of an already-compiled entry point, and the storm
@@ -3310,6 +3384,7 @@ class GenerationEngine:
             active = np.zeros((self.slots,), bool)
             for s, _ in stepped:
                 active[s] = True
+            sort_slots = sum(g.sorts for _, g in stepped)
             if self._win is not None and stepped:
                 # the step writes each stream's next position
                 self._slide_locked([(s, g, g.win.pos, g.win.pos + 1)
@@ -3376,8 +3451,12 @@ class GenerationEngine:
                     "gen/decode_step_s",
                     ("spec_step" if use_spec
                      else ("paged_step" if self._paged else "step"), 0),
-                    active=len(stepped), spec=int(use_spec)) as call:
+                    active=len(stepped), spec=int(use_spec),
+                    sort_slots=sort_slots) as call:
                 _fault.inject("engine.decode_step")
+                if sort_slots:
+                    self._sample_sorted_steps += 1
+                    stat_add("gen/sample_sorted_steps")
                 if use_spec:
                     with self._phase("gen/spec_verify",
                                      hist="gen/spec_verify_s",

@@ -39,11 +39,16 @@ def test_train_and_serve_phases_tiny():
     # on the CPU (f32) the repeat through the prefix cache and solo
     # generate() are byte-identical to the engine's greedy stream; on
     # the chip the smoke reports both without gating on them
+    # request 1 (top-k, top-p) takes two decode steps past its prefill
     assert serve["paged"]["probe_repeat"] == {
         "through_prefix_cache_agrees_for": "4/4 tokens",
-        "same_programs_identical": True}
+        "same_programs_identical": True,
+        "sample_sorted_steps": 2, "greedy_probe_sorted_steps": 0}
+    assert serve["contiguous"]["probe_repeat"]["sample_sorted_steps"] == 2
     assert serve["engine_agrees_with_solo_generate_for"] == {
         "contiguous": "4/4 tokens", "paged": "4/4 tokens"}
+    assert serve["sampled_agrees_with_solo_generate_for"] == {
+        "contiguous": "3/3 tokens", "paged": "3/3 tokens"}
 
 
 def test_latent_phase_off_the_chip():

@@ -420,3 +420,11 @@ def test_step_compiled_for_v5e_keeps_the_pool_in_place(one_chip, monkeypatch,
     for line in hlo.splitlines():
         if " copy(" in line:
             assert f"[{leaf}]" not in line, line
+    # the sampler's arms stay arms on the chip: ONE sort over the
+    # vocabulary in the whole step, inside the last branch of a
+    # conditional (a select would run it for every greedy batch)
+    wide = [line for line in hlo.splitlines()
+            if " sort(" in line and f"[{slots},{VOCAB}]" in line]
+    assert len(wide) == 1 and "sample/cond/branch_2" in wide[0], wide
+    assert sum(" conditional(" in line and "sample/cond" in line
+               for line in hlo.splitlines()) == 1
