@@ -69,6 +69,9 @@ DECODE_KERNELS = {"contiguous": ("ptpu_decode_attn",),
 # the paged step of a latent-attention (MLA) model: the same dispatch,
 # the latent arm of the same module
 LATENT_DECODE_KERNEL = "ptpu_paged_latent_decode_attn"
+# the one-token step of a KDA layer (a recurrent state read and written
+# once, in place): the state group's step holds one call a KDA layer
+KDA_STEP_KERNEL = "ptpu_kda_step"
 # per-shard (shard_map) units the four-chip programs must take on the
 # kernel arm (``ops.pallas.partition_stats()`` keys ``<unit>:kernel``)
 TRAIN_UNITS = ("flash_fwd", "flash_bwd", "rms_fwd", "rms_bwd", "rope",
@@ -771,6 +774,78 @@ def window_phase(requests, *, slots: int, max_len: int, window: int,
         server.stop()
 
 
+def state_phase(requests, *, slots: int, max_len: int, chunk: int,
+                on_chip: bool = True) -> dict:
+    """A small Kimi-Linear model (KDA layers with a recurrent state
+    beside latent layers; 16 KDA heads of 128, what the step kernel's
+    gate takes) behind a paged, prefix-cached generator with a state
+    group: every request streamed concurrently, then the greedy probe
+    again THROUGH the prefix cache — a stream that restores a state
+    snapshot and prefills only its tail — and once more cold. On the
+    chip the step's KDA layers have to take ``ptpu_kda_step`` and its
+    latent layers the latent kernel; a snapshot has to be restored; the
+    pages and the snapshots have to come back. The restored stream is
+    held to the cold one off the chip (float32); in bf16 the two run
+    programs of different shapes, so the chip reports the agreement."""
+    import paddle_tpu
+    from paddle_tpu import io
+    from paddle_tpu.core import monitor
+    from paddle_tpu.models.kimi_linear import (
+        KimiLinearConfig, KimiLinearForCausalLM,
+    )
+
+    paddle_tpu.seed(7)
+    cfg = KimiLinearConfig.tiny(
+        hidden_size=256, kda_heads=16, kda_head_dim=128, kv_lora_rank=128,
+        qk_nope_head_dim=64, qk_rope_head_dim=64, v_head_dim=64,
+        moe_intermediate_size=128, intermediate_size=512,
+        max_seq_len=max_len, dtype="bfloat16" if on_chip else "float32")
+    model = KimiLinearForCausalLM(cfg)
+    restores0 = monitor.get_stat("gen/state_restores") or 0
+    server = io.InferenceServer(port=0).start()
+    try:
+        engine = server.add_generator(
+            "state", model, slots=slots, max_len=max_len, paged=True,
+            page_tokens=PARITY_PAGE_TOKENS, prefill_chunk=chunk,
+            prefix_cache=True, state_snapshots=8)
+        text = engine.lowered_text(max(len(r.prompt) for r in requests))
+        st = engine.stats()
+        arms = {"decode_attn": st["decode_attn"], "kda_step": st["kda_step"]}
+        if on_chip:
+            check(arms == {"decode_attn": "paged_kernel",
+                           "kda_step": "kernel"},
+                  f"state engine's step took {arms}, expected the paged "
+                  "latent kernel and the KDA step kernel")
+            absent = _missing(text["decode"],
+                              (KDA_STEP_KERNEL, LATENT_DECODE_KERNEL))
+            check(not absent, f"state engine lowered without {absent}")
+        tokens, repeat = _drive(server.endpoint, "state", engine, requests,
+                                cfg.vocab_size)
+        restores = (monitor.get_stat("gen/state_restores") or 0) - restores0
+        check(restores > 0, "state: no admission restored a snapshot")
+        through = repeat.get("through_prefix_cache_agrees_for", "")
+        probe = requests[0]
+        check(on_chip or through == f"{probe.new_tokens}/"
+              f"{probe.new_tokens} tokens",
+              f"state: the restored stream agrees with the cold one for "
+              f"{through}")
+        engine.clear_prefix_cache()
+        st = engine.stats()
+        block = next(g for g in st["groups"] if g["name"] == "state")
+        check(st["pages_free"] == st["pages"]
+              and block["snapshots_free"] == block["snapshots"]
+              and st["active"] == 0 and st["broken"] is None,
+              f"state: pages or snapshots did not come back: {st['groups']}")
+        return dict(arms, streams=len(requests), probe_repeat=repeat,
+                    restores=restores,
+                    state_bytes_per_slot=block["bytes_per_slot"],
+                    kernels={"decode": [KDA_STEP_KERNEL,
+                                        LATENT_DECODE_KERNEL]
+                             if arms["kda_step"] == "kernel" else []})
+    finally:
+        server.stop()
+
+
 # ---------------------------------------------------------------------------
 # main
 # ---------------------------------------------------------------------------
@@ -809,6 +884,9 @@ def main() -> int:
         make_requests(256, (300, 280, 330, 310), (40, 24, 40, 24),
                       shared_prefix=256), slots=4, max_len=512, window=64,
         chunk=64)
+    report["serve_state"] = state_phase(
+        make_requests(256, (150, 140, 170, 160), (24, 16, 24, 16),
+                      shared_prefix=128), slots=4, max_len=256, chunk=64)
     if n_dev >= 4:
         del model
         gc.collect()

@@ -17,4 +17,7 @@ from paddle_tpu.models.deepseek_v3 import (
 from paddle_tpu.models.smallthinker import (
     SmallThinkerConfig, SmallThinkerForCausalLM,
 )
+from paddle_tpu.models.kimi_linear import (
+    KimiLinearConfig, KimiLinearForCausalLM,
+)
 from paddle_tpu.models.ernie import ErnieConfig, ErnieForPretraining, ErnieModel

@@ -12,7 +12,8 @@ Not held: the multi-token-prediction module
 (``num_nextn_predict_layers``); no training recipe is claimed.
 
 Attention (``MLAttention``): queries through a low-rank pair
-(``wq_a`` → RMSNorm → ``wq_b``), keys and values through ONE compressed
+(``wq_a`` → RMSNorm → ``wq_b``; ``q_lora_rank=None``: one ``wq``), keys
+and values through ONE compressed
 row a token (``wkv_a`` → [c_kv | k_rope]; RMSNorm on c_kv, RoPE on the
 shared k_rope) that ``wkv_b`` expands per head into [k_nope | v]. The
 cache holds the compressed row and the rope key — ``kv_lora_rank +
@@ -22,6 +23,9 @@ up-projection absorbed into the query and the output
 (``_common.latent_attention``). Rope dims pair by halves (``i`` with
 ``i + R/2``: the layout HF permutes the published adjacent pairs to);
 frequencies and the softmax scale follow YaRN as DeepSeek-V3 applies it.
+``rope=False`` (``models/kimi_linear.py``'s latent layers) rotates
+nothing: the "rope" dims stay as one shared, unrotated key part a token
+and the scale is ``(nope + rope)^-1/2``.
 
 Layers are two ``ScannedBlocks`` stacks: ``dense_blocks`` (the leading
 ``first_k_dense`` layers, SwiGLU of ``intermediate_size``) and
@@ -64,12 +68,16 @@ class DeepseekV3Config:
     num_layers: int = 61
     first_k_dense: int = 3
     num_heads: int = 128
-    q_lora_rank: int = 1536
+    # None = one full-rank query projection (``wq``), no low-rank pair
+    q_lora_rank: int | None = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     max_seq_len: int = 4096
+    # False = NoPE (Kimi-Linear's latent layers): the ``qk_rope_head_dim``
+    # dims stay, one shared key part a token, and nothing is rotated
+    rope: bool = True
     rope_base: float = 10000.0
     # YaRN (rope_factor 1 = plain RoPE)
     rope_factor: float = 40.0
@@ -146,10 +154,13 @@ class MLAttention(Module):
             return Linear(n_in, n_out, bias=False, weight_init=w,
                           dtype=dtype, key=keys[i])
 
-        self.wq_a = lin(0, E, cfg.q_lora_rank)
-        self.q_norm = RMSNorm(cfg.q_lora_rank, epsilon=cfg.rms_eps,
-                              dtype=dtype)
-        self.wq_b = lin(1, cfg.q_lora_rank, H * qk)
+        if cfg.q_lora_rank is None:
+            self.wq = lin(0, E, H * qk)
+        else:
+            self.wq_a = lin(0, E, cfg.q_lora_rank)
+            self.q_norm = RMSNorm(cfg.q_lora_rank, epsilon=cfg.rms_eps,
+                                  dtype=dtype)
+            self.wq_b = lin(1, cfg.q_lora_rank, H * qk)
         self.wkv_a = lin(2, E, cfg.kv_lora_rank + cfg.qk_rope_head_dim)
         self.kv_norm = RMSNorm(cfg.kv_lora_rank, epsilon=cfg.rms_eps,
                                dtype=dtype)
@@ -171,6 +182,8 @@ class MLAttention(Module):
     @property
     def scale(self) -> float:
         c = self.cfg
+        if not c.rope:
+            return (c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5
         m = yarn_mscale(c.rope_factor, c.rope_mscale_all_dim)
         return (c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5 * m * m
 
@@ -185,18 +198,24 @@ class MLAttention(Module):
         B, T, _ = x.shape
         H, N, R = c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim
         C = c.kv_lora_rank
-        positions = jnp.arange(T)
-        if index is not None:
-            positions = positions + index
-        cos, sin = self.rope_tables(positions)
+        cos = sin = None
+        if c.rope:
+            positions = jnp.arange(T)
+            if index is not None:
+                positions = positions + index
+            cos, sin = self.rope_tables(positions)
         with jax.named_scope("mla/q"):
-            q = self.wq_b(self.q_norm(self.wq_a(x))).reshape(B, T, H, N + R)
+            q = (self.wq(x) if c.q_lora_rank is None
+                 else self.wq_b(self.q_norm(self.wq_a(x))))
+            q = q.reshape(B, T, H, N + R)
             q_nope = q[..., :N]
-            q_rope = F.apply_rotary(q[..., N:], cos, sin)
+            q_rope = (F.apply_rotary(q[..., N:], cos, sin) if c.rope
+                      else q[..., N:])
         with jax.named_scope("mla/latent"):
             kv = self.wkv_a(x)
             c_kv = self.kv_norm(kv[..., :C])
-            k_rope = F.apply_rotary(kv[..., None, C:], cos, sin)[:, :, 0]
+            k_rope = (F.apply_rotary(kv[..., None, C:], cos, sin)[:, :, 0]
+                      if c.rope else kv[..., C:])
         w = self.wkv_b.weight.reshape(C, H, N + c.v_head_dim)
         out, payload = latent_attention(
             q_nope, q_rope, c_kv, k_rope, w[..., :N], w[..., N:],

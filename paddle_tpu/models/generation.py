@@ -31,7 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["generate", "sample_logits", "beam_search", "init_paged_cache",
-           "PagedCache", "paged_gather", "paged_scatter", "paged_write",
+           "PagedCache", "StateCache", "paged_gather", "paged_scatter",
+           "paged_write",
            "advance_key", "ngram_propose",
            "speculative_generate", "serialize_page", "deserialize_page",
            "STACKED_KV_SPEC", "POOL_KV_SPEC", "PAGE_TABLE_SPEC"]
@@ -196,6 +197,32 @@ class PagedCache:
             s = g.shape
             out.append(g.reshape(s[0], s[1] * s[2], *s[3:])[None])
         return tuple(out)
+
+
+@jax.tree_util.register_pytree_node_class
+class StateCache:
+    """A recurrent layer group's cache (``models/kimi_linear.py``'s KDA
+    layers): state of O(1) a sequence where the other groups keep
+    per-token rows. ``rows`` are the group's leaves ``[L, B, ...]`` —
+    the float32 state ``[L, B, H, dk, dv]`` and the convolution tail
+    (``K-1`` inputs of ``D`` a sequence) — which a forward reads AND
+    replaces, so they go
+    through the layers as a carry and come back whole, in the cache's
+    place. ``length`` tells the recurrence what attention learns from
+    its mask: the chunk's true token count (None = all of it; a scalar
+    or ``[B]``). Positions at or past it are padding and must be the
+    identity on the rows — a prefill bucket's padded tail, and an idle
+    slot of the fused decode step (length 0)."""
+
+    def __init__(self, rows, length=None):
+        self.rows, self.length = tuple(rows), length
+
+    def tree_flatten(self):
+        return (self.rows, self.length), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
 
 
 @jax.named_scope("kv/gather")

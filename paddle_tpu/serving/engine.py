@@ -57,6 +57,23 @@ paged cache, SOSP '23) to the framework's autoregressive path:
   (``FLAGS_gen_prefill_chunk``) admits long prompts in token slices
   interleaved with decode steps, so active streams keep emitting
   during a long prefill instead of stalling behind it.
+- **Layer groups** (a model whose cache is one entry a layer kind,
+  ``cache_groups``). A paged engine holds one full group of pages and,
+  beside it, either a *window* group — a second pool whose rows let go
+  of the pages behind a stream's window (``_WindowGroup``) — or a
+  *state* group: recurrent state of O(1) a sequence (Kimi-Linear's KDA
+  layers), kept a SLOT, not a page: ``[slots, layers, ...]`` rows that
+  every decode step reads and replaces in place (idle and prefilling
+  slots step with length 0, the identity). Pages alone cannot serve a
+  prefix there, so prefix reuse is by **state snapshot**: a prefill
+  chunk that ends on a page boundary writes its end state into a
+  bounded pool (``state_snapshots`` entries, ``_SnapshotPool``; the
+  least recently used evicted), the prefix entry of that page carries
+  the snapshot's id, a match is cut back to the deepest entry that has
+  one, and the admitted stream's FIRST prefill chunk starts from the
+  snapshot's rows (from the zero entry on a miss) — restore, chunk and
+  snapshot are one program a bucket, nothing is dispatched at
+  admission. A finished stream gives its rows back by doing nothing.
 - **Speculative decoding** (``FLAGS_gen_spec_k``, off by default).
   Decode is memory-bandwidth-bound, so the only way past the roofline
   is fewer serial target-model steps: a cheap drafter proposes up to
@@ -123,7 +140,10 @@ histograms, ``gen/spec_proposed`` / ``gen/spec_accepted`` /
 serving ``health`` op next to slot occupancy, so the controller sees
 speculation efficiency), ``gen/tokens`` / ``gen/evictions`` /
 ``gen/prefix_hits`` / ``gen/prefix_tokens_saved`` /
-``gen/prefix_evictions`` / ``gen/traps`` / ``gen/rebuilds`` /
+``gen/prefix_evictions`` / ``gen/state_restores`` /
+``gen/state_snapshots`` / ``gen/state_snapshot_evictions`` (a state
+group's prefix hits, snapshots kept and snapshots evicted) /
+``gen/traps`` / ``gen/rebuilds`` /
 ``gen/stuck`` / ``gen/quarantined`` / ``gen/quarantine_rejected`` /
 ``gen/expired_polls`` counters, and slot + page-pool occupancy in the
 serving ``health`` op. Spans (``core.trace``: recorded while
@@ -131,14 +151,18 @@ serving ``health`` op. Spans (``core.trace``: recorded while
 ``TraceAnnotation`` on the capture's host plane), all on the loop
 thread and none per token: ``gen/loop`` (one iteration; ``queue``,
 ``active``) is the parent of ``gen/idle_wait``, ``gen/admit`` (``gen``,
-``waited_ms``, ``prefix_tokens``, ``pages``; under it ``gen/kv_fetch``),
+``waited_ms``, ``prefix_tokens``, ``pages``; under it ``gen/kv_fetch``
+and, for an admission that restores a state snapshot,
+``gen/state_restore`` with ``slot``, ``snapshot``, ``tokens``),
 ``gen/dev_ops``, ``gen/prefill`` / ``gen/prefill_chunk``,
 ``gen/decode_step`` (``active``, ``spec``, ``compiled``, ``sort_slots``
 — the live slots whose request restricts its sampling, the steps that
 have one counted under ``gen/sample_sorted_steps`` — a plain paged
 step's ``decode_attn``; under it ``gen/step_dispatch`` and
 ``gen/step_wait``, or ``gen/spec_verify`` around both), ``gen/draft``
-and ``gen/emit`` (``emitted``, ``retired``). One helper, ``_phase``,
+and ``gen/emit`` (``emitted``, ``retired``; under a prefill chunk's, for
+a snapshot kept, ``gen/state_snapshot`` with ``tokens``, ``evicted``).
+One helper, ``_phase``,
 times each section with two clock reads that also feed the section's
 histogram and goodput bucket.
 """
@@ -343,7 +367,7 @@ class Generation:
                  "tenant", "admitted_ts", "first_tok_ts", "done_ts",
                  "chip_s", "ledgered", "dev_ops", "pclass", "folded",
                  "queue_booked", "sched_seq", "sched_vft", "sched_ts",
-                 "win", "sorts")
+                 "win", "sorts", "snap_src")
 
     def __init__(self, gen_id: str, prompt: np.ndarray,
                  max_new_tokens: int, temperature: float, top_k: int,
@@ -414,6 +438,10 @@ class Generation:
         # a layer-group engine's window-group row (a _WinRow) while the
         # generation holds a slot; None everywhere else
         self.win = None
+        # a state-group engine: the snapshot the first prefill chunk
+        # starts from (0 = the zero state; None once a chunk has run),
+        # pinned in the snapshot pool until that chunk is dispatched
+        self.snap_src: int | None = None
         self.pclass = "batch"
         self.folded = 0
         self.queue_booked = 0.0
@@ -459,6 +487,52 @@ class _PagePool:
 
     def refcount(self, pid: int) -> int:
         return self._ref[pid]
+
+
+class _SnapshotPool:
+    """Host-side refcounted books of a state group's snapshot pool:
+    ``num`` entries of one slot's state rows each (every KDA layer's
+    state and convolution tail). Usable ids are ``1 .. num``; id 0 is
+    the ZERO snapshot (never written: what a stream with no prefix hit
+    starts from) and ``num + 1`` the scratch entry (where a prefill
+    chunk's end state goes when nobody keeps it). The prefix cache holds
+    one reference for the entry a snapshot hangs on, an admitted stream
+    one more until its first chunk has read it. All methods run under
+    the engine's condition lock."""
+
+    def __init__(self, num: int):
+        self.num = int(num)
+        self.scratch = self.num + 1
+        self._free = list(range(self.num, 0, -1))
+        self._ref = [0] * (self.num + 2)
+
+    @property
+    def free_count(self) -> int:
+        return len(self._free)
+
+    def alloc(self) -> int:
+        """A free entry with one reference, or 0 where none is free."""
+        if not self._free:
+            return 0
+        sid = self._free.pop()
+        self._ref[sid] = 1
+        return sid
+
+    def retain(self, sid: int) -> None:
+        if sid:
+            self._ref[sid] += 1
+
+    def release(self, sid: int) -> None:
+        if not sid:
+            return
+        self._ref[sid] -= 1
+        if self._ref[sid] == 0:
+            self._free.append(sid)
+        elif self._ref[sid] < 0:
+            raise AssertionError(f"snapshot {sid} refcount underflow")
+
+    def refcount(self, sid: int) -> int:
+        return self._ref[sid]
 
 
 class _WinRow:
@@ -616,13 +690,16 @@ class _WindowGroup:
 
 class _PrefixEntry:
     __slots__ = ("key", "page", "parent_page", "children", "last_used",
-                 "wpage")
+                 "wpage", "snap")
 
     def __init__(self, key, page: int, parent_page: int, wpage: int = 0):
         self.key = key
         self.page = page
         # the same prompt page in a layer-group engine's window pool
         self.wpage = wpage
+        # a state-group engine: the snapshot of the recurrent state at
+        # this page's END (0 = none: the page cannot end a match)
+        self.snap = 0
         self.parent_page = parent_page
         self.children = 0
         self.last_used = 0
@@ -643,11 +720,22 @@ class _PrefixCache:
     ``second`` (a layer-group engine's window pool): every entry then
     holds one page of EACH pool for its prompt page, with a refcount of
     its own in both, and leaves with both — the key stays the full
-    group's page id."""
+    group's page id.
 
-    def __init__(self, page_tokens: int, second: _PagePool | None = None):
+    ``snaps`` (a state-group engine's snapshot pool): pages alone
+    cannot serve a prefix there — a stream also needs the recurrent
+    state at the prefix's end. An entry may carry a snapshot of it
+    (``snap``); :meth:`match_state` cuts a match back to the deepest
+    entry that has one, an entry that leaves releases its snapshot, and
+    :meth:`evict_snapshot` takes the least recently used snapshot from
+    an entry that stays (its pages can then only be matched THROUGH, on
+    the way to a deeper snapshot)."""
+
+    def __init__(self, page_tokens: int, second: _PagePool | None = None,
+                 snaps: _SnapshotPool | None = None):
         self._P = int(page_tokens)
         self._second = second
+        self._snaps = snaps
         self._entries: dict[tuple, _PrefixEntry] = {}
         self._by_page: dict[int, _PrefixEntry] = {}
         self._clock = 0
@@ -677,13 +765,54 @@ class _PrefixCache:
             parent = e.page
         return pages
 
+    def match_state(self, prompt: np.ndarray,
+                    pool: _PagePool) -> tuple[list[int], int]:
+        """:meth:`match` cut back to the deepest entry that holds a
+        snapshot: ``(pages, snapshot id)``, both retained for the caller
+        (``([], 0)`` where no entry on the chain has one)."""
+        pages = self.match(prompt, pool)
+        keep = max((i + 1 for i, p in enumerate(pages)
+                    if self._by_page[p].snap), default=0)
+        for pid in pages[keep:]:
+            pool.release(pid)
+        snap = self._by_page[pages[keep - 1]].snap if keep else 0
+        self._snaps.retain(snap)
+        return pages[:keep], snap
+
+    def find(self, prompt: np.ndarray) -> _PrefixEntry | None:
+        """The entry of ``prompt``'s last whole page, if its whole chain
+        is cached (no touch, nothing retained)."""
+        P, parent, e = self._P, 0, None
+        for i in range(int(prompt.size) // P):
+            e = self._entries.get((parent, prompt[i * P:(i + 1) * P]
+                                   .tobytes()))
+            if e is None:
+                return None
+            parent = e.page
+        return e
+
+    def _drop_snapshot(self, e: _PrefixEntry) -> None:
+        self._snaps.release(e.snap)
+        e.snap = 0
+
+    def evict_snapshot(self) -> bool:
+        """Free one snapshot: the least recently used among those no
+        admitted stream still has to read. Its entry stays."""
+        cands = [e for e in self._entries.values()
+                 if e.snap and self._snaps.refcount(e.snap) == 1]
+        if not cands:
+            return False
+        self._drop_snapshot(min(cands, key=lambda c: c.last_used))
+        stat_add("gen/state_snapshot_evictions")
+        return True
+
     def wpages(self, pages: list[int]) -> list[int]:
         """The window-pool pages of matched entries, by their full-pool
         page ids (as :meth:`match` returned them)."""
         return [self._by_page[p].wpage for p in pages]
 
     def insert(self, prompt: np.ndarray, gen_pages: list[int],
-               pool: _PagePool, second=None) -> None:
+               pool: _PagePool, second=None, snap: int = 0) -> None:
         """Register a finished prefill's full prompt pages. Pages whose
         chain key is already cached (matched, or raced by a concurrent
         identical prompt) are touched, not replaced — the generation
@@ -691,9 +820,13 @@ class _PrefixCache:
         layer-group engine): the window-pool page the caller hands over
         for prompt page ``i``, already retained for the cache, or 0 —
         the chain then ends at ``i`` (the stream let that page go, or
-        the pool has none to spare)."""
+        the pool has none to spare). ``snap`` (a state-group engine): a
+        snapshot of the state at ``prompt``'s end, a whole number of
+        pages, with the one reference that now becomes the last entry's
+        (given back where that entry holds a snapshot already)."""
         P = self._P
         parent = 0
+        e = None
         for i in range(int(prompt.size) // P):
             key = (parent, prompt[i * P:(i + 1) * P].tobytes())
             e = self._entries.get(key)
@@ -713,6 +846,11 @@ class _PrefixCache:
                     pe.children += 1
             self._touch(e)
             parent = e.page
+        if snap:
+            if e is None or e.snap:
+                self._snaps.release(snap)
+            else:
+                e.snap = snap
 
     def evict(self, n: int, pool: _PagePool, demote=None) -> int:
         """Free up to ``n`` pages by dropping LRU leaf entries no live
@@ -742,6 +880,8 @@ class _PrefixCache:
             pool.release(e.page)
             if second is not None:
                 second.release(e.wpage)
+            if e.snap:
+                self._drop_snapshot(e)
             freed += 1
         if freed:
             stat_add("gen/prefix_evictions", freed)
@@ -870,6 +1010,11 @@ class GenerationEngine:
     ``generate()`` in both modes, under any co-tenant mix, page reuse,
     and chunked prefill.
 
+    ``state_snapshots`` (a paged engine of a model with a recurrent
+    state group only, see the module docstring): the entries of the
+    snapshot pool that prefix hits restore from, one slot's state rows
+    each.
+
     ``mesh_tp`` defaults to ``FLAGS_gen_mesh_tp`` (0 = no mesh: the
     single-device path, byte-identical to the pre-sharding build). A
     positive degree builds the engine over a tensor-parallel device
@@ -910,7 +1055,7 @@ class GenerationEngine:
                  kv_store=None, role: str | None = None,
                  device_pt: bool | None = None,
                  async_depth: int | None = None,
-                 sched=None):
+                 sched=None, state_snapshots: int = 16):
         if slots is None:
             slots = int(flag("gen_slots"))
         if slots <= 0:
@@ -1119,6 +1264,21 @@ class GenerationEngine:
         # None for every other model, whose programs stay as they were.
         self._groups = self._layer_groups(model)
         self._win: _WindowGroup | None = None
+        # a recurrent state group (``cache_groups`` names its kind
+        # "state": Kimi-Linear's KDA layers) is slot-indexed, not paged:
+        # its host books are the snapshot pool that prefix hits restore
+        # from. None for every other model.
+        self._snaps: _SnapshotPool | None = None
+        if self._paged and self._groups and self._groups[-1][1] == "state":
+            if int(state_snapshots) < 1:
+                raise ValueError(
+                    f"state_snapshots must be >= 1, got {state_snapshots}")
+            self._snaps = _SnapshotPool(int(state_snapshots))
+        # [admissions, those that restored a snapshot]: stats()
+        self._state_admits = [0, 0]
+        # which arm the step's KDA layers took, "kernel" or "xla":
+        # decided where the step is traced, None until then
+        self._kda_step: str | None = None
 
         if self._paged:
             P = int(flag("gen_page_tokens") if page_tokens is None
@@ -1130,7 +1290,9 @@ class GenerationEngine:
             if pages is None:
                 pages = int(flag("gen_pages"))
             # ``pages``: one count, or one a layer group
-            n_groups = len(self._groups or (None,))
+            # (a state group has no pages: one count)
+            n_groups = (1 if self._snaps is not None
+                        else len(self._groups or (None,)))
             per_group = (tuple(int(n) for n in pages)
                          if isinstance(pages, (list, tuple))
                          else (int(pages),) * n_groups)
@@ -1143,13 +1305,14 @@ class GenerationEngine:
                 # equal HBM to the contiguous layout by default
                 npages = self.slots * self._maxp
             self._pool = _PagePool(npages)
-            if self._groups is not None:
+            if self._groups is not None and self._snaps is None:
                 chunk = (self._prefill_chunk if self._prefill_chunk > 0
                          else self.max_len)
                 self._win = _WindowGroup(
                     self._groups[1][1], per_group[1], P, self.slots, chunk,
                     self._maxp)
-            self._prefix = (_PrefixCache(P, self._win and self._win.pool)
+            self._prefix = (_PrefixCache(P, self._win and self._win.pool,
+                                         self._snaps)
                             if (flag("gen_prefix_cache")
                                 if prefix_cache is None else prefix_cache)
                             else None)
@@ -1176,6 +1339,16 @@ class GenerationEngine:
         import jax
         leaves = jax.tree_util.tree_leaves(self._state["cache"])
         kv_bytes = sum(int(x.nbytes) for x in leaves)
+        if self._snaps is not None:
+            # the state group is per slot, not per token: its rows (and
+            # the snapshot pool's) are device bytes of the cache, and
+            # ``bytes_per_slot`` in stats()["groups"]
+            state_rows = jax.tree_util.tree_leaves(self._state["cache"][1])
+            self._state_bytes_per_slot = sum(
+                int(x.nbytes) // self.slots for x in state_rows)
+            kv_bytes += sum(int(x.nbytes) for x in
+                            jax.tree_util.tree_leaves(self._state["snaps"]))
+            leaves = jax.tree_util.tree_leaves(self._state["cache"][0])
         self._device_info = self._layout.describe(kv_bytes)
         # bytes one token position takes in the cache leaves as they are
         # allocated (every layer, every leaf): pool pages x page tokens,
@@ -1248,16 +1421,23 @@ class GenerationEngine:
         groups = getattr(model, "cache_groups", None)
         if groups is None:
             return None
-        groups = tuple((int(n), None if w is None else int(w))
+        groups = tuple((int(n), w if w in (None, "state") else int(w))
                        for n, w in groups)
+        state = any(w == "state" for _, w in groups)
         if not self._paged:
+            if state:
+                raise ValueError(
+                    "a recurrent state group on the contiguous engine is "
+                    "not implemented: its bucketed prefill pads the prompt "
+                    "and hands the model no true length, and a recurrence "
+                    "cannot mask what attention masks; use paged=True")
             return groups            # contiguous: every position, masked
         if (len(groups) != 2 or groups[0][1] is not None
                 or groups[1][1] is None):
             raise ValueError(
                 f"cache groups {groups!r}: the paged engine holds one full "
-                "group followed by one window group; other mixes are not "
-                "implemented")
+                "group followed by one window group or by one state group; "
+                "other mixes are not implemented")
         refused = {
             "gen_spec_k (speculation)": self._spec_k > 0,
             "gen_kv_store / gen_role (the KV store's page frames)":
@@ -1265,13 +1445,21 @@ class GenerationEngine:
             "gen_sched (preemption parks a stream by folding its pages)":
                 self._sched is not None,
         }
+        why = ("layer groups (a window group that frees pages behind a "
+               "stream) is not implemented: a page id no longer covers "
+               "every layer")
+        if state:
+            refused["an int8 cache (cache_dtype)"] = (
+                self._cache_dtype is not None
+                and np.dtype(self._cache_dtype) == np.int8)
+            why = ("a recurrent state group is not implemented: a stream's "
+                   "pages no longer hold all of its cache (a rejected "
+                   "draft cannot be rolled back out of a state, a page "
+                   "frame or a folded prompt carries no snapshot)")
         for what, on in refused.items():
             if on:
                 raise ValueError(
-                    f"{what} with layer groups (a window group that frees "
-                    "pages behind a stream) is not implemented: a page id "
-                    "no longer covers every layer; serve this model "
-                    "without it")
+                    f"{what} with {why}; serve this model without it")
         return groups
 
     def _init_state(self) -> dict[str, Any]:
@@ -1289,6 +1477,17 @@ class GenerationEngine:
             cache = tuple(
                 init_paged_cache(g, pool.num_pages, self._page_tokens)
                 for g, pool in zip(proto, (self._pool, self._win.pool)))
+        elif self._snaps is not None:
+            # the latent group paged as ever; the state group's rows a
+            # slot, slot-major ([slots, L, 1, ...]: a slot's rows are the
+            # model's own layout, and the step's vmap over slots maps
+            # axis 0 of both without a copy), and the snapshot pool in
+            # the same layout
+            from paddle_tpu.models.generation import init_paged_cache
+            cache = (init_paged_cache(proto[0], self._pool.num_pages,
+                                      self._page_tokens),
+                     tuple(jnp.zeros((self.slots,) + r.shape, r.dtype)
+                           for r in proto[1].rows))
         elif self._paged:
             from paddle_tpu.models.generation import init_paged_cache
             cache = init_paged_cache(proto, self._pool.num_pages,
@@ -1310,6 +1509,10 @@ class GenerationEngine:
             # (hi, lo) words of 30 bits a name: exact past 2**31
             state["counts"] = jnp.zeros((len(self._count_names), 2),
                                         jnp.int32)
+        if self._snaps is not None:
+            state["snaps"] = tuple(
+                jnp.zeros((self._snaps.num + 2,) + r.shape, r.dtype)
+                for r in proto[1].rows)
         # commit to the device layout (identity at gen_mesh_tp=0): KV
         # leaves land sharded on the KV-head axis, scalars replicated,
         # matching the explicit shardings every entry point compiles with
@@ -1348,6 +1551,46 @@ class GenerationEngine:
             [state["counts"][:, 0] + (lo >> 30), lo & ((1 << 30) - 1)],
             axis=1))
 
+    def _advance(self, state, cache, logits, keys, subs, cnt, active):
+        """A fused step's end, whatever its cache: the live slots' picks
+        (outside the vmap: the sampler's arm is one scalar), their
+        positions moved on, their counts booked. Returns ``(state,
+        tokens)``."""
+        import jax.numpy as jnp
+
+        nxt, _ = _sample(logits, subs, state["temp"], state["top_k"],
+                         state["top_p"], active)
+        tok = jnp.where(active, nxt, state["tok"])
+        pos = state["pos"] + active.astype(jnp.int32)
+        state = self._counted(state, cnt, active[:, None, None])
+        return dict(state, cache=cache, tok=tok, pos=pos, keys=keys), tok
+
+    def _landed(self, state, logits, cnt, padded, true_len, slot, index,
+                key, temp, top_k, top_p, **leaves):
+        """A prefill's end, whatever its cache: the first token sampled
+        at the last true position of the chunk that started at ``index``
+        and the slot's state recorded as if this were the final chunk (a
+        later chunk overwrites it). ``leaves``: the state entries the
+        program replaced (``cache``, a state group's ``snaps``).
+        Returns ``(state, first token)``."""
+        import jax
+        import jax.numpy as jnp
+
+        key, sub = jax.random.split(key)
+        tok0 = _sample_one(logits[0, true_len - 1], sub, temp, top_k, top_p)
+        state = self._counted(
+            state, cnt, (jnp.arange(padded.shape[0]) < true_len)[:, None])
+        return dict(
+            state, **leaves,
+            tok=state["tok"].at[slot].set(tok0),
+            pos=state["pos"].at[slot].set(index + true_len),
+            keys=state["keys"].at[slot].set(key),
+            temp=state["temp"].at[slot].set(temp),
+            top_k=state["top_k"].at[slot].set(jnp.asarray(top_k,
+                                                          jnp.int32)),
+            top_p=state["top_p"].at[slot].set(top_p),
+        ), tok0
+
     def _build_step(self):
         """ONE fused decode for all slots: vmap the model's single-token
         cached forward over the slot axis with per-slot positions/keys/
@@ -1367,14 +1610,8 @@ class GenerationEngine:
             cache, logits, keys, subs, cnt = jax.vmap(
                 functools.partial(one, model))(
                 state["cache"], state["tok"], state["pos"], state["keys"])
-            # the pick stands outside the vmap: its arm is one scalar
-            nxt, _ = _sample(logits, subs, state["temp"], state["top_k"],
-                             state["top_p"], active)
-            tok = jnp.where(active, nxt, state["tok"])
-            pos = state["pos"] + active.astype(jnp.int32)
-            state = self._counted(state, cnt, active[:, None, None])
-            return dict(state, cache=cache, tok=tok, pos=pos,
-                        keys=keys), tok
+            return self._advance(state, cache, logits, keys, subs, cnt,
+                                 active)
 
         return self._layout.jit_entry(step, self._model, self._state,
                                       paged=False, n_in=1, n_out=1)
@@ -1393,23 +1630,10 @@ class GenerationEngine:
                     top_p):
             b1 = model.init_cache(1, S, dtype=cache_dtype)
             logits, b1, cnt = self._forward(model, padded[None], b1, 0)
-            key, sub = jax.random.split(key)
-            tok0 = _sample_one(logits[0, true_len - 1], sub, temp, top_k,
-                               top_p)
             cache = jax.tree_util.tree_map(
                 lambda big, sm: big.at[slot].set(sm), state["cache"], b1)
-            state = self._counted(
-                state, cnt, (jnp.arange(padded.shape[0]) < true_len)[:, None])
-            return dict(
-                state, cache=cache,
-                tok=state["tok"].at[slot].set(tok0),
-                pos=state["pos"].at[slot].set(true_len),
-                keys=state["keys"].at[slot].set(key),
-                temp=state["temp"].at[slot].set(temp),
-                top_k=state["top_k"].at[slot].set(jnp.asarray(top_k,
-                                                              jnp.int32)),
-                top_p=state["top_p"].at[slot].set(top_p),
-            ), tok0
+            return self._landed(state, logits, cnt, padded, true_len, slot,
+                                0, key, temp, top_k, top_p, cache=cache)
 
         return self._layout.jit_entry(prefill, self._model, self._state,
                                       paged=False, n_in=7, n_out=1)
@@ -1461,6 +1685,8 @@ class GenerationEngine:
         P, maxp = self._page_tokens, self._maxp
         slots = self.slots
         grouped = self._win is not None
+        if self._snaps is not None:
+            return self._build_state_step()
 
         def one(model, pt_row, tok, idx, key, pool):
             cache = (self._group_caches(pool, pt_row) if grouped
@@ -1489,16 +1715,104 @@ class GenerationEngine:
                 pidx = jnp.clip(state["pos"] // P, 0, maxp - 1)
                 pages = jnp.where(active, pt[jnp.arange(slots), pidx], 0)
                 pool = paged_write(pool, pages, state["pos"] % P, new)
-            nxt, _ = _sample(logits, subs, state["temp"], state["top_k"],
-                             state["top_p"], active)
-            tok = jnp.where(active, nxt, state["tok"])
-            pos = state["pos"] + active.astype(jnp.int32)
-            state = self._counted(state, cnt, active[:, None, None])
-            return dict(state, cache=pool, tok=tok, pos=pos,
-                        keys=keys), tok
+            return self._advance(state, pool, logits, keys, subs, cnt,
+                                 active)
 
         return self._layout.jit_entry(step, self._model, self._state,
                                       paged=True, n_in=2, n_out=1)
+
+    def _build_state_step(self):
+        """The paged step of a model with a state group: the latent
+        group through its ``PagedCache`` as :meth:`_build_paged_step`
+        does it, and each slot's state rows mapped beside its table row
+        — a slot's rows ARE the model's layout, so the vmap's axis is
+        the rows' first and the KDA step (``ops.kda.kda_step``: the
+        kernel's own batching rule folds the slot axis) updates the
+        donated group in place. An idle or still-prefilling slot steps
+        with a length of 0: padding, which is the identity on its rows
+        (a prefill between two of its chunks must find them as it left
+        them)."""
+        import jax
+        import jax.numpy as jnp
+
+        from paddle_tpu.models._common import paged_attn_arms
+        from paddle_tpu.models.generation import (PagedCache, StateCache,
+                                                  paged_write)
+        from paddle_tpu.ops.kda import step_arms
+
+        P, maxp, slots = self._page_tokens, self._maxp, self.slots
+
+        def one(model, pt_row, tok, idx, key, rows, live, pool):
+            cache = (PagedCache(pool, pt_row), StateCache(rows, live))
+            logits, (new, st), cnt = self._forward(
+                model, tok[None, None], cache, idx)
+            key, sub = jax.random.split(key)
+            new = jax.tree_util.tree_map(lambda n: n[:, 0, :, 0], new)
+            return logits[0, -1], key, sub, new, st.rows, cnt
+
+        def step(model, state, pt, active):
+            pool, rows = state["cache"]
+            kernel_arms = paged_attn_arms["paged_kernel"]
+            kda_kernel = step_arms["kernel"]
+            logits, keys, subs, new, rows, cnt = jax.vmap(
+                functools.partial(one, model),
+                in_axes=(0, 0, 0, 0, 0, 0, None))(
+                pt, state["tok"], state["pos"], state["keys"], rows,
+                active.astype(jnp.int32), pool)
+            self._decode_attn = (
+                "paged_kernel"
+                if paged_attn_arms["paged_kernel"] > kernel_arms
+                else "gather")
+            self._kda_step = ("kernel" if step_arms["kernel"] > kda_kernel
+                              else "xla")
+            pidx = jnp.clip(state["pos"] // P, 0, maxp - 1)
+            pages = jnp.where(active, pt[jnp.arange(slots), pidx], 0)
+            pool = paged_write(pool, pages, state["pos"] % P, new)
+            return self._advance(state, (pool, rows), logits, keys, subs,
+                                 cnt, active)
+
+        return self._layout.jit_entry(step, self._model, self._state,
+                                      paged=True, n_in=2, n_out=1)
+
+    def _build_state_prefill(self):
+        """A prefill chunk of a model with a state group:
+        :meth:`_build_paged_prefill`'s chunk with the slot's state rows
+        beside its pages, and two operands more. ``src``: where the
+        rows the chunk starts from lie — ``-1`` the slot's own (a later
+        chunk), else a snapshot id (0 = the zero snapshot: no prefix
+        hit). ``dst``: the snapshot that keeps the chunk's end state
+        (the pool's scratch entry where nobody does). So an admission's
+        restore or zeroing, the chunk and the snapshot are ONE program a
+        bucket, warmed with it."""
+        import jax
+        import jax.numpy as jnp
+
+        from paddle_tpu.models.generation import (PagedCache, StateCache,
+                                                  paged_scatter)
+
+        P = self._page_tokens
+
+        def prefill(model, state, pt, slot, padded, index, true_len, key,
+                    temp, top_k, top_p, src, dst):
+            pool, rows = state["cache"]
+            snaps = state["snaps"]
+            start = tuple(
+                jnp.where(src >= 0, s[jnp.maximum(src, 0)], r[slot])
+                for r, s in zip(rows, snaps))
+            row = pt[slot]
+            logits, (chunk, st), cnt = self._forward(
+                model, padded[None],
+                (PagedCache(pool, row), StateCache(start, true_len)), index)
+            pool = paged_scatter(pool, row, chunk, index, P,
+                                 length=true_len)
+            rows = tuple(r.at[slot].set(n) for r, n in zip(rows, st.rows))
+            snaps = tuple(s.at[dst].set(n) for s, n in zip(snaps, st.rows))
+            return self._landed(state, logits, cnt, padded, true_len, slot,
+                                index, key, temp, top_k, top_p,
+                                cache=(pool, rows), snaps=snaps)
+
+        return self._layout.jit_entry(prefill, self._model, self._state,
+                                      paged=True, n_in=11, n_out=1)
 
     def _build_paged_prefill(self):
         """Prefill ONE chunk of one slot's prompt (compiled per padded
@@ -1516,6 +1830,8 @@ class GenerationEngine:
         from paddle_tpu.models.generation import PagedCache, paged_scatter
 
         P = self._page_tokens
+        if self._snaps is not None:
+            return self._build_state_prefill()
 
         def prefill(model, state, pt, slot, padded, index, true_len, key,
                     temp, top_k, top_p):
@@ -1537,21 +1853,8 @@ class GenerationEngine:
                     model, padded[None], PagedCache(pool, row), index)
                 pool = paged_scatter(pool, row, chunk, index, P,
                                      length=true_len)
-            key, sub = jax.random.split(key)
-            tok0 = _sample_one(logits[0, true_len - 1], sub, temp, top_k,
-                               top_p)
-            state = self._counted(
-                state, cnt, (jnp.arange(padded.shape[0]) < true_len)[:, None])
-            return dict(
-                state, cache=pool,
-                tok=state["tok"].at[slot].set(tok0),
-                pos=state["pos"].at[slot].set(index + true_len),
-                keys=state["keys"].at[slot].set(key),
-                temp=state["temp"].at[slot].set(temp),
-                top_k=state["top_k"].at[slot].set(jnp.asarray(top_k,
-                                                              jnp.int32)),
-                top_p=state["top_p"].at[slot].set(top_p),
-            ), tok0
+            return self._landed(state, logits, cnt, padded, true_len, slot,
+                                index, key, temp, top_k, top_p, cache=pool)
 
         return self._layout.jit_entry(prefill, self._model, self._state,
                                       paged=True, n_in=9, n_out=1)
@@ -1784,6 +2087,8 @@ class GenerationEngine:
         if self._paged:
             pt = self._pt_upload(jnp)
             prefill = (self._state, pt, i32, padded, i32, i32, *sampling)
+            if self._snaps is not None:
+                prefill += (i32, i32)        # source and kept snapshot
             decode = (self._state, pt, active)
         else:
             prefill = (self._state, i32, padded, i32, *sampling)
@@ -2141,6 +2446,8 @@ class GenerationEngine:
                    "kv_bytes_per_token": self._kv_bytes_per_token}
             if self._paged:
                 doc["decode_attn"] = self._decode_attn
+            if self._snaps is not None:
+                doc["kda_step"] = self._kda_step
             # the model's live counts (absent for a model that names
             # none): monotone, summed on the device over live positions
             doc.update(counts)
@@ -2185,6 +2492,21 @@ class GenerationEngine:
                      "pages_slid": win.slid}]
                 doc["pages"] += win.pool.num_pages
                 doc["pages_free"] += win.pool.free_count
+            if self._snaps is not None:
+                # the paged group as a layer-group engine names it, and
+                # the slot-indexed state group with its snapshot pool
+                doc["groups"] = [
+                    {"name": "full", "layers": self._groups[0][0],
+                     "pages": doc["pages"], "pages_free": doc["pages_free"],
+                     "stream_pages_max": max(
+                         (len(g.pages) for g in self._slot_gen
+                          if g is not None), default=0)},
+                    {"name": "state", "layers": self._groups[1][0],
+                     "bytes_per_slot": self._state_bytes_per_slot,
+                     "snapshots": self._snaps.num,
+                     "snapshots_free": self._snaps.free_count,
+                     "admissions": self._state_admits[0],
+                     "restores": self._state_admits[1]}]
             # performance attribution (FLAGS_gen_ledger only): the loop
             # goodput taxonomy and per-tenant books ride health's
             # generators block, so MetricsHub rolls them up fleet-wide
@@ -2650,9 +2972,12 @@ class GenerationEngine:
         self._pool = _PagePool(self._pool.num_pages)
         if self._win is not None:
             self._win.reset()
+        if self._snaps is not None:
+            self._snaps = _SnapshotPool(self._snaps.num)
         if self._prefix is not None:
             self._prefix = _PrefixCache(self._page_tokens,
-                                        self._win and self._win.pool)
+                                        self._win and self._win.pool,
+                                        self._snaps)
 
     def _pages_free(self) -> int:
         """Free pages of the pool — of both groups' pools together."""
@@ -2675,6 +3000,12 @@ class GenerationEngine:
         if gen.win is not None:
             self._win.release(gen.win)
             gen.win = None
+        if gen.snap_src:
+            # left before its first chunk: the pin goes; the slot's rows
+            # are given back by doing nothing (the next admission's
+            # first chunk starts from its own snapshot)
+            self._snaps.release(gen.snap_src)
+        gen.snap_src = None
         if self._paged and gen.pages:
             # drop this generation's references; pages the prefix cache
             # also holds stay allocated (shareable) until evicted
@@ -2804,7 +3135,11 @@ class GenerationEngine:
                     need = -(-(gen.prompt.size + gen.max_new_tokens
                                - gen.folded + self._spec_k) // P)
                     matched: list[int] = []
-                    if self._prefix is not None:
+                    snap = 0        # a state group: the hit's snapshot
+                    if self._prefix is not None and self._snaps is not None:
+                        matched, snap = self._prefix.match_state(
+                            gen.prompt, self._pool)
+                    elif self._prefix is not None:
                         matched = self._prefix.match(gen.prompt, self._pool)
                     if (self._kv is not None and self._kv_fetch
                             and self._prefix is not None):
@@ -2849,6 +3184,8 @@ class GenerationEngine:
                             or (win is not None and wneed > win.spare())):
                         for pid in matched:     # give the hits back; retry
                             self._pool.release(pid)   # when pages free up
+                        if snap:
+                            self._snaps.release(snap)
                         if (self._plan is not None
                                 and self._plan.hol_window > 0
                                 and self._hol_bypass_locked()):
@@ -2866,6 +3203,17 @@ class GenerationEngine:
                             if matched else [], need)
                     self._slot_gen[slot] = gen
                     gen.slot = slot
+                    if self._snaps is not None:
+                        # the first chunk's program starts from this
+                        # snapshot (0: from zeros); pinned until then
+                        gen.snap_src = snap
+                        self._state_admits[0] += 1
+                        self._state_admits[1] += bool(snap)
+                        if snap:
+                            with self._phase("gen/state_restore",
+                                             slot=slot, snapshot=snap,
+                                             tokens=len(matched) * P):
+                                stat_add("gen/state_restores")
                     self._note_admitted_locked(gen, ph)
                     ph.set(prefix_tokens=len(matched) * P,
                            pages=len(gen.pages))
@@ -3245,6 +3593,38 @@ class GenerationEngine:
             gen.prompt[:b], gen.pages, self._pool,
             second=lambda i: win.hand_to_cache(gen.win, i))
 
+    def _snapshot_for_locked(self, gen: Generation, b: int) -> tuple[int, int]:
+        """Before a prefill chunk that ends at position ``b`` is
+        dispatched: ``(the snapshot that will keep its end state or 0,
+        whether one was evicted for it)``. A snapshot is taken where a
+        chunk ends on a page boundary and no entry keeps that state yet;
+        with no entry free the least recently used one goes, and where
+        every snapshot is pinned the chunk's state is not kept.
+        ``_cond`` held."""
+        if self._prefix is None or b % self._page_tokens:
+            return 0, 0
+        e = self._prefix.find(gen.prompt[:b])
+        if e is not None and e.snap:
+            return 0, 0
+        sid = self._snaps.alloc()
+        if sid:
+            return sid, 0
+        if self._prefix.evict_snapshot():
+            return self._snaps.alloc(), 1
+        return 0, 0
+
+    def _keep_snapshot_locked(self, gen: Generation, b: int, sid: int,
+                              evicted: int) -> None:
+        """The chunk that wrote snapshot ``sid`` (the state at position
+        ``b``) has been dispatched: the prompt's pages below ``b`` enter
+        the prefix cache, the last one's entry holding the snapshot — a
+        prompt's pages are entered only up to its deepest snapshot, the
+        ones past it could never be hit. ``_cond`` held."""
+        with self._phase("gen/state_snapshot", tokens=b, evicted=evicted):
+            self._prefix.insert(gen.prompt[:b], gen.pages, self._pool,
+                                snap=sid)
+            stat_add("gen/state_snapshots")
+
     def _prefill_tick(self) -> bool:
         """Advance every prefilling slot by ONE chunk (then the loop
         runs a decode step — chunked prefill interleaves with decode
@@ -3274,9 +3654,18 @@ class GenerationEngine:
                                     for s, g in work])
             pt_dev = None if not work else self._pt_device_locked(jnp)
             epoch0 = self._epoch
+            # a state group: the snapshot each chunk's end state goes to
+            kept = ({s: self._snapshot_for_locked(g, chunk_of(g)[2])
+                     for s, g in work} if self._snaps is not None else {})
         ticked = False
         for slot, gen in work:
             T0, a, b = chunk_of(gen)
+            state_ops, (keep, evicted) = (), kept.get(slot, (0, 0))
+            if self._snaps is not None:
+                src = -1 if gen.snap_src is None else gen.snap_src
+                state_ops = (jnp.asarray(src, jnp.int32),
+                             jnp.asarray(keep or self._snaps.scratch,
+                                         jnp.int32))
             final = b >= T0
             smax = self._maxp * self._page_tokens
             # cap the padded length so the traced write window stays in
@@ -3298,7 +3687,7 @@ class GenerationEngine:
                         jnp.asarray(slot, jnp.int32), jnp.asarray(padded),
                         jnp.asarray(a, jnp.int32),
                         jnp.asarray(b - a, jnp.int32), key,
-                        temp, top_k, top_p)
+                        temp, top_k, top_p, *state_ops)
                     tok0 = int(tok0) if final else None
             except Exception as e:       # a prefill trap implicates
                 self._note_trap([gen], e, exact=True)  # exactly this one
@@ -3313,8 +3702,16 @@ class GenerationEngine:
             ticked = True
             with self._phase("gen/emit", emitted=int(final)), self._cond:
                 if self._slot_gen[slot] is not gen:
-                    continue                # cancelled/reaped mid-chunk
+                    if keep:                # cancelled/reaped mid-chunk
+                        self._snaps.release(keep)
+                    continue
                 gen.prefill_pos = b
+                if self._snaps is not None:
+                    # the chunk has read its source: the pin goes
+                    self._snaps.release(gen.snap_src or 0)
+                    gen.snap_src = None
+                    if keep:
+                        self._keep_snapshot_locked(gen, b, keep, evicted)
                 if self._win is not None and self._prefix is not None:
                     # a window row holds a prompt page only until the
                     # stream has passed it: the cache takes both pages
@@ -3327,7 +3724,7 @@ class GenerationEngine:
                         chunk.t1 * 1e-9 - gen.prefill_t0)
                 if gen.win is not None:
                     gen.win.pos = int(T0)
-                elif self._prefix is not None:
+                elif self._prefix is not None and self._snaps is None:
                     self._prefix.insert(gen.prompt, gen.pages, self._pool)
                 if self._kv is not None:
                     self._kv_publish(gen)

@@ -80,6 +80,22 @@ def test_window_phase_off_the_chip():
     assert got["probe_repeat"]["same_programs_identical"]
 
 
+def test_state_phase_off_the_chip():
+    """The state-group engine's phase on the CPU (float32): the streams
+    finish, a snapshot is restored, the stream that restores one equals
+    the cold one, pages and snapshots come back whole."""
+    got = cs.state_phase(
+        cs.make_requests(256, (44, 40, 52, 50), (10, 6, 10, 6),
+                         shared_prefix=32), slots=4, max_len=96, chunk=16,
+        on_chip=False)
+    assert got["decode_attn"] == "gather" and got["kda_step"] == "xla"
+    assert got["streams"] == 4 and got["restores"] > 0
+    assert got["kernels"] == {"decode": []}
+    repeat = got["probe_repeat"]
+    assert repeat["through_prefix_cache_agrees_for"] == "10/10 tokens"
+    assert repeat["same_programs_identical"]
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("kernels", ["off", "interpreted"])
 def test_parity_phase_tiny(kernels):

@@ -428,3 +428,41 @@ def test_step_compiled_for_v5e_keeps_the_pool_in_place(one_chip, monkeypatch,
     assert len(wide) == 1 and "sample/cond/branch_2" in wide[0], wide
     assert sum(" conditional(" in line and "sample/cond" in line
                for line in hlo.splitlines()) == 1
+
+
+def test_kda_step_kernel_compiled_for_v5e_keeps_the_state_in_place(
+        one_chip, monkeypatch):
+    """``ptpu_kda_step`` at Kimi-Linear's published widths (32 heads of
+    128 x 128, float32) under the engine's ``vmap`` over slots, the
+    layer a traced index into a stacked state: Mosaic takes the
+    transposed tile of dk-vectors and the 16-head blocks, the call is
+    ONE with the slots in its grid, and the donated state is aliased —
+    no temporary and no copy of it."""
+    from paddle_tpu.ops import kda
+
+    monkeypatch.setattr(_support, "on_tpu", lambda: True)
+    slots, layers, H, D = 8, 3, 32, 128
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(rows, layer, *vecs):
+        return jax.vmap(lambda r, *x: kda.kda_step(r, layer, *x))(
+            rows, *vecs)
+
+    state = sds((slots, layers, 1, H, D, D))
+    arms = kda.step_arms["kernel"]
+    lowered = jax.jit(step, donate_argnums=(0,)).trace(
+        state, sds((), jnp.int32), *[sds((slots, 1, H, D))] * 4,
+        sds((slots, 1, H))).lower(lowering_platforms=("tpu",))
+    assert kda.step_arms["kernel"] == arms + 1
+    assert lowered.as_text().count("ptpu_kda_step") >= 1
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    nbytes = slots * layers * H * D * D * 4
+    assert mem.alias_size_in_bytes >= nbytes
+    assert mem.temp_size_in_bytes < nbytes // (slots * layers)
+    leaf = f"[{slots},{layers},{H},{D},{D}]"
+    for line in compiled.as_text().splitlines():
+        if " copy(" in line:
+            assert leaf not in line, line
