@@ -712,7 +712,10 @@ def window_phase(requests, *, slots: int, max_len: int, window: int,
     two through a shared prefix longer than the window. The window
     group has to slide (``gen/kv_pages_slid``), both pools have to come
     back whole, and on the chip every layer of the step attends through
-    ``ptpu_paged_decode_attn``. The probe's tokens are held to solo
+    ``ptpu_paged_decode_attn`` in its copy form (pages of 2 KV heads x
+    16 tokens x 128: narrower than a lane tile, whole-lane rows — the
+    form refuses the 64-wide rows this phase had before PR 36, see
+    ``paged_decode_attention.supported``). The probe's tokens are held to solo
     ``generate()`` off the chip (float32); in bf16 programs of other
     shapes round differently, so the chip reports the agreement."""
     import jax
@@ -726,7 +729,7 @@ def window_phase(requests, *, slots: int, max_len: int, window: int,
 
     paddle_tpu.seed(6)
     cfg = SmallThinkerConfig.tiny(
-        hidden_size=256, num_heads=4, num_kv_heads=2, head_dim=64,
+        hidden_size=256, num_heads=4, num_kv_heads=2, head_dim=128,
         moe_intermediate_size=128, sliding_window=window,
         max_seq_len=max_len, dtype="bfloat16" if on_chip else "float32")
     model = SmallThinkerForCausalLM(cfg)
@@ -740,9 +743,9 @@ def window_phase(requests, *, slots: int, max_len: int, window: int,
         text = engine.lowered_text(max(len(r.prompt) for r in requests))
         arm = engine.stats()["decode_attn"]
         if on_chip:
-            check(arm == "paged_kernel",
+            check(arm == "paged_copy_kernel",
                   f"window paged step attends by {arm}, expected "
-                  "paged_kernel")
+                  "paged_copy_kernel")
             absent = _missing(text["decode"], DECODE_KERNELS["paged"])
             check(not absent, f"window engine lowered without {absent}")
         tokens, repeat = _drive(server.endpoint, "window", engine, requests,
@@ -769,7 +772,7 @@ def window_phase(requests, *, slots: int, max_len: int, window: int,
                 "engine_agrees_with_solo_generate_for":
                     f"{agree}/{probe.new_tokens} tokens",
                 "kernels": {"decode": list(DECODE_KERNELS["paged"])
-                            if arm == "paged_kernel" else []}}
+                            if arm == "paged_copy_kernel" else []}}
     finally:
         server.stop()
 

@@ -34,12 +34,24 @@ def causal_lm_loss(model, head_weight, input_ids, labels,
 # How a paged program's attention read its cache, decided when the
 # program is traced and counted there (as ``ops.pallas.partition_stats``
 # counts its units), by ``cached_attention`` and ``latent_attention``
-# alike: ``"paged_kernel"`` — ``ptpu_paged_decode_attn`` or, over the
-# latent leaf, ``ptpu_paged_latent_decode_attn``, through the page table
-# — or ``"gather"`` — one layer's pages gathered and the einsum lines.
+# alike: ``"paged_copy_kernel"`` — ``ptpu_paged_decode_attn`` in the form
+# that copies K/V pages narrower than a lane tile into VMEM itself;
+# ``"paged_kernel"`` — the same kernel's block-spec form or, over the
+# latent leaf, ``ptpu_paged_latent_decode_attn``, through the page table;
+# or ``"gather"`` — one layer's pages gathered and the einsum lines.
 # The engine reads the difference around the trace of its step
 # (``GenerationEngine.stats()["decode_attn"]``).
 paged_attn_arms: collections.Counter = collections.Counter()
+
+
+def attn_arm_since(before) -> str:
+    """The arm a program traced since ``before`` (a copy of
+    ``paged_attn_arms``) attends by: a kernel form if a layer took one,
+    the copy form first."""
+    for arm in ("paged_copy_kernel", "paged_kernel"):
+        if paged_attn_arms[arm] > before[arm]:
+            return arm
+    return "gather"
 
 
 def _quant_chunk(x):
@@ -93,16 +105,25 @@ def cached_attention(q, k, v, cache, index, layer=0, window=None):
     - a ``generation.PagedCache`` — the page pool (either leaf set) and
       one sequence's page-table row, B = 1. A one-token chunk the paged
       kernel supports (``paged_decode_attention.supported``: one TPU
-      chip, lane-aligned pages) is attended by ``ptpu_paged_decode_attn``
-      through the row, live pages only; under the engine's ``vmap`` over
-      slots that is one call for all slots (the kernel's own batching
-      rule). Everything else — a prefill chunk or verify window, the
+      chip) is attended by ``ptpu_paged_decode_attn`` through the row,
+      live pages only; under the engine's ``vmap`` over slots that is
+      one call for all slots (the kernel's own batching rule). The
+      leaves' shape picks the kernel's form
+      (``paged_decode_attention.copies_pages``): float pages narrower
+      than a lane tile (SmallThinker's 4 KV heads x 16 tokens) are
+      copied into VMEM by the kernel itself, a block of 64 pages to one
+      wait — 8.9 ms a step of the window cell, 69 % of the chip's
+      bandwidth, where block specs took 36.7 — and pages of whole lane tiles
+      (OLMoE's 16 heads x 16: 3.1 ms a step, 55 % of the chip's
+      bandwidth) and the interpreter's int8 pool arrive through block
+      specs. Everything else — a prefill chunk or verify window, the
       CPU, a multi-device mesh, other shapes — gathers this layer's
       pages through the row (``PagedCache.read_layer``) for the einsum
       lines below, which are also what the tests hold the kernel to.
       Either way a paged program never holds a sequence's all-layers
-      view. Which of the two a trace took is counted in
-      ``paged_attn_arms``.
+      view. Which of the three a trace took is counted in
+      ``paged_attn_arms`` (``"paged_copy_kernel"``, ``"paged_kernel"``,
+      ``"gather"``).
 
     The [..., Hkv, S, D] layout (heads ahead of sequence) matters on
     TPU: the decode attention contracts D and batches (B, Hkv), so S×D
@@ -152,7 +173,8 @@ def cached_attention(q, k, v, cache, index, layer=0, window=None):
     if paged:
         from paddle_tpu.ops.pallas import paged_decode_attention as _pk
         if _pk.supported(q, bufs, cache.table[None]):
-            paged_attn_arms["paged_kernel"] += 1
+            paged_attn_arms["paged_copy_kernel" if _pk.copies_pages(bufs)
+                            else "paged_kernel"] += 1
             edge = ({} if window is None
                     else {"window": window, "base": cache.base})
             out = _pk.paged_decode_attention(

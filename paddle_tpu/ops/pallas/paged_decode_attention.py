@@ -3,39 +3,75 @@
 What the paged serving engine's decode step attends with on one TPU
 chip (``models._common.cached_attention`` dispatches here for a
 one-token chunk on a ``PagedCache`` when :func:`supported` holds,
-``latent_attention`` when :func:`latent_supported` does: two kernel
+``latent_attention`` when :func:`latent_supported` does: three kernel
 bodies, one batching rule, told apart by the pool's leaves). The
 other arm gathers every page of a slot's table row — capacity, not
 fill — into a per-layer contiguous view
 (``models.generation.PagedCache.read_layer``) for the einsum lines of
 ``cached_attention``. This kernel deletes that per-layer copy the same
 way ``decode_attention`` deleted the per-layer ``lax.scan`` slice — the
-page indirection moves INTO the pallas index maps. The scalar-prefetch
-row carries ``[layer, index, table...]``, and a page block's index map
+page indirection moves INTO the kernel, which reads the slot's
+device-resident page table from its scalar-prefetch row ``[layer,
+index, (lo,) table...]``, so the persistent HBM (the pool) is the only
+cache the kernel ever touches, and only its live pages. Which of the
+two K/V forms a pool takes is a function of its leaves' shape
+(:func:`copies_pages`), read where the program is traced.
+
+The block-spec form (:func:`raw_call`; pages of whole lane tiles,
+``Hkv * P`` a multiple of 128 — OLMoE's 16 KV heads x 16 tokens — and
+the int8 pool). A page block's index map
 
     page id = sp_ref[b, 2 + min((j - 1) * K + i, last_live_page)]
 
-reads the slot's device-resident page table directly — grid step ``j``
-DMAs the K physical pages ``table[(j - 1) * K : j * K]`` of the pool
-(every pool leaf is an operand K times over, operand ``i`` mapped to
-the step's ``i``-th page; K from :func:`_pages_per_step`), so the
-persistent HBM (the pool) is the only cache the kernel ever touches,
-and only its live pages. Pages past the filled prefix repeat the last
+— grid step ``j`` DMAs the K physical pages ``table[(j - 1) * K : j *
+K]`` of the pool (every pool leaf is an operand K times over, operand
+``i`` mapped to the step's ``i``-th page; K from
+:func:`_pages_per_step`). Pages past the filled prefix repeat the last
 live page id and Mosaic elides the repeated DMA, exactly the
 stacked-layer clamp trick. A step's K pages are K independent dots on
 either side of one softmax update, which is what lets the core's
 matrix units work side by side: one page a step — the first form, a
 chain of dot, update, dot — kept one unit busy at 0.55 µs a page where
-the page's bytes take 0.16.
+the page's bytes take 0.16. A page costs two pipeline DMAs whatever its
+size: ~0.28 µs on the v5e, which a 128 KB page of 16 heads hides (3.1
+ms a decode step of the OLMoE cell, 55 % of the chip's bandwidth) and a
+32 KB page of 4 heads does not (36.7 ms a step of the window cell, 17 %
+of its roofline, whatever K).
+
+The copy form (:func:`raw_copy_call`; float pages narrower than a lane
+tile — SmallThinker's 4 KV heads x 16 tokens = 64 rows). The two leaves
+stay unblocked in HBM (``pl.ANY``) and the kernel copies pages itself,
+as the latent body below does for its one leaf: a page of one layer is
+a contiguous ``[Hkv, P, D]`` slab a leaf, ONE ``make_async_copy`` into
+its ``Hkv * P`` rows of a block's buffer — rows in the order (page,
+head, offset) — :func:`_pages_per_block` pages a block, contiguous in
+VMEM BEFORE the dot; a whole block is KP straight-line starts and one
+wait a leaf on a byte-counting semaphore (only a row's first and last
+live block loop over a count), started one block ahead of its use
+across grid steps and across slots. Pages past the fill are not copied;
+with a window, pages wholly before ``lo // P`` are not copied either
+and blocks wholly before it take no grid step. Then one ``[Hq, D] x
+[KP * Hkv * P, D]^T`` product, the head / fill / window mask, one
+online-softmax update in float32, one ``[Hq, KP * Hkv * P] x [KP * Hkv
+* P, D]`` product. Read on the v5e at the window cell's shapes (48 slots x
+12.4-13.6 k positions on 2 full layers and 4 096 on 6 window layers,
+152 k pages of 32 KB a step, ten steps chained in one program): 8.9 ms
+a step at 64 pages a block where the block-spec form took 36.7 — 5.0 GB
+at 563 GB/s, 69 % of the chip's bandwidth; 32 pages a block 10.1, 16
+12.6, 128 8.5 (twice the VMEM). At OLMoE's shapes (pages of 16 heads,
+16 a block) it read 1.70 ms a step beside the block-spec form's 3.22:
+recorded, not routed — whether one K/V body can serve both is the next
+question.
 
 Everything else is the ``decode_attention`` recipe on a page-shaped
-block: the fresh token's raw k/v joins the streaming softmax as grid
-step 0; pages stream K a step after it with positions ``>= index`` masked
-(position ``p`` lives in page ``p // P`` at offset ``p % P``, matching
-``paged_gather``'s view); one block-diagonal all-heads dot per page;
-int8 pool scales fold into the logit/prob planes so HBM traffic stays
-the int8 bytes (interpreter only so far: compiled for the TPU the gate
-sends the int8 pool to the gather arm, see :func:`supported`).
+block, in both forms: the fresh token's raw k/v joins the streaming
+softmax as grid step 0; pages stream after it with positions ``>=
+index`` masked (position ``p`` lives in page ``p // P`` at offset ``p %
+P``, matching ``paged_gather``'s view); one block-diagonal all-heads
+dot; int8 pool scales fold into the logit/prob planes so HBM traffic
+stays the int8 bytes (block-spec form, interpreter only so far:
+compiled for the TPU the gate sends the int8 pool to the gather arm,
+see :func:`supported`).
 
 Pool layout contract matches ``models.generation.init_paged_cache``:
 k/v leaves ``[num_pages + 1, L, Hkv, P, D]`` (page id 0 = the reserved
@@ -60,7 +96,7 @@ shared rope key side by side, padded to whole lane tiles
 (``init_latent_cache``) — and a page of one layer is ``[16, 640]`` =
 20 KB where a K/V page is 64 KB a leaf. The K/V form would read every
 page twice (the row is key AND value), take 8x the grid steps and hand
-the matrix units 16-token stationary operands. So the second kernel
+the matrix units 16-token stationary operands. So the third kernel
 body leaves the pool unblocked in HBM (``pl.ANY``) and copies pages
 itself: a block of :func:`_latent_pages_per_block` live pages, one
 ``make_async_copy`` a page into its 16-row place of a ``[KP * 16, W]``
@@ -77,20 +113,25 @@ the latent cell's shapes (64 slots x 6.3-7.0 k rows, 5 layers): 5.4 ms
 a decode step in the cell's trace where the gather and the einsum lines
 took 22.7; chained alone in one program 6.0 — the copies alone 4.1
 (2.7 GB at 650 GB/s, whatever the page placement), the products alone
-3.3.
+3.3. (The K/V copy form keeps a body of its own: two leaves, a lower
+edge and a row order the latent body has no use for, and the latent
+step's program was to stay as it is.)
 
 Status: interpreter-mode tests (``tests/test_paged_decode_attention.py``,
-``tests/test_paged_latent_attention.py``) pin each kernel to its gather
-arm per slot, under ``jax.vmap``, and the K/V one for the int8 4-leaf
-layout; ``tests/test_paged_kernel_step.py`` holds the engine's step on
-this arm to the gather arm for both model families (tokens in float32,
-logits in bf16: the online softmax orders every sum differently from
-the einsum arm's joint f32 softmax) and compiles both for the v5e.
-Off-TPU callers take the gather arm (``dispatch_mode()`` is ``"off"``).
-Multi-device meshes do too (no ``_partition`` unit yet — the pool's
-KV-head shard would need a per-shard grid), as do prefill chunks and
-speculative verify windows (``T > 1``), and an int8 latent leaf does
-not exist (``init_latent_cache`` refuses it).
+``tests/test_paged_window_attention.py``,
+``tests/test_paged_latent_attention.py``) pin each body to its gather
+arm per slot, under ``jax.vmap``, the block-spec one for the int8 4-leaf
+layout too; ``tests/test_paged_kernel_step.py`` holds the engine's step
+on this arm to the gather arm for both model families and both K/V
+forms (tokens in float32, logits in bf16: the online softmax orders
+every sum differently from the einsum arm's joint f32 softmax) and
+compiles each for the v5e. Off-TPU callers take the gather arm
+(``dispatch_mode()`` is ``"off"``). Multi-device meshes do too (no
+``_partition`` unit yet — the pool's KV-head shard would need a
+per-shard grid), as do prefill chunks and speculative verify windows
+(``T > 1``), a narrow page with a 64-wide head (Mosaic refuses the
+copy's slice of half a lane tile), and an int8 latent leaf does not
+exist (``init_latent_cache`` refuses it).
 """
 
 from __future__ import annotations
@@ -120,6 +161,16 @@ def _one_token_on_one_chip(q, table) -> bool:
             and table.ndim == 2 and table.shape[0] == q.shape[0])
 
 
+def copies_pages(pool) -> bool:
+    """Which K/V form a pool takes, from its leaves' shape alone: float
+    pages narrower than a lane tile (``Hkv * P`` rows short of 128: 4 KV
+    heads x 16 tokens) are copied by the kernel itself
+    (:func:`raw_copy_call`), every other pool arrives through block
+    specs (:func:`raw_call`)."""
+    k = pool[0]
+    return len(pool) == 2 and bool((k.shape[2] * k.shape[3]) % LANES)
+
+
 def supported(q, pool, table) -> bool:
     """Kernel gate; callers fall back to :func:`paged_reference` when
     False. ``q`` [B, 1, Hq, D] (decode chunks only); ``pool`` the paged
@@ -138,23 +189,58 @@ def supported(q, pool, table) -> bool:
         return False
     quantized = len(pool) == 4
     if _support.on_tpu() and not _support.interpret():
-        K = _pages_per_step(table.shape[1],
-                            Hkv * P * D * k.dtype.itemsize)
-        if (Hkv * P) % LANES and ((K * Hkv * P) % LANES or quantized):
-            # a page narrower than a lane tile (4 KV heads x 16) is
-            # served by the row-joined form, whose K pages together
-            # have to fill whole tiles
-            return False
         if quantized:
             # Mosaic refuses the scale planes' [Hkv, P] -> [1, Hkv * P]
             # reshape ("unsupported shape cast", v5e): the int8 pool is
             # the interpreter's only, compiled it takes the gather arm
             return False
+        if copies_pages(pool):
+            # a copy moves whole tiles of the leaf's dtype — a page's
+            # [P, D] planes, 16 rows of bf16 by 128 lanes: Mosaic refuses
+            # to slice a 64-wide row out of the pool ("must be aligned to
+            # tiling (128)", v5e, found by AOT) — and a block's pages
+            # together fill whole lane tiles of the score plane
+            KP = _pages_per_block(table.shape[1],
+                                  Hkv * P * D * k.dtype.itemsize)
+            if (D % LANES or (P * k.dtype.itemsize) % 32
+                    or (KP * Hkv * P) % LANES):
+                return False
     if quantized and k.dtype != jnp.int8:
         return False
     if not quantized and k.dtype not in (jnp.float32, jnp.bfloat16):
         return False
     return True
+
+
+def _fresh_token(q_ref, kn_ref, vn_ref, acc_ref, m_ref, l_ref, *,
+                 scale, G, Hkv):
+    """Grid step 0 of both K/V bodies — the step's own token: p =
+    exp(s - m) = 1, l = 1, acc = v_new."""
+    q = q_ref[0].astype(jnp.float32)            # [Hq, D]
+    kn = kn_ref[0].astype(jnp.float32)          # [Hkv, D]
+    vn = vn_ref[0].astype(jnp.float32)
+    for h in range(Hkv):
+        rows = slice(h * G, (h + 1) * G)
+        s_h = jnp.sum(q[rows] * kn[h:h + 1], axis=1,
+                      keepdims=True) * scale    # [G, 1]
+        m_ref[rows, :] = jnp.broadcast_to(s_h, (G, LANES))
+        acc_ref[rows, :] = jnp.broadcast_to(vn[h:h + 1],
+                                            (G, vn.shape[1]))
+    l_ref[:, :] = jnp.ones_like(l_ref)
+
+
+def _softmax_update(s, m_ref, l_ref):
+    """One online-softmax update over a masked score plane ``s``
+    [Hq, n] in float32: the running max and sum move, and the plane's
+    probabilities come back with the factor the accumulator owes."""
+    m_prev = m_ref[:, :1]
+    l_prev = l_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)                      # [Hq, n]
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[:, :1] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    m_ref[:, :1] = m_new
+    return p, alpha
 
 
 def _kernel(sp_ref, q_ref, kn_ref, vn_ref, *rest,
@@ -171,18 +257,8 @@ def _kernel(sp_ref, q_ref, kn_ref, vn_ref, *rest,
 
     @pl.when(j == 0)
     def _fresh():
-        # the step's own token: p = exp(s - m) = 1, l = 1, acc = v_new
-        q = q_ref[0].astype(jnp.float32)            # [Hq, D]
-        kn = kn_ref[0].astype(jnp.float32)          # [Hkv, D]
-        vn = vn_ref[0].astype(jnp.float32)
-        for h in range(Hkv):
-            rows = slice(h * G, (h + 1) * G)
-            s_h = jnp.sum(q[rows] * kn[h:h + 1], axis=1,
-                          keepdims=True) * scale    # [G, 1]
-            m_ref[rows, :] = jnp.broadcast_to(s_h, (G, LANES))
-            acc_ref[rows, :] = jnp.broadcast_to(vn[h:h + 1],
-                                                (G, vn.shape[1]))
-        l_ref[:, :] = jnp.ones_like(l_ref)
+        _fresh_token(q_ref, kn_ref, vn_ref, acc_ref, m_ref, l_ref,
+                     scale=scale, G=G, Hkv=Hkv)
 
     last_page = jnp.maximum(idx - 1, 0) // P
     live = (j > 0) & ((j - 1) * K <= last_page)
@@ -218,20 +294,10 @@ def _kernel(sp_ref, q_ref, kn_ref, vn_ref, *rest,
         def plane(refs):                            # K x [Hkv, P] -> [1, K·W]
             return jnp.concatenate([r[0, 0].reshape(1, W) for r in refs], 1)
 
-        joined = bool(W % LANES)
-        if joined:
-            # a page narrower than a lane tile: the K pages join along
-            # the rows (whole tiles) before ONE dot a side, so no
-            # [Hq, W] piece is ever cut or joined inside a tile
-            s = jax.lax.dot_general(
-                q, jnp.concatenate([page(r) for r in kp_refs], axis=0),
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-        else:
-            s = jnp.concatenate([
-                jax.lax.dot_general(q, page(r), (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-                for r in kp_refs], axis=1) * scale  # [Hq, K·W]
+        s = jnp.concatenate([
+            jax.lax.dot_general(q, page(r), (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            for r in kp_refs], axis=1) * scale      # [Hq, K·W]
         if quantized:
             # per-position scale folds into the logit plane (per column)
             s = s * plane(leaves[2])
@@ -243,28 +309,16 @@ def _kernel(sp_ref, q_ref, kn_ref, vn_ref, *rest,
         if windowed:
             valid = valid & (pos >= lo)
         s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                      # [Hq, K·W]
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[:, :1] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[:, :1] = m_new
+        p, alpha = _softmax_update(s, m_ref, l_ref)
         if quantized:
             # v scale folds into the prob plane
             p = p * plane(leaves[3])
         p = p.astype(cdt)
-        if joined:
-            pv = jax.lax.dot_general(
-                p, jnp.concatenate([page(r) for r in vp_refs], axis=0),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        else:
-            pv = sum(
-                jax.lax.dot_general(p[:, i * W:(i + 1) * W], page(r),
-                                    (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-                for i, r in enumerate(vp_refs))     # [Hq, D]
+        pv = sum(
+            jax.lax.dot_general(p[:, i * W:(i + 1) * W], page(r),
+                                (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            for i, r in enumerate(vp_refs))         # [Hq, D]
         acc_ref[:, :] = acc_ref[:, :] * alpha + pv
 
     @pl.when(j == steps)
@@ -275,12 +329,17 @@ def _kernel(sp_ref, q_ref, kn_ref, vn_ref, *rest,
 
 
 def _pages_per_step(M: int, page_bytes: int) -> int:
-    """Pages one grid step streams: up to 8, within 1 MiB a leaf a step
-    (double-buffered in VMEM). Several pages a step are what gives the
-    kernel independent dots to run side by side (``_kernel``); on the
-    v5e 16 pages read the same as 8, and one page a step — a page's two
-    dots and its softmax update in a chain — took 5.9 ms a decode step
-    of the OLMoE cell where 8 take 3.1."""
+    """Pages one grid step of the block-spec form streams: up to 8,
+    within 1 MiB a leaf a step (double-buffered in VMEM). Several pages
+    a step are what gives the kernel independent dots to run side by
+    side (``_kernel``); on the v5e 16 pages read the same as 8, and one
+    page a step — a page's two dots and its softmax update in a chain —
+    took 5.9 ms a decode step of the OLMoE cell where 8 take 3.1. Only
+    pages of whole lane tiles come here compiled (:func:`copies_pages`):
+    on pages of 4 KV heads this form read 36.7 / 33.6 / 33.5 / 35.2 ms a
+    step of the window cell at 8 / 16 / 32 / 64 pages a step — two DMAs
+    a page, whatever their number — and was replaced there by the copy
+    form (:func:`_pages_per_block`)."""
     return max(1, min(8, M, (1 << 20) // page_bytes))
 
 
@@ -354,6 +413,216 @@ def raw_call(sp, q2, kn2, vn2, *pool, scale: float, windowed: bool = False):
         interpret=_support.interpret(),
         name="ptpu_paged_decode_attn",
     )(sp, *args)
+
+
+def _pages_per_block(M: int, page_bytes: int) -> int:
+    """Pages one block of the copy form holds contiguous in VMEM, a
+    leaf: up to 64, within 1 MiB (two blocks a leaf are resident, one in
+    use and one in flight) and within the table rounded up to eight
+    pages, so that a short table's block still fills whole lane tiles.
+    The block is the stationary side of both products and the unit of
+    one wait. On the v5e, at the window cell's shapes (pages of 16 KB a
+    leaf), 64 pages a block read 8.9 ms a decode step, 32 read 10.1, 16
+    read 12.6 (more grid steps and waits for the same copies) and 128
+    read 8.5 at twice the VMEM and compile time."""
+    return max(1, min(64, -(-M // 8) * 8, (1 << 20) // page_bytes))
+
+
+def _copy_kernel(sp_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
+                 kbuf, vbuf, sem, slot_ref, acc_ref, m_ref, l_ref, *,
+                 scale, P, KP, M, G, Hkv, rows, out_dtype, windowed):
+    # The K/V body for pages narrower than a lane tile. The pool stays
+    # in HBM; a page of one layer is a contiguous [Hkv, P, D] slab a
+    # leaf, copied into its Hkv·P rows of a block's buffer — rows in the
+    # order (page, head, offset) — so the block is contiguous in VMEM
+    # BEFORE its one dot a side. Grid step j >= 1 of a row attends the
+    # row's (j - 1)-th LIVE block: blocks wholly behind a window row's
+    # lower edge take no step at all.
+    HDR = 3 if windowed else 2
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    idx = sp_ref[b, 1]
+    W = Hkv * P
+    steps = -(-M // KP)
+    bufs = ((k_hbm, kbuf), (v_hbm, vbuf))
+
+    def first_page(row):                  # the first page a row still sees
+        return sp_ref[row, 2] // P if windowed else 0
+
+    def end_page(row):
+        # one past its last live page — and never past the table: an
+        # idle slot's position may lie beyond what its row maps
+        return jnp.minimum((jnp.maximum(sp_ref[row, 1], 0) + P - 1) // P, M)
+
+    def first_block(row):
+        return first_page(row) // KP
+
+    def blocks(row):                      # live blocks of a row
+        return jnp.maximum(
+            (end_page(row) + KP - 1) // KP - first_block(row), 0)
+
+    def page_copies(row, blk, slot, i, at):
+        page = sp_ref[row, HDR + blk * KP + i]
+        return [pltpu.make_async_copy(
+            hbm.at[page, sp_ref[row, 0]], buf.at[slot, pl.ds(at, Hkv)],
+            sem.at[leaf, slot]) for leaf, (hbm, buf) in enumerate(bufs)]
+
+    def each_block(row, blk, whole, page):
+        """A block's live pages, one [Hkv, P, D] copy a leaf each into
+        its place of the buffer. Pages past the fill or wholly behind
+        the window are not copied (what the buffer holds there is
+        masked: an earlier block's rows, or the first step's zeros). A
+        full block is KP copies a leaf in a straight line; only a row's
+        first and last live block loop over a count."""
+        i0 = jnp.maximum(first_page(row) - blk * KP, 0)
+        i1 = jnp.minimum(end_page(row) - blk * KP, KP)
+        full = (i0 == 0) & (i1 == KP)
+        pl.when(full)(whole)
+
+        @pl.when(jnp.logical_not(full))
+        def _some():
+            def body(i, carry):
+                page(i, pl.multiple_of(i * Hkv, Hkv))
+                return carry
+            jax.lax.fori_loop(i0, i1, body, 0)
+
+    def start(row, blk, slot):
+        def page(i, at):
+            for c in page_copies(row, blk, slot, i, at):
+                c.start()
+
+        def whole():
+            for i in range(KP):
+                page(i, i * Hkv)
+
+        each_block(row, blk, whole, page)
+
+    def wait(row, blk, slot):
+        def page(i, at):
+            for c in page_copies(row, blk, slot, i, at):
+                c.wait()
+
+        def whole():
+            # the semaphores count bytes: one wait a leaf for a block
+            for leaf, (_, buf) in enumerate(bufs):
+                full = buf.at[slot]
+                pltpu.make_async_copy(full, full, sem.at[leaf, slot]).wait()
+
+        each_block(row, blk, whole, page)
+
+    @pl.when((b == 0) & (j == 0))
+    def _first():
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        slot_ref[0] = 0
+
+    nb = blocks(b)
+
+    @pl.when(j <= nb)
+    def _prefetch():
+        # the latent kernel's schedule: every live block is started
+        # exactly once, one block ahead of its use and across slots — a
+        # row's later blocks by the step before them, its first by the
+        # last live step of the row before (row 0's by the call's first
+        # step). A row with nothing cached starts and waits nothing.
+        cur = slot_ref[0]
+        using = j >= 1
+        own = j < nb
+        row = jnp.where(own, b, jnp.minimum(b + 1, rows - 1))
+        go = jnp.where(own, using | (b == 0),
+                       (b + 1 < rows) & (blocks(row) >= 1))
+
+        @pl.when(go)
+        def _start():
+            start(row, first_block(row) + jnp.where(own, j, 0),
+                  jnp.where(using, 1 - cur, cur))
+
+    @pl.when(j == 0)
+    def _fresh():
+        _fresh_token(q_ref, kn_ref, vn_ref, acc_ref, m_ref, l_ref,
+                     scale=scale, G=G, Hkv=Hkv)
+
+    @pl.when((j >= 1) & (j <= nb))
+    def _block():
+        cur = slot_ref[0]
+        blk = first_block(b) + j - 1
+        wait(b, blk, cur)
+        slot_ref[0] = 1 - cur
+        Hq, D = q_ref.shape[1:]
+        q = q_ref[0].astype(kbuf.dtype)                    # [Hq, D]
+        # ONE block-diagonal dot for all heads over the whole block: q
+        # against [KP·Hkv·P, D] computes every cross-head product, the
+        # mask kills the wrong-head logits exactly
+        s = jax.lax.dot_general(
+            q, kbuf[cur].reshape(KP * W, D), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale    # [Hq, KP·W]
+        # column (page i, head, offset) stands at paged_gather's view
+        # position (blk·KP + i)·P + offset; one row of columns and one
+        # column of rows, broadcast in the compare
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, KP * W), 1)
+        pos = (blk * KP + col // W) * P + col % P
+        seen = pos < jnp.minimum(idx, M * P)    # the fill, within the table
+        if windowed:
+            seen = seen & (pos >= sp_ref[b, 2])
+        row_h = jax.lax.broadcasted_iota(jnp.int32, (Hq, 1), 0) // G
+        s = jnp.where((row_h == col % W // P) & seen, s, NEG_INF)
+        p, alpha = _softmax_update(s, m_ref, l_ref)
+        pv = jax.lax.dot_general(
+            p.astype(vbuf.dtype), vbuf[cur].reshape(KP * W, D),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        acc_ref[:, :] = acc_ref[:, :] * alpha + pv
+
+    @pl.when(j == steps)
+    def _finalize():
+        o_ref[0] = (acc_ref[:, :] / l_ref[:, :1]).astype(out_dtype)
+
+
+def raw_copy_call(sp, q2, kn2, vn2, kp, vp, *, scale: float,
+                  windowed: bool = False):
+    """The copy form's pallas_call on local shapes: operands as
+    :func:`raw_call`'s, the two float leaves unblocked in HBM and only
+    read. Grid ``(B, 1 + ceil(M / KP))``: step 0 the fresh token, then
+    one live block of KP pages a step, double-buffered across steps and
+    rows by the kernel's own copies."""
+    B, Hq, D = q2.shape
+    Hkv, P = kp.shape[2:4]
+    M = sp.shape[1] - (3 if windowed else 2)
+    KP = _pages_per_block(M, Hkv * P * D * kp.dtype.itemsize)
+    steps = -(-M // KP)
+    kernel = functools.partial(
+        _copy_kernel, scale=scale, P=P, KP=KP, M=M, G=Hq // Hkv,
+        Hkv=Hkv, rows=B, out_dtype=q2.dtype, windowed=windowed)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, steps + 1),
+            in_specs=[
+                pl.BlockSpec((1, Hq, D), lambda b, j, s: (b, 0, 0)),
+                pl.BlockSpec((1, Hkv, D), lambda b, j, s: (b, 0, 0)),
+                pl.BlockSpec((1, Hkv, D), lambda b, j, s: (b, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, Hq, D), lambda b, j, s: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, KP * Hkv, P, D), kp.dtype),
+                pltpu.VMEM((2, KP * Hkv, P, D), vp.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((Hq, D), jnp.float32),
+                pltpu.VMEM((Hq, LANES), jnp.float32),
+                pltpu.VMEM((Hq, LANES), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q2.dtype),
+        # the copies of one step are waited in a later one: the grid
+        # has to run in order
+        compiler_params=_support.compiler_params(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=_support.interpret(),
+        name="ptpu_paged_decode_attn",
+    )(sp, q2, kn2, vn2, kp, vp)
 
 
 def latent_supported(q, pool, table, C: int) -> bool:
@@ -684,14 +953,14 @@ def paged_decode_attention(q, k_new, v_new, pool, table, layer, index, *,
     B, T, Hq, D = q.shape
     Hkv = k_new.shape[1]
     table = jnp.asarray(table, jnp.int32)
-    raw = raw_call
+    raw = raw_copy_call if copies_pages(pool) else raw_call
     if window is None:
         idx = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (B,))
     else:
         # ``lo`` rides as the first column of the rows' table
         idx, lo = _row_edges(index, window, base, pool[0].shape[3], B)
         table = jnp.concatenate([lo[:, None], table], axis=1)
-        raw = functools.partial(raw_call, windowed=True)
+        raw = functools.partial(raw, windowed=True)
     lay = jnp.broadcast_to(jnp.asarray(layer, jnp.int32), (B,))
     out = _over_rows(raw, scale)(
         lay, idx, table, q.reshape(B, Hq, D),

@@ -43,7 +43,9 @@ paged cache, SOSP '23) to the framework's autoregressive path:
   gather ONE layer's pages through the row for the einsum arm
   (``models._common.cached_attention`` / ``latent_attention`` pick;
   ``stats()["decode_attn"]`` says which the step took, for either
-  family). Either way no slot's all-layers view is
+  family: ``"paged_copy_kernel"`` where the K/V kernel copies narrow
+  pages itself, ``"paged_kernel"``, ``"gather"``). Either way no slot's
+  all-layers view is
   ever built, and the chunk's new k/v go into the donated pool by
   in-place page updates (``generation.paged_write``): a step moves the
   pages it reads, never the pool. A generation reserves pages for its
@@ -1357,8 +1359,9 @@ class GenerationEngine:
             x.nbytes / (x.shape[0] * (self._page_tokens if self._paged
                                       else self.max_len))
             for x in leaves)
-        # how the paged step's attention reads the pool, "paged_kernel"
-        # or "gather": decided where the step is traced, None until then
+        # how the paged step's attention reads the pool,
+        # "paged_copy_kernel", "paged_kernel" or "gather": decided where
+        # the step is traced, None until then
         self._decode_attn: str | None = None
         if self._paged:
             self._step = self._build_paged_step()
@@ -1679,7 +1682,7 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        from paddle_tpu.models._common import paged_attn_arms
+        from paddle_tpu.models._common import attn_arm_since, paged_attn_arms
         from paddle_tpu.models.generation import PagedCache, paged_write
 
         P, maxp = self._page_tokens, self._maxp
@@ -1699,15 +1702,12 @@ class GenerationEngine:
 
         def step(model, state, pt, active):
             pool = state["cache"]
-            kernel_arms = paged_attn_arms["paged_kernel"]
+            arms = paged_attn_arms.copy()
             logits, keys, subs, new, cnt = jax.vmap(
                 functools.partial(one, model),
                 in_axes=(0, 0, 0, 0, None))(
                 pt, state["tok"], state["pos"], state["keys"], pool)
-            self._decode_attn = (
-                "paged_kernel"
-                if paged_attn_arms["paged_kernel"] > kernel_arms
-                else "gather")
+            self._decode_attn = attn_arm_since(arms)
             if grouped:
                 pool = self._group_step_writes(pool, pt, state["pos"],
                                                active, new)
@@ -1735,7 +1735,7 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        from paddle_tpu.models._common import paged_attn_arms
+        from paddle_tpu.models._common import attn_arm_since, paged_attn_arms
         from paddle_tpu.models.generation import (PagedCache, StateCache,
                                                   paged_write)
         from paddle_tpu.ops.kda import step_arms
@@ -1752,17 +1752,14 @@ class GenerationEngine:
 
         def step(model, state, pt, active):
             pool, rows = state["cache"]
-            kernel_arms = paged_attn_arms["paged_kernel"]
+            arms = paged_attn_arms.copy()
             kda_kernel = step_arms["kernel"]
             logits, keys, subs, new, rows, cnt = jax.vmap(
                 functools.partial(one, model),
                 in_axes=(0, 0, 0, 0, 0, 0, None))(
                 pt, state["tok"], state["pos"], state["keys"], rows,
                 active.astype(jnp.int32), pool)
-            self._decode_attn = (
-                "paged_kernel"
-                if paged_attn_arms["paged_kernel"] > kernel_arms
-                else "gather")
+            self._decode_attn = attn_arm_since(arms)
             self._kda_step = ("kernel" if step_arms["kernel"] > kda_kernel
                               else "xla")
             pidx = jnp.clip(state["pos"] // P, 0, maxp - 1)

@@ -1,13 +1,17 @@
 """Page-table-aware Pallas decode kernel (ops/pallas/paged_decode_attention.py).
 
 OpTest discipline, same contract as ``test_decode_attention.py`` but
-with the page indirection inside the index maps: in interpret mode the
-kernel must reproduce ``models.generation.paged_gather`` + masked
-attention bit-for-bit per slot, honor the physical page permutation
-(same logical sequence, different page placement → identical output),
-bound reads to the filled prefix, fold int8 pool scales exactly, and
-survive ``jax.vmap`` over slots. This is the hardware-independent
-result; the TPU timing run is the stated caveat in the module doc.
+with the page indirection inside the kernel: in interpret mode it must
+reproduce ``models.generation.paged_gather`` + masked attention
+bit-for-bit per slot, honor the physical page permutation (same logical
+sequence, different page placement → identical output), bound reads to
+the filled prefix, fold int8 pool scales exactly, and survive
+``jax.vmap`` over slots. Both K/V forms are held to it: the copy form a
+float page narrower than a lane tile takes (the kernel copies a block of
+pages into VMEM itself) and the block-spec form of every other pool,
+each at its own choice of pages a block / a grid step and at 3. This is
+the hardware-independent result; the TPU timing run is the stated caveat
+in the module doc.
 """
 
 import numpy as np
@@ -20,25 +24,50 @@ from paddle_tpu.ops.pallas import _support
 from paddle_tpu.ops.pallas import paged_decode_attention as pdk
 
 
-@pytest.fixture(autouse=True, params=["default", 3])
-def pages_per_step(request, monkeypatch):
-    """Every test at the kernel's own choice of pages a grid step (all
-    of these tables' 4 in one) and at 3: two steps of pages, the second
-    with a tail past the table that clamps to the last live page."""
-    if request.param != "default":
-        monkeypatch.setattr(pdk, "_pages_per_step",
-                            lambda M, page_bytes: request.param)
-    return request.param
+# a page of 2 KV heads x 8 tokens is 16 rows (narrow: floats take the copy
+# form, the int8 pool the block-spec form, which only the interpreter runs
+# on such a page); with eight times the heads it is one lane tile (wide:
+# the block-spec form, OLMoE's)
+HEADS = {"narrow": 1, "wide": 8}
+FORMS = ["narrow", "narrow-3", "wide", "wide-3"]
+_heads = 1
 
 
-def _steps(M):
-    """Grid steps along a slot's pages: the fresh token's, then
-    ``_pages_per_step`` pages each."""
-    return 1 - (-M // pdk._pages_per_step(M, 1))
+def set_form(param, monkeypatch):
+    """``"<width>[-<pages>]"``: both forms' pages a block / a grid step
+    patched to ``pages`` where given; returns the width's multiple of
+    the tests' heads."""
+    width, _, pages = param.partition("-")
+    if pages:
+        for name in ("_pages_per_step", "_pages_per_block"):
+            monkeypatch.setattr(pdk, name, lambda M, page_bytes: int(pages))
+    return HEADS[width]
+
+
+@pytest.fixture(autouse=True, params=FORMS)
+def form(request, monkeypatch):
+    """Every test on narrow and on wide pages, at the kernel's own
+    choice of pages a block / a grid step (all of these tables' 4 in
+    one) and at 3: two steps of pages, the second with a tail past the
+    table (the block-spec form clamps it to the last live page, the
+    copy form does not copy it)."""
+    global _heads
+    _heads = set_form(request.param, monkeypatch)
+    yield request.param
+    _heads = 1
+
+
+def _steps(M, pool):
+    """Grid steps along a slot's pages: the fresh token's, then the
+    form's pages each."""
+    pages = (pdk._pages_per_block if pdk.copies_pages(pool)
+             else pdk._pages_per_step)
+    return 1 - (-M // pages(M, 1))
 
 
 def _mk(B=2, Hq=4, Hkv=2, P=8, M=4, D=64, L=2, N=16, quant=False,
         dtype=jnp.float32, seed=0):
+    Hq, Hkv = Hq * _heads, Hkv * _heads
     rs = np.random.RandomState(seed)
     q = jnp.asarray(rs.randn(B, 1, Hq, D), dtype)
     kn = jnp.asarray(rs.randn(B, Hkv, 1, D), dtype)
@@ -178,6 +207,26 @@ def test_kernel_ignores_stale_and_unmapped():
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.parametrize("form", ["narrow", "narrow-3", "wide"],
+                         indirect=True)
+def test_a_fill_past_the_table_reads_the_table_alone(form):
+    """An idle slot's position may lie beyond what its row maps (the
+    engine steps every slot): the kernel attends the row's pages and
+    reads no table entry past it. (The block-spec form is held where
+    its pages a step divide the table, as they do in every engine that
+    runs it: at 3 pages a step its last step's index map walks past
+    the row — ROADMAP C2.)"""
+    q, kn, vn, pool, table = _mk(seed=9)
+    idx = table.shape[1] * 8 + 9
+    want = _via_paged_gather(q, kn, vn, pool, table, 1, idx, 0.125)
+    with _support.force_dispatch():
+        got = pdk.paged_decode_attention(q, kn, vn, pool, table,
+                                         jnp.int32(1), jnp.int32(idx),
+                                         scale=0.125)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
 def test_per_slot_index_vector():
     """index may be [B] — each slot masks at its own fill position."""
     q, kn, vn, pool, table = _mk(seed=4)
@@ -244,6 +293,97 @@ def test_kernel_under_vmap_matches_per_slot(quant):
             rtol=2e-5, atol=2e-5, err_msg=f"slot {s}")
 
 
+# -- the copy form: blocks, fills, and the prefetch across slots ----------------
+
+# 12 pages a slot: at 4 pages a block three blocks; at the kernel's own
+# choice one block wider than the table
+COPY_FILLS = {"nothing": 0, "one_row": 1, "first_block": 8 + 3,
+              "block_edge": 4 * 8, "past_block_edge": 4 * 8 + 1,
+              "middle_block": 6 * 8 + 5, "last_block": 11 * 8 + 2,
+              "full_table": 12 * 8}
+COPY_FORMS = pytest.mark.parametrize("form", ["narrow", "narrow-4"],
+                                     indirect=True)
+
+
+@COPY_FORMS
+@pytest.mark.parametrize("fill", list(COPY_FILLS))
+def test_copy_form_at_every_kind_of_fill(fill, form):
+    q, kn, vn, pool, table = _mk(B=3, M=12, N=40, seed=11)
+    assert pdk.copies_pages(pool)
+    idx = COPY_FILLS[fill]
+    want = _via_paged_gather(q, kn, vn, pool, table, 1, idx, 0.125)
+    with _support.force_dispatch():
+        got = pdk.paged_decode_attention(q, kn, vn, pool, table,
+                                         jnp.int32(1), jnp.int32(idx),
+                                         scale=0.125)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    ref = pdk.paged_reference(q, kn, vn, pool, table, 1, jnp.int32(idx),
+                              scale=0.125)
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@COPY_FORMS
+@pytest.mark.parametrize("fills", [
+    [5 * 8 + 1, 0, 12 * 8, 3], [0, 0, 6 * 8 + 5, 8], [9 * 8, 3, 0, 0],
+    [0, 0, 0, 0]], ids=["empty_between", "empty_first", "empty_last",
+                        "all_empty"])
+def test_copy_form_prefetches_across_an_empty_slot(fills, form):
+    """A block is started by the step before its use, a row's first by
+    the row before: a slot with nothing cached between two live ones
+    (or first, or last) starts and waits no copy of its own and hands
+    the next live row's first block on. Each slot at its own fill."""
+    q, kn, vn, pool, table = _mk(B=4, M=12, N=48, seed=12)
+    idx = jnp.asarray(fills, jnp.int32)
+    want = _via_paged_gather(q, kn, vn, pool, table, 0, fills, 0.125)
+    with _support.force_dispatch():
+        got = pdk.paged_decode_attention(q, kn, vn, pool, table,
+                                         jnp.int32(0), idx, scale=0.125)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    # the empty slot attends its own token alone
+    for b, fill in enumerate(fills):
+        if fill == 0:
+            np.testing.assert_allclose(
+                np.asarray(got[b, 0]).reshape(2, 2, -1),
+                np.broadcast_to(np.asarray(vn[b])[:, :1], (2, 2, 64)),
+                rtol=2e-6, atol=2e-6)
+
+
+@COPY_FORMS
+@pytest.mark.parametrize("fill", [1, 5, 8])
+def test_copy_form_on_a_row_of_one_page(fill, form):
+    q, kn, vn, pool, table = _mk(B=2, M=1, N=4, seed=13)
+    want = _via_paged_gather(q, kn, vn, pool, table, 1, fill, 0.125)
+    with _support.force_dispatch():
+        got = pdk.paged_decode_attention(q, kn, vn, pool, table,
+                                         jnp.int32(1), jnp.int32(fill),
+                                         scale=0.125)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_the_form_follows_the_leaves(form):
+    """Float pages short of a lane tile take the copy form — the pool
+    left unblocked in HBM, two operands — and every other pool the
+    block-spec form, each leaf an operand a page of the step."""
+    for quant in (False, True):
+        q, kn, vn, pool, table = _mk(quant=quant)
+        copies = form.startswith("narrow") and not quant
+        assert pdk.copies_pages(pool) == copies
+        with _support.force_dispatch():
+            jaxpr = jax.make_jaxpr(lambda *a: pdk.paged_decode_attention(
+                *a, pool, table, jnp.int32(1), jnp.int32(20),
+                scale=0.125))(q, kn, vn)
+        (call,) = [e for e, _ in walk_eqns(jaxpr.jaxpr)
+                   if e.primitive.name == "pallas_call"]
+        assert call.params["name"] == "ptpu_paged_decode_attn"
+        pages = 1 if copies else pdk._pages_per_step(table.shape[1], 1)
+        assert len(call.invars) == 4 + len(pool) * pages
+
+
 def walk_eqns(jaxpr, path=()):
     """``(eqn, names of the primitives enclosing it)`` for every
     equation of a jaxpr, sub-jaxprs included."""
@@ -279,9 +419,10 @@ def test_vmap_over_slots_is_one_call_with_the_slots_in_its_grid():
     twice = tuple(jnp.stack([x, x]) for x in (q, kn, vn, table, idx))
     with _support.force_dispatch():
         assert _pallas_calls(jax.make_jaxpr(jax.vmap(one))(
-            q, kn, vn, table, idx)) == [((3, _steps(M)), ("custom_vmap_call",))]
+            q, kn, vn, table, idx)) == [
+                ((3, _steps(M, pool)), ("custom_vmap_call",))]
         assert _pallas_calls(jax.make_jaxpr(jax.vmap(two))(*twice)) == [
-            ((6, _steps(M)), ("custom_vmap_call",))]
+            ((6, _steps(M, pool)), ("custom_vmap_call",))]
         got = jax.vmap(two)(*twice)
         want = pdk.paged_decode_attention(q, kn, vn, pool, table,
                                           jnp.int32(1), idx, scale=0.125)
@@ -338,13 +479,15 @@ def test_fallback_arm_dispatch(monkeypatch):
     route through the pallas_call."""
     q, kn, vn, pool, table = _mk(seed=6)
     calls = {}
-    orig = pdk.raw_call
 
-    def spy(*a, **kw):
-        calls["n"] = calls.get("n", 0) + 1
-        return orig(*a, **kw)
+    def spy(orig):
+        def call(*a, **kw):
+            calls["n"] = calls.get("n", 0) + 1
+            return orig(*a, **kw)
+        return call
 
-    monkeypatch.setattr(pdk, "raw_call", spy)
+    for raw in ("raw_call", "raw_copy_call"):
+        monkeypatch.setattr(pdk, raw, spy(getattr(pdk, raw)))
     out_f = pdk.paged_decode_attention(q, kn, vn, pool, table,
                                        jnp.int32(0), jnp.int32(10),
                                        scale=0.125)

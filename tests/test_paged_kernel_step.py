@@ -19,7 +19,11 @@ window — of the latent model too) lower to the same text whether or not
 the gates are open, and the K/V step does with the latent arm there or
 not; and the step compiled for the v5e keeps the pool in place. The
 latent model's step (``DeepseekV3ForCausalLM`` at the tiny preset) is
-held the same way.
+held the same way. The K/V model's pages of 2 KV heads x 8 tokens are
+narrower than a lane tile: its float step takes the kernel's copy form
+(``stats()["decode_attn"] == "paged_copy_kernel"``), its int8 step and
+the ``wide`` model's (16 KV heads: a page is one lane tile, OLMoE's
+case) the block-spec form.
 """
 
 import contextlib
@@ -75,7 +79,14 @@ def _latent(dtype, seed=12, **kw):
     return DeepseekV3ForCausalLM(DeepseekV3Config.tiny(**args))
 
 
-FAMILIES = {"llama": _llama, "latent": _latent}
+def _wide(dtype, seed=14, **kw):
+    """16 KV heads x 8 tokens: a page of one lane tile, the block-spec
+    form's (OLMoE's head count at half its head width)."""
+    return _llama(dtype, seed, hidden_size=16 * D, num_heads=16,
+                  num_kv_heads=16, **kw)
+
+
+FAMILIES = {"llama": _llama, "latent": _latent, "wide": _wide}
 
 
 @pytest.fixture(scope="module")
@@ -85,7 +96,8 @@ def model():
 
 @pytest.fixture(scope="module")
 def models(model):
-    return {"llama": model, "latent": _latent("float32")}
+    return {"llama": model, "latent": _latent("float32"),
+            "wide": _wide("float32")}
 
 
 GATES = {"paged_kernel": (), "gather": ("supported", "latent_supported"),
@@ -126,7 +138,14 @@ def _hand_state(eng, seed):
     return state
 
 
-def _steps(model, which, quant):
+def _stat(which, family, quant):
+    """What ``stats()["decode_attn"]`` reads on arm ``which``: the
+    narrow float pages' kernel is the copy form."""
+    copies = which != "gather" and family == "llama" and not quant
+    return "paged_copy_kernel" if copies else which
+
+
+def _steps(model, which, quant, family="llama"):
     with arm(which), GenerationEngine(
             model, slots=SLOTS, max_len=MAXLEN, paged=True, page_tokens=P,
             pages=PAGES, cache_dtype=jnp.int8 if quant else None,
@@ -137,13 +156,13 @@ def _steps(model, which, quant):
         for _ in range(STEPS):
             state, tok = eng._step(state, pt, active)
             toks.append(np.asarray(tok))
-        assert eng.stats()["decode_attn"] == which
+        assert eng.stats()["decode_attn"] == _stat(which, family, quant)
         return np.stack(toks), np.asarray(state["pos"])
 
 
 @pytest.mark.parametrize("family,quant", [
-    ("llama", False), ("llama", True), ("latent", False)],
-    ids=["f32", "int8", "latent-f32"])
+    ("llama", False), ("llama", True), ("latent", False), ("wide", False)],
+    ids=["f32", "int8", "latent-f32", "wide-f32"])
 def test_step_tokens_equal_on_both_arms(models, family, quant):
     """34 steps from one hand-built pool: every live slot crosses page
     edges (and slot 2 starts on one), the idle slot reads the null page
@@ -151,8 +170,8 @@ def test_step_tokens_equal_on_both_arms(models, family, quant):
     latent pool's pad columns hold noise here: a zero pad on the query
     is what keeps them out of the score.)"""
     model = models[family]
-    got, pos = _steps(model, "paged_kernel", quant)
-    want, _ = _steps(model, "gather", quant)
+    got, pos = _steps(model, "paged_kernel", quant, family)
+    want, _ = _steps(model, "gather", quant, family)
     np.testing.assert_array_equal(got, want)
     assert list(pos) == [p + STEPS * a for p, a in zip(POS, ACTIVE)]
     assert (got[:, 3] == 7).all()
@@ -160,8 +179,8 @@ def test_step_tokens_equal_on_both_arms(models, family, quant):
 
 
 @pytest.mark.parametrize("family,quant", [
-    ("llama", False), ("llama", True), ("latent", False)],
-    ids=["bf16", "int8", "latent-bf16"])
+    ("llama", False), ("llama", True), ("latent", False), ("wide", False)],
+    ids=["bf16", "int8", "latent-bf16", "wide-bf16"])
 def test_bf16_logits_within_chip_smoke_tolerance(family, quant):
     """The two arms are two evaluations of one mathematics (a joint
     float32 softmax against an online one): in bf16 their logits differ
@@ -192,7 +211,7 @@ def test_bf16_logits_within_chip_smoke_tolerance(family, quant):
             <= chip_smoke.LOGIT_MAX_RTOL)
 
 
-def _serve_shared_template(model, which):
+def _serve_shared_template(model, which, family):
     """A first request leaves the template's pages in the prefix cache;
     two more then decode side by side on those pages."""
     rs = np.random.RandomState(9)
@@ -222,14 +241,14 @@ def _serve_shared_template(model, which):
             t.join(timeout=300)
         assert not any(t.is_alive() for t in threads)
         assert get_stat("gen/prefix_hits") - hits == 2
-        assert eng.stats()["decode_attn"] == which
+        assert eng.stats()["decode_attn"] == _stat(which, family, False)
     return out
 
 
 @pytest.mark.parametrize("family", list(FAMILIES))
 def test_shared_template_through_prefix_cache(models, family):
-    got = _serve_shared_template(models[family], "paged_kernel")
-    want = _serve_shared_template(models[family], "gather")
+    got = _serve_shared_template(models[family], "paged_kernel", family)
+    want = _serve_shared_template(models[family], "gather", family)
     assert got == want and len(got[0]) == 4 * P
 
 
@@ -268,18 +287,29 @@ def _shapes(eqns):
             if hasattr(v.aval, "shape")}
 
 
-def test_step_holds_one_kernel_call_a_layer_over_all_slots(model):
+@pytest.mark.parametrize("family", ["llama", "wide"])
+def test_step_holds_one_kernel_call_a_layer_over_all_slots(models, family):
+    model = models[family]
+    hkv = model.config.num_kv_heads
     eqns = _step_eqns(model, "paged_kernel")
     calls = _attn_calls(eqns)
     assert len(calls) == 1                      # the layer scan's body
     call, path = calls[0]
-    # the fresh token's step, then the row's pages a few a step
-    assert call.params["grid_mapping"].grid == (
-        SLOTS, 1 - (-M // pdk._pages_per_step(M, HKV * P * D * 4)))
+    # the fresh token's step, then the row's pages: a block of them the
+    # kernel copies itself (narrow pages, the pool two unblocked
+    # operands), or a few a step through block specs (each leaf an
+    # operand a page)
+    page_bytes = hkv * P * D * 4
+    pages, operands = ((pdk._pages_per_block(M, page_bytes), 2)
+                       if family == "llama" else
+                       (pdk._pages_per_step(M, page_bytes),
+                        2 * pdk._pages_per_step(M, page_bytes)))
+    assert call.params["grid_mapping"].grid == (SLOTS, 1 - (-M // pages))
+    assert len(call.invars) == 4 + operands
     assert "scan" in path and "while" not in path, path
     # the views a gather builds: pages through the row, and the
     # contiguous [Hkv, M * P, D] it reshapes them to
-    pages, view = (SLOTS, M, HKV, P, D), (SLOTS, 1, HKV, M * P, D)
+    pages, view = (SLOTS, M, hkv, P, D), (SLOTS, 1, hkv, M * P, D)
     assert not {pages, view} & _shapes(eqns)
     gather = _step_eqns(model, "gather")
     assert not _attn_calls(gather)
@@ -351,6 +381,44 @@ def test_only_the_plain_step_changes_with_the_gate(models):
     assert c["latent"] == b["latent"]
 
 
+def test_olmoe_shaped_pages_keep_the_block_spec_call(monkeypatch):
+    """16 KV heads x 16 tokens x 128 in bf16 (256 rows a page: OLMoE's
+    pool) where the kernel would be compiled: the call is the block-spec
+    form's — every leaf an operand a page of the step, the grid's slot
+    axis parallel, three scratch buffers and no semaphore — and a page
+    of 4 KV heads takes the copy form beside it: two pool operands, the
+    grid in order."""
+    monkeypatch.setattr(_support, "on_tpu", lambda: True)
+    monkeypatch.setattr(_support, "dispatch_mode", lambda: "raw")
+    slots, m = 16, 128
+
+    def call_of(hkv):
+        q = jnp.zeros((slots, 1, 16, 128), jnp.bfloat16)
+        new = jnp.zeros((slots, hkv, 1, 128), jnp.bfloat16)
+        pool = (jnp.zeros((9, 8, hkv, 16, 128), jnp.bfloat16),) * 2
+        table = jnp.zeros((slots, m), jnp.int32)
+        assert pdk.supported(q, pool, table)
+        jaxpr = jax.make_jaxpr(lambda q, kn, vn, k, v: (
+            pdk.paged_decode_attention(q, kn, vn, (k, v), table,
+                                       jnp.int32(3), jnp.int32(70),
+                                       scale=0.1)))(q, new, new, *pool)
+        (call,) = [e for e, _ in walk_eqns(jaxpr.jaxpr)
+                   if e.primitive.name == "pallas_call"]
+        assert call.params["name"] == "ptpu_paged_decode_attn"
+        mapping = call.params["grid_mapping"]
+        return (mapping.grid, len(call.invars), mapping.num_scratch_operands,
+                tuple(call.params["compiler_params"]["mosaic_tpu"]
+                      .dimension_semantics))
+
+    k = pdk._pages_per_step(m, 16 * 16 * 128 * 2)
+    assert k == 8
+    assert call_of(16) == ((slots, 1 + m // k), 4 + 2 * k, 3,
+                           ("parallel", "arbitrary"))
+    kp = pdk._pages_per_block(m, 4 * 16 * 128 * 2)
+    assert call_of(4) == ((slots, 1 + m // kp), 4 + 2, 7,
+                          ("arbitrary", "arbitrary"))
+
+
 # -- compiled for the chip (no chip needed: libtpu compiles for a described
 # -- v5e); the topology is described inside the fixture, never at import ----
 
@@ -366,7 +434,8 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("pool_dtype", ["bf16", "int8", "latent-bf16"])
+@pytest.mark.parametrize("pool_dtype",
+                         ["bf16", "int8", "latent-bf16", "bf16-narrow"])
 def test_step_compiled_for_v5e_keeps_the_pool_in_place(one_chip, monkeypatch,
                                                        pool_dtype):
     """Trap 2: the kernel only reads the pool, the write after the vmap
@@ -376,11 +445,15 @@ def test_step_compiled_for_v5e_keeps_the_pool_in_place(one_chip, monkeypatch,
     The int8 pool compiles too, on the gather arm: Mosaic refuses the
     kernel's reshape of the scale planes, and the gate knows. The latent
     step compiles with its own kernel (manual copies out of the pool
-    left unblocked in HBM) under the same bounds."""
+    left unblocked in HBM) under the same bounds, and so does a
+    SmallThinker-shaped step — pages of 4 KV heads x 16 tokens, a full
+    and a window layer group — with the K/V kernel's copy form in both
+    groups."""
     # hkv * p is one lane tile and a row a whole one; the pool is too
     # large for XLA to stage a copy of it in fast memory
     hkv, d, p, slots, maxlen = 8, 128, 16, 4, 512
-    which = "gather" if pool_dtype == "int8" else "paged_kernel"
+    which = {"int8": "gather", "bf16-narrow": "paged_copy_kernel"}.get(
+        pool_dtype, "paged_kernel")
     kernel, kw = "ptpu_paged_decode_attn", {}
     if pool_dtype == "latent-bf16":
         # a latent row of whole lane tiles (128 + 64 -> 256) whose value
@@ -388,6 +461,16 @@ def test_step_compiled_for_v5e_keeps_the_pool_in_place(one_chip, monkeypatch,
         model = _latent("bfloat16", seed=13, kv_lora_rank=128,
                         qk_rope_head_dim=64, max_seq_len=maxlen)
         kernel, kw = "ptpu_paged_latent_decode_attn", {"pages": 2048}
+    elif pool_dtype == "bf16-narrow":
+        from paddle_tpu.models.smallthinker import (
+            SmallThinkerConfig, SmallThinkerForCausalLM,
+        )
+        paddle_tpu.seed(13)
+        model = SmallThinkerForCausalLM(SmallThinkerConfig.tiny(
+            vocab_size=VOCAB, hidden_size=256, num_heads=28, num_kv_heads=4,
+            head_dim=128, sliding_window=128, max_seq_len=maxlen,
+            dtype="bfloat16"))
+        kw = {"pages": (512, 1024), "prefill_chunk": 64}
     else:
         model = _llama("bfloat16", seed=13, hidden_size=hkv * d,
                        num_layers=4, num_heads=hkv, num_kv_heads=hkv,
@@ -403,23 +486,24 @@ def test_step_compiled_for_v5e_keeps_the_pool_in_place(one_chip, monkeypatch,
             model, slots=slots, max_len=maxlen, paged=True, page_tokens=p,
             cache_dtype=jnp.int8 if pool_dtype == "int8" else None,
             queue_max=4, **kw) as eng:
-        pool = eng._state["cache"]
+        pool = jax.tree_util.tree_leaves(eng._state["cache"])
         lowered = eng._step._jitted.trace(
             abstract(model), abstract(eng._state),
-            abstract(jnp.asarray(eng._pt)),
+            abstract(eng._pt_upload(jnp)),
             abstract(jnp.zeros((slots,), bool))).lower(
                 lowering_platforms=("tpu",))
         assert eng.stats()["decode_attn"] == which
-    assert ((kernel in lowered.as_text()) == (which == "paged_kernel"))
+    assert ((kernel in lowered.as_text()) == (which != "gather"))
     compiled = lowered.compile()
     hlo = compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(int(x.nbytes) for x in pool)
-    assert mem.temp_size_in_bytes < int(pool[0].nbytes)
-    leaf = ",".join(str(d) for d in pool[0].shape)
-    for line in hlo.splitlines():
-        if " copy(" in line:
-            assert f"[{leaf}]" not in line, line
+    rows = [x for x in pool if x.ndim == 5]      # without int8's scales
+    assert mem.temp_size_in_bytes < min(int(x.nbytes) for x in rows)
+    for leaf in {",".join(str(d) for d in x.shape) for x in rows}:
+        for line in hlo.splitlines():
+            if " copy(" in line:
+                assert f"[{leaf}]" not in line, line
     # the sampler's arms stay arms on the chip: ONE sort over the
     # vocabulary in the whole step, inside the last branch of a
     # conditional (a select would run it for every greedy batch)

@@ -5,9 +5,11 @@ only the live logical pages from ``base`` on, the query at position
 kernel has to reproduce an independent masked attention over the
 absolute positions — at the edges ``t < W``, ``t == W - 1``, ``t ==
 W``, a window that starts mid-page, a base that is not 0 — per slot,
-under ``jax.vmap``, in the lane-aligned form and in the row-joined form
-a page of 4 KV heads x 16 tokens takes, and leave the program of every
-other model as it was."""
+under ``jax.vmap``, in the copy form a float page narrower than a lane
+tile takes (4 KV heads x 16 tokens: the kernel copies a block of pages
+itself, and neither copies nor visits what lies wholly behind the
+window) and in the block-spec form of a lane-aligned page, and leave the
+program of every other model as it was."""
 
 import numpy as np
 import jax
@@ -18,21 +20,29 @@ from paddle_tpu.models._common import cached_attention
 from paddle_tpu.models.generation import PagedCache
 from paddle_tpu.ops.pallas import _support
 from paddle_tpu.ops.pallas import paged_decode_attention as pdk
+from test_paged_decode_attention import FORMS, set_form, walk_eqns
 
 W, P = 24, 8
 
 
-@pytest.fixture(params=["default", 3])
-def pages_per_step(request, monkeypatch):
-    """At the kernel's own choice of pages a grid step and at 3: steps
-    wholly behind the window, a step the window starts inside."""
-    if request.param != "default":
-        monkeypatch.setattr(pdk, "_pages_per_step",
-                            lambda M, page_bytes: request.param)
-    return request.param
+# 2 KV heads x 8 tokens are 16 rows (narrow: the copy form); with eight
+# times the heads a page is one lane tile (wide: the block-spec form)
+_heads = 1
+
+
+@pytest.fixture(params=FORMS)
+def form(request, monkeypatch):
+    """On narrow and on wide pages, at the kernel's own choice of pages
+    a block / a grid step and at 3: steps wholly behind the window, a
+    step the window starts inside."""
+    global _heads
+    _heads = set_form(request.param, monkeypatch)
+    yield request.param
+    _heads = 1
 
 
 def _mk(B=3, Hq=4, Hkv=2, M=6, D=64, L=2, N=24, seed=0, dtype=jnp.float32):
+    Hq, Hkv = Hq * _heads, Hkv * _heads
     rs = np.random.RandomState(seed)
     q = jnp.asarray(rs.randn(B, 1, Hq, D), dtype)
     kn = jnp.asarray(rs.randn(B, Hkv, 1, D), dtype)
@@ -83,11 +93,12 @@ EDGES = {
     "base-behind": (45, 1),          # sees 22..44; pages 1.. are mapped
     "base-at-edge": (45, 2),         # the row starts where the window does
     "base-not-0": (83, 7),           # sees 60..82 of a row from page 7
+    "past-the-row": (70, 0),         # an idle slot's: sees 47 alone
     "row-full": (96 + 7, 7 + 6 - 6)}
 
 
 @pytest.mark.parametrize("edge", list(EDGES), ids=list(EDGES))
-def test_kernel_masks_what_has_slid_out(edge, pages_per_step):
+def test_kernel_masks_what_has_slid_out(edge, form):
     t, base = EDGES[edge]
     if edge == "row-full":
         t, base = (base + 6) * P - 1, base       # the row's last position
@@ -105,7 +116,7 @@ def test_kernel_masks_what_has_slid_out(edge, pages_per_step):
     np.testing.assert_allclose(np.asarray(ref), want, rtol=2e-5, atol=2e-5)
 
 
-def test_a_slot_each_with_its_own_position_and_base(pages_per_step):
+def test_a_slot_each_with_its_own_position_and_base(form):
     q, kn, vn, pool, table = _mk()
     idx = jnp.asarray([10, 45, 83], jnp.int32)
     base = jnp.asarray([0, 2, 7], jnp.int32)
@@ -117,7 +128,7 @@ def test_a_slot_each_with_its_own_position_and_base(pages_per_step):
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
 
 
-def test_pages_behind_the_window_are_never_read(pages_per_step):
+def test_pages_behind_the_window_are_never_read(form):
     """Poison every position that has slid out, the null page and the
     pages of no slot: the output does not move."""
     q, kn, vn, pool, table = _mk()
@@ -143,12 +154,14 @@ def test_pages_behind_the_window_are_never_read(pages_per_step):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
 
 
-def test_the_row_joined_form_of_a_narrow_page(pages_per_step):
-    """4 KV heads x 8 tokens is a quarter of a lane tile: the K pages of
-    a step join along the rows before one dot a side. Same numbers as
-    the lane-aligned form's reference, with and without a window."""
+@pytest.mark.parametrize("form", ["narrow", "narrow-3"], indirect=True)
+def test_the_row_joined_form_of_a_narrow_page(form):
+    """4 KV heads x 8 tokens is a quarter of a lane tile, SmallThinker's
+    28 query heads: the pages of a block stand joined along the rows in
+    VMEM before one dot a side. Same numbers as the lane-aligned form's
+    reference, with and without a window."""
     q, kn, vn, pool, table = _mk(Hq=28, Hkv=4, D=128, M=8, N=30, seed=3)
-    assert (4 * P) % pdk.LANES
+    assert (4 * P) % pdk.LANES and pdk.copies_pages(pool)
     for window, t, base in ((None, 50, 0), (W, 50, 3), (W, 20, 0)):
         kw = {} if window is None else dict(window=window,
                                             base=jnp.int32(base))
@@ -162,7 +175,47 @@ def test_the_row_joined_form_of_a_narrow_page(pages_per_step):
                                    atol=2e-5)
 
 
-def test_under_vmap_it_is_one_call_over_the_slots():
+# a row of 12 pages, the window 24 positions: at 4 pages a block the
+# fill and the lower edge each fall in the first, the middle and the
+# last block, and whole blocks lie behind the window
+BLOCK_EDGES = {
+    "both_in_first": (20, 0),        # sees 0..19
+    "lo_first_fill_middle": (45, 0),  # sees 22..44: pages 2..5
+    "lo_middle_fill_last": (70, 0),  # sees 47..69: pages 5..8
+    "both_in_last": (95, 0),         # sees 72..94: pages 9..11
+    "block_edge": (64, 0),           # sees 41..63: the fill ends block 1
+    "base_not_0": (8 * 5 + 70, 5)}   # the same rows from logical page 5
+
+
+@pytest.mark.parametrize("form", ["narrow", "narrow-4"], indirect=True)
+@pytest.mark.parametrize("edge", list(BLOCK_EDGES))
+def test_copy_form_blocks_at_the_fill_and_at_the_window(edge, form):
+    t, base = BLOCK_EDGES[edge]
+    q, kn, vn, pool, table = _mk(M=12, N=40, seed=5)
+    want = _by_positions(q, kn, vn, pool, table, 1, t, base, 0.125, W)
+    with _support.force_dispatch():
+        got = pdk.paged_decode_attention(
+            q, kn, vn, pool, table, jnp.int32(1), jnp.int32(t), scale=0.125,
+            window=W, base=jnp.int32(base))
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("form", ["narrow", "narrow-4"], indirect=True)
+def test_copy_form_a_slot_with_nothing_cached_between_two_live(form):
+    """The cross-slot prefetch under a window: slot 1 has nothing
+    cached (position 0), its neighbours stand in different blocks."""
+    q, kn, vn, pool, table = _mk(M=12, N=40, seed=6)
+    idx = jnp.asarray([70, 0, 8 * 3 + 95], jnp.int32)
+    base = jnp.asarray([0, 0, 3], jnp.int32)
+    want = _by_positions(q, kn, vn, pool, table, 0, idx, base, 0.125, W)
+    with _support.force_dispatch():
+        got = pdk.paged_decode_attention(q, kn, vn, pool, table,
+                                         jnp.int32(0), idx, scale=0.125,
+                                         window=W, base=base)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+
+
+def test_under_vmap_it_is_one_call_over_the_slots(form):
     """The engine's shape: ``cached_attention`` under ``vmap`` over
     slots, each slot its row, base and position, the pool unmapped."""
     q, kn, vn, pool, table = _mk()
@@ -180,10 +233,12 @@ def test_under_vmap_it_is_one_call_over_the_slots():
     with _support.force_dispatch():
         fn = jax.vmap(one)
         got = fn(q, k, v, table, base, idx)
-        jaxpr = str(jax.make_jaxpr(fn)(q, k, v, table, base, idx))
+        jaxpr = jax.make_jaxpr(fn)(q, k, v, table, base, idx)
     want = _by_positions(q, kn, vn, pool, table, 1, idx, base, 64 ** -0.5, W)
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
-    assert jaxpr.count("pallas_call") == 1 and "while" not in jaxpr
+    # one call, and no loop over the slots around it
+    assert [path for e, path in walk_eqns(jaxpr.jaxpr)
+            if e.primitive.name == "pallas_call"] == [("custom_vmap_call",)]
 
 
 @pytest.mark.parametrize("T", [1, 5])
@@ -241,18 +296,35 @@ def test_without_a_window_the_program_is_the_old_one():
 
 
 def test_the_compiled_gate_takes_the_narrow_page(monkeypatch):
-    """Where the kernel would be compiled a page of 4 heads x 16 tokens
-    (64 lanes) is taken when the step's pages fill whole tiles, and the
-    int8 pool stays refused."""
+    """Where the kernel would be compiled a float page of 4 heads x 16
+    tokens (64 rows) takes the copy form when its copies move whole
+    tiles (16 rows of bf16, 128 lanes) and a block's pages fill whole
+    lane tiles — a short table's block is rounded up to eight pages —
+    and the int8 pool stays refused."""
     monkeypatch.setattr(_support, "on_tpu", lambda: True)
     monkeypatch.setattr(_support, "interpret", lambda: False)
     monkeypatch.setattr(_support, "dispatch_mode", lambda: "raw")
     q = jnp.zeros((2, 1, 28, 128), jnp.bfloat16)
     table = jnp.zeros((2, 289), jnp.int32)
-    pool = tuple(jnp.zeros((4, 6, 4, 16, 128), jnp.bfloat16)
-                 for _ in range(2))
-    assert pdk.supported(q, pool, table)
-    assert not pdk.supported(q, pool, table[:, :1])          # one page a step
+
+    def pool(hkv=4, p=16, d=128, dtype=jnp.bfloat16):
+        return tuple(jnp.zeros((4, 6, hkv, p, d), dtype) for _ in range(2))
+
+    assert pdk.copies_pages(pool()) and pdk.supported(q, pool(), table)
+    assert pdk.supported(q, pool(), table[:, :1])           # a block of 8
+    # half-lane rows (chip_smoke's window model before PR 36): Mosaic
+    # refuses to slice them out of the pool
+    assert not pdk.supported(q[..., :64], pool(d=64), table)
+    # 8 rows of bf16 are half a packed tile
+    assert not pdk.supported(q, pool(p=8), table)
+    # one KV head x 8 float32 rows: a block of eight pages is half a tile
+    assert not pdk.supported(q.astype(jnp.float32),
+                             pool(hkv=1, p=8, dtype=jnp.float32),
+                             table[:, :1])
+    # lane-aligned pages are the block-spec form's, as before
+    assert not pdk.copies_pages(pool(hkv=16))
+    assert pdk.supported(q[:, :, :16], pool(hkv=16), table)
     quant = (jnp.zeros((4, 6, 4, 16, 128), jnp.int8),) * 2 + (
         jnp.zeros((4, 6, 4, 16), jnp.float32),) * 2
+    assert not pdk.copies_pages(quant)
     assert not pdk.supported(q, quant, table)
