@@ -10,8 +10,7 @@ resource deltas.
 TPU mapping: device memory is XLA's (``jax.local_devices()[0]
 .memory_stats()`` is the authoritative source, surfaced here); the
 registry tracks host-side counters — steps, tokens, data-pipeline stalls,
-checkpoint writes — and the ``StepTimer`` derives steps/sec and
-tokens/sec the way the reference's benchmark monitors do.
+checkpoint writes.
 """
 
 from __future__ import annotations
@@ -20,13 +19,12 @@ import bisect
 import math
 import re
 import threading
-import time
 from typing import Any
 
 __all__ = ["StatRegistry", "stats", "stat_add", "stat_set", "get_stat",
            "observe", "get_histogram", "export_stats", "export_histograms",
            "export_prometheus", "merge_histograms", "hist_fraction_above",
-           "reset_stats", "StepTimer", "device_memory_stats",
+           "reset_stats", "device_memory_stats",
            "host_rss_bytes", "host_peak_rss_bytes"]
 
 
@@ -313,42 +311,6 @@ def export_prometheus(prefix: str | None = None) -> str:
         lines.append(f"{hn}_sum {h['sum']:g}")
         lines.append(f"{hn}_count {h['count']:g}")
     return "\n".join(lines) + ("\n" if lines else "")
-
-
-class StepTimer:
-    """Rolling step timing: records steps/sec (and tokens/sec when a
-    per-step token count is given) into the registry."""
-
-    def __init__(self, name: str = "train", window: int = 20):
-        self.name = name
-        self.window = window
-        # (perf_counter, tokens) per tick; the first entry anchors the
-        # window, so token sums cover ticks 1..end (the steps the window
-        # interval actually spans). Concurrent tickers (async eval thread
-        # + train loop) mutate the window under a lock, like StatRegistry.
-        self._lock = threading.Lock()
-        self._ticks: list[tuple[float, int]] = []
-
-    def tick(self, tokens: int | None = None) -> None:
-        now = time.perf_counter()
-        with self._lock:
-            self._ticks.append((now, int(tokens or 0)))
-            if len(self._ticks) > self.window + 1:
-                self._ticks.pop(0)
-            window = list(self._ticks)
-        stat_add(f"{self.name}/steps", 1)
-        if tokens:
-            stat_add(f"{self.name}/tokens", tokens)
-        if len(window) >= 2:
-            dt = window[-1][0] - window[0][0]
-            n = len(window) - 1
-            sps = n / dt if dt > 0 else 0.0
-            stat_set(f"{self.name}/steps_per_sec", sps)
-            # windowed token sum, NOT last-tick-tokens * steps/sec —
-            # variable-length batches would misreport otherwise
-            tok = sum(t for _, t in window[1:])
-            if tok and dt > 0:
-                stat_set(f"{self.name}/tokens_per_sec", tok / dt)
 
 
 def device_memory_stats(device=None) -> dict[str, Any]:

@@ -21,8 +21,13 @@ Design constraints, in order:
    ``jax.profiler.TraceAnnotation`` carrying its attributes and
    ``span_id``: in a capture it is an event of its thread's line in the
    ``/host:CPU`` plane, beside the device planes. The ring record keeps
-   ``ts`` from the realtime clock the profiler stamps with and ``dur``
-   from the monotonic one.
+   ``ts`` from the realtime clock the profiler stamps with (what joins
+   it to the capture's event) and ``mono`` / ``dur`` from the monotonic
+   one: ``ts`` and ``mono`` are two reads, so order, nesting and self
+   time are judged on ``mono`` alone. ``cpu`` is the thread's own CPU
+   time inside the span: ``dur - cpu`` is what the thread spent waiting
+   (a lock, the interpreter lock, a blocking call), which a wall clock
+   cannot tell from work.
 3. **Bounded memory, outliving the capture.** Records land in one
    process-wide ring (``FLAGS_trace_buffer`` entries) that is read
    after the capture has ended (:func:`get_spans`, :func:`snapshot`)
@@ -111,7 +116,7 @@ def _flag_capacity() -> int:
     try:
         return int(flag("trace_buffer"))
     except KeyError:               # flag not registered yet (import order)
-        return 4096
+        return 16384
 
 
 _RING = _Ring(_flag_capacity())
@@ -181,10 +186,11 @@ _NOOP = _NoopSpan()
 class _Span:
     """One open span: a ``TraceAnnotation`` while it is open, a ring
     record on exit. ``t0``/``t1`` (``perf_counter_ns``) are its two
-    clock reads, for a caller that times the same section."""
+    clock reads, for a caller that times the same section; the thread's
+    CPU clock is read inside them, so ``cpu <= dur`` but for a tick."""
 
     __slots__ = ("name", "attrs", "trace_id", "span_id", "parent_id",
-                 "t0", "t1", "_ts", "_ann")
+                 "t0", "t1", "_ts", "_c0", "_ann")
 
     def __init__(self, name: str, attrs: dict,
                  trace_id: str | None = None,
@@ -216,10 +222,12 @@ class _Span:
                                 **self.attrs)
         self._ann.__enter__()
         self._ts = time.time_ns()          # realtime: the profiler's clock
-        self.t0 = time.perf_counter_ns()   # monotonic: exact duration
+        self.t0 = time.perf_counter_ns()   # monotonic: order, duration
+        self._c0 = time.thread_time_ns()   # this thread's CPU time
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        cpu = time.thread_time_ns() - self._c0
         self.t1 = time.perf_counter_ns()
         self._ann.__exit__(exc_type, exc, tb)
         stack = getattr(_ctx, "stack", None)
@@ -228,17 +236,13 @@ class _Span:
         if recording():                    # ended mid-span: drop it
             if exc_type is not None:
                 self.attrs["error"] = exc_type.__name__
-            _record(self.name, self._ts * 1e-9, (self.t1 - self.t0) * 1e-9,
-                    self.trace_id, self.span_id, self.parent_id, self.attrs)
+            _RING.record({
+                "name": self.name, "ts": self._ts * 1e-9,
+                "mono": self.t0 * 1e-9, "dur": (self.t1 - self.t0) * 1e-9,
+                "cpu": cpu * 1e-9, "tid": threading.get_ident(),
+                "trace_id": self.trace_id, "span_id": self.span_id,
+                "parent_id": self.parent_id, "attrs": self.attrs})
         return False
-
-
-def _record(name: str, ts: float, dur: float, trace_id: str, span_id: str,
-            parent_id: str | None, attrs: dict) -> None:
-    _RING.record({"name": name, "ts": ts, "dur": dur,
-                  "tid": threading.get_ident(), "trace_id": trace_id,
-                  "span_id": span_id, "parent_id": parent_id,
-                  "attrs": attrs})
 
 
 def span(name: str, **attrs: Any):
@@ -317,6 +321,8 @@ def to_chrome_events(spans: list[dict], pid: int | str = 0,
         args = {"trace_id": s["trace_id"], "span_id": s["span_id"]}
         if s.get("parent_id"):
             args["parent_id"] = s["parent_id"]
+        if "cpu" in s:                  # a record of an older peer has none
+            args["cpu"] = s["cpu"]
         args.update(s.get("attrs") or {})
         events.append({
             "name": s["name"], "ph": "X",

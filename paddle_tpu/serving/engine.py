@@ -156,15 +156,23 @@ thread and none per token: ``gen/loop`` (one iteration; ``queue``,
 ``waited_ms``, ``prefix_tokens``, ``pages``; under it ``gen/kv_fetch``
 and, for an admission that restores a state snapshot,
 ``gen/state_restore`` with ``slot``, ``snapshot``, ``tokens``),
-``gen/dev_ops``, ``gen/prefill`` / ``gen/prefill_chunk``,
-``gen/decode_step`` (``active``, ``spec``, ``compiled``, ``sort_slots``
-— the live slots whose request restricts its sampling, the steps that
-have one counted under ``gen/sample_sorted_steps`` — a plain paged
-step's ``decode_attn``; under it ``gen/step_dispatch`` and
-``gen/step_wait``, or ``gen/spec_verify`` around both), ``gen/draft``
-and ``gen/emit`` (``emitted``, ``retired``; under a prefill chunk's, for
-a snapshot kept, ``gen/state_snapshot`` with ``tokens``, ``evicted``).
-One helper, ``_phase``,
+``gen/dev_ops``, ``gen/table_upload`` (a dirty page table going up),
+``gen/prefill`` / ``gen/prefill_chunk`` (under them ``gen/prefill_wait``
+round the readback of a first token), ``gen/decode_step`` (``active``,
+``spec``, ``compiled``, ``sort_slots`` — the live slots whose request
+restricts its sampling, the steps that have one counted under
+``gen/sample_sorted_steps`` — a plain paged step's ``decode_attn``;
+under it ``gen/step_dispatch`` and ``gen/step_wait``, or
+``gen/spec_verify`` around both), ``gen/draft`` and ``gen/emit``
+(``emitted``, ``retired``; under a prefill chunk's, for a snapshot kept,
+``gen/state_snapshot`` with ``tokens``, ``evicted``). What the loop puts
+on the chip and what it has seen finish: ``gen/launch`` (``seq``,
+``entry``) is the child span round exactly the call into a compiled
+engine program, numbered by one counter on the loop thread
+(``_launch``), and the span that blocks on a result carries ``landed``,
+the ``seq`` it waited for (``gen/step_wait``, ``gen/prefill_wait``,
+``gen/draft``); between a landing that leaves nothing outstanding and
+the next launch the chip's queue is empty. One helper, ``_phase``,
 times each section with two clock reads that also feed the section's
 histogram and goodput bucket.
 """
@@ -1378,8 +1386,10 @@ class GenerationEngine:
         self._queue: deque[Generation] = deque()
         # gen_async_depth lookahead books: dispatched decode steps whose
         # token readback is deferred — entries are (stepped snapshot,
-        # device tokens, epoch at dispatch, chip share); oldest first
+        # device tokens, epoch at dispatch, chip share, launch number);
+        # oldest first
         self._pending: deque[tuple] = deque()
+        self._launched = 0      # compiled engine programs enqueued so far
         self._slot_gen: list[Generation | None] = [None] * self.slots
         self._gens: dict[str, Generation] = {}
         self._stopping = False
@@ -2016,9 +2026,13 @@ class GenerationEngine:
             fn = self._draft_fns[bucket] = self._build_draft_fn(bucket)
         padded = np.full((bucket,), self._pad, np.int32)
         padded[:T] = ctx
-        with self._phase("gen/draft", entry=("draft", bucket)):
-            out = np.asarray(fn(jnp.asarray(padded),
-                                jnp.asarray(T, jnp.int32)))
+        with self._phase("gen/draft", entry=("draft", bucket)) as call:
+            ops = jnp.asarray(padded), jnp.asarray(T, jnp.int32)
+            with self._launch("draft"):
+                out = fn(*ops)
+            del ops
+            out = np.asarray(out)
+            call.set(landed=self._launched)
         return out[:cap]
 
     def _build_draft_fn(self, bucket: int):
@@ -2127,6 +2141,20 @@ class GenerationEngine:
         else:
             span = _trace.span(name, **attrs)
         return _Phase(self, span, goodput, hist, entry)
+
+    def _launch(self, entry: str) -> _Phase | _NoopPhase:
+        """The section round exactly one call into a compiled engine
+        program (``gen/launch``: ``seq``, ``entry``), numbered as it is
+        enqueued. The device runs one stream in order, so the readback
+        that names this ``seq`` as ``landed`` proves every earlier
+        launch finished too; what the enclosing span holds outside this
+        one is operand staging. The request's key program and operand
+        transfers are staging, not launches. Loop thread only."""
+        self._launched += 1
+        ph = self._phase("gen/launch", seq=self._launched)
+        if ph is not _NOOP_PHASE:
+            ph.set(entry=entry)
+        return ph
 
     def _gen_event(self, gen: Generation, name: str, **attrs) -> None:
         """Zero-duration stream-lifecycle event (admitted / retire /
@@ -2842,7 +2870,8 @@ class GenerationEngine:
         if self._pt_dev is not None:
             return self._pt_dev
         if self._sched_pt is None:
-            self._sched_pt = self._pt_upload(jnp)
+            with self._phase("gen/table_upload"):
+                self._sched_pt = self._pt_upload(jnp)
         return self._sched_pt
 
     def _fail_active_locked(self, msg: str) -> list[Generation]:
@@ -3679,13 +3708,26 @@ class GenerationEngine:
                                  index=a, tokens=b - a,
                                  final=final) as chunk:
                     _fault.inject("engine.prefill")
-                    self._state, tok0 = self._prefill_fn(
-                        self._state, pt_dev,
-                        jnp.asarray(slot, jnp.int32), jnp.asarray(padded),
-                        jnp.asarray(a, jnp.int32),
-                        jnp.asarray(b - a, jnp.int32), key,
-                        temp, top_k, top_p, *state_ops)
-                    tok0 = int(tok0) if final else None
+                    ops = (jnp.asarray(slot, jnp.int32), jnp.asarray(padded),
+                           jnp.asarray(a, jnp.int32),
+                           jnp.asarray(b - a, jnp.int32))
+                    with self._launch("paged_prefill"):
+                        self._state, tok0 = self._prefill_fn(
+                            self._state, pt_dev, *ops, key,
+                            temp, top_k, top_p, *state_ops)
+                    # let go while the call is in flight, as a temporary
+                    # would be: the runtime then frees the operands off
+                    # this thread (freed after the readback, the loop
+                    # pays for it: 0.6 ms a step on the chip, PR 37)
+                    del ops
+                    if final:
+                        # a chunk that is not the last reads nothing
+                        # back: it launches and lands nothing
+                        with self._phase("gen/prefill_wait",
+                                         landed=self._launched):
+                            tok0 = int(tok0)
+                    else:
+                        tok0 = None
             except Exception as e:       # a prefill trap implicates
                 self._note_trap([gen], e, exact=True)  # exactly this one
                 raise
@@ -3744,11 +3786,14 @@ class GenerationEngine:
                              ("prefill", bucket), gen, slot=slot,
                              prompt_len=T0, bucket=bucket) as call:
                 _fault.inject("engine.prefill")
-                self._state, tok0 = self._prefill_fn(
-                    self._state, jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(padded), jnp.asarray(T0, jnp.int32), key,
-                    temp, top_k, top_p)
-                tok0 = int(tok0)
+                ops = (jnp.asarray(slot, jnp.int32), jnp.asarray(padded),
+                       jnp.asarray(T0, jnp.int32))
+                with self._launch("prefill"):
+                    self._state, tok0 = self._prefill_fn(
+                        self._state, *ops, key, temp, top_k, top_p)
+                del ops             # see _prefill_tick
+                with self._phase("gen/prefill_wait", landed=self._launched):
+                    tok0 = int(tok0)
         except Exception as e:           # a prefill trap implicates
             self._note_trap([gen], e, exact=True)     # exactly this one
             raise
@@ -3835,16 +3880,17 @@ class GenerationEngine:
             use_spec = bool(dlens.any())
         lookahead = self._async_depth > 0 and not use_spec
         args = (pt_dev,) if self._paged else ()
+        entry = ("spec_step" if use_spec
+                 else ("paged_step" if self._paged else "step"))
         try:
-            # gen/step_dispatch: operands and the call until it returns
-            # (the device starts before it does); gen/step_wait: the
-            # host blocked on the device for the tokens
+            # gen/step_dispatch: operand staging, then gen/launch round
+            # the call until it returns (the device starts before it
+            # does); gen/step_wait: the host blocked on the device for
+            # the tokens of the launch it names as landed
             with self._phase(
                     "gen/decode_step",
                     "spec_verify" if use_spec else "decode",
-                    "gen/decode_step_s",
-                    ("spec_step" if use_spec
-                     else ("paged_step" if self._paged else "step"), 0),
+                    "gen/decode_step_s", (entry, 0),
                     active=len(stepped), spec=int(use_spec),
                     sort_slots=sort_slots) as call:
                 _fault.inject("engine.decode_step")
@@ -3856,20 +3902,28 @@ class GenerationEngine:
                                      hist="gen/spec_verify_s",
                                      drafted=int(dlens.sum())):
                         with self._phase("gen/step_dispatch"):
-                            self._state, out, emit = self._spec_step(
-                                self._state, *args, jnp.asarray(active),
-                                jnp.asarray(drafts), jnp.asarray(dlens))
-                        with self._phase("gen/step_wait"):
+                            ops = (jnp.asarray(active), jnp.asarray(drafts),
+                                   jnp.asarray(dlens))
+                            with self._launch(entry):
+                                self._state, out, emit = self._spec_step(
+                                    self._state, *args, *ops)
+                            del ops         # see _prefill_tick
+                        with self._phase("gen/step_wait",
+                                         landed=self._launched):
                             out = np.asarray(out)
                             emit = np.asarray(emit)
                 else:
                     with self._phase("gen/step_dispatch"):
-                        self._state, toks = self._step(
-                            self._state, *args, jnp.asarray(active))
+                        mask = jnp.asarray(active)
+                        with self._launch(entry):
+                            self._state, toks = self._step(
+                                self._state, *args, mask)
+                        del mask            # see _prefill_tick
                     if self._paged:
                         call.set(decode_attn=self._decode_attn)
                     if not lookahead:
-                        with self._phase("gen/step_wait"):
+                        with self._phase("gen/step_wait",
+                                         landed=self._launched):
                             toks = np.asarray(toks)
         except Exception as e:
             # the fused step shares one compiled call: every stepped
@@ -3891,7 +3945,8 @@ class GenerationEngine:
             # late, safe because post-EOS steps write only pads.
             # _consec_traps is NOT reset here: only the readback in
             # _finish_step proves the device work actually ran.
-            self._pending.append((stepped, toks, epoch0, chip_share))
+            self._pending.append((stepped, toks, epoch0, chip_share,
+                                  self._launched))
             while len(self._pending) > self._async_depth:
                 self._drain_pending(1)
             self._pace()
@@ -3952,8 +4007,8 @@ class GenerationEngine:
             if n is not None:
                 n -= 1
 
-    def _finish_step(self, stepped, toks_dev, epoch0,
-                     chip_share) -> None:
+    def _finish_step(self, stepped, toks_dev, epoch0, chip_share,
+                     seq) -> None:
         """Second half of a lookahead decode step: the now-explicit
         blocking readback — measured and booked as ``host_gather``
         instead of swept in by ``tick`` — followed by the same
@@ -3963,7 +4018,7 @@ class GenerationEngine:
         or reassigned by an earlier entry is skipped by the identity
         guard, so lagged post-EOS tokens are never delivered."""
         try:
-            with self._phase("gen/step_wait", "host_gather"):
+            with self._phase("gen/step_wait", "host_gather", landed=seq):
                 toks = np.asarray(toks_dev)
         except Exception as e:
             self._note_trap([g for _, g in stepped], e)

@@ -370,20 +370,6 @@ def test_preemption_sigterm_saves_and_exits(tmp_path):
 # monitor satellites
 # ---------------------------------------------------------------------------
 
-def test_step_timer_windowed_tokens_per_sec():
-    monitor.reset_stats("tt/")
-    t = monitor.StepTimer("tt", window=8)
-    for tok in (100, 200, 300):
-        t.tick(tokens=tok)
-    sps = monitor.get_stat("tt/steps_per_sec")
-    tps = monitor.get_stat("tt/tokens_per_sec")
-    assert sps > 0 and tps > 0
-    # dt cancels in the ratio: windowed mean of the ticks the interval
-    # spans = (200+300)/2, NOT the old last-tick value 300
-    assert tps / sps == pytest.approx((200 + 300) / 2)
-    assert monitor.get_stat("tt/tokens") == 600
-
-
 def test_host_rss_current_vs_peak():
     cur, peak = monitor.host_rss_bytes(), monitor.host_peak_rss_bytes()
     assert isinstance(cur, int) and isinstance(peak, int)

@@ -154,19 +154,9 @@ def test_train_step_increments_fleet_steps():
     assert monitor.get_stat("fleet/steps") == 3
 
 
-def test_step_timer_and_host_monitors():
-    import time as _time
-
+def test_host_monitors():
     from paddle_tpu.core import monitor
 
-    monitor.reset_stats("bench/")
-    t = monitor.StepTimer("bench", window=4)
-    for _ in range(5):
-        t.tick(tokens=128)
-        _time.sleep(0.01)
-    assert monitor.get_stat("bench/steps") == 5
-    assert monitor.get_stat("bench/steps_per_sec") > 0
-    assert monitor.get_stat("bench/tokens_per_sec") > 0
     assert monitor.host_rss_bytes() > 10 * 1024 * 1024
     mem = monitor.device_memory_stats()
     assert isinstance(mem, dict)

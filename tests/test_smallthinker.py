@@ -357,7 +357,9 @@ def test_streams_sharing_a_template_equal_solo_generate(model, template,
         # stream prefilled its tail alone
         hit = (monitor.get_stat("gen/prefix_tokens_saved") or 0) - mid
         assert hit == template.size // P * P > 2 * WINDOW
-        assert mid - saved0 in (0, hit)     # b may have raced a's prefill
+        # b may have raced a's prefill, which enters the cache a chunk's
+        # whole pages at a time: any whole number of pages up to the hit
+        assert 0 <= mid - saved0 <= hit and (mid - saved0) % P == 0
         st = eng.stats()
         assert st["groups"][1]["stream_pages_peak"] <= ROW_PAGES
         assert st["groups"][1]["pages_slid"] > 0
