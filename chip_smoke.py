@@ -625,9 +625,16 @@ def serve_phase(model, requests, *, slots: int, max_len: int,
             g = health["generators"][name]
             _check_generator(name, g, platform=devices[0].platform,
                              devices=len(devices))
+            # a poll waits on its own stream's wake-up: the polls that
+            # waited, the wake-ups that found tokens or an end, and those
+            # that found nothing (a missed wake-up shows as a poll that
+            # waited out its time instead)
+            log(f"serve {name}: polls {g['poll']}")
+            check(g["poll"]["wakes"] > 0,
+                  f"{name}: no poll was woken: {g['poll']}")
             report.setdefault(name, {}).update(
                 streams=len(requests), probe_repeat=repeats[name],
-                compiles=g["compiles"],
+                compiles=g["compiles"], poll=g["poll"],
                 device=g["device"],
                 pages=({k: g[k] for k in ("pages", "pages_free",
                                           "prefix_entries")}

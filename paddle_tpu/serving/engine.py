@@ -181,6 +181,7 @@ from __future__ import annotations
 
 import functools
 import hashlib
+import queue
 import random as _random_mod
 import threading
 import time
@@ -377,7 +378,7 @@ class Generation:
                  "tenant", "admitted_ts", "first_tok_ts", "done_ts",
                  "chip_s", "ledgered", "dev_ops", "pclass", "folded",
                  "queue_booked", "sched_seq", "sched_vft", "sched_ts",
-                 "win", "sorts", "snap_src")
+                 "win", "sorts", "snap_src", "wake", "waiting")
 
     def __init__(self, gen_id: str, prompt: np.ndarray,
                  max_new_tokens: int, temperature: float, top_k: int,
@@ -458,6 +459,12 @@ class Generation:
         self.sched_seq = 0
         self.sched_vft = 0.0
         self.sched_ts = 0.0
+        # the stream's own wake-up: a poll waits on it (never on the
+        # engine's lock) and counts itself in ``waiting``; whatever hands
+        # the stream a token, an end or an error puts one in
+        # (``GenerationEngine._wake``), which never blocks the loop
+        self.wake: queue.SimpleQueue = queue.SimpleQueue()
+        self.waiting = 0
 
 
 class _PagePool:
@@ -1382,7 +1389,15 @@ class GenerationEngine:
             self._spec_step = (self._build_spec_step()
                                if self._spec_k > 0 else None)
 
+        # the loop thread's lock and wake-up: admission, stepping and
+        # retirement books; a poll waits on its stream's own wake-up
         self._cond = threading.Condition()
+        # polls that had to wait, wake-ups that found tokens or an end,
+        # and those that found nothing (``stats()["poll"]``), and each
+        # stream's count of waiting polls; a lock of their own, which
+        # the loop thread never takes
+        self._poll_lock = threading.Lock()
+        self._poll_counts = {"waits": 0, "wakes": 0, "wakes_empty": 0}
         self._queue: deque[Generation] = deque()
         # gen_async_depth lookahead books: dispatched decode steps whose
         # token readback is deferred — entries are (stepped snapshot,
@@ -2348,12 +2363,19 @@ class GenerationEngine:
         ones (long-poll). Returns ``{"tokens", "done", "error",
         "queued"}``. Polling refreshes the generation's TTL — a client
         that stops polling for ``ttl_s`` is treated as disconnected and
-        its slot reclaimed."""
+        its slot reclaimed.
+
+        A poll takes the engine's lock only for a stream that has ended
+        (its final poll) or an id it does not know; one that waits,
+        waits on its stream's own wake-up. Without the lock it reads
+        what the loop may be writing, which is sound because a stream's
+        tokens are only ever appended, ``done`` is set after the last of
+        them and before the slot is let go, and ``error`` is read under
+        the lock."""
         start = max(int(start), 0)
-        deadline = time.monotonic() + max(float(wait_s), 0.0)
-        with self._cond:
-            gen = self._gens.get(gen_id)
-            if gen is None:
+        gen = self._gens.get(gen_id)
+        if gen is None:
+            with self._cond:         # a reap's pop and tombstone, whole
                 if gen_id in self._expired:
                     # reaped by the TTL (possibly while this poll was
                     # in flight): typed, so the caller can tell "your
@@ -2363,18 +2385,15 @@ class GenerationEngine:
                         f"{EXPIRED_MARKER} generation {gen_id} was "
                         "reaped by the poll TTL (client presumed "
                         "disconnected); restart it")
-                raise KeyError(f"unknown generation {gen_id!r} "
-                               "(finished long ago, evicted, or never "
-                               "started here)")
-            gen.last_poll = time.monotonic()
-            while (not gen.done and len(gen.tokens) <= start
-                   and not self._stopping):
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
-                    break
-                self._cond.wait(remaining)
-                gen.last_poll = time.monotonic()
-            if gen.done:
+            raise KeyError(f"unknown generation {gen_id!r} "
+                           "(finished long ago, evicted, or never "
+                           "started here)")
+        gen.last_poll = now = time.monotonic()
+        if not self._polled(gen, start) and float(wait_s) > 0:
+            self._poll_wait(gen, start, now + float(wait_s))
+        slot = gen.slot
+        if gen.done:
+            with self._cond:
                 # this response tells the caller the generation finished
                 # and hands over every token past ``start`` — fully
                 # delivered (the condition a sticky drain waits on
@@ -2382,9 +2401,75 @@ class GenerationEngine:
                 gen.delivered = True
                 self._ledger_finalize(
                     gen, "complete" if gen.error is None else "failed")
-            return {"tokens": list(gen.tokens[start:]), "done": gen.done,
-                    "error": gen.error,
-                    "queued": gen.slot is None and not gen.done}
+                return {"tokens": gen.tokens[start:], "done": True,
+                        "error": gen.error, "queued": False}
+        return {"tokens": gen.tokens[start:], "done": False, "error": None,
+                "queued": slot is None}
+
+    def _polled(self, gen: Generation, start: int) -> bool:
+        """A poll from ``start`` has something to return: a token past
+        it, the stream's end, or the engine's."""
+        return gen.done or len(gen.tokens) > start or self._stopping
+
+    def _poll_wait(self, gen: Generation, start: int,
+                   deadline: float) -> None:
+        """Wait on the stream's own wake-up until :meth:`_polled` or
+        ``deadline``. Clear, then re-check, then wait: a wake-up put in
+        after the clear is kept, so none falls between the check and
+        the wait, and one left over from an earlier poll is cleared."""
+        wake = gen.wake
+        wakes = empty = 0
+        woke = False
+        with self._poll_lock:
+            gen.waiting += 1
+        try:
+            while True:
+                try:
+                    while True:
+                        wake.get_nowait()
+                except queue.Empty:
+                    pass
+                if self._polled(gen, start):
+                    wakes += woke
+                    break
+                empty += woke
+                remaining = deadline - time.monotonic()
+                if remaining <= 0:
+                    break
+                try:
+                    woke = wake.get(timeout=remaining)
+                except queue.Empty:
+                    woke = False
+                gen.last_poll = time.monotonic()
+        finally:
+            with self._poll_lock:
+                gen.waiting -= 1
+                others = gen.waiting
+                counts = self._poll_counts
+                counts["waits"] += 1
+                counts["wakes"] += wakes
+                counts["wakes_empty"] += empty
+            if others:              # another poll of this stream waits:
+                wake.put(True)      # hand the wake-up on
+        stat_add("gen/poll_waits")
+        if wakes:
+            stat_add("gen/poll_wakes", wakes)
+        if empty:
+            stat_add("gen/poll_wakes_empty", empty)
+
+    @staticmethod
+    def _wake(gens) -> int:
+        """Hand each stream of ``gens`` its wake-up: a poll waiting on
+        it returns with what it was given. Called once ``_cond`` is let
+        go where the site allows, so that a woken poll never finds it
+        held; takes no lock a poll may hold. Returns how many of the
+        streams had a poll waiting."""
+        woken = 0
+        for gen in gens:
+            if gen.wake.empty():    # else one is in already
+                gen.wake.put(True)
+            woken += gen.waiting > 0
+        return woken
 
     def cancel(self, gen_id: str) -> bool:
         """Cancel a generation and free its slot (idempotent; unknown
@@ -2409,7 +2494,8 @@ class GenerationEngine:
             # covers the done-but-undelivered case too: a cancel is the
             # last event this engine will ever see for the generation
             self._ledger_finalize(gen, "cancelled")
-            self._cond.notify_all()
+            self._cond.notify_all()        # the loop: a slot is free
+        self._wake((gen,))
         return True
 
     def stats(self) -> dict:
@@ -2469,6 +2555,8 @@ class GenerationEngine:
                    "async_depth": self._async_depth,
                    "pending_steps": len(self._pending),
                    "kv_bytes_per_token": self._kv_bytes_per_token}
+            with self._poll_lock:
+                doc["poll"] = dict(self._poll_counts)
             if self._paged:
                 doc["decode_attn"] = self._decode_attn
             if self._snaps is not None:
@@ -2662,7 +2750,8 @@ class GenerationEngine:
             self._watchdog.join(timeout=2.0)
         self._thread.join(timeout=10.0)
         with self._cond:
-            for gen in list(self._gens.values()):
+            gens = list(self._gens.values())
+            for gen in gens:
                 if not gen.done:
                     gen.done = True
                     gen.error = gen.error or "engine stopped"
@@ -2676,7 +2765,9 @@ class GenerationEngine:
             self._pending.clear()
             if self._paged:
                 self._pt_clear_locked()
-            self._cond.notify_all()
+        # a poll that waited through the loop's last iteration returns
+        # with the stream's end (one that comes now returns at once)
+        self._wake(gens)
         if self._kv is not None and self._kv_owned:
             self._kv.close()   # shared stores outlive their engines
 
@@ -2911,7 +3002,7 @@ class GenerationEngine:
         fresh = self._init_state()           # allocate outside the lock
         with self._cond:
             self._rebuilds += 1
-            self._fail_active_locked(msg)
+            victims = self._fail_active_locked(msg)
             if self._paged:
                 self._reset_pools_locked()
                 stat_set("gen/pages_free", self._pages_free())
@@ -2920,7 +3011,7 @@ class GenerationEngine:
             # stays counted, the tail since then is lost with the state
             self._counts_base = dict(self._counts_host)
             self._stuck = False
-            self._cond.notify_all()
+        self._wake(victims)
 
     def _watchdog_loop(self) -> None:
         """Stuck-step detection: active work but no loop heartbeat for
@@ -2965,7 +3056,7 @@ class GenerationEngine:
                     self._ledger_finalize(admitting, "failed")
                     victims = victims + [admitting]
                 self._stuck = True
-                self._cond.notify_all()
+            self._wake(victims)
             self._note_trap(victims,
                             TimeoutError("stuck decode step"))
 
@@ -2974,7 +3065,8 @@ class GenerationEngine:
         with self._cond:
             self._broken = msg
             self._stuck = False       # broken supersedes stuck
-            for gen in list(self._gens.values()):
+            gens = list(self._gens.values())
+            for gen in gens:
                 if not gen.done:
                     gen.done = True
                     gen.error = msg
@@ -2990,7 +3082,7 @@ class GenerationEngine:
                 self._pt_clear_locked()   # reset the books for stats()
                 self._reset_pools_locked()
             self._pending.clear()
-            self._cond.notify_all()
+        self._wake(gens)
 
     def _reset_pools_locked(self) -> None:
         """Fresh page books (rebuild / break): nothing of the old device
@@ -3086,7 +3178,7 @@ class GenerationEngine:
                 # done-but-never-delivered generations retire here too:
                 # the reap is the last event this engine sees for them
                 self._ledger_finalize(g, "expired")
-                self._cond.notify_all()
+            self._wake((g,))
 
     def _admit(self) -> None:
         while True:
@@ -3556,30 +3648,36 @@ class GenerationEngine:
         stat_add("gen/tokens")
         self._deliver_locked(gen, (tok0,))
 
-    def _emit_step_locked(self, emit_ph, stepped, chip_share, toks,
-                          accepted=None) -> None:
-        """Body of a decode step's ``gen/emit``, sync or lagged: every
-        stepped slot its generation still holds (not cancelled
+    def _emit_step(self, stepped, chip_share, toks, accepted=None) -> None:
+        """A decode step's ``gen/emit``, sync or lagged: under ``_cond``
+        every stepped slot its generation still holds (not cancelled
         mid-step, not retired by an earlier lagged entry) takes its
         share of the step's chip-seconds and its token ``toks[slot]``
-        — of a speculative step, the tokens ``accepted(slot, gen)``.
-        ``_cond`` held."""
+        — of a speculative step, the tokens ``accepted(slot, gen)``;
+        then, with the lock let go, those streams' polls are woken."""
         emitted = retired = 0
-        for s, gen in stepped:
-            if self._slot_gen[s] is not gen:
-                continue
-            if self._ledger is not None:
-                gen.chip_s += chip_share
-            e, r = self._deliver_locked(
-                gen, (toks[s],) if accepted is None else accepted(s, gen))
-            emitted += e
-            retired += r
-        self._emit_total += emitted
-        self._decode_iters += 1
-        if emitted:
-            stat_add("gen/tokens", emitted)
-        self._cond.notify_all()
-        emit_ph.set(emitted=emitted, retired=retired)
+        moved = []
+        with self._phase("gen/emit") as emit_ph:
+            with self._cond:
+                if accepted is not None:
+                    self._spec_verify_steps += 1
+                for s, gen in stepped:
+                    if self._slot_gen[s] is not gen:
+                        continue
+                    if self._ledger is not None:
+                        gen.chip_s += chip_share
+                    e, r = self._deliver_locked(
+                        gen, (toks[s],) if accepted is None
+                        else accepted(s, gen))
+                    emitted += e
+                    retired += r
+                    moved.append(gen)
+                self._emit_total += emitted
+                self._decode_iters += 1
+                if emitted:
+                    stat_add("gen/tokens", emitted)
+            emit_ph.set(emitted=emitted, retired=retired,
+                        woken=self._wake(moved))
 
     def _slide_locked(self, rows) -> None:
         """Before the upload of the tables for the programs about to be
@@ -3739,36 +3837,39 @@ class GenerationEngine:
                 raise _EpochChanged("prefill chunk outlived the "
                                     "watchdog deadline")
             ticked = True
-            with self._phase("gen/emit", emitted=int(final)), self._cond:
-                if self._slot_gen[slot] is not gen:
-                    if keep:                # cancelled/reaped mid-chunk
-                        self._snaps.release(keep)
-                    continue
-                gen.prefill_pos = b
-                if self._snaps is not None:
-                    # the chunk has read its source: the pin goes
-                    self._snaps.release(gen.snap_src or 0)
-                    gen.snap_src = None
-                    if keep:
-                        self._keep_snapshot_locked(gen, b, keep, evicted)
-                if self._win is not None and self._prefix is not None:
-                    # a window row holds a prompt page only until the
-                    # stream has passed it: the cache takes both pages
-                    # of every whole page as soon as a chunk filled it
-                    self._prefix_insert_groups_locked(gen, a, b)
-                if not final:
-                    continue
-                gen.prefilling = False
-                observe("gen/prefill_s",
-                        chunk.t1 * 1e-9 - gen.prefill_t0)
-                if gen.win is not None:
-                    gen.win.pos = int(T0)
-                elif self._prefix is not None and self._snaps is None:
-                    self._prefix.insert(gen.prompt, gen.pages, self._pool)
-                if self._kv is not None:
-                    self._kv_publish(gen)
-                self._first_token_locked(gen, tok0)
-                self._cond.notify_all()
+            with self._phase("gen/emit", emitted=int(final)) as emit_ph:
+                with self._cond:
+                    if self._slot_gen[slot] is not gen:
+                        if keep:            # cancelled/reaped mid-chunk
+                            self._snaps.release(keep)
+                        continue
+                    gen.prefill_pos = b
+                    if self._snaps is not None:
+                        # the chunk has read its source: the pin goes
+                        self._snaps.release(gen.snap_src or 0)
+                        gen.snap_src = None
+                        if keep:
+                            self._keep_snapshot_locked(gen, b, keep,
+                                                       evicted)
+                    if self._win is not None and self._prefix is not None:
+                        # a window row holds a prompt page only until the
+                        # stream has passed it: the cache takes both pages
+                        # of every whole page as soon as a chunk filled it
+                        self._prefix_insert_groups_locked(gen, a, b)
+                    if not final:
+                        continue
+                    gen.prefilling = False
+                    observe("gen/prefill_s",
+                            chunk.t1 * 1e-9 - gen.prefill_t0)
+                    if gen.win is not None:
+                        gen.win.pos = int(T0)
+                    elif self._prefix is not None and self._snaps is None:
+                        self._prefix.insert(gen.prompt, gen.pages,
+                                            self._pool)
+                    if self._kv is not None:
+                        self._kv_publish(gen)
+                    self._first_token_locked(gen, tok0)
+                emit_ph.set(woken=self._wake((gen,)))
         return ticked
 
     def _prefill(self, gen: Generation, slot: int) -> None:
@@ -3807,7 +3908,7 @@ class GenerationEngine:
             if self._slot_gen[slot] is not gen:   # cancelled mid-prefill
                 return
             self._first_token_locked(gen, tok0)
-            self._cond.notify_all()
+        self._wake((gen,))
 
     def _decode_step(self, jnp) -> bool:
         if self._pending and self._spec_k > 0:
@@ -3973,14 +4074,10 @@ class GenerationEngine:
                                 proposed=dlen, accepted=acc)
             return [int(t) for t in out[s, :n]]
 
-        with self._phase("gen/emit") as emit_ph, self._cond:
-            if use_spec:
-                self._spec_verify_steps += 1
-                self._emit_step_locked(emit_ph, stepped, chip_share, None,
-                                       accepted)
-            else:
-                self._emit_step_locked(emit_ph, stepped, chip_share,
-                                       toks.tolist())
+        if use_spec:
+            self._emit_step(stepped, chip_share, None, accepted)
+        else:
+            self._emit_step(stepped, chip_share, toks.tolist())
         self._pace()
         return True
 
@@ -4030,6 +4127,4 @@ class GenerationEngine:
             # in flight — its tokens are garbage; the loop's stuck
             # latch forces the rebuild/break decision
             return
-        with self._phase("gen/emit") as emit_ph, self._cond:
-            self._emit_step_locked(emit_ph, stepped, chip_share,
-                                   toks.tolist())
+        self._emit_step(stepped, chip_share, toks.tolist())

@@ -8,6 +8,7 @@ bucket — must be byte-identical to a solo
 ``models.generation.generate`` call.
 """
 
+import collections
 import threading
 import time
 
@@ -654,3 +655,173 @@ def test_engine_programs_carry_kv_and_sample_scopes(paged_engine, model):
         for name, text in eng.lowered_text(6).items():
             for scope in ("kv/write", "sample", "attn"):
                 assert scope in text, f"{scope} missing from {name}"
+
+
+# -- a poll waits on its own stream's wake-up, not on the engine's lock ---
+
+class _HeldLoop(GenerationEngine):
+    """An engine whose loop does nothing: streams stay where the test
+    seats them, and the test hands them tokens and ends itself."""
+
+    def _iterate(self, jnp, it):
+        time.sleep(0.002)
+        return self._stopping
+
+
+def _seat(eng, n, max_new=8, eos=None):
+    """``n`` started streams moved from the queue into slots 0..n-1."""
+    gids = [eng.start(np.arange(1, 4, dtype=np.int32), max_new,
+                      eos_token_id=eos) for _ in range(n)]
+    with eng._cond:
+        gens = [eng._gens[g] for g in gids]
+        for s, g in enumerate(gens):
+            eng._queue.remove(g)
+            eng._slot_gen[s], g.slot = g, s
+    return gids, gens
+
+
+def _waiting_polls(eng, gids, gens, wait_s=5.0):
+    """A thread a stream, each blocked in ``poll(wait_s=...)``; returns
+    the threads and ``{i: (doc, monotonic time it returned)}``."""
+    out = {}
+
+    def run(i, gid):
+        doc = eng.poll(gid, wait_s=wait_s)
+        out[i] = (doc, time.monotonic())
+
+    threads = [threading.Thread(target=run, args=(i, g), daemon=True,
+                                name=f"poller-{i}")
+               for i, g in enumerate(gids)]
+    [t.start() for t in threads]
+    deadline = time.monotonic() + 5.0
+    while any(g.waiting == 0 for g in gens):
+        assert time.monotonic() < deadline, "polls never waited"
+        time.sleep(0.005)
+    return threads, out
+
+
+class _CountingCond:
+    """The engine's condition, counting ``with`` entries by thread."""
+
+    def __init__(self, cond):
+        self._inner = cond
+        self.takes = collections.Counter()
+
+    def __enter__(self):
+        self.takes[threading.current_thread().name] += 1
+        return self._inner.__enter__()
+
+    def __exit__(self, *exc):
+        return self._inner.__exit__(*exc)
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+
+@pytest.fixture
+def held(model):
+    eng = _HeldLoop(model, slots=4, max_len=32, ttl_s=30.0)
+    yield eng
+    eng.close()
+
+
+def test_a_delivery_wakes_its_own_stream_alone(held):
+    gids, gens = _seat(held, 4)
+    threads, out = _waiting_polls(held, gids, gens)
+    before, wakes0 = held.stats()["poll"], get_stat("gen/poll_wakes")
+    held._emit_step([(0, gens[0])], 0.0, [7, 0, 0, 0])
+    threads[0].join(1.0)
+    assert not threads[0].is_alive()
+    assert out[0][0]["tokens"] == [7] and not out[0][0]["done"]
+    after = held.stats()["poll"]
+    assert after["wakes"] == before["wakes"] + 1
+    assert after["wakes_empty"] == before["wakes_empty"]
+    assert get_stat("gen/poll_wakes") == wakes0 + 1
+    time.sleep(0.05)            # the other three were not woken
+    assert all(t.is_alive() for t in threads[1:])
+    assert [g.waiting for g in gens[1:]] == [1, 1, 1]
+    for g in gids[1:]:
+        held.cancel(g)
+    [t.join(1.0) for t in threads]
+    assert [out[i][0]["error"] for i in (1, 2, 3)] == ["cancelled"] * 3
+    assert held.stats()["poll"]["waits"] == before["waits"] + 4
+
+
+def test_a_waiting_poll_neither_holds_nor_takes_the_engine_lock(held):
+    gids, gens = _seat(held, 4)
+    held._cond = counting = _CountingCond(held._cond)
+    threads, out = _waiting_polls(held, gids, gens)
+    t0 = time.monotonic()
+    with held._cond:                # never behind a poller
+        took = time.monotonic() - t0
+    assert took < 0.05
+    held._emit_step([(s, g) for s, g in enumerate(gens)], 0.0,
+                    [11, 12, 13, 14])
+    [t.join(1.0) for t in threads]
+    assert [out[i][0]["tokens"] for i in range(4)] == [[11], [12], [13],
+                                                       [14]]
+    assert not any(n for name, n in counting.takes.items()
+                   if name.startswith("poller-"))
+
+
+@pytest.mark.parametrize("end", ["complete", "eos", "cancel", "reap",
+                                 "break", "close", "rebuild"])
+def test_every_end_returns_a_waiting_poll(model, end):
+    from paddle_tpu.serving.engine import EXPIRED_MARKER, RESET_MARKER
+
+    eng = _HeldLoop(model, slots=2, max_len=32, ttl_s=30.0)
+    try:
+        gids, gens = _seat(eng, 1, max_new=1 if end == "complete" else 8,
+                           eos=9 if end == "eos" else None)
+        threads, out = _waiting_polls(eng, gids, gens)
+        if end == "reap":
+            eng._ttl_s = 0.01
+            time.sleep(0.02)
+        t0 = time.monotonic()
+        if end in ("complete", "eos"):
+            eng._emit_step([(0, gens[0])], 0.0,
+                           [5 if end == "complete" else 9, 0])
+        elif end == "cancel":
+            eng.cancel(gids[0])
+        elif end == "reap":
+            eng._reap_expired()
+        elif end == "break":
+            eng._break(RuntimeError("boom"))
+        elif end == "close":
+            eng.close()
+        else:
+            eng._rebuild(RuntimeError("trap"))
+        threads[0].join(1.0)
+        assert not threads[0].is_alive()
+        doc, t1 = out[0]
+        assert t1 - t0 < 0.5 and doc["done"]
+        err = doc["error"]
+        if end in ("complete", "eos"):
+            assert err is None
+            assert doc["tokens"] == [5 if end == "complete" else 9]
+        else:
+            assert err == {"cancel": "cancelled",
+                           "break": "RuntimeError: boom",
+                           "close": "engine stopped"}.get(end, err)
+            assert end not in ("reap", "rebuild") or err.startswith(
+                EXPIRED_MARKER if end == "reap" else RESET_MARKER)
+        assert eng.stats()["poll"] == {"waits": 1, "wakes": 1,
+                                       "wakes_empty": 0}
+    finally:
+        eng.close()
+
+
+def test_two_polls_of_one_stream_both_return(held):
+    """A second poll of the same stream (a client that retried) is not
+    left waiting out its time: the first to wake hands the wake-up on."""
+    gids, gens = _seat(held, 1)
+    threads, out = _waiting_polls(held, gids * 2, gens * 2)
+    deadline = time.monotonic() + 5.0
+    while gens[0].waiting < 2:
+        assert time.monotonic() < deadline
+        time.sleep(0.005)
+    t0 = time.monotonic()
+    held._emit_step([(0, gens[0])], 0.0, [3, 0, 0, 0])
+    [t.join(1.0) for t in threads]
+    assert [out[i][0]["tokens"] for i in (0, 1)] == [[3], [3]]
+    assert max(out[i][1] for i in (0, 1)) - t0 < 0.5
