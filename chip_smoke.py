@@ -15,7 +15,10 @@ seed), in ONE process that holds the chip throughout:
    ``ops.pallas.force_interpret()``;
 3. serve — the trained weights behind ``io.InferenceServer`` on a
    loopback port, a contiguous-cache and a paged+prefix-cache generator,
-   concurrent ``InferenceClient.generate`` streams from threads;
+   concurrent ``InferenceClient.generate`` streams from threads; then
+   small models of the other serving families — latent attention, window
+   and full layers, a recurrent state group, block diffusion
+   (``block_phase``: the block step through ``ptpu_paged_block_attn``);
 4. on a host with >= 4 chips, additionally ZeRO-3 x4 training and
    ``mesh_tp=4`` serving, with every Pallas unit asserted on the kernel
    arm of its per-shard (shard_map) dispatch and state spread over all
@@ -72,6 +75,9 @@ LATENT_DECODE_KERNEL = "ptpu_paged_latent_decode_attn"
 # the one-token step of a KDA layer (a recurrent state read and written
 # once, in place): the state group's step holds one call a KDA layer
 KDA_STEP_KERNEL = "ptpu_kda_step"
+# a block-diffusion step: the K/V kernel's copy form at B query rows a
+# slot, each slot's live pages read once a layer for the whole block
+BLOCK_ATTN_KERNEL = "ptpu_paged_block_attn"
 # per-shard (shard_map) units the four-chip programs must take on the
 # kernel arm (``ops.pallas.partition_stats()`` keys ``<unit>:kernel``)
 TRAIN_UNITS = ("flash_fwd", "flash_bwd", "rms_fwd", "rms_bwd", "rope",
@@ -856,6 +862,78 @@ def state_phase(requests, *, slots: int, max_len: int, chunk: int,
         server.stop()
 
 
+def block_phase(requests, *, slots: int, max_len: int, chunk: int,
+                on_chip: bool = True) -> dict:
+    """A small SDAR model (block diffusion over blocks of 4, head-wise
+    q/k norm, pages of 2 KV heads x 16 tokens x 128) in float32 behind a
+    paged, prefix-cached engine: the greedy requests streamed
+    concurrently (the engine refuses sampled ones), every stream held to
+    the solo ``block_diffusion_generate`` byte for byte, on the chip too.
+    Matmuls run at "highest" for the phase — the global setting, which
+    the engine's loop thread reads, and which gives the kernel's products
+    float32 contraction — so that the two, programs of other shapes,
+    round alike to the last bits. On the chip the block step has to
+    attend through ``ptpu_paged_block_attn``; the pool has to come back
+    whole."""
+    import jax
+
+    import paddle_tpu
+    from paddle_tpu.models.sdar import SDARConfig, SDARForCausalLM
+    from paddle_tpu.serving.engine import GenerationEngine
+
+    paddle_tpu.seed(8)
+    cfg = SDARConfig.tiny(
+        hidden_size=256, num_heads=8, num_kv_heads=2, head_dim=128,
+        moe_intermediate_size=128, max_seq_len=max_len, dtype="float32")
+    model = SDARForCausalLM(cfg)
+    greedy = [r for r in requests if not r.sampling]
+    precision = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", "highest")
+    try:
+        with GenerationEngine(model, slots=slots, max_len=max_len,
+                              paged=True, page_tokens=PARITY_PAGE_TOKENS,
+                              prefill_chunk=chunk, prefix_cache=True,
+                              async_depth=1) as engine:
+            text = engine.lowered_text(max(len(r.prompt) for r in greedy))
+            ids = [engine.start(r.prompt, r.new_tokens) for r in greedy]
+            streams = []
+            for gid in ids:
+                toks = []
+                while True:
+                    doc = engine.poll(gid, len(toks), wait_s=30.0)
+                    check(doc["error"] is None, f"block: {doc['error']}")
+                    toks += doc["tokens"]
+                    if doc["done"]:
+                        break
+                streams.append(toks)
+            arm = engine.stats()["block_diffusion"]["attn"]
+            if on_chip:
+                check(arm == "paged_block_kernel",
+                      f"block step attends by {arm}, expected "
+                      "paged_block_kernel")
+                absent = _missing(text["decode"], (BLOCK_ATTN_KERNEL,))
+                check(not absent, f"block engine lowered without {absent}")
+            agree = sum(_agree(toks, np.asarray(model.generate(
+                r.prompt, r.new_tokens))[0, len(r.prompt):].tolist())
+                for toks, r in zip(streams, greedy))
+            total = sum(r.new_tokens for r in greedy)
+            check(agree == total,
+                  f"block: {agree}/{total} tokens agree with solo generation")
+            engine.clear_prefix_cache()
+            st = engine.stats()
+            check(st["pages_free"] == st["pages"] and st["active"] == 0
+                  and st["broken"] is None,
+                  f"block: the pool did not come back whole: {st}")
+    finally:
+        jax.config.update("jax_default_matmul_precision", precision)
+    return {"block_attn": arm, "streams": len(streams),
+            "block_diffusion": st["block_diffusion"],
+            "engine_agrees_with_solo_generation_for":
+                f"{agree}/{total} tokens",
+            "kernels": {"decode": [BLOCK_ATTN_KERNEL]
+                        if arm == "paged_block_kernel" else []}}
+
+
 # ---------------------------------------------------------------------------
 # main
 # ---------------------------------------------------------------------------
@@ -896,6 +974,9 @@ def main() -> int:
         chunk=64)
     report["serve_state"] = state_phase(
         make_requests(256, (150, 140, 170, 160), (24, 16, 24, 16),
+                      shared_prefix=128), slots=4, max_len=256, chunk=64)
+    report["serve_block"] = block_phase(
+        make_requests(256, (150, 140, 170, 160), (24, 18, 25, 16),
                       shared_prefix=128), slots=4, max_len=256, chunk=64)
     if n_dev >= 4:
         del model

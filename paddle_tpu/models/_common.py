@@ -38,7 +38,9 @@ def causal_lm_loss(model, head_weight, input_ids, labels,
 # that copies K/V pages narrower than a lane tile into VMEM itself;
 # ``"paged_kernel"`` — the same kernel's block-spec form or, over the
 # latent leaf, ``ptpu_paged_latent_decode_attn``, through the page table;
-# or ``"gather"`` — one layer's pages gathered and the einsum lines.
+# or ``"gather"`` — one layer's pages gathered and the einsum lines;
+# ``"paged_block_kernel"`` — ``ptpu_paged_block_attn``, the copy form
+# at B query rows a slot (a block-diffusion step).
 # The engine reads the difference around the trace of its step
 # (``GenerationEngine.stats()["decode_attn"]``).
 paged_attn_arms: collections.Counter = collections.Counter()
@@ -47,8 +49,8 @@ paged_attn_arms: collections.Counter = collections.Counter()
 def attn_arm_since(before) -> str:
     """The arm a program traced since ``before`` (a copy of
     ``paged_attn_arms``) attends by: a kernel form if a layer took one,
-    the copy form first."""
-    for arm in ("paged_copy_kernel", "paged_kernel"):
+    the copy forms first."""
+    for arm in ("paged_block_kernel", "paged_copy_kernel", "paged_kernel"):
         if paged_attn_arms[arm] > before[arm]:
             return arm
     return "gather"
@@ -73,7 +75,15 @@ def window_mask(T: int, window):
     return (jnp.arange(T)[:, None] - jnp.arange(T)[None, :]) < window
 
 
-def cached_attention(q, k, v, cache, index, layer=0, window=None):
+def block_mask(T: int, block: int):
+    """``[T, T]`` block-causal mask of a chunk that starts on a block
+    boundary: row ``t`` sees row ``u`` iff ``u // block <= t // block``
+    — every earlier block and its whole own block, both ways."""
+    at = jnp.arange(T) // block
+    return at[None, :] <= at[:, None]
+
+
+def cached_attention(q, k, v, cache, index, layer=0, window=None, block=1):
     """Static-KV-cache attention core shared by every attention family
     (llama GQA, GPT fused-MHA, MoE). ``cache`` holds the FULL stacked
     read-only buffers ([L, B, Hkv, S, D] — see ``init_kv_cache``) and
@@ -139,6 +149,18 @@ def cached_attention(q, k, v, cache, index, layer=0, window=None):
     only the live pages from logical page ``cache.base`` on, and cached
     positions count from there.
 
+    ``block`` (static; 1 = causal, every model but a block-diffusion
+    one): block-causal attention over blocks of ``block`` positions. The
+    chunk starts on a block boundary; cached positions ``< index`` are
+    all seen and the chunk's rows see each other where ``t // block >=
+    u // block`` (:func:`block_mask`; a one-block chunk — the engine's
+    block step — all both ways). The cold first chunk takes the mask
+    through the einsum attention too, never the flash kernel. A
+    one-block chunk on a ``PagedCache`` the block kernel takes
+    (``paged_decode_attention.block_supported``) is attended by
+    ``ptpu_paged_block_attn``: each slot's live pages read once a layer
+    for all ``block`` query rows (``"paged_block_kernel"``).
+
     Returns ``(out [B, T, Hq, D], payload)`` where payload leaves are the
     chunk k/v in buffer layout ([B, Hkv, T, D], scales [B, Hkv, T]).
     """
@@ -162,6 +184,9 @@ def cached_attention(q, k, v, cache, index, layer=0, window=None):
         payload = (kt.astype(bufs[0].dtype), vt.astype(bufs[1].dtype))
 
     if index is None or (isinstance(index, int) and index == 0):
+        if block > 1:
+            out = F.scaled_dot_product_attention(q, k, v, block_mask(T, block))
+            return out, payload
         # prefill: nothing behind us — plain causal over the raw chunk
         # (flash-kernel eligible); a chunk no longer than the window
         # never meets its lower edge
@@ -172,7 +197,13 @@ def cached_attention(q, k, v, cache, index, layer=0, window=None):
     idx = jnp.asarray(index, jnp.int32)
     if paged:
         from paddle_tpu.ops.pallas import paged_decode_attention as _pk
-        if _pk.supported(q, bufs, cache.table[None]):
+        if (block > 1 and T == block
+                and _pk.block_supported(q, bufs, cache.table[None])):
+            paged_attn_arms["paged_block_kernel"] += 1
+            out = _pk.paged_block_attention(
+                q, kt, vt, bufs, cache.table[None], layer, idx, scale=scale)
+            return out, payload
+        if block == 1 and _pk.supported(q, bufs, cache.table[None]):
             paged_attn_arms["paged_copy_kernel" if _pk.copies_pages(bufs)
                             else "paged_kernel"] += 1
             edge = ({} if window is None
@@ -185,7 +216,7 @@ def cached_attention(q, k, v, cache, index, layer=0, window=None):
         sl = cache.read_layer(layer)
     else:
         from paddle_tpu.ops.pallas import decode_attention as _dk
-        if window is None and _dk.supported(q, cache):
+        if window is None and block == 1 and _dk.supported(q, cache):
             out = _dk.decode_attention(q, kt, vt, cache, layer, idx,
                                        scale=scale)
             return out, payload
@@ -221,7 +252,8 @@ def cached_attention(q, k, v, cache, index, layer=0, window=None):
                 )[None, None, None]
     s_c = jnp.where(seen, s_c.astype(jnp.float32), neg)
     s_n = jnp.einsum("bkgtd,bkud->bkgtu", qh, kt) * scale
-    chunk_causal = (jnp.arange(T)[None, :] <= jnp.arange(T)[:, None])
+    chunk_causal = (block_mask(T, block) if block > 1 else
+                    jnp.arange(T)[None, :] <= jnp.arange(T)[:, None])
     if window is not None:
         chunk_causal = chunk_causal & (
             jnp.arange(T)[:, None] - jnp.arange(T)[None, :] < window)

@@ -32,7 +32,8 @@ import numpy as np
 
 __all__ = ["generate", "sample_logits", "beam_search", "init_paged_cache",
            "PagedCache", "StateCache", "paged_gather", "paged_scatter",
-           "paged_write",
+           "paged_write", "paged_write_block", "transfer_schedule",
+           "block_pick", "block_diffusion_generate",
            "advance_key", "ngram_propose",
            "speculative_generate", "serialize_page", "deserialize_page",
            "STACKED_KV_SPEC", "POOL_KV_SPEC", "PAGE_TABLE_SPEC"]
@@ -268,6 +269,36 @@ def paged_write(pool, pages, offs, rows):
             x = jax.lax.dynamic_index_in_dim(r, i, 0)    # [1, L, Hkv, *rest]
             x = jnp.expand_dims(x, 3).astype(leaf.dtype)
             here = (jnp.arange(leaf.shape[3]) == offs[i]).reshape(
+                (1, 1, 1, -1) + (1,) * (leaf.ndim - 4))
+            out.append(jax.lax.dynamic_update_slice(
+                leaf, jnp.where(here, x, page), start))
+        return tuple(out)
+
+    return jax.lax.fori_loop(0, n, body, tuple(pool))
+
+
+@jax.named_scope("kv/write")
+def paged_write_block(pool, pages, offs, rows):
+    """:func:`paged_write` for ``n`` blocks of ``T`` positions: block
+    ``i`` (``rows`` leaves ``[n, L, Hkv, T, *rest]``) goes to page
+    ``pages[i]`` at in-page offsets ``offs[i] .. offs[i] + T`` — ``T``
+    divides the page and ``offs[i]`` is a multiple of it, so a block
+    never crosses a page. One whole-page update a block, as
+    :func:`paged_write` makes one a position."""
+    n = pages.shape[0]
+    zero = jnp.zeros((), jnp.int32)
+
+    def body(i, pool):
+        out = []
+        for leaf, r in zip(pool, rows):
+            start = (pages[i],) + (zero,) * (leaf.ndim - 1)
+            page = jax.lax.dynamic_slice(leaf, start, (1,) + leaf.shape[1:])
+            x = jax.lax.dynamic_index_in_dim(r, i, 0)    # [1, L, Hkv, T, ...]
+            P, T = leaf.shape[3], x.shape[3]
+            # offset p of the page takes row p % T of the block
+            x = jnp.concatenate([x] * (P // T), axis=3).astype(leaf.dtype)
+            at = jnp.arange(P) - offs[i]
+            here = ((at >= 0) & (at < T)).reshape(
                 (1, 1, 1, -1) + (1,) * (leaf.ndim - 4))
             out.append(jax.lax.dynamic_update_slice(
                 leaf, jnp.where(here, x, page), start))
@@ -584,6 +615,107 @@ def generate(model, input_ids, max_new_tokens: int, *,
             (jnp.asarray(1, jnp.int32), seq, cache, next_tok, finished,
              key))
     return seq
+
+
+def transfer_schedule(masked: int, steps: int) -> list[int]:
+    """The linear transfer schedule of block diffusion: how many of a
+    block's ``masked`` positions each of ``steps`` denoising steps fixes
+    — ``masked // steps`` a step, the remainder one each to the first
+    steps."""
+    base, rem = divmod(int(masked), int(steps))
+    return [base + (i < rem) for i in range(int(steps))]
+
+
+def block_pick(logits, masked, n, mask_token_id: int):
+    """One denoising step's pick, greedy with static low-confidence
+    remasking: at every masked position (``masked`` [..., B]) the
+    logits' [..., B, V] best token ``x0`` other than ``[MASK]`` itself
+    and its probability ``c``; the ``n`` [...] masked positions of
+    highest ``c`` are fixed to their ``x0`` (equal confidences: the lower
+    position first). Returns ``(x0, the positions fixed)``. The serving
+    engine's block step and :func:`block_diffusion_generate` both pick
+    here."""
+    lg = logits.astype(jnp.float32)
+    x0 = jnp.argmax(jnp.where(jnp.arange(lg.shape[-1]) == mask_token_id,
+                              -jnp.inf, lg), axis=-1).astype(jnp.int32)
+    conf = jnp.exp(jnp.take_along_axis(lg, x0[..., None], -1)[..., 0]
+                   - jax.nn.logsumexp(lg, axis=-1))
+    _, order = jax.lax.top_k(jnp.where(masked, conf, -jnp.inf),
+                             masked.shape[-1])
+    rank = jnp.argsort(order, axis=-1)
+    return x0, masked & (rank < jnp.asarray(n)[..., None])
+
+
+def block_diffusion_generate(model, input_ids, max_new_tokens: int, *,
+                             block_length: int, denoising_steps: int,
+                             mask_token_id: int,
+                             eos_token_id: int | None = None,
+                             pad_token_id: int = 0, cache_dtype=None):
+    """Greedy block-diffusion decode of ONE sequence on the contiguous
+    cache of a model whose attention is block-causal over blocks of
+    ``block_length`` (``models/sdar.py``): the oracle the serving
+    engine's block step is held to.
+
+    The prompt's first ``len // B`` blocks are prefilled; the first
+    generated block holds the prompt's remainder and ``[MASK]`` in its
+    other positions. Each denoising step forwards the block's B
+    positions against the cache and fixes the masked positions of
+    highest confidence (:func:`block_pick`), as many as
+    :func:`transfer_schedule` gives that step for the block's masked
+    count; once none is masked, a commit forwards the block's final
+    tokens and writes their K/V, and the next block starts ``B`` on. The
+    stream ends after the block that holds EOS or reaches
+    ``max_new_tokens``; tokens past either are computed and not emitted.
+
+    A position is masked until a step fixes it, whatever id the prompt
+    put there, and no position is fixed to ``[MASK]``.
+
+    Returns ``[1, T0 + max_new_tokens]`` int32 as :func:`generate`
+    (``pad_token_id`` past the end). Host-driven; one jitted forward a
+    chunk shape."""
+    ids = np.asarray(input_ids, np.int32).reshape(-1)
+    T0, B = ids.size, int(block_length)
+    max_new_tokens = int(max_new_tokens)
+    L0 = T0 // B * B
+    end = L0 + -(-(T0 - L0 + max_new_tokens) // B) * B
+    cache = model.init_cache(1, end, dtype=cache_dtype)
+    fwd = jax.jit(lambda m, x, c, i: m.forward_with_cache(x, c, index=i))
+    if L0:
+        _, cache = fwd(model, jnp.asarray(ids[None, :L0]), cache,
+                       jnp.int32(0))
+    p0, first = L0, T0 - L0           # the prompt's share of block one
+    blk = np.full((B,), mask_token_id, np.int32)
+    blk[:first] = ids[L0:]
+    emitted = []
+    while True:
+        sched = transfer_schedule(B - first, denoising_steps)
+        fixed = np.where(np.arange(B) < first, -2, -1).astype(np.int32)
+        step = 0
+        while (fixed == -1).any():
+            logits, _ = fwd(model, jnp.asarray(blk[None]), cache,
+                            jnp.int32(p0))
+            x0, fix = (np.asarray(t) for t in block_pick(
+                logits[0], jnp.asarray(fixed == -1),
+                sched[min(step, len(sched) - 1)], mask_token_id))
+            blk = np.where(fix, x0, blk).astype(np.int32)
+            fixed[fix] = step
+            step += 1
+        done = False
+        for t in blk[first:]:
+            emitted.append(int(t))
+            if ((eos_token_id is not None and t == eos_token_id)
+                    or len(emitted) >= max_new_tokens):
+                done = True
+                break
+        if done:
+            break
+        _, cache = fwd(model, jnp.asarray(blk[None]), cache, jnp.int32(p0))
+        p0, first = p0 + B, 0
+        blk = np.full((B,), mask_token_id, np.int32)
+    seq = np.full((1, T0 + max_new_tokens), pad_token_id, np.int32)
+    seq[0, :T0] = ids
+    seq[0, T0:T0 + len(emitted)] = emitted
+    return jnp.asarray(seq)
 
 
 def beam_search(model, input_ids, max_new_tokens: int, *,

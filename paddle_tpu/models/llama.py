@@ -101,22 +101,39 @@ class LlamaConfig:
 
 
 class LlamaAttention(Module):
+    """GQA attention of the Llama family. Three more things a config may
+    ask for (each absent from a config that does not name it, and then
+    absent from the module, whose tree and programs stay as they were):
+    ``head_dim`` wider or narrower than ``hidden / heads`` (the q and o
+    projections are ``heads x head_dim`` wide); ``qk_norm`` — one
+    ``RMSNorm(head_dim)`` over every query head and one over every key
+    head, before rotation (Qwen3's head-wise form); ``attn_block`` B > 1
+    — block-causal attention: a position sees every earlier block of B
+    positions and its whole own block, both ways
+    (``_common.block_mask``)."""
+
     def __init__(self, cfg: LlamaConfig, key=None):
         keys = rng.split_key(key, 4)
         E = cfg.hidden_size
-        head_dim = E // cfg.num_heads
+        head_dim = getattr(cfg, "head_dim", None) or E // cfg.num_heads
+        q_dim = cfg.num_heads * head_dim
         kv_dim = cfg.num_kv_heads * head_dim
         dtype = jnp.dtype(cfg.dtype)
         init = Normal(0.0, cfg.init_std)
         out_init = Normal(0.0, cfg.init_std / math.sqrt(2 * cfg.num_layers))
-        self.wq = Linear(E, E, bias=False, weight_init=init, dtype=dtype,
+        self.wq = Linear(E, q_dim, bias=False, weight_init=init, dtype=dtype,
                          key=keys[0], pspec=P("fsdp", "tp"))
         self.wk = Linear(E, kv_dim, bias=False, weight_init=init, dtype=dtype,
                          key=keys[1], pspec=P("fsdp", "tp"))
         self.wv = Linear(E, kv_dim, bias=False, weight_init=init, dtype=dtype,
                          key=keys[2], pspec=P("fsdp", "tp"))
-        self.wo = Linear(E, E, bias=False, weight_init=out_init, dtype=dtype,
-                         key=keys[3], pspec=P("tp", "fsdp"))
+        self.wo = Linear(q_dim, E, bias=False, weight_init=out_init,
+                         dtype=dtype, key=keys[3], pspec=P("tp", "fsdp"))
+        if getattr(cfg, "qk_norm", False):
+            self.q_norm = RMSNorm(head_dim, epsilon=cfg.rms_eps, dtype=dtype)
+            self.k_norm = RMSNorm(head_dim, epsilon=cfg.rms_eps, dtype=dtype)
+        if getattr(cfg, "attn_block", 1) > 1:
+            self.attn_block = int(cfg.attn_block)
         self.num_heads = cfg.num_heads
         self.num_kv_heads = cfg.num_kv_heads
         self.head_dim = head_dim
@@ -151,6 +168,9 @@ class LlamaAttention(Module):
         v = jax.ad_checkpoint.checkpoint_name(
             self.wv(x), "qkv").reshape(B, T, self.num_kv_heads,
                                        self.head_dim)
+        if hasattr(self, "q_norm"):
+            q, k = self.q_norm(q), self.k_norm(k)
+        block = getattr(self, "attn_block", 1)
         if positions is None:
             # inside a manual-sp region (pipeline∘sp) the local T is one
             # sequence slice: RoPE must rotate by absolute positions
@@ -162,11 +182,16 @@ class LlamaAttention(Module):
                                       self.rope_base)
         q = F.apply_rotary(q, cos, sin)
         k = F.apply_rotary(k, cos, sin)
+        width = self.num_heads * self.head_dim
         if cache is not None:
             from paddle_tpu.models._common import cached_attention
             out, payload = cached_attention(q, k, v, cache, index,
-                                            layer=layer)
-            return self.wo(out.reshape(B, T, E)), payload
+                                            layer=layer, block=block)
+            return self.wo(out.reshape(B, T, width)), payload
+        if block > 1:
+            from paddle_tpu.models._common import block_mask
+            out = F.scaled_dot_product_attention(q, k, v, block_mask(T, block))
+            return self.wo(out.reshape(B, T, width))
         # activations: shard heads over tp inside the einsum via sharded
         # inputs; flash path kicks in on TPU for supported shapes
         if self.seq_mode != "none":
@@ -177,7 +202,7 @@ class LlamaAttention(Module):
             out = attn_fn(q, k, v, causal=True)
         else:
             out = F.scaled_dot_product_attention(q, k, v, causal=True)
-        return self.wo(out.reshape(B, T, E))
+        return self.wo(out.reshape(B, T, width))
 
 
 class LlamaMLP(Module):

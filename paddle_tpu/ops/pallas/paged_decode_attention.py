@@ -63,6 +63,18 @@ at 563 GB/s, 69 % of the chip's bandwidth; 32 pages a block 10.1, 16
 recorded, not routed — whether one K/V body can serve both is the next
 question.
 
+The block form (``ptpu_paged_block_attn``; :func:`block_supported`,
+:func:`paged_block_attention`): the copy form at T > 1 query rows a
+slot, for one block of a block-diffusion step (``models/sdar.py``),
+whose rows see every cached position before the block and each other,
+both ways. A slot's rows are its ``Hq x T`` queries in the order (head,
+t), so the KV head of query row r is ``r // (G * T)``; grid step 0 is the
+block's own T rows (:func:`_fresh_block`, the vector unit's T products a
+row); then each live block of pages is copied once and multiplied with
+all ``Hq x T`` rows. A slot's live pages are read once a layer for the
+whole block, where T one-token calls would read them T times. The
+one-token programs are text-identical with the form beside them.
+
 Everything else is the ``decode_attention`` recipe on a page-shaped
 block, in both forms: the fresh token's raw k/v joins the streaming
 softmax as grid step 0; pages stream after it with positions ``>=
@@ -129,7 +141,8 @@ compiles each for the v5e. Off-TPU callers take the gather arm
 (``dispatch_mode()`` is ``"off"``). Multi-device meshes do too (no
 ``_partition`` unit yet — the pool's KV-head shard would need a
 per-shard grid), as do prefill chunks and speculative verify windows
-(``T > 1``), a narrow page with a 64-wide head (Mosaic refuses the
+(``T > 1``, but for one block of a block-diffusion step on narrow
+pages: the block form), a narrow page with a 64-wide head (Mosaic refuses the
 copy's slice of half a lane tile), and an int8 latent leaf does not
 exist (``init_latent_cache`` refuses it).
 """
@@ -227,6 +240,29 @@ def _fresh_token(q_ref, kn_ref, vn_ref, acc_ref, m_ref, l_ref, *,
         acc_ref[rows, :] = jnp.broadcast_to(vn[h:h + 1],
                                             (G, vn.shape[1]))
     l_ref[:, :] = jnp.ones_like(l_ref)
+
+
+def _fresh_block(q_ref, kn_ref, vn_ref, acc_ref, m_ref, l_ref, *,
+                 scale, G, Hkv, T):
+    """Grid step 0 of the block form — the step's own T rows, which every
+    query row of the block sees (block-causal: one whole block both
+    ways): rows in the order (KV head, query head of its group, t), keys
+    (KV head, u); per KV head T products a row on the vector unit, their
+    softmax started in float32."""
+    q = q_ref[0].astype(jnp.float32)            # [Hkv * G * T, D]
+    kn = kn_ref[0].astype(jnp.float32)          # [Hkv * T, D]
+    vn = vn_ref[0].astype(jnp.float32)
+    R = G * T
+    for h in range(Hkv):
+        rows = slice(h * R, (h + 1) * R)
+        s = [jnp.sum(q[rows] * kn[h * T + u:h * T + u + 1], axis=1,
+                     keepdims=True) * scale for u in range(T)]   # T x [R, 1]
+        m = functools.reduce(jnp.maximum, s)
+        p = [jnp.exp(x - m) for x in s]
+        m_ref[rows, :] = jnp.broadcast_to(m, (R, LANES))
+        l_ref[rows, :] = jnp.broadcast_to(sum(p), (R, LANES))
+        acc_ref[rows, :] = sum(pu * vn[h * T + u:h * T + u + 1]
+                               for u, pu in enumerate(p))
 
 
 def _softmax_update(s, m_ref, l_ref):
@@ -430,14 +466,16 @@ def _pages_per_block(M: int, page_bytes: int) -> int:
 
 def _copy_kernel(sp_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
                  kbuf, vbuf, sem, slot_ref, acc_ref, m_ref, l_ref, *,
-                 scale, P, KP, M, G, Hkv, rows, out_dtype, windowed):
+                 scale, P, KP, M, G, Hkv, rows, out_dtype, windowed, T=1):
     # The K/V body for pages narrower than a lane tile. The pool stays
     # in HBM; a page of one layer is a contiguous [Hkv, P, D] slab a
     # leaf, copied into its Hkv·P rows of a block's buffer — rows in the
     # order (page, head, offset) — so the block is contiguous in VMEM
     # BEFORE its one dot a side. Grid step j >= 1 of a row attends the
     # row's (j - 1)-th LIVE block: blocks wholly behind a window row's
-    # lower edge take no step at all.
+    # lower edge take no step at all. ``T`` > 1 (the block form): each
+    # query head brings T rows, the KV head of query row r is r // (G·T),
+    # and grid step 0 is the block's own T rows (:func:`_fresh_block`).
     HDR = 3 if windowed else 2
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -539,8 +577,12 @@ def _copy_kernel(sp_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
 
     @pl.when(j == 0)
     def _fresh():
-        _fresh_token(q_ref, kn_ref, vn_ref, acc_ref, m_ref, l_ref,
-                     scale=scale, G=G, Hkv=Hkv)
+        if T > 1:
+            _fresh_block(q_ref, kn_ref, vn_ref, acc_ref, m_ref, l_ref,
+                         scale=scale, G=G, Hkv=Hkv, T=T)
+        else:
+            _fresh_token(q_ref, kn_ref, vn_ref, acc_ref, m_ref, l_ref,
+                         scale=scale, G=G, Hkv=Hkv)
 
     @pl.when((j >= 1) & (j <= nb))
     def _block():
@@ -564,7 +606,7 @@ def _copy_kernel(sp_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
         seen = pos < jnp.minimum(idx, M * P)    # the fill, within the table
         if windowed:
             seen = seen & (pos >= sp_ref[b, 2])
-        row_h = jax.lax.broadcasted_iota(jnp.int32, (Hq, 1), 0) // G
+        row_h = jax.lax.broadcasted_iota(jnp.int32, (Hq, 1), 0) // (G * T)
         s = jnp.where((row_h == col % W // P) & seen, s, NEG_INF)
         p, alpha = _softmax_update(s, m_ref, l_ref)
         pv = jax.lax.dot_general(
@@ -578,20 +620,23 @@ def _copy_kernel(sp_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
 
 
 def raw_copy_call(sp, q2, kn2, vn2, kp, vp, *, scale: float,
-                  windowed: bool = False):
+                  windowed: bool = False, T: int = 1):
     """The copy form's pallas_call on local shapes: operands as
     :func:`raw_call`'s, the two float leaves unblocked in HBM and only
     read. Grid ``(B, 1 + ceil(M / KP))``: step 0 the fresh token, then
     one live block of KP pages a step, double-buffered across steps and
-    rows by the kernel's own copies."""
+    rows by the kernel's own copies. ``T`` > 1 is the block form
+    (``ptpu_paged_block_attn``): q2 [B, Hq·T, D] in the order (head, t),
+    kn2 / vn2 [B, Hkv·T, D] in the order (KV head, u)."""
     B, Hq, D = q2.shape
     Hkv, P = kp.shape[2:4]
     M = sp.shape[1] - (3 if windowed else 2)
     KP = _pages_per_block(M, Hkv * P * D * kp.dtype.itemsize)
     steps = -(-M // KP)
     kernel = functools.partial(
-        _copy_kernel, scale=scale, P=P, KP=KP, M=M, G=Hq // Hkv,
-        Hkv=Hkv, rows=B, out_dtype=q2.dtype, windowed=windowed)
+        _copy_kernel, scale=scale, P=P, KP=KP, M=M, G=Hq // (Hkv * T),
+        Hkv=Hkv, rows=B, out_dtype=q2.dtype, windowed=windowed,
+        **({"T": T} if T > 1 else {}))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -599,8 +644,8 @@ def raw_copy_call(sp, q2, kn2, vn2, kp, vp, *, scale: float,
             grid=(B, steps + 1),
             in_specs=[
                 pl.BlockSpec((1, Hq, D), lambda b, j, s: (b, 0, 0)),
-                pl.BlockSpec((1, Hkv, D), lambda b, j, s: (b, 0, 0)),
-                pl.BlockSpec((1, Hkv, D), lambda b, j, s: (b, 0, 0)),
+                pl.BlockSpec((1, Hkv * T, D), lambda b, j, s: (b, 0, 0)),
+                pl.BlockSpec((1, Hkv * T, D), lambda b, j, s: (b, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
@@ -621,7 +666,7 @@ def raw_copy_call(sp, q2, kn2, vn2, kp, vp, *, scale: float,
         compiler_params=_support.compiler_params(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_support.interpret(),
-        name="ptpu_paged_decode_attn",
+        name="ptpu_paged_block_attn" if T > 1 else "ptpu_paged_decode_attn",
     )(sp, q2, kn2, vn2, kp, vp)
 
 
@@ -966,6 +1011,60 @@ def paged_decode_attention(q, k_new, v_new, pool, table, layer, index, *,
         lay, idx, table, q.reshape(B, Hq, D),
         k_new.reshape(B, Hkv, D), v_new.reshape(B, Hkv, D), tuple(pool))
     return out.reshape(B, 1, Hq, D)
+
+
+def block_supported(q, pool, table) -> bool:
+    """Gate of the block form (``ptpu_paged_block_attn``): ``q`` [B, T,
+    Hq, D] with T > 1 — one block of a block-diffusion step, whose rows
+    see each other both ways — of a float dtype, raw dispatch (one TPU
+    chip), on a float pool the copy form takes (:func:`copies_pages`:
+    pages narrower than a lane tile), on the copy form's Mosaic terms
+    where it is compiled. Everything else — a prefill chunk of several
+    blocks is the caller's to keep off, wide pages, the int8 pool, a
+    mesh, the CPU — stays on the gather arm and the einsum lines."""
+    if not (_support.dispatch_mode() == "raw" and q.ndim == 4
+            and q.shape[1] > 1 and q.dtype in (jnp.float32, jnp.bfloat16)
+            and table.ndim == 2 and table.shape[0] == q.shape[0]):
+        return False
+    k = pool[0]
+    if k.ndim != 5 or not copies_pages(pool) or k.dtype not in (
+            jnp.float32, jnp.bfloat16):
+        return False
+    _, _, Hkv, P, Dk = k.shape
+    D, Hq = q.shape[3], q.shape[2]
+    if Dk != D or D not in (64, 128, 256) or Hq % Hkv or P % 8:
+        return False
+    if _support.on_tpu() and not _support.interpret():
+        KP = _pages_per_block(table.shape[1],
+                              Hkv * P * D * k.dtype.itemsize)
+        if (D % LANES or (P * k.dtype.itemsize) % 32
+                or (KP * Hkv * P) % LANES):
+            return False
+    return True
+
+
+def paged_block_attention(q, k_new, v_new, pool, table, layer, index, *,
+                          scale: float):
+    """One block-diffusion step's attention through the page table: q
+    [B, T, Hq, D] the block's T rows; k_new / v_new [B, Hkv, T, D] their
+    raw k/v (the pool's copy of them is not read: the context ends at
+    ``index``); ``pool``, ``table`` [B, M], ``layer``, ``index`` (the
+    block's first position, scalar or [B]) as
+    :func:`paged_decode_attention`. Every query row sees each cached
+    position ``< index`` and all T rows of its block. Returns [B, T, Hq,
+    D]. The caller has asked :func:`block_supported`. Under ``jax.vmap``
+    with the pool unmapped the mapped axis joins B: one call a layer,
+    each slot's live pages copied once for its T·Hq query rows."""
+    B, T, Hq, D = q.shape
+    Hkv = k_new.shape[1]
+    idx = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (B,))
+    lay = jnp.broadcast_to(jnp.asarray(layer, jnp.int32), (B,))
+    out = _over_rows(functools.partial(raw_copy_call, T=T), scale)(
+        lay, idx, jnp.asarray(table, jnp.int32),
+        q.transpose(0, 2, 1, 3).reshape(B, Hq * T, D),
+        k_new.reshape(B, Hkv * T, D), v_new.reshape(B, Hkv * T, D),
+        tuple(pool))
+    return out.reshape(B, Hq, T, D).transpose(0, 2, 1, 3)
 
 
 def paged_latent_decode_attention(q_full, new, pool, table, layer, index, *,

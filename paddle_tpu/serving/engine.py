@@ -76,6 +76,26 @@ paged cache, SOSP '23) to the framework's autoregressive path:
   snapshot's rows (from the zero entry on a miss) — restore, chunk and
   snapshot are one program a bucket, nothing is dispatched at
   admission. A finished stream gives its rows back by doing nothing.
+- **Block diffusion** (a model that ``generates_by = "block_diffusion"``:
+  ``models/sdar.py``; paged engines only). A step no longer gives a
+  slot one token: it forwards the slot's block of B positions at the
+  block's first position (block-causal: the context before the block,
+  the block's own rows both ways — ``ptpu_paged_block_attn`` on one
+  chip), writes the B rows' K/V into the block's page in place, and on
+  the device fixes the masked positions of highest confidence, as many
+  as the linear transfer schedule gives that step
+  (``generation.block_pick``, ``transfer_schedule``); a block with
+  nothing masked is committed by the same program — its final rows
+  overwrite the page — and the slot moves B on. The block, its step
+  count and the step each position was fixed at live in the donated
+  state; the readback is ``[slots, 2B + 3]``, and a block's tokens
+  reach the stream at the step that fixes its last position, and a
+  finished stream's final poll hands back its blocks (``blocks``). A
+  prompt is
+  prefilled in whole blocks (chunks end on block boundaries, so prefix
+  reuse by page stays exact); its remainder rides the first generated
+  block, whose prompt positions are never masked. Greedy streams equal
+  ``generation.block_diffusion_generate`` byte for byte.
 - **Speculative decoding** (``FLAGS_gen_spec_k``, off by default).
   Decode is memory-bandwidth-bound, so the only way past the roofline
   is fewer serial target-model steps: a cheap drafter proposes up to
@@ -145,6 +165,9 @@ speculation efficiency), ``gen/tokens`` / ``gen/evictions`` /
 ``gen/prefix_evictions`` / ``gen/state_restores`` /
 ``gen/state_snapshots`` / ``gen/state_snapshot_evictions`` (a state
 group's prefix hits, snapshots kept and snapshots evicted) /
+``gen/block_slot_steps`` / ``gen/block_tokens_fixed`` /
+``gen/block_commits`` (a block-diffusion engine's live slot-steps,
+positions fixed and blocks committed; ``stats()["block_diffusion"]``) /
 ``gen/traps`` / ``gen/rebuilds`` /
 ``gen/stuck`` / ``gen/quarantined`` / ``gen/quarantine_rejected`` /
 ``gen/expired_polls`` counters, and slot + page-pool occupancy in the
@@ -161,7 +184,9 @@ and, for an admission that restores a state snapshot,
 round the readback of a first token), ``gen/decode_step`` (``active``,
 ``spec``, ``compiled``, ``sort_slots`` — the live slots whose request
 restricts its sampling, the steps that have one counted under
-``gen/sample_sorted_steps`` — a plain paged step's ``decode_attn``;
+``gen/sample_sorted_steps`` — a plain paged step's ``decode_attn``; a
+block step's ``fixing`` and ``committing``, its slots with masked
+positions and those whose block is whole;
 under it ``gen/step_dispatch`` and ``gen/step_wait``, or
 ``gen/spec_verify`` around both), ``gen/draft`` and ``gen/emit``
 (``emitted``, ``retired``; under a prefill chunk's, for a snapshot kept,
@@ -378,7 +403,8 @@ class Generation:
                  "tenant", "admitted_ts", "first_tok_ts", "done_ts",
                  "chip_s", "ledgered", "dev_ops", "pclass", "folded",
                  "queue_booked", "sched_seq", "sched_vft", "sched_ts",
-                 "win", "sorts", "snap_src", "wake", "waiting")
+                 "win", "sorts", "snap_src", "wake", "waiting",
+                 "blocks", "bstart", "bphase", "bneed")
 
     def __init__(self, gen_id: str, prompt: np.ndarray,
                  max_new_tokens: int, temperature: float, top_k: int,
@@ -465,6 +491,14 @@ class Generation:
         # (``GenerationEngine._wake``), which never blocks the loop
         self.wake: queue.SimpleQueue = queue.SimpleQueue()
         self.waiting = 0
+        # a block-diffusion engine: every finished block as ``(first
+        # position, ids, the denoising step each was fixed at)``; whether
+        # the next step starts the stream's first block; the steps taken
+        # in the current block and the denoising steps it needs
+        self.blocks: list = []
+        self.bstart = False
+        self.bphase = 0
+        self.bneed = 0
 
 
 class _PagePool:
@@ -1350,6 +1384,14 @@ class GenerationEngine:
         # the spec step's second upload all share it.
         self._pt_dev = (self._pt_place() if self._device_pt else None)
         self._sched_pt = None
+        # block diffusion (a model that ``generates_by`` it: SDAR): a
+        # step forwards a block of B positions a slot, fixes the masked
+        # positions it is most confident of and commits whole blocks to
+        # the pool (``_build_block_step``); None for every other model,
+        # whose programs stay as they were
+        self._blockdiff: dict | None = None
+        if getattr(model, "generates_by", None) == "block_diffusion":
+            self._blockdiff = self._block_setup(model)
         self._state: dict[str, Any] = self._init_state()
         # topology for stats()/health: static for the engine's lifetime
         # (the cache pool never resizes), so computed once here
@@ -1490,6 +1532,55 @@ class GenerationEngine:
                     f"{what} with {why}; serve this model without it")
         return groups
 
+    def _block_setup(self, model) -> dict:
+        """A block-diffusion model's books: its block, denoising steps,
+        ``[MASK]`` and the transfer schedule as data (``[B + 1, steps]``:
+        the positions each step fixes of a block that began with that
+        many masked), and the totals ``stats()`` reports. What the block
+        step does not carry is refused here, by name (``gen_mesh_tp`` by
+        the model's ``shard_for_inference``, earlier)."""
+        B = int(model.block_length)
+        steps = int(model.denoising_steps)
+        refused = {
+            "the contiguous engine (paged=False)": not self._paged,
+            "gen_spec_k (speculation)": self._spec_k > 0,
+            "a window or state layer group (cache_groups)":
+                self._groups is not None,
+            "gen_kv_store / gen_role (the KV store's page frames)":
+                self._kv is not None or self._role != "both",
+            "gen_sched (preemption parks a stream by folding its pages)":
+                self._sched is not None,
+            "an int8 cache (cache_dtype)": (
+                self._cache_dtype is not None
+                and np.dtype(self._cache_dtype) == np.int8),
+        }
+        for what, on in refused.items():
+            if on:
+                raise ValueError(
+                    f"{what} with block diffusion is not implemented: a "
+                    "step forwards a block of positions a slot and commits "
+                    "whole blocks; serve this model without it")
+        if self._page_tokens % B or (self._prefill_chunk > 0
+                                     and self._prefill_chunk % B):
+            raise ValueError(
+                f"page_tokens ({self._page_tokens}) and prefill_chunk "
+                f"({self._prefill_chunk}) must be whole blocks of {B}: a "
+                "block never crosses a page and a chunk ends on a block")
+        from paddle_tpu.models.generation import transfer_schedule
+        return {"B": B, "steps": steps, "mask": int(model.mask_token_id),
+                "sched": np.asarray([transfer_schedule(m, steps)
+                                     for m in range(B + 1)], np.int32),
+                "slot_steps": 0, "tokens_fixed": 0, "commits": 0}
+
+    def _reserve(self, prompt_len: int, max_new: int) -> int:
+        """Positions a request may write: prompt + ``max_new_tokens`` (+
+        the speculative scratch); a block-diffusion request's last block
+        whole."""
+        if self._blockdiff is None:
+            return prompt_len + max_new + self._spec_k
+        B = self._blockdiff["B"]
+        return -(-(prompt_len + max_new) // B) * B
+
     def _init_state(self) -> dict[str, Any]:
         """Fresh device-side engine state (the batched KV cache/page
         pool plus per-slot token/position/key/sampling arrays). Called
@@ -1541,6 +1632,16 @@ class GenerationEngine:
             state["snaps"] = tuple(
                 jnp.zeros((self._snaps.num + 2,) + r.shape, r.dtype)
                 for r in proto[1].rows)
+        if self._blockdiff is not None:
+            # a slot's block: its ids ([MASK] where not yet fixed), the
+            # denoising steps taken, the step each position was fixed at
+            # (-1 not yet, -2 the prompt's); ``pos`` is its first position
+            B = self._blockdiff["B"]
+            state.update(
+                blk=jnp.full((self.slots, B), self._blockdiff["mask"],
+                             jnp.int32),
+                bstep=jnp.zeros((self.slots,), jnp.int32),
+                bfix=jnp.full((self.slots, B), -1, jnp.int32))
         # commit to the device layout (identity at gen_mesh_tp=0): KV
         # leaves land sharded on the KV-head axis, scalars replicated,
         # matching the explicit shardings every entry point compiles with
@@ -1715,6 +1816,8 @@ class GenerationEngine:
         grouped = self._win is not None
         if self._snaps is not None:
             return self._build_state_step()
+        if self._blockdiff is not None:
+            return self._build_block_step()
 
         def one(model, pt_row, tok, idx, key, pool):
             cache = (self._group_caches(pool, pt_row) if grouped
@@ -1792,6 +1895,94 @@ class GenerationEngine:
             pool = paged_write(pool, pages, state["pos"] % P, new)
             return self._advance(state, (pool, rows), logits, keys, subs,
                                  cnt, active)
+
+        return self._layout.jit_entry(step, self._model, self._state,
+                                      paged=True, n_in=2, n_out=1)
+
+    def _build_block_step(self):
+        """ONE block step for all slots of a block-diffusion model. The
+        operand ``ops`` [slots, B + 3] int32 is, a slot, ``[live, first
+        position of a block that starts here or -1, how many of its
+        positions the prompt gives, its B ids]``. Each
+        live slot forwards its block's B positions at the block's first
+        position on its ``PagedCache`` — block-causal: the context ends
+        there, the B rows see each other — through
+        ``ptpu_paged_block_attn`` or the gather arm (``cached_attention``
+        picks; the arm is kept for :meth:`stats`), and writes the B rows'
+        K/V into the block's page in place (``block/commit``): a
+        denoising step's rows are never read, since a later context
+        starts past them only once the commit — the same program on a
+        block with nothing masked — has written the final ones. Then the
+        pick on the device (``block/pick``, ``generation.block_pick``):
+        the schedule's count of the masked positions of highest
+        confidence are fixed. A position is masked while no step has
+        fixed it (``bfix`` -1; -2 a prompt's), whatever its id. A
+        committing slot moves on to a fresh block B later. Returns
+        ``(state, out)``, ``out`` [slots, 2B + 3]: the block's ids after
+        the step, the denoising step each position was fixed at (-1 not
+        yet, -2 the prompt's), whether this step fixed the block's last
+        masked position, how many it fixed, and whether it committed."""
+        import jax
+        import jax.numpy as jnp
+
+        from paddle_tpu.models._common import attn_arm_since, paged_attn_arms
+        from paddle_tpu.models.generation import (PagedCache, block_pick,
+                                                  paged_write_block)
+
+        P, maxp, slots = self._page_tokens, self._maxp, self.slots
+        bd = self._blockdiff
+        B, MASK, steps = bd["B"], bd["mask"], bd["steps"]
+        sched = bd["sched"]
+
+        def one(model, pt_row, ids, p0, pool):
+            logits, new, cnt = self._forward(model, ids[None],
+                                             PagedCache(pool, pt_row), p0)
+            new = jax.tree_util.tree_map(lambda n: n[:, 0], new)
+            return logits[0], new, cnt
+
+        def step(model, state, pt, ops):
+            live = ops[:, 0] > 0
+            begin = ops[:, 1] >= 0
+            blk = jnp.where(begin[:, None], ops[:, 3:], state["blk"])
+            p0 = jnp.where(begin, ops[:, 1], state["pos"])
+            at = jnp.where(begin, 0, state["bstep"])
+            given = jnp.arange(B)[None, :] < ops[:, 2:3]
+            bfix = jnp.where(begin[:, None], jnp.where(given, -2, -1),
+                             state["bfix"])
+            arms = paged_attn_arms.copy()
+            with jax.named_scope("block/attn"):
+                logits, new, cnt = jax.vmap(
+                    functools.partial(one, model),
+                    in_axes=(0, 0, 0, None))(pt, blk, p0, state["cache"])
+            self._decode_attn = attn_arm_since(arms)
+            with jax.named_scope("block/commit"):
+                page = pt[jnp.arange(slots), jnp.clip(p0 // P, 0, maxp - 1)]
+                pool = paged_write_block(state["cache"],
+                                         jnp.where(live, page, 0), p0 % P,
+                                         new)
+            with jax.named_scope("block/pick"):
+                masked = jnp.any(bfix == -1, axis=1)
+                # the schedule of a block that began with every position
+                # the prompt did not give masked
+                n = jnp.asarray(sched)[jnp.sum(bfix != -2, axis=1),
+                                       jnp.clip(at, 0, steps - 1)]
+                x0, fix = block_pick(logits, bfix == -1,
+                                     jnp.where(live, n, 0), MASK)
+                blk = jnp.where(fix, x0, blk)
+                bfix = jnp.where(fix, at[:, None], bfix)
+                fixing = live & masked
+                commit = live & ~masked
+                done = fixing & ~jnp.any(bfix == -1, axis=1)
+                out = jnp.concatenate(
+                    [blk, bfix, done[:, None], jnp.sum(fix, axis=1)[:, None],
+                     commit[:, None]], axis=1).astype(jnp.int32)
+            state = self._counted(state, cnt, live[:, None, None])
+            return dict(
+                state, cache=pool,
+                blk=jnp.where(commit[:, None], MASK, blk),
+                pos=jnp.where(commit, p0 + B, p0),
+                bstep=jnp.where(commit, 0, at + fixing),
+                bfix=jnp.where(commit[:, None], -1, bfix)), out
 
         return self._layout.jit_entry(step, self._model, self._state,
                                       paged=True, n_in=2, n_out=1)
@@ -2116,6 +2307,9 @@ class GenerationEngine:
             if self._snaps is not None:
                 prefill += (i32, i32)        # source and kept snapshot
             decode = (self._state, pt, active)
+            if self._blockdiff is not None:
+                decode = (self._state, pt, jnp.zeros(
+                    (self.slots, self._blockdiff["B"] + 3), jnp.int32))
         else:
             prefill = (self._state, i32, padded, i32, *sampling)
             decode = (self._state, active)
@@ -2267,13 +2461,19 @@ class GenerationEngine:
         rng_skip = int(rng_skip)
         if rng_skip < 0:
             raise ValueError("rng_skip must be >= 0")
+        if self._blockdiff is not None and float(temperature) > 0.0:
+            raise ValueError(
+                "sampled requests (temperature > 0) with block diffusion "
+                "are not implemented: the block step picks greedily; send "
+                "temperature=0")
         # with speculation on, a slot's verify step writes a fixed
         # K+1-token window at the decode position — the last emitted
         # token can sit at prompt+max_new-1, so spec_k scratch positions
         # past the declared worst case keep that write in bounds
         # (dynamic_update_slice clamps its start; an out-of-bounds
-        # window would silently shift live positions)
-        reserve = prompt.size + max_new_tokens + self._spec_k
+        # window would silently shift live positions); a block-diffusion
+        # request writes its last block whole
+        reserve = self._reserve(prompt.size, max_new_tokens)
         if reserve > self.max_len:
             spec = (f" + spec_k ({self._spec_k}) scratch"
                     if self._spec_k else "")
@@ -2361,7 +2561,10 @@ class GenerationEngine:
              wait_s: float = 0.0) -> dict:
         """Drain tokens past ``start``; blocks up to ``wait_s`` for new
         ones (long-poll). Returns ``{"tokens", "done", "error",
-        "queued"}``. Polling refreshes the generation's TTL — a client
+        "queued"}``, and a block-diffusion stream's final poll
+        ``"blocks"`` too: ``[[first position, ids, the denoising step
+        each was fixed at (-2 the prompt's)], ...]``, the last block
+        whole even where the stream ended inside it. Polling refreshes the generation's TTL — a client
         that stops polling for ``ttl_s`` is treated as disconnected and
         its slot reclaimed.
 
@@ -2401,8 +2604,14 @@ class GenerationEngine:
                 gen.delivered = True
                 self._ledger_finalize(
                     gen, "complete" if gen.error is None else "failed")
-                return {"tokens": gen.tokens[start:], "done": True,
-                        "error": gen.error, "queued": False}
+                doc = {"tokens": gen.tokens[start:], "done": True,
+                       "error": gen.error, "queued": False}
+                if self._blockdiff is not None:
+                    # the stream's record: each block as it ended, and
+                    # the denoising step each position was fixed at
+                    doc["blocks"] = [[int(p0), ids.tolist(), at.tolist()]
+                                     for p0, ids, at in gen.blocks]
+                return doc
         return {"tokens": gen.tokens[start:], "done": False, "error": None,
                 "queued": slot is None}
 
@@ -2561,6 +2770,15 @@ class GenerationEngine:
                 doc["decode_attn"] = self._decode_attn
             if self._snaps is not None:
                 doc["kda_step"] = self._kda_step
+            if self._blockdiff is not None:
+                bd = self._blockdiff
+                doc["block_diffusion"] = {
+                    "block_length": bd["B"],
+                    "denoising_steps": bd["steps"],
+                    "slot_steps": bd["slot_steps"],
+                    "tokens_fixed": bd["tokens_fixed"],
+                    "commits": bd["commits"],
+                    "attn": self._decode_attn}
             # the model's live counts (absent for a model that names
             # none): monotone, summed on the device over live positions
             doc.update(counts)
@@ -3250,8 +3468,9 @@ class GenerationEngine:
                     # tokens into the prompt: max_new shrinks by the same
                     # amount, so its reservation never grows past the
                     # original worst case (folded is 0 for fresh requests)
-                    need = -(-(gen.prompt.size + gen.max_new_tokens
-                               - gen.folded + self._spec_k) // P)
+                    need = -(-self._reserve(gen.prompt.size,
+                                            gen.max_new_tokens - gen.folded)
+                             // P)
                     matched: list[int] = []
                     snap = 0        # a state group: the hit's snapshot
                     if self._prefix is not None and self._snaps is not None:
@@ -3338,6 +3557,12 @@ class GenerationEngine:
                     gen.prefilling = True
                     gen.prefill_pos = len(matched) * P
                     gen.prefill_t0 = ph.t0 * 1e-9
+                    if (self._blockdiff is not None
+                            and gen.prefill_pos >= self._prefilled(gen)):
+                        # whole blocks of the prompt all cached (or none
+                        # to prefill): the next step starts its block
+                        gen.prefilling = False
+                        gen.bstart = True
                     self._pt[slot] = 0
                     self._pt[slot, :len(gen.pages)] = gen.pages
                     self._pt_sync_row_locked(slot)
@@ -3749,6 +3974,15 @@ class GenerationEngine:
                                 snap=sid)
             stat_add("gen/state_snapshots")
 
+    def _prefilled(self, gen: Generation) -> int:
+        """Prompt positions the prefill writes: all of them, or of a
+        block-diffusion prompt its whole blocks — the remainder rides
+        the first generated block."""
+        if self._blockdiff is None:
+            return int(gen.prompt.size)
+        B = self._blockdiff["B"]
+        return int(gen.prompt.size) // B * B
+
     def _prefill_tick(self) -> bool:
         """Advance every prefilling slot by ONE chunk (then the loop
         runs a decode step — chunked prefill interleaves with decode
@@ -3759,7 +3993,7 @@ class GenerationEngine:
         import jax.numpy as jnp
 
         def chunk_of(gen):
-            T0 = gen.prompt.size
+            T0 = self._prefilled(gen)
             a = gen.prefill_pos
             C = self._prefill_chunk if self._prefill_chunk > 0 else T0 - a
             if self._plan is not None and self._plan.prefill_chunk:
@@ -3818,9 +4052,11 @@ class GenerationEngine:
                     # this thread (freed after the readback, the loop
                     # pays for it: 0.6 ms a step on the chip, PR 37)
                     del ops
-                    if final:
+                    if final and self._blockdiff is None:
                         # a chunk that is not the last reads nothing
-                        # back: it launches and lands nothing
+                        # back: it launches and lands nothing (nor does
+                        # a block-diffusion prompt's last: its first
+                        # tokens come from the block step)
                         with self._phase("gen/prefill_wait",
                                          landed=self._launched):
                             tok0 = int(tok0)
@@ -3837,7 +4073,8 @@ class GenerationEngine:
                 raise _EpochChanged("prefill chunk outlived the "
                                     "watchdog deadline")
             ticked = True
-            with self._phase("gen/emit", emitted=int(final)) as emit_ph:
+            with self._phase("gen/emit", emitted=int(
+                    final and self._blockdiff is None)) as emit_ph:
                 with self._cond:
                     if self._slot_gen[slot] is not gen:
                         if keep:            # cancelled/reaped mid-chunk
@@ -3868,6 +4105,9 @@ class GenerationEngine:
                                             self._pool)
                     if self._kv is not None:
                         self._kv_publish(gen)
+                    if self._blockdiff is not None:
+                        gen.bstart = True       # the next step begins
+                        continue                # its first block
                     self._first_token_locked(gen, tok0)
                 emit_ph.set(woken=self._wake((gen,)))
         return ticked
@@ -3910,7 +4150,141 @@ class GenerationEngine:
             self._first_token_locked(gen, tok0)
         self._wake((gen,))
 
+    def _block_step(self, jnp) -> bool:
+        """The loop's step of a block-diffusion engine: every slot past
+        its prefill takes one block step (:meth:`_build_block_step`); a
+        slot whose prompt has just been prefilled begins its first block
+        — the prompt's remainder, ``[MASK]`` after it — in the same
+        program. The host knows from the schedule which slots fix and
+        which commit (``gen/decode_step``'s ``fixing``, ``committing``);
+        the tokens come back with the step's readback, lagged as the
+        plain step's under ``gen_async_depth``."""
+        bd = self._blockdiff
+        B, MASK, steps = bd["B"], bd["mask"], bd["steps"]
+        with self._cond:
+            stepped = [(s, g) for s, g in enumerate(self._slot_gen)
+                       if g is not None and not g.prefilling]
+            if not stepped and not self._pending:
+                return False
+            ops = np.zeros((self.slots, B + 3), np.int32)
+            ops[:, 1] = -1
+            fixing = 0
+            for s, g in stepped:
+                ops[s, 0] = 1
+                if g.bstart:
+                    g.bstart = False
+                    p0 = self._prefilled(g)
+                    rem = g.prompt[p0:]
+                    ops[s, 1:3] = p0, rem.size
+                    ops[s, 3:] = MASK
+                    ops[s, 3:3 + rem.size] = rem
+                    g.bphase, g.bneed = 0, min(B - rem.size, steps)
+                if g.bphase < g.bneed:
+                    g.bphase += 1
+                    fixing += 1
+                else:                   # a commit; then a fresh block
+                    g.bphase, g.bneed = 0, min(B, steps)
+            pt_dev = self._pt_device_locked(jnp) if stepped else None
+            epoch0 = self._epoch
+        if not stepped:
+            self._drain_pending()
+            return True
+        lookahead = self._async_depth > 0
+        try:
+            with self._phase("gen/decode_step", "decode",
+                             "gen/decode_step_s", ("paged_step", 0),
+                             active=len(stepped), spec=0, sort_slots=0,
+                             fixing=fixing,
+                             committing=len(stepped) - fixing) as call:
+                _fault.inject("engine.decode_step")
+                with self._phase("gen/step_dispatch"):
+                    dev_ops = jnp.asarray(ops)
+                    with self._launch("paged_step"):
+                        self._state, out = self._step(self._state, pt_dev,
+                                                      dev_ops)
+                    del dev_ops             # see _prefill_tick
+                call.set(decode_attn=self._decode_attn)
+                if not lookahead:
+                    with self._phase("gen/step_wait",
+                                     landed=self._launched):
+                        out = np.asarray(out)
+        except Exception as e:
+            self._note_trap([g for _, g in stepped], e)
+            raise
+        chip_share = (call.dt / len(stepped)
+                      if self._ledger is not None else 0.0)
+        self._last_beat = time.monotonic()
+        if lookahead:
+            self._pending.append((stepped, out, epoch0, chip_share,
+                                  self._launched))
+            while len(self._pending) > self._async_depth:
+                self._drain_pending(1)
+            self._pace()
+            return True
+        self._consec_traps = 0           # real device work succeeded
+        if self._epoch != epoch0:
+            raise _EpochChanged("decode step outlived the watchdog "
+                                "deadline")
+        self._emit_block(stepped, chip_share, out)
+        self._pace()
+        return True
+
+    def _emit_block(self, stepped, chip_share, out) -> None:
+        """A block step's ``gen/emit``, sync or lagged: under ``_cond``
+        the step's totals (live slot-steps, positions fixed, commits:
+        ``stats()["block_diffusion"]`` and the ``gen/block_*`` counters)
+        and, for every stepped slot its generation still holds whose
+        block this step completed, the block recorded (first position,
+        ids, the step each was fixed at) and its tokens delivered — the
+        prompt's share of a first block left out, tokens past EOS or
+        ``max_new_tokens`` dropped by ``_deliver_locked``. Then, with the
+        lock let go, those streams' polls are woken."""
+        bd = self._blockdiff
+        B = bd["B"]
+        emitted = retired = 0
+        moved = []
+        rows = [s for s, _ in stepped]
+        fixed = int(out[rows, 2 * B + 1].sum())
+        commits = int(out[rows, 2 * B + 2].sum())
+        with self._phase("gen/emit") as emit_ph:
+            with self._cond:
+                bd["slot_steps"] += len(stepped)
+                bd["tokens_fixed"] += fixed
+                bd["commits"] += commits
+                for s, gen in stepped:
+                    if self._slot_gen[s] is not gen:
+                        continue
+                    if self._ledger is not None:
+                        gen.chip_s += chip_share
+                    if not out[s, 2 * B]:
+                        continue
+                    p0 = self._prefilled(gen) + B * len(gen.blocks)
+                    ids = out[s, :B].copy()
+                    gen.blocks.append((p0, ids, out[s, B:2 * B].copy()))
+                    if not gen.tokens:
+                        if self._ledger is not None:
+                            gen.first_tok_ts = time.monotonic()
+                        observe("gen/ttft_s", time.monotonic() - gen.created)
+                    e, r = self._deliver_locked(
+                        gen, ids[max(int(gen.prompt.size) - p0, 0):].tolist())
+                    emitted += e
+                    retired += r
+                    moved.append(gen)
+                self._emit_total += emitted
+                self._decode_iters += 1
+                if emitted:
+                    stat_add("gen/tokens", emitted)
+            stat_add("gen/block_slot_steps", len(stepped))
+            if fixed:
+                stat_add("gen/block_tokens_fixed", fixed)
+            if commits:
+                stat_add("gen/block_commits", commits)
+            emit_ph.set(emitted=emitted, retired=retired,
+                        woken=self._wake(moved))
+
     def _decode_step(self, jnp) -> bool:
+        if self._blockdiff is not None:
+            return self._block_step(jnp)
         if self._pending and self._spec_k > 0:
             # speculative drafting (and the occupancy-shed decision)
             # reads host-side context — flush the dispatch lookahead
@@ -4126,5 +4500,8 @@ class GenerationEngine:
             # the watchdog failed this entry's generations while it was
             # in flight — its tokens are garbage; the loop's stuck
             # latch forces the rebuild/break decision
+            return
+        if self._blockdiff is not None:
+            self._emit_block(stepped, chip_share, toks)
             return
         self._emit_step(stepped, chip_share, toks.tolist())

@@ -172,3 +172,17 @@ def test_compile_cache_placement(monkeypatch):
         jax.config.update("jax_compilation_cache_dir", dir_before)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           secs_before)
+
+
+def test_block_phase_off_the_chip():
+    """The block-diffusion engine's phase on the CPU (float32): the
+    greedy streams finish, each equals the solo block-diffusion
+    generation, the pool comes back whole."""
+    got = cs.block_phase(
+        cs.make_requests(256, (30, 26, 35, 33), (6, 5, 7, 5),
+                         shared_prefix=16), slots=3, max_len=64, chunk=16,
+        on_chip=False)
+    assert got["block_attn"] == "gather" and got["streams"] == 3
+    assert got["engine_agrees_with_solo_generation_for"] == "18/18 tokens"
+    assert got["kernels"] == {"decode": []}
+    assert got["block_diffusion"]["commits"] > 0

@@ -500,3 +500,85 @@ def test_fallback_arm_dispatch(monkeypatch):
     assert calls.get("n", 0) >= 1              # kernel arm engaged
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_f),
                                rtol=2e-5, atol=2e-5)
+
+
+# -- the block form (ptpu_paged_block_attn): T query rows a slot ----------
+
+def _mk_block(B=3, T=4, M=12, N=40, seed=21):
+    """A block step's operands on narrow pages: q [B, T, Hq, D], the
+    block's own k/v [B, Hkv, T, D]."""
+    _, _, _, pool, table = _mk(B=B, M=M, N=N, seed=seed)
+    rs = np.random.RandomState(seed + 100)
+    Hkv, D = pool[0].shape[2], pool[0].shape[4]
+    q = jnp.asarray(rs.randn(B, T, 2 * Hkv, D), jnp.float32)
+    kn = jnp.asarray(rs.randn(B, Hkv, T, D), jnp.float32)
+    vn = jnp.asarray(rs.randn(B, Hkv, T, D), jnp.float32)
+    return q, kn, vn, pool, table
+
+
+def _block_via_gather(q, kn, vn, pool, table, layer, fills):
+    """The gather arm a slot at a time: ``cached_attention`` on the
+    slot's ``PagedCache`` with ``block`` = T, off the kernel."""
+    from paddle_tpu.models._common import cached_attention
+    from paddle_tpu.models.generation import PagedCache
+    T = q.shape[1]
+    outs = []
+    for b, fill in enumerate(fills):
+        out, _ = cached_attention(
+            q[b:b + 1], kn[b:b + 1].transpose(0, 2, 1, 3),
+            vn[b:b + 1].transpose(0, 2, 1, 3), PagedCache(pool, table[b]),
+            jnp.int32(fill), layer=layer, block=T)
+        outs.append(out[0])
+    return jnp.stack(outs)
+
+
+BLOCK_FILLS = [[0, 4, 12], [32, 52, 96], [8 * 8, 0, 11 * 8 + 4]]
+
+
+@COPY_FORMS
+@pytest.mark.parametrize("fills", BLOCK_FILLS,
+                         ids=["short", "edges", "empty_between"])
+def test_block_form_matches_the_gather_arm(fills, form):
+    """T = 4 rows a slot, each slot at its own fill: every row sees the
+    cached positions before its block's first and all four rows of the
+    block, both ways; the kernel under ``vmap`` over slots (the engine's
+    call) against the gather arm."""
+    q, kn, vn, pool, table = _mk_block()
+    want = _block_via_gather(q, kn, vn, pool, table, 1, fills)
+    assert pdk.block_supported(q, pool, table) is False      # off the chip
+    with _support.force_dispatch():
+        assert pdk.block_supported(q, pool, table)
+        got = jax.vmap(
+            lambda qb, kb, vb, row, idx: pdk.paged_block_attention(
+                qb[None], kb[None], vb[None], pool, row[None], 1, idx,
+                scale=q.shape[-1] ** -0.5)[0])(
+            q, kn, vn, table, jnp.asarray(fills, jnp.int32))
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@COPY_FORMS
+def test_block_form_is_its_own_kernel(form):
+    """The block form lowers to ``ptpu_paged_block_attn``, one call for
+    all slots under ``vmap``; a one-token chunk stays
+    ``ptpu_paged_decode_attn``; the gate refuses T = 1, wide pages and
+    the int8 pool."""
+    q, kn, vn, pool, table = _mk_block()
+    idx = jnp.asarray([4, 8, 12], jnp.int32)
+    with _support.force_dispatch():
+        closed = jax.make_jaxpr(jax.vmap(
+            lambda qb, kb, vb, row, i: pdk.paged_block_attention(
+                qb[None], kb[None], vb[None], pool, row[None], 0, i,
+                scale=0.125)[0]))(q, kn, vn, table, idx)
+        calls = [(e.params["name"], e.params["grid_mapping"].grid)
+                 for e, _ in walk_eqns(closed.jaxpr)
+                 if e.primitive.name == "pallas_call"]
+        assert calls == [("ptpu_paged_block_attn",
+                          (3, _steps(table.shape[1], pool)))]
+        assert not pdk.block_supported(q[:, :1], pool, table)
+        wide = tuple(jnp.tile(leaf, (1, 1, 8, 1, 1)) for leaf in pool)
+        assert not pdk.block_supported(jnp.tile(q, (1, 1, 8, 1)), wide,
+                                       table)
+        _, _, _, qpool, _ = _mk(quant=True)
+        assert not pdk.block_supported(q, qpool, table[:, :4])

@@ -550,3 +550,38 @@ def test_kda_step_kernel_compiled_for_v5e_keeps_the_state_in_place(
     for line in compiled.as_text().splitlines():
         if " copy(" in line:
             assert leaf not in line, line
+
+
+def test_block_kernel_compiled_for_v5e_at_the_block_cells_widths(one_chip,
+                                                                 monkeypatch):
+    """``ptpu_paged_block_attn`` at the block-diffusion cell's widths —
+    64 slots, blocks of 4 rows of 32 query heads x 128, pages of 4 KV
+    heads x 16 tokens, 145 pages a row, a pool of 6 layers — under the
+    engine's ``vmap`` over slots: Mosaic takes the block form in its VMEM
+    budget, ONE call with the slots in its grid, and the pool is only
+    read (no temporary of its size)."""
+    from paddle_tpu.ops.pallas import paged_decode_attention as pdk
+
+    monkeypatch.setattr(_support, "on_tpu", lambda: True)
+    slots, T, Hq, Hkv, D, P, M, N, L = 64, 4, 32, 4, 128, 16, 145, 2048, 6
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = (sds((N + 1, L, Hkv, P, D)), sds((N + 1, L, Hkv, P, D)))
+    assert pdk.block_supported(jax.ShapeDtypeStruct((1, T, Hq, D),
+                                                    jnp.bfloat16),
+                               pool, jax.ShapeDtypeStruct((1, M), jnp.int32))
+
+    def step(q, k, v, table, index, pool):
+        return jax.vmap(lambda qb, kb, vb, row, i: pdk.paged_block_attention(
+            qb[None], kb[None], vb[None], pool, row[None], 3, i,
+            scale=D ** -0.5)[0])(q, k, v, table, index)
+
+    lowered = jax.jit(step).trace(
+        sds((slots, T, Hq, D)), sds((slots, Hkv, T, D)),
+        sds((slots, Hkv, T, D)), sds((slots, M), jnp.int32),
+        sds((slots,), jnp.int32), pool).lower(lowering_platforms=("tpu",))
+    assert lowered.as_text().count("ptpu_paged_block_attn") == 1
+    mem = lowered.compile().memory_analysis()
+    assert mem.temp_size_in_bytes < (N + 1) * L * Hkv * P * D * 2
