@@ -605,7 +605,9 @@ def serve_phase(model, requests, *, slots: int, max_len: int,
                 text = engine.lowered_text(longest)
                 decode = DECODE_KERNELS[name]
                 if name == "paged":
-                    arm = "gather" if mesh_tp > 1 else "paged_kernel"
+                    # the headline model's pages, 16 KV heads x 16
+                    # tokens x 128, are copied by the kernel itself
+                    arm = "gather" if mesh_tp > 1 else "paged_copy_kernel"
                     got = engine.stats()["decode_attn"]
                     check(got == arm, f"paged step attends by {got}, "
                                       f"expected {arm}")

@@ -35,8 +35,9 @@ def causal_lm_loss(model, head_weight, input_ids, labels,
 # program is traced and counted there (as ``ops.pallas.partition_stats``
 # counts its units), by ``cached_attention`` and ``latent_attention``
 # alike: ``"paged_copy_kernel"`` — ``ptpu_paged_decode_attn`` in the form
-# that copies K/V pages narrower than a lane tile into VMEM itself;
-# ``"paged_kernel"`` — the same kernel's block-spec form or, over the
+# that copies the pages of a float K/V pool into VMEM itself;
+# ``"paged_kernel"`` — the same kernel's block-spec form (the int8 pool,
+# a 64-wide head on pages of whole lane tiles) or, over the
 # latent leaf, ``ptpu_paged_latent_decode_attn``, through the page table;
 # or ``"gather"`` — one layer's pages gathered and the einsum lines;
 # ``"paged_block_kernel"`` — ``ptpu_paged_block_attn``, the copy form
@@ -119,14 +120,14 @@ def cached_attention(q, k, v, cache, index, layer=0, window=None, block=1):
       live pages only; under the engine's ``vmap`` over slots that is
       one call for all slots (the kernel's own batching rule). The
       leaves' shape picks the kernel's form
-      (``paged_decode_attention.copies_pages``): float pages narrower
-      than a lane tile (SmallThinker's 4 KV heads x 16 tokens) are
-      copied into VMEM by the kernel itself, a block of 64 pages to one
-      wait — 8.9 ms a step of the window cell, 69 % of the chip's
-      bandwidth, where block specs took 36.7 — and pages of whole lane tiles
-      (OLMoE's 16 heads x 16: 3.1 ms a step, 55 % of the chip's
-      bandwidth) and the interpreter's int8 pool arrive through block
-      specs. Everything else — a prefill chunk or verify window, the
+      (``paged_decode_attention.copies_pages``): the pages of a float
+      pool are copied into VMEM by the kernel itself, a block of them
+      to one wait a leaf — SmallThinker's 4 KV heads x 16 tokens 64
+      pages a block, 8.9 ms a step of the window cell where block specs
+      took 36.7; OLMoE's 16 heads x 16 x 128 16 pages a block, 1.70 ms a
+      step where block specs took 3.22 — and a 64-wide head on pages of
+      whole lane tiles (whose copy Mosaic refuses) and the interpreter's
+      int8 pool arrive through block specs. Everything else — a prefill chunk or verify window, the
       CPU, a multi-device mesh, other shapes — gathers this layer's
       pages through the row (``PagedCache.read_layer``) for the einsum
       lines below, which are also what the tests hold the kernel to.

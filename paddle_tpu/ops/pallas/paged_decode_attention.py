@@ -17,9 +17,9 @@ cache the kernel ever touches, and only its live pages. Which of the
 two K/V forms a pool takes is a function of its leaves' shape
 (:func:`copies_pages`), read where the program is traced.
 
-The block-spec form (:func:`raw_call`; pages of whole lane tiles,
-``Hkv * P`` a multiple of 128 — OLMoE's 16 KV heads x 16 tokens — and
-the int8 pool). A page block's index map
+The block-spec form (:func:`raw_call`; the int8 pool, and a 64-wide
+head on pages of whole lane tiles, ``Hkv * P`` a multiple of 128, whose
+copy Mosaic refuses). A page block's index map
 
     page id = sp_ref[b, 2 + min((j - 1) * K + i, last_live_page)]
 
@@ -33,22 +33,24 @@ either side of one softmax update, which is what lets the core's
 matrix units work side by side: one page a step — the first form, a
 chain of dot, update, dot — kept one unit busy at 0.55 µs a page where
 the page's bytes take 0.16. A page costs two pipeline DMAs whatever its
-size: ~0.28 µs on the v5e, which a 128 KB page of 16 heads hides (3.1
-ms a decode step of the OLMoE cell, 55 % of the chip's bandwidth) and a
-32 KB page of 4 heads does not (36.7 ms a step of the window cell, 17 %
-of its roofline, whatever K).
+size: ~0.28 µs on the v5e, which a 32 KB page of 4 heads does not hide
+(36.7 ms a step of the window cell, 17 % of its roofline, whatever K)
+and a 128 KB page of 16 heads half hides (3.22 ms a decode step of the
+OLMoE cell, ~55 % of the chip's bandwidth).
 
-The copy form (:func:`raw_copy_call`; float pages narrower than a lane
-tile — SmallThinker's 4 KV heads x 16 tokens = 64 rows). The two leaves
-stay unblocked in HBM (``pl.ANY``) and the kernel copies pages itself,
-as the latent body below does for its one leaf: a page of one layer is
-a contiguous ``[Hkv, P, D]`` slab a leaf, ONE ``make_async_copy`` into
-its ``Hkv * P`` rows of a block's buffer — rows in the order (page,
-head, offset) — :func:`_pages_per_block` pages a block, contiguous in
-VMEM BEFORE the dot; a whole block is KP straight-line starts and one
-wait a leaf on a byte-counting semaphore (only a row's first and last
-live block loop over a count), started one block ahead of its use
-across grid steps and across slots. Pages past the fill are not copied;
+The copy form (:func:`raw_copy_call`; every float pool whose copies
+Mosaic takes — :func:`copies_pages`: pages narrower than a lane tile,
+SmallThinker's 4 KV heads x 16 tokens = 64 rows, and pages of whole lane
+tiles with a 128-wide head, OLMoE's 16 KV heads x 16 tokens = 256 rows).
+The two leaves stay unblocked in HBM (``pl.ANY``) and the kernel copies
+pages itself, as the latent body below does for its one leaf: a page of
+one layer is a contiguous ``[Hkv, P, D]`` slab a leaf, ONE
+``make_async_copy`` into its ``Hkv * P`` rows of a block's buffer — rows
+in the order (page, head, offset) — :func:`_pages_per_block` pages a
+block, contiguous in VMEM BEFORE the dot; a whole block is KP
+straight-line starts and one wait a leaf on a byte-counting semaphore
+(only a row's first and last live block loop over a count), started one
+block ahead of its use across grid steps and across slots. Pages past the fill are not copied;
 with a window, pages wholly before ``lo // P`` are not copied either
 and blocks wholly before it take no grid step. Then one ``[Hq, D] x
 [KP * Hkv * P, D]^T`` product, the head / fill / window mask, one
@@ -58,10 +60,12 @@ online-softmax update in float32, one ``[Hq, KP * Hkv * P] x [KP * Hkv
 152 k pages of 32 KB a step, ten steps chained in one program): 8.9 ms
 a step at 64 pages a block where the block-spec form took 36.7 — 5.0 GB
 at 563 GB/s, 69 % of the chip's bandwidth; 32 pages a block 10.1, 16
-12.6, 128 8.5 (twice the VMEM). At OLMoE's shapes (pages of 16 heads,
-16 a block) it read 1.70 ms a step beside the block-spec form's 3.22:
-recorded, not routed — whether one K/V body can serve both is the next
-question.
+12.6, 128 8.5 (twice the VMEM). At OLMoE's shapes (16 slots x ~68 pages
+of 64 KB a leaf, 16 pages a block, 8 layers) it read 1.70 ms a step
+where the block-spec form took 3.22 — ~1.15 GB at ~680 GB/s, 83 % of
+the chip's bandwidth — though at one query head a KV head (G = 1) the
+one block-diagonal product computes 16 times the logits it keeps; 8
+pages a block read 1.73, 32 read 1.74.
 
 The block form (``ptpu_paged_block_attn``; :func:`block_supported`,
 :func:`paged_block_attention`): the copy form at T > 1 query rows a
@@ -175,13 +179,21 @@ def _one_token_on_one_chip(q, table) -> bool:
 
 
 def copies_pages(pool) -> bool:
-    """Which K/V form a pool takes, from its leaves' shape alone: float
-    pages narrower than a lane tile (``Hkv * P`` rows short of 128: 4 KV
-    heads x 16 tokens) are copied by the kernel itself
-    (:func:`raw_copy_call`), every other pool arrives through block
-    specs (:func:`raw_call`)."""
+    """Which K/V form a pool takes, from its leaves' shape alone: the
+    kernel copies the pages of a float pool itself
+    (:func:`raw_copy_call`) — pages narrower than a lane tile (``Hkv *
+    P`` rows short of 128: SmallThinker's 4 KV heads x 16 tokens) and
+    pages of whole lane tiles whose copies Mosaic takes, a head of
+    whole lane tiles and a page of whole packed tiles (OLMoE's 16 KV
+    heads x 16 tokens x 128). The int8 pool and a 64-wide head on pages
+    of whole lane tiles arrive through block specs (:func:`raw_call`)."""
+    if len(pool) != 2:
+        return False
     k = pool[0]
-    return len(pool) == 2 and bool((k.shape[2] * k.shape[3]) % LANES)
+    Hkv, P, D = k.shape[2:]
+    if (Hkv * P) % LANES:
+        return True
+    return not (D % LANES or (P * k.dtype.itemsize) % 32)
 
 
 def supported(q, pool, table) -> bool:
@@ -370,12 +382,13 @@ def _pages_per_step(M: int, page_bytes: int) -> int:
     a step are what gives the kernel independent dots to run side by
     side (``_kernel``); on the v5e 16 pages read the same as 8, and one
     page a step — a page's two dots and its softmax update in a chain —
-    took 5.9 ms a decode step of the OLMoE cell where 8 take 3.1. Only
-    pages of whole lane tiles come here compiled (:func:`copies_pages`):
-    on pages of 4 KV heads this form read 36.7 / 33.6 / 33.5 / 35.2 ms a
-    step of the window cell at 8 / 16 / 32 / 64 pages a step — two DMAs
-    a page, whatever their number — and was replaced there by the copy
-    form (:func:`_pages_per_block`)."""
+    took 5.9 ms a decode step of the OLMoE cell where 8 took 3.1. Only
+    a 64-wide head on pages of whole lane tiles comes here compiled
+    (:func:`copies_pages`; the int8 pool only interpreted): two DMAs a
+    page, whatever their number, read 36.7 / 33.6 / 33.5 / 35.2 ms a
+    step of the window cell at 8 / 16 / 32 / 64 pages a step, and 3.22
+    ms a step of the OLMoE cell at 8, where the copy form
+    (:func:`_pages_per_block`) reads 8.9 and 1.70."""
     return max(1, min(8, M, (1 << 20) // page_bytes))
 
 
@@ -467,7 +480,7 @@ def _pages_per_block(M: int, page_bytes: int) -> int:
 def _copy_kernel(sp_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
                  kbuf, vbuf, sem, slot_ref, acc_ref, m_ref, l_ref, *,
                  scale, P, KP, M, G, Hkv, rows, out_dtype, windowed, T=1):
-    # The K/V body for pages narrower than a lane tile. The pool stays
+    # The K/V body for float pools (copies_pages). The pool stays
     # in HBM; a page of one layer is a contiguous [Hkv, P, D] slab a
     # leaf, copied into its Hkv·P rows of a block's buffer — rows in the
     # order (page, head, offset) — so the block is contiguous in VMEM
@@ -1018,10 +1031,12 @@ def block_supported(q, pool, table) -> bool:
     Hq, D] with T > 1 — one block of a block-diffusion step, whose rows
     see each other both ways — of a float dtype, raw dispatch (one TPU
     chip), on a float pool the copy form takes (:func:`copies_pages`:
-    pages narrower than a lane tile), on the copy form's Mosaic terms
-    where it is compiled. Everything else — a prefill chunk of several
-    blocks is the caller's to keep off, wide pages, the int8 pool, a
-    mesh, the CPU — stays on the gather arm and the einsum lines."""
+    pages narrower than a lane tile, or of whole lane tiles with a
+    128-wide head), on the copy form's Mosaic terms where it is
+    compiled. Everything else — a prefill chunk of several blocks is
+    the caller's to keep off, a 64-wide head on pages of whole lane
+    tiles, the int8 pool, a mesh, the CPU — stays on the gather arm and
+    the einsum lines."""
     if not (_support.dispatch_mode() == "raw" and q.ndim == 4
             and q.shape[1] > 1 and q.dtype in (jnp.float32, jnp.bfloat16)
             and table.ndim == 2 and table.shape[0] == q.shape[0]):

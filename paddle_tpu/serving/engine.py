@@ -43,8 +43,8 @@ paged cache, SOSP '23) to the framework's autoregressive path:
   gather ONE layer's pages through the row for the einsum arm
   (``models._common.cached_attention`` / ``latent_attention`` pick;
   ``stats()["decode_attn"]`` says which the step took, for either
-  family: ``"paged_copy_kernel"`` where the K/V kernel copies narrow
-  pages itself, ``"paged_kernel"``, ``"gather"``). Either way no slot's
+  family: ``"paged_copy_kernel"`` where the K/V kernel copies a float
+  pool's pages itself, ``"paged_kernel"``, ``"gather"``). Either way no slot's
   all-layers view is
   ever built, and the chunk's new k/v go into the donated pool by
   in-place page updates (``generation.paged_write``): a step moves the

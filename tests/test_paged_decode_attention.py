@@ -6,10 +6,12 @@ reproduce ``models.generation.paged_gather`` + masked attention
 bit-for-bit per slot, honor the physical page permutation (same logical
 sequence, different page placement → identical output), bound reads to
 the filled prefix, fold int8 pool scales exactly, and survive
-``jax.vmap`` over slots. Both K/V forms are held to it: the copy form a
-float page narrower than a lane tile takes (the kernel copies a block of
-pages into VMEM itself) and the block-spec form of every other pool,
-each at its own choice of pages a block / a grid step and at 3. This is
+``jax.vmap`` over slots. Both K/V forms are held to it: the copy form
+every float pool takes whose copies Mosaic accepts (the kernel copies a
+block of pages into VMEM itself; narrow pages and OLMoE's 16 KV heads x
+128) and the block-spec form of the int8 pool and of a 64-wide head on
+pages of whole lane tiles, each at its own choice of pages a block / a
+grid step and at 3. This is
 the hardware-independent result; the TPU timing run is the stated caveat
 in the module doc.
 """
@@ -26,22 +28,24 @@ from paddle_tpu.ops.pallas import paged_decode_attention as pdk
 
 # a page of 2 KV heads x 8 tokens is 16 rows (narrow: floats take the copy
 # form, the int8 pool the block-spec form, which only the interpreter runs
-# on such a page); with eight times the heads it is one lane tile (wide:
-# the block-spec form, OLMoE's)
-HEADS = {"narrow": 1, "wide": 8}
+# on such a page); with eight times the heads and a 128-wide head it is
+# one lane tile of rows (wide: OLMoE's kind of page, floats the copy form
+# too); the same page with a 64-wide head (wide64) is the block-spec
+# form's, whose copy Mosaic refuses
+WIDTHS = {"narrow": (1, 64), "wide": (8, 128), "wide64": (8, 64)}
 FORMS = ["narrow", "narrow-3", "wide", "wide-3"]
-_heads = 1
+_heads, _dim = 1, 64
 
 
 def set_form(param, monkeypatch):
     """``"<width>[-<pages>]"``: both forms' pages a block / a grid step
     patched to ``pages`` where given; returns the width's multiple of
-    the tests' heads."""
+    the tests' heads and its head width."""
     width, _, pages = param.partition("-")
     if pages:
         for name in ("_pages_per_step", "_pages_per_block"):
             monkeypatch.setattr(pdk, name, lambda M, page_bytes: int(pages))
-    return HEADS[width]
+    return WIDTHS[width]
 
 
 @pytest.fixture(autouse=True, params=FORMS)
@@ -51,10 +55,10 @@ def form(request, monkeypatch):
     one) and at 3: two steps of pages, the second with a tail past the
     table (the block-spec form clamps it to the last live page, the
     copy form does not copy it)."""
-    global _heads
-    _heads = set_form(request.param, monkeypatch)
+    global _heads, _dim
+    _heads, _dim = set_form(request.param, monkeypatch)
     yield request.param
-    _heads = 1
+    _heads, _dim = 1, 64
 
 
 def _steps(M, pool):
@@ -65,9 +69,9 @@ def _steps(M, pool):
     return 1 - (-M // pages(M, 1))
 
 
-def _mk(B=2, Hq=4, Hkv=2, P=8, M=4, D=64, L=2, N=16, quant=False,
+def _mk(B=2, Hq=4, Hkv=2, P=8, M=4, D=None, L=2, N=16, quant=False,
         dtype=jnp.float32, seed=0):
-    Hq, Hkv = Hq * _heads, Hkv * _heads
+    Hq, Hkv, D = Hq * _heads, Hkv * _heads, D or _dim
     rs = np.random.RandomState(seed)
     q = jnp.asarray(rs.randn(B, 1, Hq, D), dtype)
     kn = jnp.asarray(rs.randn(B, Hkv, 1, D), dtype)
@@ -207,15 +211,15 @@ def test_kernel_ignores_stale_and_unmapped():
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.parametrize("form", ["narrow", "narrow-3", "wide"],
-                         indirect=True)
+@pytest.mark.parametrize("form", ["narrow", "narrow-3", "wide", "wide-3",
+                                  "wide64"], indirect=True)
 def test_a_fill_past_the_table_reads_the_table_alone(form):
     """An idle slot's position may lie beyond what its row maps (the
     engine steps every slot): the kernel attends the row's pages and
-    reads no table entry past it. (The block-spec form is held where
-    its pages a step divide the table, as they do in every engine that
-    runs it: at 3 pages a step its last step's index map walks past
-    the row — ROADMAP C2.)"""
+    reads no table entry past it. (The block-spec form — a 64-wide head
+    on wide pages — is held where its pages a step divide the table, as
+    they do in every engine that runs it: at 3 pages a step its last
+    step's index map walks past the row — ROADMAP C2.)"""
     q, kn, vn, pool, table = _mk(seed=9)
     idx = table.shape[1] * 8 + 9
     want = _via_paged_gather(q, kn, vn, pool, table, 1, idx, 0.125)
@@ -366,12 +370,13 @@ def test_copy_form_on_a_row_of_one_page(fill, form):
 
 
 def test_the_form_follows_the_leaves(form):
-    """Float pages short of a lane tile take the copy form — the pool
-    left unblocked in HBM, two operands — and every other pool the
-    block-spec form, each leaf an operand a page of the step."""
+    """Float pages take the copy form — narrow ones and wide ones of a
+    128-wide head alike, the pool left unblocked in HBM, two operands —
+    and the int8 pool the block-spec form, each leaf an operand a page
+    of the step."""
     for quant in (False, True):
         q, kn, vn, pool, table = _mk(quant=quant)
-        copies = form.startswith("narrow") and not quant
+        copies = not quant
         assert pdk.copies_pages(pool) == copies
         with _support.force_dispatch():
             jaxpr = jax.make_jaxpr(lambda *a: pdk.paged_decode_attention(
@@ -382,6 +387,71 @@ def test_the_form_follows_the_leaves(form):
         assert call.params["name"] == "ptpu_paged_decode_attn"
         pages = 1 if copies else pdk._pages_per_step(table.shape[1], 1)
         assert len(call.invars) == 4 + len(pool) * pages
+
+
+@pytest.mark.parametrize("form", ["wide64", "wide64-3"], indirect=True)
+@pytest.mark.parametrize("idx", [7, 17, 32])
+def test_a_64_wide_head_on_wide_pages_keeps_the_block_spec_form(idx, form):
+    """16 KV heads x 8 tokens x 64: rows of whole lane tiles, but a copy
+    of a page would slice half a lane tile out of the pool, so the float
+    pool stays on the block-spec form — each leaf an operand a page of
+    the step — and matches the gather arm."""
+    q, kn, vn, pool, table = _mk(seed=17)
+    assert not pdk.copies_pages(pool)
+    want = _via_paged_gather(q, kn, vn, pool, table, 1, idx, 0.125)
+    with _support.force_dispatch():
+        assert pdk.supported(q, pool, table)
+        jaxpr = jax.make_jaxpr(lambda *a: pdk.paged_decode_attention(
+            *a, pool, table, jnp.int32(1), jnp.int32(idx),
+            scale=0.125))(q, kn, vn)
+        got = pdk.paged_decode_attention(q, kn, vn, pool, table,
+                                         jnp.int32(1), jnp.int32(idx),
+                                         scale=0.125)
+    (call,) = [e for e, _ in walk_eqns(jaxpr.jaxpr)
+               if e.primitive.name == "pallas_call"]
+    pages = pdk._pages_per_step(table.shape[1], 1)
+    assert len(call.invars) == 4 + len(pool) * pages
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+# OLMoE's pages — 16 KV heads x 16 tokens x 128 — in float32: 8 pages a
+# block (128 KB a page a leaf); each case three slots at their own fills
+OLMOE_ROWS = {
+    # fills off the page edges: a full block, then a part of one
+    "off_page_edges": (12, [9 * 16 + 3, 5 * 16 + 7, 12 * 16 - 1]),
+    # an idle slot's position past its row: the copies stop at the table
+    "past_the_row": (12, [12 * 16 + 9, 40, 12 * 16 + 100]),
+    # a row of three pages, shorter than one block
+    "shorter_than_a_block": (3, [2 * 16 + 5, 3 * 16, 1]),
+}
+
+
+@pytest.mark.parametrize("form", ["wide"], indirect=True)
+@pytest.mark.parametrize("G", [1, 2])
+@pytest.mark.parametrize("case", list(OLMOE_ROWS))
+def test_copy_form_on_pages_of_sixteen_kv_heads(case, G, form):
+    M, fills = OLMOE_ROWS[case]
+    Hkv, P, D, N = 16, 16, 128, 40
+    rs = np.random.RandomState(50 + G)
+    q = jnp.asarray(rs.randn(3, 1, G * Hkv, D), jnp.float32)
+    kn, vn = (jnp.asarray(rs.randn(3, Hkv, 1, D), jnp.float32)
+              for _ in range(2))
+    pool = tuple(jnp.asarray(rs.randn(N + 1, 2, Hkv, P, D), jnp.float32)
+                 for _ in range(2))
+    ids = rs.permutation(np.arange(1, N + 1))[:3 * M]
+    table = jnp.asarray(ids.reshape(3, M).astype(np.int32))
+    assert pdk.copies_pages(pool)
+    assert pdk._pages_per_block(M, Hkv * P * D * 4) == 8
+    idx = jnp.asarray(fills, jnp.int32)
+    want = pdk.paged_reference(q, kn, vn, pool, table, 1, idx, scale=0.088)
+    with _support.force_dispatch():
+        assert pdk.supported(q, pool, table)
+        got = pdk.paged_decode_attention(q, kn, vn, pool, table,
+                                         jnp.int32(1), idx, scale=0.088)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
 
 
 def walk_eqns(jaxpr, path=()):
@@ -454,7 +524,8 @@ def test_supported_gates():
     with _support.force_dispatch():
         assert pdk.supported(q, pool, table)
         # prefill chunk (T > 1) is not this kernel's job
-        assert not pdk.supported(jnp.zeros((2, 4, 4, 64)), pool, table)
+        assert not pdk.supported(jnp.zeros((2, 4) + q.shape[2:]), pool,
+                                 table)
         # head_dim off the MXU grid
         assert not pdk.supported(
             jnp.zeros((2, 1, 4, 32)),
@@ -562,8 +633,8 @@ def test_block_form_matches_the_gather_arm(fills, form):
 def test_block_form_is_its_own_kernel(form):
     """The block form lowers to ``ptpu_paged_block_attn``, one call for
     all slots under ``vmap``; a one-token chunk stays
-    ``ptpu_paged_decode_attn``; the gate refuses T = 1, wide pages and
-    the int8 pool."""
+    ``ptpu_paged_decode_attn``; the gate refuses T = 1, a 64-wide head
+    on wide pages and the int8 pool."""
     q, kn, vn, pool, table = _mk_block()
     idx = jnp.asarray([4, 8, 12], jnp.int32)
     with _support.force_dispatch():
@@ -582,3 +653,24 @@ def test_block_form_is_its_own_kernel(form):
                                        table)
         _, _, _, qpool, _ = _mk(quant=True)
         assert not pdk.block_supported(q, qpool, table[:, :4])
+
+
+@pytest.mark.parametrize("form", ["wide"], indirect=True)
+def test_block_form_on_pages_of_sixteen_kv_heads(form):
+    """Pages of 16 KV heads x 8 tokens x 128 (a lane tile of rows) take
+    the block form too: T = 4 rows a slot at one query head a KV head,
+    against the gather arm."""
+    q, kn, vn, pool, table = _mk_block()
+    q = q[:, :, :q.shape[2] // 2]                 # G = 1
+    assert pool[0].shape[2:] == (16, 8, 128)
+    fills = BLOCK_FILLS[2]
+    want = _block_via_gather(q, kn, vn, pool, table, 1, fills)
+    with _support.force_dispatch():
+        assert pdk.block_supported(q, pool, table)
+        got = jax.vmap(
+            lambda qb, kb, vb, row, idx: pdk.paged_block_attention(
+                qb[None], kb[None], vb[None], pool, row[None], 1, idx,
+                scale=q.shape[-1] ** -0.5)[0])(
+            q, kn, vn, table, jnp.asarray(fills, jnp.int32))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)

@@ -20,14 +20,15 @@ the gates are open, and the K/V step does with the latent arm there or
 not; and the step compiled for the v5e keeps the pool in place. The
 latent model's step (``DeepseekV3ForCausalLM`` at the tiny preset) is
 held the same way. The K/V model's pages of 2 KV heads x 8 tokens are
-narrower than a lane tile: its float step takes the kernel's copy form
-(``stats()["decode_attn"] == "paged_copy_kernel"``), its int8 step and
-the ``wide`` model's (16 KV heads: a page is one lane tile, OLMoE's
-case) the block-spec form.
+narrower than a lane tile, the ``wide`` model's (16 KV heads x 8 tokens
+x 128) one lane tile of rows, OLMoE's kind of page: both float steps
+take the kernel's copy form (``stats()["decode_attn"] ==
+"paged_copy_kernel"``), the int8 step the block-spec form.
 """
 
 import contextlib
 import dataclasses
+import functools
 import hashlib
 import os
 import sys
@@ -60,13 +61,15 @@ M = MAXLEN // P
 STEPS = 34
 
 
-def _llama(dtype, seed=11, **kw):
+def _llama(dtype, seed=11, intermediate_size=None, **kw):
     paddle_tpu.seed(seed)
     args = dict(vocab_size=VOCAB, hidden_size=HQ * D, num_layers=L,
                 num_heads=HQ, num_kv_heads=HKV, max_seq_len=MAXLEN)
     args.update(kw)
-    return LlamaForCausalLM(dataclasses.replace(LlamaConfig.tiny(**args),
-                                                dtype=dtype))
+    cfg = dataclasses.replace(LlamaConfig.tiny(**args), dtype=dtype)
+    if intermediate_size:
+        cfg = dataclasses.replace(cfg, intermediate_size=intermediate_size)
+    return LlamaForCausalLM(cfg)
 
 
 def _latent(dtype, seed=12, **kw):
@@ -80,10 +83,11 @@ def _latent(dtype, seed=12, **kw):
 
 
 def _wide(dtype, seed=14, **kw):
-    """16 KV heads x 8 tokens: a page of one lane tile, the block-spec
-    form's (OLMoE's head count at half its head width)."""
-    return _llama(dtype, seed, hidden_size=16 * D, num_heads=16,
-                  num_kv_heads=16, **kw)
+    """16 KV heads x 8 tokens x 128: a page of one lane tile of rows, the
+    copy form's (OLMoE's heads and head width, at half its page); a
+    narrow MLP keeps the model small."""
+    return _llama(dtype, seed, hidden_size=16 * 128, num_heads=16,
+                  num_kv_heads=16, intermediate_size=256, **kw)
 
 
 FAMILIES = {"llama": _llama, "latent": _latent, "wide": _wide}
@@ -139,9 +143,9 @@ def _hand_state(eng, seed):
 
 
 def _stat(which, family, quant):
-    """What ``stats()["decode_attn"]`` reads on arm ``which``: the
-    narrow float pages' kernel is the copy form."""
-    copies = which != "gather" and family == "llama" and not quant
+    """What ``stats()["decode_attn"]`` reads on arm ``which``: a float
+    pool's kernel is the copy form, narrow pages and wide alike."""
+    copies = which != "gather" and family != "latent" and not quant
     return "paged_copy_kernel" if copies else which
 
 
@@ -291,25 +295,21 @@ def _shapes(eqns):
 def test_step_holds_one_kernel_call_a_layer_over_all_slots(models, family):
     model = models[family]
     hkv = model.config.num_kv_heads
+    d = model.config.hidden_size // model.config.num_heads
     eqns = _step_eqns(model, "paged_kernel")
     calls = _attn_calls(eqns)
     assert len(calls) == 1                      # the layer scan's body
     call, path = calls[0]
     # the fresh token's step, then the row's pages: a block of them the
-    # kernel copies itself (narrow pages, the pool two unblocked
-    # operands), or a few a step through block specs (each leaf an
-    # operand a page)
-    page_bytes = hkv * P * D * 4
-    pages, operands = ((pdk._pages_per_block(M, page_bytes), 2)
-                       if family == "llama" else
-                       (pdk._pages_per_step(M, page_bytes),
-                        2 * pdk._pages_per_step(M, page_bytes)))
+    # kernel copies itself, narrow pages and wide alike (the pool two
+    # unblocked operands)
+    pages = pdk._pages_per_block(M, hkv * P * d * 4)
     assert call.params["grid_mapping"].grid == (SLOTS, 1 - (-M // pages))
-    assert len(call.invars) == 4 + operands
+    assert len(call.invars) == 4 + 2
     assert "scan" in path and "while" not in path, path
     # the views a gather builds: pages through the row, and the
     # contiguous [Hkv, M * P, D] it reshapes them to
-    pages, view = (SLOTS, M, hkv, P, D), (SLOTS, 1, hkv, M * P, D)
+    pages, view = (SLOTS, M, hkv, P, d), (SLOTS, 1, hkv, M * P, d)
     assert not {pages, view} & _shapes(eqns)
     gather = _step_eqns(model, "gather")
     assert not _attn_calls(gather)
@@ -383,19 +383,21 @@ def test_only_the_plain_step_changes_with_the_gate(models):
 
 def test_olmoe_shaped_pages_keep_the_block_spec_call(monkeypatch):
     """16 KV heads x 16 tokens x 128 in bf16 (256 rows a page: OLMoE's
-    pool) where the kernel would be compiled: the call is the block-spec
-    form's — every leaf an operand a page of the step, the grid's slot
-    axis parallel, three scratch buffers and no semaphore — and a page
-    of 4 KV heads takes the copy form beside it: two pool operands, the
-    grid in order."""
+    pool) where the kernel would be compiled: the call is the copy
+    form's — two pool operands, 16 pages a block, the grid in order,
+    the double buffers, their semaphores and the slot word beside the
+    three scratch buffers — as for a page of 4 KV heads (64 pages a
+    block). The same page with a 64-wide head keeps the block-spec call:
+    every leaf an operand a page of the step, the grid's slot axis
+    parallel, three scratch buffers and no semaphore."""
     monkeypatch.setattr(_support, "on_tpu", lambda: True)
     monkeypatch.setattr(_support, "dispatch_mode", lambda: "raw")
     slots, m = 16, 128
 
-    def call_of(hkv):
-        q = jnp.zeros((slots, 1, 16, 128), jnp.bfloat16)
-        new = jnp.zeros((slots, hkv, 1, 128), jnp.bfloat16)
-        pool = (jnp.zeros((9, 8, hkv, 16, 128), jnp.bfloat16),) * 2
+    def call_of(hkv, d=128):
+        q = jnp.zeros((slots, 1, 16, d), jnp.bfloat16)
+        new = jnp.zeros((slots, hkv, 1, d), jnp.bfloat16)
+        pool = (jnp.zeros((9, 8, hkv, 16, d), jnp.bfloat16),) * 2
         table = jnp.zeros((slots, m), jnp.int32)
         assert pdk.supported(q, pool, table)
         jaxpr = jax.make_jaxpr(lambda q, kn, vn, k, v: (
@@ -410,17 +412,28 @@ def test_olmoe_shaped_pages_keep_the_block_spec_call(monkeypatch):
                 tuple(call.params["compiler_params"]["mosaic_tpu"]
                       .dimension_semantics))
 
-    k = pdk._pages_per_step(m, 16 * 16 * 128 * 2)
-    assert k == 8
-    assert call_of(16) == ((slots, 1 + m // k), 4 + 2 * k, 3,
-                           ("parallel", "arbitrary"))
+    kp = pdk._pages_per_block(m, 16 * 16 * 128 * 2)
+    assert kp == 16
+    assert call_of(16) == ((slots, 1 + m // kp), 4 + 2, 7,
+                           ("arbitrary", "arbitrary"))
     kp = pdk._pages_per_block(m, 4 * 16 * 128 * 2)
     assert call_of(4) == ((slots, 1 + m // kp), 4 + 2, 7,
                           ("arbitrary", "arbitrary"))
+    k = pdk._pages_per_step(m, 16 * 16 * 64 * 2)
+    assert k == 8
+    assert call_of(16, d=64) == ((slots, 1 + m // k), 4 + 2 * k, 3,
+                                 ("parallel", "arbitrary"))
 
 
 # -- compiled for the chip (no chip needed: libtpu compiles for a described
 # -- v5e); the topology is described inside the fixture, never at import ----
+
+def _abstract(tree, sharding):
+    """Every leaf as a shape on the described chip."""
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
 
 @pytest.fixture(scope="module")
 def one_chip():
@@ -435,7 +448,8 @@ def one_chip():
 
 
 @pytest.mark.parametrize("pool_dtype",
-                         ["bf16", "int8", "latent-bf16", "bf16-narrow"])
+                         ["bf16", "int8", "latent-bf16", "bf16-narrow",
+                          "bf16-olmoe"])
 def test_step_compiled_for_v5e_keeps_the_pool_in_place(one_chip, monkeypatch,
                                                        pool_dtype):
     """Trap 2: the kernel only reads the pool, the write after the vmap
@@ -448,13 +462,17 @@ def test_step_compiled_for_v5e_keeps_the_pool_in_place(one_chip, monkeypatch,
     left unblocked in HBM) under the same bounds, and so does a
     SmallThinker-shaped step — pages of 4 KV heads x 16 tokens, a full
     and a window layer group — with the K/V kernel's copy form in both
-    groups."""
+    groups. Pages of whole lane tiles take the copy form too: 8 KV heads
+    x 16 tokens x 128, and OLMoE's 16 KV heads x 16 x 128 (16 pages a
+    block)."""
     # hkv * p is one lane tile and a row a whole one; the pool is too
     # large for XLA to stage a copy of it in fast memory
     hkv, d, p, slots, maxlen = 8, 128, 16, 4, 512
-    which = {"int8": "gather", "bf16-narrow": "paged_copy_kernel"}.get(
-        pool_dtype, "paged_kernel")
-    kernel, kw = "ptpu_paged_decode_attn", {}
+    which = {"int8": "gather", "latent-bf16": "paged_kernel"}.get(
+        pool_dtype, "paged_copy_kernel")
+    kernel, kw, ffn = "ptpu_paged_decode_attn", {}, None
+    if pool_dtype == "bf16-olmoe":
+        hkv, ffn = 16, 256
     if pool_dtype == "latent-bf16":
         # a latent row of whole lane tiles (128 + 64 -> 256) whose value
         # part is one: what the compiled gate asks for
@@ -474,13 +492,9 @@ def test_step_compiled_for_v5e_keeps_the_pool_in_place(one_chip, monkeypatch,
     else:
         model = _llama("bfloat16", seed=13, hidden_size=hkv * d,
                        num_layers=4, num_heads=hkv, num_kv_heads=hkv,
-                       max_seq_len=maxlen)
+                       max_seq_len=maxlen, intermediate_size=ffn)
     monkeypatch.setattr(_support, "on_tpu", lambda: True)
-
-    def abstract(tree):
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=one_chip), tree)
+    abstract = functools.partial(_abstract, sharding=one_chip)
 
     with GenerationEngine(
             model, slots=slots, max_len=maxlen, paged=True, page_tokens=p,
@@ -512,6 +526,34 @@ def test_step_compiled_for_v5e_keeps_the_pool_in_place(one_chip, monkeypatch,
     assert len(wide) == 1 and "sample/cond/branch_2" in wide[0], wide
     assert sum(" conditional(" in line and "sample/cond" in line
                for line in hlo.splitlines()) == 1
+
+
+def test_a_64_wide_head_on_wide_pages_compiles_on_the_block_spec_form(
+        one_chip, monkeypatch):
+    """16 KV heads x 16 tokens x 64: rows of whole lane tiles, but the
+    copy form would slice half a lane tile out of the pool, which Mosaic
+    refuses — so the step keeps the block-spec form of the kernel, and
+    it compiles for the v5e. (Such a step stages the pool through fast
+    memory and back: ROADMAP C2.)"""
+    monkeypatch.setattr(_support, "on_tpu", lambda: True)
+    slots, maxlen = 4, 512
+    model = _llama("bfloat16", seed=13, hidden_size=16 * 64, num_layers=4,
+                   num_heads=16, num_kv_heads=16, max_seq_len=maxlen,
+                   intermediate_size=256)
+    abstract = functools.partial(_abstract, sharding=one_chip)
+
+    with GenerationEngine(model, slots=slots, max_len=maxlen, paged=True,
+                          page_tokens=16, queue_max=4) as eng:
+        pool = eng._state["cache"]
+        assert not pdk.copies_pages(pool)
+        lowered = eng._step._jitted.trace(
+            abstract(model), abstract(eng._state),
+            abstract(eng._pt_upload(jnp)),
+            abstract(jnp.zeros((slots,), bool))).lower(
+                lowering_platforms=("tpu",))
+        assert eng.stats()["decode_attn"] == "paged_kernel"
+    assert "ptpu_paged_decode_attn" in lowered.as_text()
+    lowered.compile()
 
 
 def test_kda_step_kernel_compiled_for_v5e_keeps_the_state_in_place(

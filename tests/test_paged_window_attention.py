@@ -5,11 +5,12 @@ only the live logical pages from ``base`` on, the query at position
 kernel has to reproduce an independent masked attention over the
 absolute positions — at the edges ``t < W``, ``t == W - 1``, ``t ==
 W``, a window that starts mid-page, a base that is not 0 — per slot,
-under ``jax.vmap``, in the copy form a float page narrower than a lane
-tile takes (4 KV heads x 16 tokens: the kernel copies a block of pages
-itself, and neither copies nor visits what lies wholly behind the
-window) and in the block-spec form of a lane-aligned page, and leave the
-program of every other model as it was."""
+under ``jax.vmap``, in the copy form a float pool takes (4 KV heads x 16
+tokens, or a lane-aligned page of a 128-wide head: the kernel copies a
+block of pages itself, and neither copies nor visits what lies wholly
+behind the window) and in the block-spec form of a lane-aligned page of
+a 64-wide head, and leave the program of every other model as it
+was."""
 
 import numpy as np
 import jax
@@ -25,9 +26,11 @@ from test_paged_decode_attention import FORMS, set_form, walk_eqns
 W, P = 24, 8
 
 
-# 2 KV heads x 8 tokens are 16 rows (narrow: the copy form); with eight
-# times the heads a page is one lane tile (wide: the block-spec form)
-_heads = 1
+# 2 KV heads x 8 tokens x 64 are 16 rows (narrow: the copy form); with
+# eight times the heads and a 128-wide head a page is one lane tile of
+# rows (wide: the copy form too), with a 64-wide head the block-spec
+# form's (wide64)
+_heads, _dim = 1, 64
 
 
 @pytest.fixture(params=FORMS)
@@ -35,14 +38,15 @@ def form(request, monkeypatch):
     """On narrow and on wide pages, at the kernel's own choice of pages
     a block / a grid step and at 3: steps wholly behind the window, a
     step the window starts inside."""
-    global _heads
-    _heads = set_form(request.param, monkeypatch)
+    global _heads, _dim
+    _heads, _dim = set_form(request.param, monkeypatch)
     yield request.param
-    _heads = 1
+    _heads, _dim = 1, 64
 
 
-def _mk(B=3, Hq=4, Hkv=2, M=6, D=64, L=2, N=24, seed=0, dtype=jnp.float32):
-    Hq, Hkv = Hq * _heads, Hkv * _heads
+def _mk(B=3, Hq=4, Hkv=2, M=6, D=None, L=2, N=24, seed=0,
+        dtype=jnp.float32):
+    Hq, Hkv, D = Hq * _heads, Hkv * _heads, D or _dim
     rs = np.random.RandomState(seed)
     q = jnp.asarray(rs.randn(B, 1, Hq, D), dtype)
     kn = jnp.asarray(rs.randn(B, Hkv, 1, D), dtype)
@@ -114,6 +118,23 @@ def test_kernel_masks_what_has_slid_out(edge, form):
                               base=jnp.int32(base))
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(ref), want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("form", ["wide64", "wide64-3"], indirect=True)
+@pytest.mark.parametrize("edge", ["mid-page", "base-not-0", "past-the-row"])
+def test_the_block_spec_form_masks_what_has_slid_out(edge, form):
+    """A 64-wide head on lane-aligned pages stays on the block-spec form
+    (its copy would slice half a lane tile out of the pool): the same
+    lower edge, through the index map's clamp and the mask."""
+    t, base = EDGES[edge]
+    q, kn, vn, pool, table = _mk()
+    assert not pdk.copies_pages(pool)
+    want = _by_positions(q, kn, vn, pool, table, 1, t, base, 0.125, W)
+    with _support.force_dispatch():
+        got = pdk.paged_decode_attention(
+            q, kn, vn, pool, table, jnp.int32(1), jnp.int32(t), scale=0.125,
+            window=W, base=jnp.int32(base))
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
 
 
 def test_a_slot_each_with_its_own_position_and_base(form):
@@ -234,7 +255,8 @@ def test_under_vmap_it_is_one_call_over_the_slots(form):
         fn = jax.vmap(one)
         got = fn(q, k, v, table, base, idx)
         jaxpr = jax.make_jaxpr(fn)(q, k, v, table, base, idx)
-    want = _by_positions(q, kn, vn, pool, table, 1, idx, base, 64 ** -0.5, W)
+    want = _by_positions(q, kn, vn, pool, table, 1, idx, base,
+                         q.shape[-1] ** -0.5, W)
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
     # one call, and no loop over the slots around it
     assert [path for e, path in walk_eqns(jaxpr.jaxpr)
@@ -321,9 +343,13 @@ def test_the_compiled_gate_takes_the_narrow_page(monkeypatch):
     assert not pdk.supported(q.astype(jnp.float32),
                              pool(hkv=1, p=8, dtype=jnp.float32),
                              table[:, :1])
-    # lane-aligned pages are the block-spec form's, as before
-    assert not pdk.copies_pages(pool(hkv=16))
+    # lane-aligned pages of a 128-wide head are the copy form's too
+    # (OLMoE's 16 KV heads x 16 tokens), of a 64-wide head the
+    # block-spec form's
+    assert pdk.copies_pages(pool(hkv=16))
     assert pdk.supported(q[:, :, :16], pool(hkv=16), table)
+    assert not pdk.copies_pages(pool(hkv=16, d=64))
+    assert pdk.supported(q[:, :, :16, :64], pool(hkv=16, d=64), table)
     quant = (jnp.zeros((4, 6, 4, 16, 128), jnp.int8),) * 2 + (
         jnp.zeros((4, 6, 4, 16), jnp.float32),) * 2
     assert not pdk.copies_pages(quant)
