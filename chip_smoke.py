@@ -792,6 +792,60 @@ def window_phase(requests, *, slots: int, max_len: int, window: int,
         server.stop()
 
 
+def mixed_heads_phase(*, prompt_len: int, new_tokens: int, max_len: int,
+                      window: int, chunk: int, on_chip: bool = True) -> dict:
+    """A small Laguna model (a dense full layer, then window layers of
+    24 query heads and a full one of 16 over 8 KV heads of 128, a gate on
+    every head) behind a paged, prefix-cached engine with a group a layer
+    kind: one greedy stream, held to solo ``generate()`` off the chip
+    (float32) and reported on it (bf16 programs of other shapes round
+    differently). ``stats()["attention"]`` names each group's head counts
+    and arm; on the chip both groups take the K/V kernel's copy form
+    (pages of 8 KV heads x 16 tokens x 128), the window layers under
+    ``ptpu_paged_decode_attn_window``."""
+    import jax
+
+    from paddle_tpu.models.laguna import LagunaConfig, LagunaForCausalLM
+    from paddle_tpu.serving.engine import GenerationEngine
+
+    cfg = LagunaConfig.tiny(
+        hidden_size=256, num_layers=5, num_heads=16, window_heads=24,
+        num_kv_heads=8, head_dim=128, moe_intermediate_size=128,
+        shared_expert_intermediate_size=128, sliding_window=window,
+        max_seq_len=max_len, dtype="bfloat16" if on_chip else "float32")
+    model = LagunaForCausalLM(cfg, key=jax.random.PRNGKey(7))
+    prompt = np.random.default_rng(7).integers(
+        1, cfg.vocab_size, prompt_len, dtype=np.int32)
+    with GenerationEngine(model, slots=2, max_len=max_len, paged=True,
+                          page_tokens=PARITY_PAGE_TOKENS,
+                          prefill_chunk=chunk, prefix_cache=True) as engine:
+        text = engine.lowered_text(prompt_len)["decode"]
+        gid, toks = engine.start(prompt, new_tokens), []
+        while True:
+            r = engine.poll(gid, len(toks), wait_s=300.0)
+            check(r["error"] is None, f"mixed heads: {r['error']}")
+            toks += r["tokens"]
+            if r["done"]:
+                break
+        attention = engine.stats()["attention"]
+    check([(a["Hq"], a["Hkv"]) for a in attention] == [(16, 8), (24, 8)],
+          f"mixed heads: groups attend as {attention}")
+    if on_chip:
+        check(all(a["arm"] == "paged_copy_kernel" for a in attention),
+              f"mixed heads: a group left the copy form: {attention}")
+        check(attention[1]["kernel"] == "ptpu_paged_decode_attn_window"
+              and "ptpu_paged_decode_attn_window" in text,
+              f"mixed heads: the window layers' kernel is not named apart: "
+              f"{attention}")
+    agree = _agree(toks, _solo(model, Request(prompt, new_tokens, {})))
+    check(on_chip or agree == new_tokens,
+          f"mixed heads: {agree}/{new_tokens} tokens agree with solo "
+          "generate()")
+    return {"attention": attention,
+            "engine_agrees_with_solo_generate_for":
+                f"{agree}/{new_tokens} tokens"}
+
+
 def state_phase(requests, *, slots: int, max_len: int, chunk: int,
                 on_chip: bool = True) -> dict:
     """A small Kimi-Linear model (KDA layers with a recurrent state
@@ -974,6 +1028,8 @@ def main() -> int:
         make_requests(256, (300, 280, 330, 310), (40, 24, 40, 24),
                       shared_prefix=256), slots=4, max_len=512, window=64,
         chunk=64)
+    report["serve_mixed_heads"] = mixed_heads_phase(
+        prompt_len=300, new_tokens=24, max_len=512, window=64, chunk=64)
     report["serve_state"] = state_phase(
         make_requests(256, (150, 140, 170, 160), (24, 16, 24, 16),
                       shared_prefix=128), slots=4, max_len=256, chunk=64)

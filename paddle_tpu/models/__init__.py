@@ -21,4 +21,5 @@ from paddle_tpu.models.kimi_linear import (
     KimiLinearConfig, KimiLinearForCausalLM,
 )
 from paddle_tpu.models.sdar import SDARConfig, SDARForCausalLM
+from paddle_tpu.models.laguna import LagunaConfig, LagunaForCausalLM
 from paddle_tpu.models.ernie import ErnieConfig, ErnieForPretraining, ErnieModel

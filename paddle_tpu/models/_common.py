@@ -45,6 +45,11 @@ def causal_lm_loss(model, head_weight, input_ids, labels,
 # The engine reads the difference around the trace of its step
 # (``GenerationEngine.stats()["decode_attn"]``).
 paged_attn_arms: collections.Counter = collections.Counter()
+# The same, a layer kind at a time: ``(window, Hq, Hkv, arm, kernel)``
+# for each paged attention layer traced (``kernel`` the Pallas name in
+# the device trace, None on the gather arm); the engine reads the
+# difference around its step's trace (``stats()["attention"]``).
+paged_attn_layers: collections.Counter = collections.Counter()
 
 
 def attn_arm_since(before) -> str:
@@ -55,6 +60,19 @@ def attn_arm_since(before) -> str:
         if paged_attn_arms[arm] > before[arm]:
             return arm
     return "gather"
+
+
+def attn_layers_since(before) -> dict:
+    """``{window: {"Hq", "Hkv", "arm", "kernel"}}`` of the paged layer
+    kinds a program traced since ``before`` (a copy of
+    ``paged_attn_layers``); window None is the full layers'."""
+    out = {}
+    for key, n in paged_attn_layers.items():
+        if n > before[key]:
+            window, hq, hkv, arm, kernel = key
+            out[window] = {"Hq": hq, "Hkv": hkv, "arm": arm,
+                           "kernel": kernel}
+    return out
 
 
 def _quant_chunk(x):
@@ -84,7 +102,8 @@ def block_mask(T: int, block: int):
     return at[None, :] <= at[:, None]
 
 
-def cached_attention(q, k, v, cache, index, layer=0, window=None, block=1):
+def cached_attention(q, k, v, cache, index, layer=0, window=None, block=1,
+                     kernel_name=None):
     """Static-KV-cache attention core shared by every attention family
     (llama GQA, GPT fused-MHA, MoE). ``cache`` holds the FULL stacked
     read-only buffers ([L, B, Hkv, S, D] — see ``init_kv_cache``) and
@@ -162,6 +181,12 @@ def cached_attention(q, k, v, cache, index, layer=0, window=None, block=1):
     ``ptpu_paged_block_attn``: each slot's live pages read once a layer
     for all ``block`` query rows (``"paged_block_kernel"``).
 
+    ``kernel_name`` (None: ``ptpu_paged_decode_attn``): the one-token
+    paged kernel's name in the device trace, for a model that reads a
+    layer kind apart (Laguna's window layers:
+    ``ptpu_paged_decode_attn_window``). Each paged layer's arm is also
+    counted by kind in ``paged_attn_layers``.
+
     Returns ``(out [B, T, Hq, D], payload)`` where payload leaves are the
     chunk k/v in buffer layout ([B, Hkv, T, D], scales [B, Hkv, T]).
     """
@@ -205,15 +230,21 @@ def cached_attention(q, k, v, cache, index, layer=0, window=None, block=1):
                 q, kt, vt, bufs, cache.table[None], layer, idx, scale=scale)
             return out, payload
         if block == 1 and _pk.supported(q, bufs, cache.table[None]):
-            paged_attn_arms["paged_copy_kernel" if _pk.copies_pages(bufs)
-                            else "paged_kernel"] += 1
+            arm = ("paged_copy_kernel" if _pk.copies_pages(bufs)
+                   else "paged_kernel")
+            paged_attn_arms[arm] += 1
+            paged_attn_layers[window, Hq, Hkv, arm,
+                              kernel_name or "ptpu_paged_decode_attn"] += 1
             edge = ({} if window is None
                     else {"window": window, "base": cache.base})
+            if kernel_name is not None:
+                edge["name"] = kernel_name
             out = _pk.paged_decode_attention(
                 q, kt, vt, bufs, cache.table[None], layer, idx, scale=scale,
                 **edge)
             return out, payload
         paged_attn_arms["gather"] += 1
+        paged_attn_layers[window, Hq, Hkv, "gather", None] += 1
         sl = cache.read_layer(layer)
     else:
         from paddle_tpu.ops.pallas import decode_attention as _dk

@@ -33,6 +33,7 @@ __all__ = [
     "binary_cross_entropy_with_logits", "mse_loss", "l1_loss",
     "smooth_l1_loss", "nll_loss", "kl_div", "label_smooth",
     "scaled_dot_product_attention", "rotary_embedding", "apply_rotary",
+    "apply_partial_rotary",
     "avg_pool2d", "max_pool2d", "adaptive_avg_pool2d", "conv2d", "pad",
     "interpolate", "unfold", "clip", "normalize", "cosine_similarity",
     # extended surface (see sections below)
@@ -614,6 +615,17 @@ def apply_rotary(x, cos, sin):
     rot1 = x1 * cos - x2 * sin
     rot2 = x2 * cos + x1 * sin
     return jnp.concatenate([rot1, rot2], axis=-1).astype(x.dtype)
+
+
+def apply_partial_rotary(x, cos, sin, rotary_dim: int):
+    """:func:`apply_rotary` on the first ``rotary_dim`` dims of each
+    head (cos/sin ``[..., rotary_dim/2]``, pairs ``(i, i + rotary_dim/2)``);
+    the other dims pass through unrotated."""
+    if rotary_dim == x.shape[-1]:
+        return apply_rotary(x, cos, sin)
+    return jnp.concatenate(
+        [apply_rotary(x[..., :rotary_dim], cos, sin), x[..., rotary_dim:]],
+        axis=-1)
 
 
 # ---------------------------------------------------------------------------

@@ -67,7 +67,9 @@ is the combine. On that form the layer may also read its routing
 logits from another tensor than the one its experts multiply
 (``route_from=``: SmallThinker routes from the block's input, ahead of
 the norm and of attention), divide the softmax gates at the picks by
-their sum (``norm_topk``), and gate with relu (``act="relu"``: ReGLU).
+their sum (``norm_topk``), scale them after that (``routed_scale``, on
+the softmax route as on the sigmoid one: Laguna's 2.5), and gate with
+relu (``act="relu"``: ReGLU).
 """
 
 from __future__ import annotations
@@ -422,6 +424,8 @@ class MoEMLP(Module):
                 expert, gate = softmax_picks(logits, self.top_k)
                 if getattr(self, "norm_topk", False):
                     gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+                if self.routed_scale != 1.0:
+                    gate = gate * self.routed_scale
             # one_hot of an id outside [0, count) is a zero row
             here = jax.nn.one_hot(expert - first, count, dtype=gate.dtype)
             gates = jnp.einsum("nk,nkc->nc", gate, here)       # [N, held]

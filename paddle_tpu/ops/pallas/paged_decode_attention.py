@@ -392,7 +392,8 @@ def _pages_per_step(M: int, page_bytes: int) -> int:
     return max(1, min(8, M, (1 << 20) // page_bytes))
 
 
-def raw_call(sp, q2, kn2, vn2, *pool, scale: float, windowed: bool = False):
+def raw_call(sp, q2, kn2, vn2, *pool, scale: float, windowed: bool = False,
+             name: str = "ptpu_paged_decode_attn"):
     """The pallas_call on local shapes: sp int32 [B, 2 + M] rows of
     ``[layer, index, table...]``; q2 [B, Hq, D]; kn2/vn2 [B, Hkv, D];
     ``pool`` the paged leaves. Returns [B, Hq, D]. Grid ``(B, 1 +
@@ -402,7 +403,8 @@ def raw_call(sp, q2, kn2, vn2, *pool, scale: float, windowed: bool = False):
     arrive by K block DMAs of the ordinary pipeline. ``windowed``: the
     rows are ``[layer, index, lo, table...]`` and positions before
     ``lo`` (the first a window layer's query still sees, in the row's
-    own coordinates) are masked, the pages wholly before it skipped."""
+    own coordinates) are masked, the pages wholly before it skipped.
+    ``name`` is the kernel's name in the device trace."""
     B, Hq, D = q2.shape
     Hkv = kn2.shape[1]
     G = Hq // Hkv
@@ -460,7 +462,7 @@ def raw_call(sp, q2, kn2, vn2, *pool, scale: float, windowed: bool = False):
         compiler_params=_support.compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_support.interpret(),
-        name="ptpu_paged_decode_attn",
+        name=name,
     )(sp, *args)
 
 
@@ -633,14 +635,15 @@ def _copy_kernel(sp_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
 
 
 def raw_copy_call(sp, q2, kn2, vn2, kp, vp, *, scale: float,
-                  windowed: bool = False, T: int = 1):
+                  windowed: bool = False, T: int = 1, name: str | None = None):
     """The copy form's pallas_call on local shapes: operands as
     :func:`raw_call`'s, the two float leaves unblocked in HBM and only
     read. Grid ``(B, 1 + ceil(M / KP))``: step 0 the fresh token, then
     one live block of KP pages a step, double-buffered across steps and
     rows by the kernel's own copies. ``T`` > 1 is the block form
     (``ptpu_paged_block_attn``): q2 [B, Hq·T, D] in the order (head, t),
-    kn2 / vn2 [B, Hkv·T, D] in the order (KV head, u)."""
+    kn2 / vn2 [B, Hkv·T, D] in the order (KV head, u). ``name`` (None:
+    the form's own) is the kernel's name in the device trace."""
     B, Hq, D = q2.shape
     Hkv, P = kp.shape[2:4]
     M = sp.shape[1] - (3 if windowed else 2)
@@ -679,7 +682,8 @@ def raw_copy_call(sp, q2, kn2, vn2, kp, vp, *, scale: float,
         compiler_params=_support.compiler_params(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_support.interpret(),
-        name="ptpu_paged_block_attn" if T > 1 else "ptpu_paged_decode_attn",
+        name=name or ("ptpu_paged_block_attn" if T > 1
+                      else "ptpu_paged_decode_attn"),
     )(sp, q2, kn2, vn2, kp, vp)
 
 
@@ -987,7 +991,7 @@ def _over_rows(raw, scale: float):
 
 def paged_decode_attention(q, k_new, v_new, pool, table, layer, index, *,
                            scale: float, window: int | None = None,
-                           base=None):
+                           base=None, name: str | None = None):
     """q [B, 1, Hq, D]; k_new/v_new [B, Hkv, 1, D] (this step's raw
     k/v, not yet in the pool); ``pool`` the paged leaves; ``table``
     [B, M] int32 per-slot page rows (the engine's device-resident
@@ -1004,7 +1008,12 @@ def paged_decode_attention(q, k_new, v_new, pool, table, layer, index, *,
     logical pages, entry 0 being logical page ``base`` (scalar or [B];
     None = 0): the kernel works in the row's coordinates, reads its live
     pages from the first still seen, and masks what has slid out inside
-    that page."""
+    that page.
+
+    ``name`` (None: ``ptpu_paged_decode_attn``) is the kernel's name in
+    the device trace: a model whose window layers are to be read apart
+    names them ``ptpu_paged_decode_attn_window``, which a reader that
+    matches the default name still finds."""
     if not supported(q, pool, table):
         return paged_reference(q, k_new, v_new, pool, table, layer,
                                index, scale=scale, window=window, base=base)
@@ -1019,6 +1028,8 @@ def paged_decode_attention(q, k_new, v_new, pool, table, layer, index, *,
         idx, lo = _row_edges(index, window, base, pool[0].shape[3], B)
         table = jnp.concatenate([lo[:, None], table], axis=1)
         raw = functools.partial(raw, windowed=True)
+    if name is not None:
+        raw = functools.partial(raw, name=name)
     lay = jnp.broadcast_to(jnp.asarray(layer, jnp.int32), (B,))
     out = _over_rows(raw, scale)(
         lay, idx, table, q.reshape(B, Hq, D),

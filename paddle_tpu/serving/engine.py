@@ -1420,6 +1420,8 @@ class GenerationEngine:
         # "paged_copy_kernel", "paged_kernel" or "gather": decided where
         # the step is traced, None until then
         self._decode_attn: str | None = None
+        # the same a layer kind, ``{window: {Hq, Hkv, arm, kernel}}``
+        self._attn_layers: dict = {}
         if self._paged:
             self._step = self._build_paged_step()
             self._prefill_fn = self._build_paged_prefill()
@@ -1808,7 +1810,10 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        from paddle_tpu.models._common import attn_arm_since, paged_attn_arms
+        from paddle_tpu.models._common import (attn_arm_since,
+                                               attn_layers_since,
+                                               paged_attn_arms,
+                                               paged_attn_layers)
         from paddle_tpu.models.generation import PagedCache, paged_write
 
         P, maxp = self._page_tokens, self._maxp
@@ -1831,11 +1836,13 @@ class GenerationEngine:
         def step(model, state, pt, active):
             pool = state["cache"]
             arms = paged_attn_arms.copy()
+            kinds = paged_attn_layers.copy()
             logits, keys, subs, new, cnt = jax.vmap(
                 functools.partial(one, model),
                 in_axes=(0, 0, 0, 0, None))(
                 pt, state["tok"], state["pos"], state["keys"], pool)
             self._decode_attn = attn_arm_since(arms)
+            self._attn_layers = attn_layers_since(kinds)
             if grouped:
                 pool = self._group_step_writes(pool, pt, state["pos"],
                                                active, new)
@@ -2823,6 +2830,14 @@ class GenerationEngine:
                      "pages_slid": win.slid}]
                 doc["pages"] += win.pool.num_pages
                 doc["pages_free"] += win.pool.free_count
+                # how the step attends a group: query and KV heads of its
+                # layers, the arm and the kernel's trace name (the name
+                # alone before the step is traced)
+                doc["attention"] = [
+                    dict({"name": name},
+                         **self._attn_layers.get(window, {}))
+                    for name, window in (("full", None),
+                                         ("window", win.window))]
             if self._snaps is not None:
                 # the paged group as a layer-group engine names it, and
                 # the slot-indexed state group with its snapshot pool

@@ -454,6 +454,43 @@ def test_copy_form_on_pages_of_sixteen_kv_heads(case, G, form):
                                rtol=2e-5, atol=2e-5)
 
 
+# Laguna's pages — 8 KV heads x 16 tokens x 128, one lane tile of rows —
+# under 6 and 9 query heads a KV head (its full and its window layers),
+# in float32 (16 pages a block, more than the row): two slots at their
+# own fills, off a page edge and past the row; the window rows start at
+# their own logical page
+EIGHT_HEADS = {"G6-full": (6, None, [0, 0]), "G9-window": (9, 40, [2, 1])}
+
+
+@pytest.mark.parametrize("form", ["wide"], indirect=True)
+@pytest.mark.parametrize("case", list(EIGHT_HEADS))
+def test_copy_form_on_pages_of_eight_kv_heads(case, form):
+    G, window, base = EIGHT_HEADS[case]
+    Hkv, P, D, N, M = 8, 16, 128, 24, 6
+    rs = np.random.RandomState(60 + G)
+    q = jnp.asarray(rs.randn(2, 1, G * Hkv, D), jnp.float32)
+    kn, vn = (jnp.asarray(rs.randn(2, Hkv, 1, D), jnp.float32)
+              for _ in range(2))
+    pool = tuple(jnp.asarray(rs.randn(N + 1, 2, Hkv, P, D), jnp.float32)
+                 for _ in range(2))
+    ids = rs.permutation(np.arange(1, N + 1))[:2 * M]
+    table = jnp.asarray(ids.reshape(2, M).astype(np.int32))
+    assert pdk.copies_pages(pool) and Hkv * P == pdk.LANES
+    base = jnp.asarray(base, jnp.int32)
+    idx = base * P + jnp.asarray([5 * P + 3, M * P + 9], jnp.int32)
+    edge = {} if window is None else {"window": window, "base": base}
+    want = pdk.paged_reference(q, kn, vn, pool, table, 1, idx, scale=0.088,
+                               **edge)
+    with _support.force_dispatch():
+        assert pdk.supported(q, pool, table)
+        got = pdk.paged_decode_attention(q, kn, vn, pool, table,
+                                         jnp.int32(1), idx, scale=0.088,
+                                         **edge)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
 def walk_eqns(jaxpr, path=()):
     """``(eqn, names of the primitives enclosing it)`` for every
     equation of a jaxpr, sub-jaxprs included."""
